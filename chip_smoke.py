@@ -1,0 +1,485 @@
+"""Chip smoke test: the ResNet-50 training path, end to end, on the TPU.
+
+    python3 chip_smoke.py
+
+One process.  Drives `mx.mod.Module` over `mxnet_tpu.models` ResNet-50
+at full width (batch 256, 3x224x224, bfloat16, random weights from a
+seed) through the entry points a user calls — `Module.fit` and
+`Module.bulk_step` — then compiles and runs the Pallas flash-attention
+kernels at four lengths, then, on a host with four chips, runs the same
+network data-parallel over them.  It fails (non-zero exit, no result
+line) when JAX finds no TPU, when any phase raises, and when run
+without the rest of the checkout.  It sets no JAX_PLATFORMS and no
+compile-cache directory: both are placed from outside.
+
+The timings it prints are smoke timings, not a benchmark.  The last
+line of stdout is one JSON object with exactly these keys,
+{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}};
+phases run, compile seconds and cache hits are on the `summary:` line
+before it.
+"""
+import gc
+import importlib.metadata
+import json
+import os
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import jaxlib
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+SEED = 20260926
+# dispatches of a new program before the steady state: the first
+# compiles it, and the second compiles it once more because the donated
+# outputs it is fed are committed to their device where the freshly
+# initialised weights were not (jax keys executables on that)
+WARM = 2
+
+
+def log(msg):
+    print(msg, flush=True)
+
+
+class CompileLog:
+    """What jax compiled, as jax itself reports it (jax.monitoring):
+    every backend compile request with its seconds, and how many the
+    persistent cache answered."""
+
+    def __init__(self):
+        self.requests = 0
+        self.seconds = 0.0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+
+    def _on_event(self, event, **_):
+        if event == '/jax/compilation_cache/cache_hits':
+            self.cache_hits += 1
+        elif event == '/jax/compilation_cache/cache_misses':
+            self.cache_misses += 1
+
+    def _on_duration(self, event, duration, **_):
+        if event == '/jax/core/compile/backend_compile_duration':
+            self.requests += 1
+            self.seconds += duration
+
+
+class ArgSpy:
+    """Stands in for a compiled step (exec_cache.TimedJit) and keeps the
+    abstract arguments of its last call, so that the very program the
+    module dispatched can be lowered again for its compiled text."""
+
+    def __init__(self, step):
+        self.step = step
+        self.avals = None
+
+    def __call__(self, *args):
+        self.avals = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding)
+            if isinstance(x, jax.Array) else x, args)
+        return self.step(*args)
+
+    def compiled_text(self):
+        return self.step.lower(*self.avals).compile().as_text()
+
+
+# ---------------------------------------------------------------------------
+# Phase A — train, full width, one chip
+# ---------------------------------------------------------------------------
+
+def make_module(ctxs, num_layers, num_classes, image):
+    import mxnet_tpu as mx
+    from mxnet_tpu import models
+    sym = models.get_symbol(
+        'resnet', num_layers=num_layers, num_classes=num_classes,
+        image_shape=','.join(str(d) for d in image), dtype='bfloat16')
+    return mx.mod.Module(sym, context=ctxs if len(ctxs) > 1 else ctxs[0])
+
+
+# the optimizer bench.py and examples/image_classification/common/fit.py
+# train this model with
+OPTIMIZER_PARAMS = {'learning_rate': 0.1, 'momentum': 0.9, 'wd': 1e-4,
+                    'multi_precision': True}
+
+
+def synthetic(rng, n, image, num_classes):
+    x = rng.random((n,) + tuple(image), dtype=np.float32)
+    y = rng.integers(0, num_classes, n).astype(np.float32)
+    return x, y
+
+
+def assert_placed(mod, devices):
+    """Every parameter, gradient, aux state, optimizer state and input
+    buffer of the bound module lives on exactly `devices`."""
+    want = set(devices)
+    ex = mod._exec_group.executor
+    fu = mod._fused_updater
+    groups = {'arg': ex.arg_dict, 'grad': ex.grad_dict, 'aux': ex.aux_dict,
+              'momentum': fu.states, 'master': fu.masters}
+    n = 0
+    for kind, group in groups.items():
+        for name, arr in group.items():
+            for leaf in jax.tree_util.tree_leaves(
+                    getattr(arr, '_data', arr)):
+                got = leaf.devices()
+                assert got == want, \
+                    '%s %s lives on %s, wanted %s' % (kind, name, got, want)
+                n += 1
+    return n
+
+
+def block(mod):
+    """Wait for the device: every weight the last dispatch wrote."""
+    ex = mod._exec_group.executor
+    jax.block_until_ready([ex.arg_dict[n]._data for n in ex._diff_names])
+
+
+def phase_a(clog, ctx, batch=256, image=(3, 224, 224), num_layers=50,
+            num_classes=1000, fit_batches=6, bulk=16):
+    import mxnet_tpu as mx
+    from mxnet_tpu import profiler
+    dev = ctx.jax_device()
+    rng = np.random.default_rng(SEED)
+    mx.random.seed(SEED)
+    mod = make_module([ctx], num_layers, num_classes, image)
+
+    # (a) Module.fit, one short epoch from NDArrayIter — the entry
+    # examples/image_classification/train_imagenet.py uses
+    x, y = synthetic(rng, fit_batches * batch, image, num_classes)
+    train = mx.io.NDArrayIter(x, y, batch_size=batch,
+                              label_name='softmax_label')
+    metric = mx.metric.create(['acc', 'ce'])
+    marks = []          # (seconds, exec-cache misses, jax compile requests)
+    w_first = []        # fc1_weight rows after the first step
+
+    def on_batch(param):
+        block(mod)
+        marks.append((time.perf_counter(),
+                      profiler.exec_cache_stats()['exec_cache_misses'],
+                      clog.requests))
+        ce = dict(param.eval_metric.get_name_value())['cross-entropy']
+        assert np.isfinite(ce), 'fit batch %d: loss %r' % (param.nbatch, ce)
+        if not w_first:
+            w = mod._exec_group.executor.arg_dict['fc1_weight']
+            w_first.append(np.asarray(w._data[:2], np.float32))
+
+    t0 = time.perf_counter()
+    mod.fit(train, eval_metric=metric, num_epoch=1, optimizer='sgd',
+            optimizer_params=dict(OPTIMIZER_PARAMS),
+            initializer=mx.init.Xavier(rnd_type='gaussian',
+                                       factor_type='in', magnitude=2),
+            batch_end_callback=on_batch)
+    block(mod)
+    assert len(marks) == fit_batches
+    fit_warm_s = [marks[0][0] - t0, marks[1][0] - marks[0][0]]
+    steady = [(b[0] - a[0]) * 1e3 for a, b in zip(marks[WARM - 1:],
+                                                   marks[WARM:])]
+    assert marks[-1][1] == marks[WARM - 1][1], \
+        'fit: exec-cache misses after warm-up: %s' % [m[1] for m in marks]
+    assert marks[-1][2] == marks[WARM - 1][2], \
+        'fit: jax compiles after warm-up: %s' % [m[2] for m in marks]
+    ex = mod._exec_group.executor
+    w_fit = np.asarray(ex.arg_dict['fc1_weight']._data[:2], np.float32)
+    assert np.isfinite(w_fit).all()
+    assert not np.array_equal(w_fit, w_first[0]), \
+        'fit: fc1_weight did not change'
+    out = mod.get_outputs()[0]
+    assert out.shape == (batch, num_classes), out.shape
+    assert np.isfinite(np.asarray(out._data, np.float32)).all()
+    n_fit = assert_placed(mod, [dev])
+    ce = dict(metric.get_name_value())['cross-entropy']
+    log('phase A fit: %d batches of %d, cross-entropy %.4f, warm-up steps '
+        '%.1f s + %.1f s (compiles included), steady steps %s ms, '
+        '%d buffers on %s'
+        % (fit_batches, batch, ce, fit_warm_s[0], fit_warm_s[1],
+           ' '.join('%.1f' % ms for ms in steady), n_fit, dev))
+    del train, x, y
+
+    # (b) bulk_step dispatches of K pre-staged batches — bench.py's
+    # headline configuration
+    batches = []
+    for _ in range(bulk):
+        bx, by = synthetic(rng, batch, image, num_classes)
+        batches.append(mx.io.DataBatch(
+            data=[mx.nd.array(bx, ctx=ctx)],
+            label=[mx.nd.array(by, ctx=ctx)]))
+    for b in batches:
+        assert b.data[0]._data.devices() == {dev}
+    bulk_ms = []
+    for i in range(WARM + 1):
+        before = (profiler.exec_cache_stats()['exec_cache_misses'],
+                  clog.requests)
+        t0 = time.perf_counter()
+        mod.bulk_step(batches=batches, scan_dtype='bfloat16')
+        block(mod)
+        bulk_ms.append((time.perf_counter() - t0) * 1e3)
+        after = (profiler.exec_cache_stats()['exec_cache_misses'],
+                 clog.requests)
+        if i >= WARM:
+            assert after == before, \
+                'bulk_step: compiled in steady state: %s -> %s' \
+                % (before, after)
+    w_bulk = np.asarray(ex.arg_dict['fc1_weight']._data[:2], np.float32)
+    assert np.isfinite(w_bulk).all()
+    assert not np.array_equal(w_bulk, w_fit), \
+        'bulk_step: fc1_weight did not change'
+    out = mod.get_outputs()[0]
+    assert np.isfinite(np.asarray(out._data, np.float32)).all()
+    n_bulk = assert_placed(mod, [dev])
+    log('phase A bulk_step: K=%d, warm-up dispatches %.1f s + %.1f s '
+        '(compiles included), steady dispatch %.1f ms = %.2f ms a step, '
+        '%d buffers on %s'
+        % (bulk, bulk_ms[0] / 1e3, bulk_ms[1] / 1e3, bulk_ms[-1],
+           bulk_ms[-1] / bulk, n_bulk, dev))
+    return {'fit_steady_step_ms': [round(ms, 2) for ms in steady],
+            'bulk_steady_step_ms': round(bulk_ms[-1] / bulk, 2)}
+
+
+# ---------------------------------------------------------------------------
+# Phase B — the Pallas flash-attention kernels compile
+# ---------------------------------------------------------------------------
+
+def phase_b(lengths=(2048, 12288, 16384, 32768), parity_at=2048, bh=8,
+            d=128, expect_custom_call=True):
+    from mxnet_tpu import pallas_ops
+
+    def fwd(q, k, v):
+        return pallas_ops.flash_attention(q, k, v, causal=True)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    def dense_loss(q, k, v):
+        return pallas_ops._dense_attention_lse(
+            q, k, v, True, 1.0 / d ** 0.5)[0].astype(jnp.float32).sum()
+
+    def timed(fn, n_calls, *args):
+        lowered = jax.jit(fn).lower(*args)
+        calls = lowered.as_text().count('tpu_custom_call')
+        if expect_custom_call:
+            assert calls >= n_calls, \
+                'lowered text has %d tpu_custom_call, wanted %d: the ' \
+                'kernel did not take the Mosaic path' % (calls, n_calls)
+        t0 = time.perf_counter()
+        compiled = lowered.compile()
+        compile_s = time.perf_counter() - t0
+        jax.block_until_ready(compiled(*args))
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(compiled(*args))
+        return out, compile_s, (time.perf_counter() - t0) * 1e3
+
+    result = {}
+    for t in lengths:
+        keys = jax.random.split(jax.random.PRNGKey(SEED + t), 3)
+        q, k, v = (jax.random.normal(kk, (1, bh, t, d), jnp.bfloat16)
+                   for kk in keys)
+        out, fwd_s, fwd_ms = timed(fwd, 1, q, k, v)
+        grads, bwd_s, bwd_ms = timed(jax.grad(loss, (0, 1, 2)), 3, q, k, v)
+        assert out.shape == q.shape
+        for name, a in zip(('out', 'dq', 'dk', 'dv'), (out,) + grads):
+            assert bool(jnp.isfinite(a.astype(jnp.float32)).all()), \
+                'flash T=%d: %s not finite' % (t, name)
+        line = ('phase B flash T=%d: forward compile %.1f s run %.2f ms, '
+                'forward+backward compile %.1f s run %.2f ms'
+                % (t, fwd_s, fwd_ms, bwd_s, bwd_ms))
+        if t == parity_at:
+            ref = pallas_ops._dense_attention_lse(
+                q, k, v, True, 1.0 / d ** 0.5)[0]
+            ref_grads = jax.grad(dense_loss, (0, 1, 2))(q, k, v)
+            worst = 0.0
+            for name, a, b in zip(('out', 'dq', 'dk', 'dv'),
+                                  (out,) + grads, (ref,) + ref_grads):
+                a = np.asarray(a, np.float32)
+                b = np.asarray(b, np.float32)
+                # bf16 carries 8 bits: compare against the tensor's scale
+                err = np.abs(a - b).max() / max(np.abs(b).max(), 1e-6)
+                assert err < 2e-2, \
+                    'flash T=%d: %s differs from dense by %.3g of its ' \
+                    'scale' % (t, name, err)
+                worst = max(worst, err)
+            line += ', parity with dense %.2g of scale' % worst
+        log(line)
+        result['T=%d' % t] = {'forward_ms': round(fwd_ms, 2),
+                              'forward_backward_ms': round(bwd_ms, 2)}
+    return result
+
+
+# ---------------------------------------------------------------------------
+# Phase C — data parallel over four chips
+# ---------------------------------------------------------------------------
+
+def phase_c(ctxs, batch=1024, image=(3, 224, 224), num_layers=50,
+            num_classes=1000):
+    import mxnet_tpu as mx
+    n = len(ctxs)
+    devices = [c.jax_device() for c in ctxs]
+    assert len(set(devices)) == n
+    gc.collect()
+    base = [(d.memory_stats() or {}).get('bytes_in_use') for d in devices]
+    rng = np.random.default_rng(SEED + 1)
+    x, y = synthetic(rng, batch, image, num_classes)
+    data_batch = mx.io.DataBatch(data=[mx.nd.array(x)],
+                                 label=[mx.nd.array(y)])
+    shapes = dict(
+        data_shapes=[mx.io.DataDesc('data', (batch,) + tuple(image))],
+        label_shapes=[mx.io.DataDesc('softmax_label', (batch,))])
+    init = mx.init.Xavier(rnd_type='gaussian', factor_type='in',
+                          magnitude=2)
+
+    def cross_entropy(mod):
+        p = np.asarray(mod.get_outputs()[0]._data, np.float32)
+        assert p.shape == (batch, num_classes) and np.isfinite(p).all()
+        return float(-np.log(np.maximum(
+            p[np.arange(batch), y.astype(np.int64)], 1e-30)).mean())
+
+    mx.random.seed(SEED)
+    mod = make_module(ctxs, num_layers, num_classes, image)
+    mod.bind(**shapes)
+    mod.init_params(initializer=init)
+    mod.init_optimizer(optimizer='sgd',
+                       optimizer_params=dict(OPTIMIZER_PARAMS))
+    arg0, aux0 = mod.get_params()
+    arg0 = {k: v.copy() for k, v in arg0.items()}
+    aux0 = {k: v.copy() for k, v in aux0.items()}
+
+    eg = mod._exec_group
+    mesh_devs = list(eg.mesh.devices.flat)
+    assert len(set(mesh_devs)) == n and set(mesh_devs) == set(devices), \
+        'mesh holds %s' % mesh_devs
+    step_s = []
+    for i in range(WARM + 1):
+        if i == WARM:
+            spy = ArgSpy(mod._fused_step)
+            mod._fused_step = spy
+        t0 = time.perf_counter()
+        mod.forward_backward(data_batch)
+        mod.update()
+        block(mod)
+        step_s.append(time.perf_counter() - t0)
+        if i == 0:
+            loss_n = cross_entropy(mod)
+
+    ex = eg.executor
+    shards = ex.arg_dict['data']._data.addressable_shards
+    per = (batch // n,) + tuple(image)
+    assert len(shards) == n and \
+        {s.device for s in shards} == set(devices) and \
+        all(s.data.shape == per for s in shards), \
+        'data shards: %s' % [(s.device, s.data.shape) for s in shards]
+    for name in ex._diff_names:
+        a = ex.arg_dict[name]._data
+        assert a.sharding.is_fully_replicated and \
+            a.devices() == set(devices), \
+            '%s is not replicated over the mesh: %s' % (name, a.sharding)
+    n_bufs = assert_placed(mod, devices)
+    text = spy.compiled_text()
+    assert 'all-reduce' in text, 'compiled step has no all-reduce'
+    del spy
+    gc.collect()
+    # nothing piled on the first chip: what this phase added to each
+    # (the programs phases A and B loaded onto chip 0 are in `base`;
+    # the CPU backend, where this is dry-run at a tiny size, keeps no
+    # memory statistics)
+    grown = None
+    if base[0] is not None:
+        grown = [d.memory_stats()['bytes_in_use'] - b
+                 for d, b in zip(devices, base)]
+        assert (max(grown) - min(grown)) <= 0.1 * max(grown), \
+            'device memory grew unevenly: %s' % grown
+
+    # the same first step on one chip: forward in training mode from
+    # the same weights over the same global batch
+    del mod, eg, ex
+    gc.collect()
+    one = make_module(ctxs[:1], num_layers, num_classes, image)
+    one.bind(**shapes)
+    one.init_params(initializer=init, arg_params=arg0, aux_params=aux0)
+    one.forward(data_batch, is_train=True)
+    loss_1 = cross_entropy(one)
+    assert abs(loss_n - loss_1) <= 2e-2 * abs(loss_1), \
+        'first-step loss: %d chips %.5f, one chip %.5f' \
+        % (n, loss_n, loss_1)
+    log('phase C: %d chips, global batch %d, data shards of %s, %d '
+        'buffers on the mesh, all-reduce in the compiled step, bytes '
+        'added a chip %s, warm-up steps %.1f s + %.1f s (compiles included), '
+        'steady step %.1f ms, first-step loss %.5f against %.5f on one '
+        'chip' % (n, batch, per, n_bufs, grown, step_s[0], step_s[1],
+                  step_s[-1] * 1e3, loss_n, loss_1))
+    return {'steady_step_ms': round(step_s[-1] * 1e3, 2)}
+
+
+# ---------------------------------------------------------------------------
+
+def result_line(devices):
+    """The last line of stdout: these keys and no others (the driver's
+    contract), the device as jax reports it."""
+    return json.dumps({'ok': True, 'device': {
+        'platform': devices[0].platform, 'kind': devices[0].device_kind,
+        'count': len(devices)}})
+
+
+def main():
+    platforms = os.environ.get('JAX_PLATFORMS')
+    devices = jax.devices()
+    d0 = devices[0]
+    log('jax %s  jaxlib %s  libtpu %s  python %s'
+        % (jax.__version__, jaxlib.__version__,
+           importlib.metadata.version('libtpu'), sys.version.split()[0]))
+    log('default backend %s  devices[0] platform=%s device_kind=%r  '
+        'count=%d  JAX_PLATFORMS=%r'
+        % (jax.default_backend(), d0.platform, d0.device_kind,
+           len(devices), platforms))
+    if d0.platform != 'tpu':
+        sys.exit('chip_smoke: no TPU: jax.devices() holds %s under '
+                 'JAX_PLATFORMS=%r' % ([str(d) for d in devices],
+                                       platforms))
+    clog = CompileLog()
+
+    # the native runtime is built from what git commits, never loaded
+    # stale (make is a no-op when libmxtpu.so is current)
+    make = subprocess.run(['make', '-s', '-C', os.path.join(HERE, 'src')],
+                          capture_output=True, text=True)
+    if make.returncode:
+        sys.exit('native: build failed\n%s%s' % (make.stdout, make.stderr))
+    log('native: built')
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import _core, exec_cache
+    assert os.path.dirname(os.path.dirname(
+        os.path.abspath(mx.__file__))) == HERE, mx.__file__
+    cache_dir = exec_cache.setup_persistent_cache()
+    log('compile cache: %s (JAX_COMPILATION_CACHE_DIR=%r)'
+        % (cache_dir, os.environ.get('JAX_COMPILATION_CACHE_DIR')))
+    assert _core.available(), 'native runtime did not load'
+    log('native: loaded %s' % _core._LIB_PATH)
+
+    t_all = time.perf_counter()
+    log('--- smoke timings below, not a benchmark ---')
+    result = {'A': phase_a(clog, mx.tpu(0))}
+    gc.collect()
+    result['B'] = phase_b()
+    if len(devices) >= 4:
+        result['C'] = phase_c([mx.tpu(i) for i in range(4)])
+    else:
+        log('phase C not run: %d chip(s)' % len(devices))
+    log('compiles: %d requests, %.1f s in the backend, persistent cache '
+        '%d hits / %d misses; %.1f s in all'
+        % (clog.requests, clog.seconds, clog.cache_hits,
+           clog.cache_misses, time.perf_counter() - t_all))
+    log('summary: ' + json.dumps({
+        'phases': list(result), 'compile_requests': clog.requests,
+        'compile_s': round(clog.seconds, 1),
+        'cache_hits': clog.cache_hits, 'cache_misses': clog.cache_misses,
+        'cache_dir': cache_dir, 'smoke_timing_ms': result}))
+    print(result_line(devices), flush=True)
+
+
+if __name__ == '__main__':
+    main()

@@ -31,9 +31,7 @@ def get_symbol(name, dtype):
 
 
 def run_one(name, batch, steps, bulk, dtype, image_shape):
-    import jax
-    ctx = mx.tpu() if any(d.platform != 'cpu' for d in jax.devices()) \
-        else mx.cpu()
+    ctx = mx.tpu()
     net = get_symbol(name, dtype)
     mod = mx.mod.Module(net, context=ctx)
     mod.bind(data_shapes=[mx.io.DataDesc('data', (batch,) + image_shape)],
@@ -77,6 +75,9 @@ def main():
     ap.add_argument('--image-shape', default='3,224,224')
     args = ap.parse_args()
     shape = tuple(int(x) for x in args.image_shape.split(','))
+    # no TPU: MXNetError here, not an 'error' row per configuration
+    device = mx.tpu().jax_device()
+    print('device: %s (%s)' % (device, device.device_kind))
     rows = []
     for net in args.networks.split(','):
         for bs in (int(b) for b in args.batch_sizes.split(',')):

@@ -40,12 +40,16 @@ def add_fit_args(parser):
 
 
 def _contexts(args):
+    """--tpus names chips, and a missing one is an error; without it the
+    first chip when there is one, else the CPU.  Either way the choice
+    is logged."""
     if args.tpus:
-        return [mx.tpu(int(i)) for i in args.tpus.split(',')]
-    import jax
-    if any(d.platform not in ('cpu',) for d in jax.devices()):
-        return [mx.tpu(0)]
-    return [mx.cpu(0)]
+        ctxs = [mx.tpu(int(i)) for i in args.tpus.split(',')]
+    else:
+        ctxs = [mx.tpu(0)] if mx.num_gpus() else [mx.cpu(0)]
+    logging.info('training on %s: %s', ctxs,
+                 [str(c.jax_device()) for c in ctxs])
+    return ctxs
 
 
 def _lr_scheduler(args, epoch_size, kv):

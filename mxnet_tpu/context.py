@@ -19,8 +19,8 @@ class Context:
     ----------
     device_type : {'cpu', 'tpu', 'gpu', 'cpu_pinned'}
         'gpu' and 'cpu_pinned' are accepted for reference-script
-        compatibility; 'gpu' resolves to the accelerator backend
-        ('tpu' when present), 'cpu_pinned' to 'cpu'.
+        compatibility; 'gpu' resolves like 'tpu', 'cpu_pinned' like
+        'cpu'.
     device_id : int
     """
     _default_ctx = threading.local()
@@ -66,21 +66,30 @@ class Context:
     def jax_device(self):
         """Resolve to a concrete jax.Device.
 
-        'tpu'/'gpu' pick from the default (accelerator) backend when one
-        exists, else fall back to CPU devices so accelerator-context code
-        runs in CPU test environments (the reference's cpu(0)/cpu(1)
-        multi-device-testing trick, tests/python/unittest/test_multi_device_exec.py).
+        'cpu'/'cpu_pinned' wrap modulo the CPU device count (the
+        reference's cpu(0)/cpu(1) multi-device-testing trick,
+        tests/python/unittest/test_multi_device_exec.py).  'tpu'/'gpu'
+        resolve only to a TPU device with that index: a host without
+        one, or a device_id out of range, raises — an accelerator
+        context never lands on the CPU or on another chip.
         """
         import jax
-        dt = self.device_type
-        if dt in ('cpu', 'cpu_pinned'):
-            try:
-                devs = jax.devices('cpu')
-            except RuntimeError:
-                devs = jax.devices()
-        else:
-            devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+        if self.device_type in ('cpu', 'cpu_pinned'):
+            devs = jax.devices('cpu')
+            return devs[self.device_id % len(devs)]
+        devs = _tpu_devices()
+        if not 0 <= self.device_id < len(devs):
+            from .base import MXNetError
+            raise MXNetError(
+                '%s: no such accelerator; jax.devices() holds %s'
+                % (self, ['%s:%d' % (d.platform, d.id)
+                          for d in jax.devices()]))
+        return devs[self.device_id]
+
+
+def _tpu_devices():
+    import jax
+    return [d for d in jax.devices() if d.platform == 'tpu']
 
 
 def cpu(device_id=0):
@@ -100,13 +109,10 @@ def cpu_pinned(device_id=0):
     return Context('cpu_pinned', device_id)
 
 
-def num_devices():
-    """Number of accelerator devices visible (reference: mx.context.num_gpus)."""
-    import jax
-    return len(jax.devices())
-
-
-num_gpus = num_devices
+def num_gpus():
+    """Number of accelerator (TPU) devices visible (reference:
+    mx.context.num_gpus); 0 on a CPU-only host."""
+    return len(_tpu_devices())
 
 
 def current_context():

@@ -17,18 +17,16 @@ Two layers of reuse:
     fwd_monitor / fwd_bwd, plus fused multistep programs and AOT
     memory-analysis compilations) is shared across executors whose
     signatures match, LRU-bounded by MXNET_TPU_EXEC_CACHE_SIZE.
-  * cross-process: MXNET_TPU_PERSISTENT_CACHE_DIR (opt-in) points
-    JAX's on-disk compilation cache at a directory, so a second
+  * cross-process: JAX's on-disk compilation cache, so a second
     process cold-starts warm — the XLA compile is fetched from disk
-    even though Python re-traces.
+    even though Python re-traces.  Its directory is placed from
+    outside: JAX_COMPILATION_CACHE_DIR when set, else a fixed
+    <checkout>/.jax_cache on a TPU backend, else off (see
+    setup_persistent_cache).
 
 Env knobs (documented in docs/PERF.md):
   MXNET_TPU_EXEC_CACHE=1|0         in-process cache (default on)
   MXNET_TPU_EXEC_CACHE_SIZE=N      LRU entries (default 64)
-  MXNET_TPU_PERSISTENT_CACHE_DIR   on-disk XLA cache dir (default off;
-                                   inert on the CPU backend — see
-                                   setup_persistent_cache)
-  MXNET_TPU_PERSISTENT_CACHE_FORCE=1  enable it on CPU anyway
 
 Counters (exposed via profiler.exec_cache_stats / profiler.summary):
   hits / misses        signature lookups at bind time
@@ -45,7 +43,6 @@ _LOCK = threading.RLock()
 _CACHE = OrderedDict()          # signature-scoped key -> cached object
 _STATS = {'hits': 0, 'misses': 0, 'total_compile_s': 0.0}
 _PERSISTENT_DIR = None          # set once by setup_persistent_cache
-_WARNED_CPU_CACHE = False       # one warning per process (CPU guard)
 
 # Every env knob whose value is baked into the TRACED program must be
 # registered here ((name, default) read at bind time) — a trace-affecting
@@ -79,58 +76,43 @@ def _max_entries():
         return 64
 
 
+def default_cache_dir():
+    """<checkout>/.jax_cache: fixed relative to the package's parent
+    directory, whatever the cwd — the path is part of the cache key, so
+    a directory that moves between runs never hits."""
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        '.jax_cache')
+
+
 def setup_persistent_cache():
-    """Point JAX's on-disk compilation cache at
-    MXNET_TPU_PERSISTENT_CACHE_DIR (idempotent; no-op when unset).
+    """Turn on JAX's on-disk compilation cache and return its directory
+    (None when off).  Idempotent; Executor calls it at every bind, and
+    only the first call does work — it must run before the first
+    compilation, because jax decides whether the cache is in use then.
 
-    Must run before the first compilation: jax memoizes cache-usability
-    per backend on first use, so Executor calls this at every bind —
-    only the first call with the env var set does work.
-
-    CPU-backend guard: XLA:CPU executable (de)serialization is
-    UNRELIABLE on the pinned jax — a warm-started process re-running a
-    cached program that contains gather/scatter (an Embedding
-    gradient, for one) gets silently corrupted buffers (weights at
-    1e12+ after a handful of steps; measured while building the
-    round-12 bucketing bench, cold process exact / warm process
-    garbage on the identical script).  Silent wrong-weights training
-    is disqualifying, so on the CPU backend the on-disk cache stays
-    OFF unless MXNET_TPU_PERSISTENT_CACHE_FORCE=1 explicitly accepts
-    the risk.  Accelerator backends are unaffected."""
-    global _PERSISTENT_DIR, _WARNED_CPU_CACHE
-    target = os.environ.get('MXNET_TPU_PERSISTENT_CACHE_DIR') or None
-    if target is None or target == _PERSISTENT_DIR:
+    The directory is placed from outside.  JAX_COMPILATION_CACHE_DIR
+    set: jax already reads it, so no directory is set in code.  Unset,
+    on a TPU backend: `default_cache_dir()`.  Unset, on the CPU
+    backend: off — XLA:CPU executable deserialization returned
+    corrupted buffers for gather/scatter programs (an Embedding
+    gradient: cold process exact, warm process weights at 1e12+ after a
+    handful of steps on the identical script, docs/PERF.md round 12),
+    and silent wrong-weights training is disqualifying."""
+    global _PERSISTENT_DIR
+    if _PERSISTENT_DIR is not None:
         return _PERSISTENT_DIR
     import jax
-    if jax.default_backend() == 'cpu' and \
-            os.environ.get('MXNET_TPU_PERSISTENT_CACHE_FORCE',
-                           '0') in ('0', ''):
-        if not _WARNED_CPU_CACHE:
-            _WARNED_CPU_CACHE = True
-            import warnings
-            warnings.warn(
-                'MXNET_TPU_PERSISTENT_CACHE_DIR ignored on the CPU '
-                'backend: XLA:CPU deserialized executables can return '
-                'corrupted results (gather/scatter programs).  Set '
-                'MXNET_TPU_PERSISTENT_CACHE_FORCE=1 to override.')
-        return None
-    jax.config.update('jax_compilation_cache_dir', target)
+    target = os.environ.get('JAX_COMPILATION_CACHE_DIR') or None
+    if target is None:
+        if jax.default_backend() != 'tpu':
+            return None
+        target = default_cache_dir()
+        jax.config.update('jax_compilation_cache_dir', target)
     # default thresholds skip small/fast programs; cache everything —
     # the point is cold-start elimination, not disk economy
-    for knob, val in (('jax_persistent_cache_min_compile_time_secs', 0),
-                      ('jax_persistent_cache_min_entry_size_bytes', -1)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:  # older jax without the knob
-            pass
-    # jax memoizes "is the cache used?" at the FIRST compile per task;
-    # environments whose site hooks import jax (and may compile) before
-    # this code runs would silently keep the cache off — drop the memo
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:   # private API moved: stay best-effort
-        pass
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
+    jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
     _PERSISTENT_DIR = target
     return _PERSISTENT_DIR
 

@@ -612,8 +612,7 @@ class Executor:
         (MXNET_EXEC_BULK_EXEC_MAX_NODE_TRAIN, graph_executor.cc:1135):
         where the reference amortizes engine-push overhead by fusing op
         runs into segments, this amortizes the host->device dispatch
-        latency (dominant on tunneled/remote accelerators) over K full
-        steps, keeping the MXU busy back-to-back.
+        latency over K full steps, keeping the MXU busy back-to-back.
 
         scan_names: args fed per-step (data/label).  In stacked mode
         the caller passes them stacked on a leading K axis; with

@@ -23,12 +23,22 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+
+def default_interpret(*operands):
+    """Pallas interpret mode for a call on `operands` when the caller
+    did not choose: False wherever the call will run on a TPU (Mosaic
+    compiles it), True elsewhere (Mosaic targets nothing else).
+    Concrete arrays answer with the devices they live on; tracers
+    (inside jit / shard_map) carry no placement, and jit runs
+    uncommitted work on the default backend, so that answers for
+    them."""
+    for x in operands:
+        if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+            return any(d.platform != 'tpu' for d in x.devices())
+    return jax.default_backend() != 'tpu'
 
 
 def _online_softmax_step(q, kblk, vblk, m, l, acc, scale, causal,
@@ -134,6 +144,16 @@ _VMEM_RESIDENT_BYTES = 6 * 1024 * 1024
 # backward tile edge (see _flash_bwd_impl); 1024 measured best on
 # v5e-class — 2048 OOMs the 16 MB VMEM with double buffering
 _BWD_BLOCK = 1024
+
+
+def _bwd_resident_bytes():
+    """Resident budget of the BACKWARD kernels: two thirds of the
+    forward's.  They hold the same double-buffered sequence pair next
+    to the f32 score temporaries of a _BWD_BLOCK-edge tile, which the
+    forward's smaller tiles do not have: at a 6 MB pair (T=12288,
+    d=128, bf16) Mosaic asks 19.5 MB of the 16 MB scoped VMEM and
+    refuses; at 4 MB (T=8192) it compiles (v5e, libtpu 0.0.34)."""
+    return _VMEM_RESIDENT_BYTES * 2 // 3
 
 
 def _try_fit(t, cap):
@@ -674,7 +694,7 @@ def _flash_bwd_shared(causal, scale, block_q, interpret, res, g,
             interpret)
     fitted_q = _try_fit(tq, max(block_q, _BWD_BLOCK))
     fitted_k = _try_fit(tk, max(block_q, _BWD_BLOCK))
-    if 2 * max(tq, tk) * d * itemsize <= _VMEM_RESIDENT_BYTES:
+    if 2 * max(tq, tk) * d * itemsize <= _bwd_resident_bytes():
         # resident schedule: one head's full sequence (q+dO in the
         # dK/dV kernel, k+v in the dQ kernel) sits in VMEM — BOTH
         # sides must fit, hence max(tq, tk)
@@ -742,12 +762,10 @@ def _validate_attn_shapes(q, k, v, causal, fn):
 
 
 def _needs_dense_fallback(tq, tk, block_q):
-    """No Pallas, or a length no schedule can tile: the check runs
-    _try_fit with exactly the caps the forward AND backward schedules
-    will use (_schedule_caps), so the predicate and the kernels can
-    never disagree."""
-    if not _HAS_PALLAS:
-        return True
+    """A length no schedule can tile — a property of the shape, never
+    of the device: the check runs _try_fit with exactly the caps the
+    forward AND backward schedules will use (_schedule_caps), so the
+    predicate and the kernels can never disagree."""
     return any(_try_fit(t, cap) < 8 and t > 8
                for t, cap in _schedule_caps(tq, tk, block_q))
 
@@ -772,8 +790,8 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     """flash_attention variant that ALSO returns the per-row logsumexp
     (bh, tq, 1) — the merge currency for ring attention / partial
     softmax combination — and is differentiable in BOTH outputs (the
-    lse cotangent folds into the backward's D preprocess).  Falls back
-    to a dense jnp computation when Pallas is unavailable."""
+    lse cotangent folds into the backward's D preprocess).  Lengths
+    no schedule can tile take the dense jnp computation."""
     _validate_attn_shapes(q, k, v, causal, 'flash_attention_with_lse')
     b, h, tq, d = q.shape
     tk = k.shape[2]
@@ -781,12 +799,12 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
         scale = 1.0 / (d ** 0.5)
     if block_q is None:
         block_q = max(256, min(1024, tq // 32))
-    # dense fallback: no Pallas, or a sequence length with no usable
-    # power-of-two block factor (natively differentiable either way)
+    # dense route: a sequence length with no usable power-of-two
+    # block factor (natively differentiable either way)
     if _needs_dense_fallback(tq, tk, block_q):
         return _dense_attention_lse(q, k, v, causal, scale)
     if interpret is None:
-        interpret = jax.devices()[0].platform != 'tpu'
+        interpret = default_interpret(q, k, v)
     return _flash_lse(q, k, v, bool(causal), float(scale), int(block_q),
                       bool(interpret))
 
@@ -819,7 +837,6 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         from .parallel.ring_attention import full_attention
         return full_attention(q, k, v, causal=causal, scale=scale)
     if interpret is None:
-        # Mosaic targets TPU only; interpret everywhere else (cpu, gpu)
-        interpret = jax.devices()[0].platform != 'tpu'
+        interpret = default_interpret(q, k, v)
     return _flash(q, k, v, bool(causal), float(scale), int(block_q),
                   bool(interpret))

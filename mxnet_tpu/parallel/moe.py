@@ -13,7 +13,7 @@ through via the residual connection in the caller.
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

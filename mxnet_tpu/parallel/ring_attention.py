@@ -16,18 +16,6 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
-def _mark_varying(t, axis_name):
-    """Mark an accumulator as varying over the ring axis so scan carry
-    types line up under JAX's manual-axes (vma) checking.  pcast is the
-    jax>=0.9 spelling; pvary its deprecated predecessor; older JAX has
-    neither and needs no marking."""
-    if hasattr(lax, 'pcast'):
-        return lax.pcast(t, (axis_name,), to='varying')
-    if hasattr(lax, 'pvary'):
-        return lax.pvary(t, (axis_name,))
-    return t
-
-
 def _block_attn(q, k, v, scale, q_pos, k_pos, causal, m, l, o):
     """One block's contribution with online-softmax accumulation."""
     s = jnp.einsum('...qd,...kd->...qk', q, k) * scale
@@ -110,7 +98,8 @@ def _ring_attention_flash(q, k, v, axis_name, causal, scale, interpret):
     o0 = jnp.zeros(q.shape, jnp.float32)
     m0 = jnp.full((b, h, t_local, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((b, h, t_local, 1), jnp.float32)
-    o0, m0, l0 = (_mark_varying(t, axis_name) for t in (o0, m0, l0))
+    o0, m0, l0 = (lax.pcast(t, (axis_name,), to='varying')
+                  for t in (o0, m0, l0))
     (_, _, _, o_u, _, l), _ = lax.scan(body, (k, v, idx, o0, m0, l0),
                                        None, length=n)
     return (o_u / jnp.maximum(l, 1e-37)).astype(q.dtype)
@@ -137,7 +126,8 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None,
         scale = 1.0 / (d ** 0.5)
     if use_flash:
         assert q.ndim == 4, 'use_flash needs [B, H, T_local, D] shards'
-        interpret = jax.devices()[0].platform != 'tpu'
+        from .. import pallas_ops
+        interpret = pallas_ops.default_interpret(q, k, v)
         return _ring_attention_flash(q, k, v, axis_name, causal, scale,
                                      interpret)
     q_pos = idx * t_local + jnp.arange(t_local)
@@ -158,7 +148,8 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None,
     o0 = jnp.zeros(q.shape, dtype=jnp.float32)
     # mark accumulators as varying over the ring axis so scan carry
     # types line up under JAX's manual-axes checking
-    m0, l0, o0 = (_mark_varying(t, axis_name) for t in (m0, l0, o0))
+    m0, l0, o0 = (lax.pcast(t, (axis_name,), to='varying')
+                  for t in (m0, l0, o0))
     (k, v, _, m, l, o), _ = lax.scan(
         body, (k, v, idx, m0, l0, o0), None, length=n)
     out = o / jnp.maximum(l, 1e-37)[..., None]
@@ -171,10 +162,9 @@ def ring_self_attention(q, k, v, mesh, seq_axis='sp', causal=False,
     use_flash routes each hop through the Pallas kernel (Pallas calls
     carry no vma metadata, so the flash path disables shard_map's vma
     checking for this call)."""
-    from ._compat import shard_map
     spec = P(None, None, seq_axis, None)
     kwargs = {'check_vma': False} if use_flash else {}
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=seq_axis,
                           causal=causal, scale=scale,
                           use_flash=use_flash),

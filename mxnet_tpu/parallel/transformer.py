@@ -20,7 +20,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .ring_attention import (ring_attention, ring_self_attention,

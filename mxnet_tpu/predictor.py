@@ -216,12 +216,9 @@ class Predictor(object):
             lowered = jax.jit(fwd).lower(data_vals)
         finally:
             jax.config.update('jax_use_shardy_partitioner', prev)
-        # output avals from the lowering we already have — no second
-        # trace; eval_shape remains the fallback for older jax
-        try:
-            outs = [o.aval for o in lowered.out_info]
-        except AttributeError:
-            outs = jax.eval_shape(fwd, data_vals)
+        # output shapes from the lowering we already have — no second
+        # trace
+        outs = lowered.out_info
         manifest = []
         for n, v in zip(self._input_names, data_vals):
             manifest.append('input %s %s %s' % (
@@ -234,25 +231,16 @@ class Predictor(object):
         text = lowered.as_text()   # params baked in: serialize ONCE
         with open(prefix + '.stablehlo', 'w') as f:
             f.write(text)
-        # the .stablehlo + .manifest pair must be complete even when the
-        # optional HloModuleProto emission below fails, so the manifest
-        # is written before the conversion attempt
         with open(prefix + '.manifest', 'w') as f:
             f.write('\n'.join(manifest) + '\n')
         # ALSO emit the HloModuleProto: the C++ runner consumes this
         # form because PjRtClient::CompileAndLoad(XlaComputation) needs
-        # no MLIR parser in the deployment process.  Only the
-        # conversion API's absence is survivable (older jaxlibs keep
-        # the .stablehlo artifact); I/O failures must surface.
-        try:
-            from jax._src.lib import xla_client
-            convert = xla_client._xla.mlir.mlir_module_to_xla_computation
-        except (ImportError, AttributeError):
-            convert = None
-        if convert is not None:
-            comp = convert(text, use_tuple_args=False, return_tuple=False)
-            with open(prefix + '.hlo.pb', 'wb') as f:
-                f.write(comp.as_serialized_hlo_module_proto())
+        # no MLIR parser in the deployment process.
+        from jax._src.lib import xla_client
+        comp = xla_client._xla.mlir.mlir_module_to_xla_computation(
+            text, use_tuple_args=False, return_tuple=False)
+        with open(prefix + '.hlo.pb', 'wb') as f:
+            f.write(comp.as_serialized_hlo_module_proto())
         return manifest
 
 

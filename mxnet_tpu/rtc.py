@@ -11,14 +11,11 @@ onto Pallas grid/BlockSpecs rather than CUDA blocks/threads.
 """
 import numpy as np
 import jax
+from jax.experimental import pallas as pl
 
 from . import ndarray as nd
 from .base import MXNetError
-
-try:
-    from jax.experimental import pallas as pl
-except Exception:  # pragma: no cover
-    pl = None
+from .pallas_ops import default_interpret
 
 
 class Rtc(object):
@@ -45,8 +42,6 @@ class Rtc(object):
     """
 
     def __init__(self, name, inputs, outputs, kernel):
-        if pl is None:
-            raise MXNetError('mx.rtc requires jax.experimental.pallas')
         if isinstance(inputs, dict):
             inputs = list(inputs)
         if isinstance(outputs, dict):
@@ -102,9 +97,7 @@ class Rtc(object):
                 'Rtc.push: block_dims has no Pallas equivalent (blocking '
                 'is expressed via BlockSpecs inside the kernel); ignoring',
                 stacklevel=2)
-        # interpret mode off-TPU so kernels run in tests on CPU
-        interpret = all(d.platform == 'cpu'
-                        for d in ins[0]._data.devices())
+        interpret = default_interpret(ins[0]._data)
         fn = self._get_fn(
             tuple(tuple(x.shape) for x in ins),
             tuple(x.dtype for x in ins),

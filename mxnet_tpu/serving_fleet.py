@@ -993,10 +993,9 @@ class ModelRegistry(object):
 
     def export_artifacts(self, name, batch_buckets=None):
         """The model's `export_compiled` artifacts (one per rung when
-        batch_buckets is given) — with MXNET_TPU_PERSISTENT_CACHE_DIR
-        set (and the backend allowing it; the PR-7 CPU guard applies)
-        the compile also lands in the on-disk XLA cache, so a FRESH
-        process re-warms this model from disk."""
+        batch_buckets is given) — with the on-disk compile cache on
+        (exec_cache.setup_persistent_cache) the compile also lands
+        there, so a FRESH process re-warms this model from disk."""
         ent = self._entry(name)
         self.engine(name)               # ensure resident
         holder = ent.holder

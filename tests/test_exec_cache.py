@@ -7,6 +7,7 @@ train-step sharing across Modules, the memory_cost AOT reuse, profiler
 counter exposure, prefetch_to_device equivalence/placement, and
 PrefetchingIter worker-thread lifecycle."""
 import gc
+import os
 import threading
 
 import numpy as np
@@ -186,29 +187,54 @@ def test_profiler_counters_exposed():
     assert 'exec_cache_hits=' in text and 'total_compile_s=' in text
 
 
-def test_persistent_cache_writes_to_disk(tmp_path, monkeypatch):
+def test_persistent_cache_dir_from_jax_env(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: that directory and no other —
+    jax reads the variable itself, the code sets no directory."""
     import jax
-    cc = pytest.importorskip('jax._src.compilation_cache')
-    monkeypatch.setenv('MXNET_TPU_PERSISTENT_CACHE_DIR', str(tmp_path))
-    # the CPU-backend corruption guard (exec_cache round 12) would
-    # no-op this test's write; force-enable for the mechanics check
-    monkeypatch.setenv('MXNET_TPU_PERSISTENT_CACHE_FORCE', '1')
-    # jax memoizes cache usability at first compile; reset so the
-    # fresh dir takes effect inside this already-compiling process
+    from jax._src import compilation_cache as cc
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path))
     monkeypatch.setattr(exec_cache, '_PERSISTENT_DIR', None)
-    assert exec_cache.setup_persistent_cache() == str(tmp_path)
+    default = exec_cache.default_cache_dir()
+    before = os.listdir(default) if os.path.isdir(default) else None
+    # jax read its environment at import; stand in for that read
+    jax.config.update('jax_compilation_cache_dir', str(tmp_path))
     try:
+        assert exec_cache.setup_persistent_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        # jax memoizes cache usability at first compile; this process
+        # has long since compiled
         cc.reset_cache()
         ex = _mlp(num_hidden=21).simple_bind(mx.cpu(), data=(2, 6))
         ex.arg_dict['data'][:] = np.random.rand(2, 6)
         ex.forward()
         assert list(tmp_path.iterdir()), \
             'no on-disk compilation cache entry'
+        assert before == (os.listdir(default)
+                          if os.path.isdir(default) else None)
     finally:
         # turn the disk cache back OFF for the rest of the suite
         # (every later compile would otherwise pay disk writes)
         jax.config.update('jax_compilation_cache_dir', None)
+        jax.config.update('jax_persistent_cache_min_compile_time_secs',
+                          1.0)
+        jax.config.update('jax_persistent_cache_min_entry_size_bytes', 0)
         cc.reset_cache()
+
+
+def test_persistent_cache_off_on_cpu(monkeypatch):
+    import jax
+    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+    monkeypatch.setattr(exec_cache, '_PERSISTENT_DIR', None)
+    assert exec_cache.setup_persistent_cache() is None
+    assert jax.config.jax_compilation_cache_dir is None
+
+
+def test_default_cache_dir_ignores_cwd(tmp_path, monkeypatch):
+    checkout = os.path.dirname(os.path.dirname(
+        os.path.abspath(mx.__file__)))
+    monkeypatch.chdir(tmp_path)
+    assert exec_cache.default_cache_dir() == \
+        os.path.join(checkout, '.jax_cache')
 
 
 # ---------------------------------------------------------------------------

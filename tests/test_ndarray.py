@@ -154,6 +154,19 @@ def test_copyto_context():
     np.testing.assert_allclose(c.asnumpy(), a.asnumpy())
 
 
+def test_accelerator_context_never_lands_on_cpu():
+    """On the CPU backend tpu()/gpu() raise and name the platform they
+    found; cpu() still wraps modulo the eight virtual devices."""
+    import jax
+    for ctx in (mx.tpu(0), mx.gpu(0)):
+        with pytest.raises(mx.MXNetError, match=r'cpu:0'):
+            ctx.jax_device()
+    assert mx.num_gpus() == 0
+    cpus = jax.devices('cpu')
+    assert mx.cpu(7).jax_device() == cpus[7]
+    assert mx.cpu(9).jax_device() == cpus[1]
+
+
 def test_astype():
     a = nd.ones((2,))
     assert a.astype(np.int32).dtype == np.int32

@@ -140,7 +140,7 @@ def test_transformer_train_step_dp_tp_sp():
 
 def test_collectives_api():
     mesh = make_mesh({'data': 8})
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def f(x):
@@ -180,7 +180,7 @@ def test_pipeline_matches_sequential():
     x = rs.randn(M, mb, D).astype(np.float32)
 
     import jax
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def run(params, micro):

@@ -548,7 +548,7 @@ def test_quantized_allreduce_shardmap_parity():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from mxnet_tpu.parallel import collectives
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from mxnet_tpu.parallel.mesh import make_mesh
     mesh = make_mesh()                       # all 8 virtual devices
     rng = np.random.RandomState(4)

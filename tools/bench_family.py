@@ -3,9 +3,10 @@
 The reference publishes single-K80 numbers for the image-classification
 family (example/image-classification/README.md:149-156 + the scaling
 table's 1-GPU rows, reproduced in BASELINE.md).  bench.py measures ONE
-model per process (BENCH_MODEL, with its own poisoned-client-safe OOM
-fallback); this tool just drives bench.py once per model and relays the
-JSON lines — one emitter, one retry ladder, no duplicated harness.
+model per process (BENCH_MODEL); this tool just drives bench.py once per
+model and relays the JSON lines — one emitter, no duplicated harness.
+It imports bench.py for its tables only and never touches jax itself,
+so each child finds the chip free.
 
   python tools/bench_family.py [--models resnet-50,inception-bn]
                                [--batch N] [--steps N] [--bulk N]
@@ -25,8 +26,8 @@ def main():
     p = argparse.ArgumentParser()
     p.add_argument('--models', default=','.join(bench.K80_IMG_S))
     p.add_argument('--batch', type=int, default=0,
-                   help='0 = bench.py per-model default ladder '
-                        '(bench.BATCH_LADDER / 256,128,64)')
+                   help='0 = bench.py per-model default '
+                        '(bench.DEFAULT_BATCH, else 256)')
     p.add_argument('--steps', type=int, default=4)
     p.add_argument('--warmup', type=int, default=2)
     p.add_argument('--bulk', type=int, default=16)
@@ -154,7 +155,7 @@ def main():
             env['BENCH_BATCH'] = str(args.batch)
         else:
             # a stray exported BENCH_BATCH must not silently override
-            # the per-model ladder
+            # the per-model default
             env.pop('BENCH_BATCH', None)
         proc = subprocess.run([sys.executable, bench_py], env=env,
                               capture_output=True, text=True)
