@@ -1293,8 +1293,7 @@ def overlap_bench():
     psum), so the measured delta is schedule-only; a parity gate
     asserts it.  Emits ONE JSON line with best-of-N steps/s per arm
     (the rig's cpu-shares throttle swings single passes ~2x), the
-    reduce_buckets_issued / overlap_window_ms counters, and the
-    parity max-abs-diff.
+    reduce_buckets_issued counter, and the parity max-abs-diff.
 
     Needs >= BENCH_OVERLAP_DEVICES devices: when the process has
     fewer (no TPU pod on this rig), re-execs itself on a virtual CPU
@@ -1395,9 +1394,8 @@ def overlap_bench():
     buckets = fs_i._reduce_plan.n_buckets if not zero else None
     best = {'interleaved': 0.0, 'end': 0.0}
     # measure with the profiler ON: dispatches then synchronize, so
-    # per-dispatch wall time (and the overlap_window_ms estimate it
-    # feeds) reflects execution, not async enqueue — both arms pay
-    # the same sync
+    # per-dispatch wall time reflects execution, not async enqueue —
+    # both arms pay the same sync
     profiler.clear()
     profiler.profiler_set_state('run')
     try:
@@ -1481,7 +1479,6 @@ def overlap_bench():
         'hidden': hidden, 'layers': layers, 'zero': zero,
         'reduce_buckets': buckets,
         'reduce_buckets_issued': cm['reduce_buckets_issued'],
-        'overlap_window_ms': round(cm['overlap_window_ms'], 3),
         'steps_per_pass': steps, 'passes': passes,
         'parity_max_abs_diff': max_diff,
         'parity_ok': bool(max_diff < 1e-5),

@@ -8,6 +8,7 @@ exist for everything, so the package works without the build; `lib()`
 builds on demand with make when a toolchain is present.
 """
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -25,9 +26,24 @@ class NativeError(RuntimeError):
 
 
 def _build():
-    subprocess.check_call(
-        ['make', '-s', '-j4'], cwd=_SRC_DIR,
-        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+    """Build the library unless another process has: one builder at a
+    time under an exclusive lock on src/.build.lock (pytest-xdist's
+    workers all import this at once on a fresh checkout), and the link
+    lands under a temporary name that os.replace moves into place, so
+    nobody ever loads a half-written library."""
+    with open(os.path.join(_SRC_DIR, '.build.lock'), 'w') as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if os.path.exists(_LIB_PATH):
+            return
+        tmp = '%s.%d.tmp' % (_LIB_PATH, os.getpid())
+        try:
+            subprocess.check_call(
+                ['make', '-s', '-j4', 'TARGET=' + tmp], cwd=_SRC_DIR,
+                stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+            os.replace(tmp, _LIB_PATH)
+        finally:
+            if os.path.exists(tmp):
+                os.remove(tmp)
 
 
 def _declare(lib):

@@ -369,20 +369,24 @@ class Executor:
                     args = list(args)
                     split_beta[ni] = args[2]
                     args[2] = jnp.zeros_like(args[2])
-                outs, updated = op.apply(eff_attrs, args, auxs, op_ctx)
-                if do_split and ni in split_conv:
-                    bval = split_beta[split_conv[ni]]
-                    x1 = args[0]
-                    bval = bval.astype(x1.dtype)
-                    if eff_attrs.get('__layout__') == 'NHWC':
-                        b_in = jnp.broadcast_to(bval,
-                                                (1,) + x1.shape[1:])
-                    else:
-                        b_in = jnp.broadcast_to(bval[:, None, None],
-                                                (1,) + x1.shape[1:])
-                    outs2, _ = op.apply(eff_attrs, [b_in, args[1]], [],
-                                        op_ctx)
-                    outs = [outs[0] + outs2[0]]
+                # HLO metadata only ('BatchNorm.stage1_unit1_bn1'): a
+                # device trace then sums kernel time by operator and node
+                with jax.named_scope('%s.%s' % (op.name, node.name)):
+                    outs, updated = op.apply(eff_attrs, args, auxs,
+                                             op_ctx)
+                    if do_split and ni in split_conv:
+                        bval = split_beta[split_conv[ni]]
+                        x1 = args[0]
+                        bval = bval.astype(x1.dtype)
+                        if eff_attrs.get('__layout__') == 'NHWC':
+                            b_in = jnp.broadcast_to(
+                                bval, (1,) + x1.shape[1:])
+                        else:
+                            b_in = jnp.broadcast_to(
+                                bval[:, None, None], (1,) + x1.shape[1:])
+                        outs2, _ = op.apply(eff_attrs, [b_in, args[1]],
+                                            [], op_ctx)
+                        outs = [outs[0] + outs2[0]]
                 results[ni] = outs
                 layouts[ni] = [out_layout
                                if getattr(o, 'ndim', 0) == 4 else 'NCHW'
@@ -742,7 +746,8 @@ class Executor:
                               embed_mod._Override(r, iv, e['dim'])
                               for e, r, iv in zip(sparse_rt, rv,
                                                   invs_l)}
-                        with embed_mod.override_scope(ov):
+                        with embed_mod.override_scope(ov), \
+                                jax.named_scope('forward'):
                             outs, new_aux = run_graph(
                                 tuple(merged), aux_vals, sub, True)
                         return outs, new_aux
@@ -764,13 +769,15 @@ class Executor:
                         # the sparse reduction itself
                         didx = [j for j in range(len(grads))
                                 if j not in sparse_dset]
-                        red = grad_reduce([grads[j] for j in didx])
+                        with jax.named_scope('grad_reduce'):
+                            red = grad_reduce([grads[j] for j in didx])
                         for j, g in zip(didx, red):
                             grads[j] = g
                 else:
                     def f(dv):
-                        outs, new_aux = run_graph(tuple(merge(dv)),
-                                                  aux_vals, sub, True)
+                        with jax.named_scope('forward'):
+                            outs, new_aux = run_graph(
+                                tuple(merge(dv)), aux_vals, sub, True)
                         return outs, new_aux
 
                     f = _maybe_remat(f, remat_mode)
@@ -781,11 +788,15 @@ class Executor:
                     grads, = vjp_fn(heads)
                     grads = list(grads)
                     if grad_reduce is not None:
-                        grads = grad_reduce(grads)
-                new_ws, new_moms, new_masters = step_math(
-                    list(diff_vals), grads, moms, masters, lr_t, wd_t)
+                        with jax.named_scope('grad_reduce'):
+                            grads = grad_reduce(grads)
+                with jax.named_scope('update'):
+                    new_ws, new_moms, new_masters = step_math(
+                        list(diff_vals), grads, moms, masters, lr_t,
+                        wd_t)
                 if metric is not None:
-                    mc = metric[1](mc, outs, sv)
+                    with jax.named_scope('metric_fold'):
+                        mc = metric[1](mc, outs, sv)
                 return (tuple(new_ws), new_aux, new_moms, new_masters,
                         key, outs, mc)
 
@@ -921,7 +932,7 @@ class Executor:
         moms, masters = self._align_step_placement(diff_vals, moms,
                                                    masters, zero=zero)
         self.fused_dispatches += 1
-        with profiler.scope(self._name('fused_multistep')):
+        with profiler.scope('executor.dispatch', 'fused_step'):
             (outs, new_aux, new_ws, new_moms, new_masters, self._key,
              mcarry) = step(diff_vals, scan_vals, inv_vals, aux_vals,
                             self._key, moms, masters, lrs, wds)
@@ -1022,7 +1033,7 @@ class Executor:
         if monitor_active:
             # collect-all jit: every node output is materialized — only
             # when the monitor is actually collecting this batch
-            with profiler.scope(self._name('forward_monitor')):
+            with profiler.scope('executor.forward_monitor'):
                 outs, new_aux, mon = self._fwd_monitor(
                     arg_vals, aux_vals, sub, bool(is_train))
                 self._maybe_block(outs)
@@ -1032,11 +1043,11 @@ class Executor:
                 self._monitor_callback(name, nd.NDArray(v, self._ctx))
         elif is_train:
             self._stash = (arg_vals, aux_vals, sub)
-            with profiler.scope(self._name('forward_train')):
+            with profiler.scope('executor.forward_train'):
                 outs, new_aux = self._fwd_train(arg_vals, aux_vals, sub)
                 self._maybe_block(outs)
         else:
-            with profiler.scope(self._name('forward')):
+            with profiler.scope('executor.forward'):
                 outs, new_aux = self._fwd_eval(arg_vals, aux_vals, sub)
                 self._maybe_block(outs)
             if self._has_aux_always:
@@ -1127,9 +1138,6 @@ class Executor:
             self._partial_state = None
         return total - state['done'] if state['done'] < total else 0
 
-    def _name(self, suffix):
-        return '%s_%s' % (self._symbol.name or 'executor', suffix)
-
     @staticmethod
     def _maybe_block(outs):
         """When profiling, wait for device completion INSIDE the scope —
@@ -1143,7 +1151,7 @@ class Executor:
             raise MXNetError('backward called before forward(is_train=True)')
         arg_vals, aux_vals, sub = self._stash
         heads = self._default_head_grads(out_grads)
-        with profiler.scope(self._name('backward')):
+        with profiler.scope('executor.backward'):
             outs, new_aux, grads = self._fwd_bwd(arg_vals, aux_vals, sub,
                                                  heads)
             self._maybe_block(grads)
@@ -1162,7 +1170,7 @@ class Executor:
         self._key, sub = jax.random.split(self._key)
         self._stash = (arg_vals, aux_vals, sub)
         heads = self._default_head_grads(out_grads)
-        with profiler.scope(self._name('forward_backward')):
+        with profiler.scope('executor.forward_backward'):
             outs, new_aux, grads = self._fwd_bwd(arg_vals, aux_vals, sub,
                                                  heads)
             self._maybe_block(grads)

@@ -50,7 +50,7 @@ stretches across what used to be per-batch metric/LR host syncs.
 
 Observability: profiler.gluon_fused_stats() (gluon_fused_steps /
 gluon_fused_dispatches), the 'gluon_fused' span category, the
-reduce_buckets_issued / overlap_window_ms / scan_fused_metric_steps
+reduce_buckets_issued / scan_fused_metric_steps
 comm counters, and the ZeRO comm/state counters Module feeds.
 Bench: BENCH_GLUON=1 and BENCH_OVERLAP=1 in bench.py.  Docs:
 docs/PERF.md rounds 10-11.
@@ -884,7 +884,7 @@ class FusedStep:
         frozen = [self._gather_param(p) for p in self._frozen_params]
         # MoE routing counters: snapshot the cumulative aux counts
         # BEFORE the dispatch donates them (profiler-on dispatches are
-        # synchronized anyway — see dt_ms below)
+        # synchronized anyway — see `synced` below)
         moe_idx = [(i, p._moe_counter)
                    for i, p in enumerate(self._aux_params)
                    if getattr(p, '_moe_counter', None)]
@@ -904,7 +904,6 @@ class FusedStep:
                 exec_cache.put(self._splan.facts_key(),
                                (dict(self._splan.src),
                                 dict(self._splan.slots)))
-        t0 = time.perf_counter()
         synced = profiler.is_running()
         with profiler.scope('gluon_fused_%s' % ('bulk' if bulk
                                                 else 'step'),
@@ -915,9 +914,6 @@ class FusedStep:
                 arrays, lrs, wds)
             if synced:
                 jax.block_until_ready(loss_out)
-        # only a synchronized dispatch's wall time says anything about
-        # device execution (async enqueue returns immediately)
-        dt_ms = (time.perf_counter() - t0) * 1e3 if synced else 0.0
         for p, w in zip(self._params, new_ws):
             self._writeback_param(p, w)
         for p, a in zip(self._aux_params, new_aux):
@@ -933,7 +929,7 @@ class FusedStep:
             self._metric_fold.commit(mdeltas)
         self._trainer._last_update_mode = 'fused'
         profiler.add_gluon_fused_stats(steps=k, dispatches=1)
-        self._note_reduce_counters(fu, k, dt_ms)
+        self._note_reduce_counters(fu, k)
         if self._splan is not None:
             self._note_embed_counters(fu, k, rungs)
         rs, ag = fu.comm_bytes_per_step()
@@ -968,12 +964,11 @@ class FusedStep:
         out = [nd.NDArray(v, ctx) for v in loss_out]
         return jtu.tree_unflatten(self._loss_treedef, out)
 
-    def _note_reduce_counters(self, fu, k, dt_ms):
+    def _note_reduce_counters(self, fu, k):
         """Feed the round-11 profiler counters after a dispatch of k
         steps: gradient-bucket collectives issued (reduce plan
         buckets, or the ZeRO layout's) and device-folded metric steps
-        (one model, profiler.note_reduce_dispatch; dt_ms is 0.0 for
-        async dispatches — no overlap window is estimated then)."""
+        (one model, profiler.note_reduce_dispatch)."""
         buckets = 0
         if self._mesh is not None:
             if self._zero and fu._layout is not None:
@@ -981,7 +976,7 @@ class FusedStep:
             elif not self._zero and self._reduce_plan is not None:
                 buckets = self._reduce_plan.n_buckets
         profiler.note_reduce_dispatch(
-            buckets, self._interleave, k, dt_ms=dt_ms,
+            buckets, k,
             metric_steps=k if self._metric_fold is not None else 0)
 
     @staticmethod
@@ -1411,8 +1406,6 @@ class PipelinedStep(FusedStep):
                 (stage_ws, stem_ws, head_ws, self._pipe_opt,
                  self._rng, arrays[0], arrays[1], lrs, wds))
             self._programs[local] = prog
-        t0 = time.perf_counter()
-        synced = profiler.is_running()
         with profiler.scope('gluon_pipe_%s' % ('bulk' if bulk
                                                else 'step'),
                             'gluon_fused'):
@@ -1420,9 +1413,8 @@ class PipelinedStep(FusedStep):
              self._rng) = prog(stage_ws, stem_ws, head_ws,
                                self._pipe_opt, self._rng, arrays[0],
                                arrays[1], lrs, wds)
-            if synced:
+            if profiler.is_running():
                 jax.block_until_ready(loss_out)
-        dt_ms = (time.perf_counter() - t0) * 1e3 if synced else 0.0
         for j, stacked in enumerate(new_stage):
             self._writeback_stage_leaf(j, stacked)
         for p, w in zip(self._stem_params2, new_stem):
@@ -1430,7 +1422,7 @@ class PipelinedStep(FusedStep):
         for p, w in zip(self._head_params2, new_head):
             self._writeback_param(p, w)
         self._trainer._last_update_mode = 'fused'
-        self._note_pipe_counters(k, dt_ms)
+        self._note_pipe_counters(k)
         ctx = self._ctxs[0]
         out = [nd.NDArray(v, ctx) for v in loss_out]
         return jtu.tree_unflatten(self._loss_treedef, out)
@@ -1484,7 +1476,7 @@ class PipelinedStep(FusedStep):
             'pipe_bulk' if bulk else 'pipe_step', k,
             self._placement_fp())
 
-    def _note_pipe_counters(self, k, dt_ms):
+    def _note_pipe_counters(self, k):
         param_b, state_b = self._pipe_state_accounting()
         profiler.add_gluon_fused_stats(steps=k, dispatches=1)
         self._pipe_mod.note_pipe_counters(

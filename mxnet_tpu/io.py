@@ -17,6 +17,7 @@ from itertools import chain
 import numpy as np
 
 from . import ndarray as nd
+from . import profiler
 from .ndarray import NDArray
 
 DataDesc = namedtuple('DataDesc', ['name', 'shape', 'dtype', 'layout'])
@@ -400,7 +401,9 @@ class PrefetchToDeviceIter(_StagedBatchMixin, DataIter):
     to the same device is a no-op).
 
     input_stall_ms accumulates host wall time spent inside next() —
-    the time the training loop was blocked on input — so callers
+    the time the training loop was blocked on input (the 'io.next'
+    span; 'io.host_batch' and 'io.stage' split it into the wrapped
+    iterator's next() and the enqueueing of the copy) — so callers
     (bench.py) can report per-step input stall.
     """
 
@@ -447,24 +450,28 @@ class PrefetchToDeviceIter(_StagedBatchMixin, DataIter):
     def _fill(self):
         while not self._exhausted and len(self._buf) < self.size:
             try:
-                self._buf.append(self._stage(self.data_iter.next()))
+                with profiler.scope('io.host_batch', 'io'):
+                    batch = self.data_iter.next()
             except StopIteration:
                 self._exhausted = True
+                return
+            with profiler.scope('io.stage', 'io'):
+                self._buf.append(self._stage(batch))
 
     def iter_next(self):
-        t0 = time.perf_counter()
-        self._fill()
-        if not self._buf:
+        if self._exhausted and not self._buf:
             self.current_batch = None
             return False
-        self.current_batch = self._buf.popleft()
-        self._fill()     # enqueue the next copy before returning
-        stall_ms = (time.perf_counter() - t0) * 1e3
+        with profiler.scope('io.next', 'io') as span:
+            self._fill()
+            served = int(bool(self._buf))
+            self.current_batch = self._buf.popleft() if served else None
+            self._fill()     # enqueue the next copy before returning
+        stall_ms = span.seconds * 1e3
         self.input_stall_ms += stall_ms
-        self.batches_served += 1
-        from . import profiler
-        profiler.add_input_stats(stall_ms=stall_ms, batches=1)
-        return True
+        self.batches_served += served
+        profiler.add_input_stats(stall_ms=stall_ms, batches=served)
+        return bool(served)
 
     def next(self):
         if self.iter_next():
@@ -483,11 +490,13 @@ def stage_to_device(arrays, device=None, mesh=None):
     the raw jax arrays — the staging primitive PrefetchToDeviceIter
     and the serving engine's dynamic batcher share.  `device` accepts
     a Context or a raw jax device; with `mesh` the arrays are
-    batch-sharded over it instead."""
+    batch-sharded over it instead.  The bytes handed to either count
+    as the profiler's h2d_bytes."""
     import jax
     if hasattr(device, 'jax_device'):
         device = device.jax_device()
     out = []
+    put_bytes = 0
     for a in arrays:
         data = a._data if isinstance(a, NDArray) else \
             jax.numpy.asarray(np.asarray(a))
@@ -496,7 +505,11 @@ def stage_to_device(arrays, device=None, mesh=None):
             data = pmesh.shard_batch(mesh, data)
         elif device is not None:
             data = jax.device_put(data, device)
+        if mesh is not None or device is not None:
+            put_bytes += data.nbytes
         out.append(data)
+    if put_bytes:
+        profiler.add_input_stats(h2d_bytes=put_bytes)
     return out
 
 

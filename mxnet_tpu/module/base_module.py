@@ -311,6 +311,11 @@ class BaseModule:
         its signal handlers in one finally regardless of how the loop
         exits — normal completion, Preempted, or an error).
 
+        Spans: each pass of the per-step loop is one 'fit.step' (with
+        the iterator's 'io.next' it tiles the loop); inside it
+        'fit.metric' is the metric fold, which waits for the step's
+        outputs, and 'fit.callback' the user's batch_end_callback.
+
         Overlapped metric pipeline: XLA dispatch is async, but the
         reference loop's per-batch `update_metric` materializes the
         step's outputs — a host sync that re-serializes every step.
@@ -341,19 +346,21 @@ class BaseModule:
             # the env knob
             ahead = resolve_step_ahead()
         pending = deque()               # (labels, preds, epoch, nbatch)
+        steps_done = 0                  # the 'fit.step' spans' number
 
         def _fold_one():
             labels, preds, ep, nb = pending.popleft()
-            tw = time.perf_counter()
-            eval_metric.update_dict(labels, preds)
+            with profiler.scope('fit.metric', 'fit') as fold:
+                eval_metric.update_dict(labels, preds)
             profiler.add_overlap_stats(
                 deferred_metric_folds=1,
-                dispatch_wait_ms=(time.perf_counter() - tw) * 1e3)
+                dispatch_wait_ms=fold.seconds * 1e3)
             if batch_end_callback is not None:
-                _fire(batch_end_callback,
-                      BatchEndParam(epoch=ep, nbatch=nb,
-                                    eval_metric=eval_metric,
-                                    locals=locals()))
+                with profiler.scope('fit.callback', 'fit'):
+                    _fire(batch_end_callback,
+                          BatchEndParam(epoch=ep, nbatch=nb,
+                                        eval_metric=eval_metric,
+                                        locals=locals()))
 
         def _drain():
             while pending:
@@ -381,46 +388,52 @@ class BaseModule:
             else:
                 for nbatch, data_batch in enumerate(train_data):
                     nbatch += epoch_off
-                    if monitor is not None:
-                        monitor.tic()
-                    try:
-                        self.forward_backward(data_batch)
-                        self.update()
-                    except MXNetError:
-                        _drain()        # preempt commit reads metric
-                        self._peer_death_preempt(checkpoint, _ckpt_step,
-                                                 nbatch, epoch)
-                        raise
-                    snap = self.metric_snapshot(data_batch.label) \
-                        if ahead else None
-                    if snap is None:
-                        self.update_metric(eval_metric,
-                                           data_batch.label)
-                    if monitor is not None:
-                        monitor.toc_print()
-                    if snap is None:
-                        if batch_end_callback is not None:
-                            _fire(batch_end_callback,
-                                  BatchEndParam(epoch=epoch,
-                                                nbatch=nbatch,
-                                                eval_metric=eval_metric,
-                                                locals=locals()))
-                    else:
-                        pending.append(snap + (epoch, nbatch))
-                        while len(pending) > ahead:
-                            _fold_one()
-                        profiler.add_overlap_stats(
-                            train_steps=1,
-                            steps_ahead=len(pending))
-                    if checkpoint is not None and \
-                            checkpoint.will_act(1):
-                        # the coming boundary consumes the metric
-                        # (best-tracking in save / the preemption
-                        # commit): flush the deferred folds so the
-                        # snapshot sees exactly the serialized loop's
-                        # state
-                        _drain()
-                    _ckpt_step(nbatch + 1, 1, epoch)
+                    steps_done += 1
+                    with profiler.scope('fit.step', 'fit',
+                                        step=steps_done):
+                        if monitor is not None:
+                            monitor.tic()
+                        try:
+                            self.forward_backward(data_batch)
+                            self.update()
+                        except MXNetError:
+                            _drain()    # preempt commit reads metric
+                            self._peer_death_preempt(
+                                checkpoint, _ckpt_step, nbatch, epoch)
+                            raise
+                        snap = self.metric_snapshot(data_batch.label) \
+                            if ahead else None
+                        if snap is None:
+                            with profiler.scope('fit.metric', 'fit'):
+                                self.update_metric(eval_metric,
+                                                   data_batch.label)
+                        if monitor is not None:
+                            monitor.toc_print()
+                        if snap is None:
+                            if batch_end_callback is not None:
+                                with profiler.scope('fit.callback',
+                                                    'fit'):
+                                    _fire(batch_end_callback,
+                                          BatchEndParam(
+                                              epoch=epoch, nbatch=nbatch,
+                                              eval_metric=eval_metric,
+                                              locals=locals()))
+                        else:
+                            pending.append(snap + (epoch, nbatch))
+                            while len(pending) > ahead:
+                                _fold_one()
+                            profiler.add_overlap_stats(
+                                train_steps=1,
+                                steps_ahead=len(pending))
+                        if checkpoint is not None and \
+                                checkpoint.will_act(1):
+                            # the coming boundary consumes the metric
+                            # (best-tracking in save / the preemption
+                            # commit): flush the deferred folds so the
+                            # snapshot sees exactly the serialized
+                            # loop's state
+                            _drain()
+                        _ckpt_step(nbatch + 1, 1, epoch)
 
             _drain()                    # epoch boundary logs the metric
             for name, val in eval_metric.get_name_value():

@@ -14,6 +14,7 @@ import numpy as np
 import jax
 
 from .. import ndarray as nd
+from .. import profiler
 from ..base import MXNetError
 from ..executor import Executor
 from ..parallel import mesh as pmesh
@@ -123,11 +124,13 @@ class DataParallelExecutorGroup:
     def load_data_batch(self, data_batch):
         """The reference's _load_data/_load_label slicing loop
         (executor_group.py:388) becomes sharded placement."""
-        for name, value in zip(self.data_names, data_batch.data):
-            self._place_input(name, value)
-        if self.label_names and data_batch.label:
-            for name, value in zip(self.label_names, data_batch.label):
+        with profiler.scope('module.load_batch', 'fused_step'):
+            for name, value in zip(self.data_names, data_batch.data):
                 self._place_input(name, value)
+            if self.label_names and data_batch.label:
+                for name, value in zip(self.label_names,
+                                       data_batch.label):
+                    self._place_input(name, value)
 
     # ------------------------------------------------------------------
     def forward(self, data_batch=None, is_train=None):

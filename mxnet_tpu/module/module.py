@@ -15,6 +15,7 @@ from .. import metric as metric_mod
 from .. import model as model_mod
 from .. import ndarray as nd
 from .. import optimizer as opt_mod
+from .. import profiler
 from ..base import MXNetError
 from .base_module import BaseModule
 from .executor_group import DataParallelExecutorGroup
@@ -514,33 +515,27 @@ class Module(BaseModule):
         return self._fused_step
 
     def _run_fused_step(self):
-        import time
         ex = self._exec_group.executor
         fu = self._fused_updater
         fnames = ex._diff_names
-        if fu.param_names != fnames:
-            fu.param_names = list(fnames)
-        weights = [ex.arg_dict[n] for n in fnames]
-        moms, masters, lrs, wds = fu.host_prep(weights)
-        self._ensure_fused_program(ex, fu, fnames)
-        from .. import profiler
-        t0 = time.perf_counter()
-        synced = profiler.is_running()   # executor blocks only then
+        with profiler.scope('module.host_prep', 'fused_step'):
+            if fu.param_names != fnames:
+                fu.param_names = list(fnames)
+            weights = [ex.arg_dict[n] for n in fnames]
+            moms, masters, lrs, wds = fu.host_prep(weights)
+            self._ensure_fused_program(ex, fu, fnames)
         new_moms, new_masters = ex.run_fused_train_step(
             self._fused_step, fnames, moms, masters, lrs, wds,
             zero=bool(fu.zero))
         fu.commit(new_moms, new_masters)
-        self._note_step_counters(
-            1, (time.perf_counter() - t0) * 1e3 if synced else 0.0)
+        self._note_step_counters(1)
 
-    def _note_step_counters(self, k, dt_ms=0.0, metric_steps=0):
+    def _note_step_counters(self, k, metric_steps=0):
         """Feed the profiler's comm/memory counters after k fused
         steps: ZeRO reduce-scatter / all-gather payload bytes,
         per-device optimizer-state residency, and the round-11
         reduce/metric counters (one model,
-        profiler.note_reduce_dispatch; dt_ms must be 0.0 for async
-        dispatches — no overlap window is estimated then)."""
-        from .. import profiler
+        profiler.note_reduce_dispatch)."""
         fu = self._fused_updater
         if fu is None:
             return
@@ -549,17 +544,14 @@ class Module(BaseModule):
             profiler.add_comm_bytes(reduce_scattered=rs * k,
                                     all_gathered=ag * k)
         profiler.set_optimizer_state_bytes(fu.state_bytes_per_device())
-        buckets, interleave = 0, True
+        buckets = 0
         if self._exec_group.mesh is not None:
             if fu.zero and fu._layout is not None:
                 buckets = len(fu._layout.buckets)
-                interleave = fu._interleave
             elif not fu.zero and \
                     getattr(self, '_reduce_plan', None) is not None:
                 buckets = self._reduce_plan.n_buckets
-                interleave = self._reduce_plan.interleave
-        profiler.note_reduce_dispatch(buckets, interleave, k,
-                                      dt_ms=dt_ms,
+        profiler.note_reduce_dispatch(buckets, k,
                                       metric_steps=metric_steps)
 
     def _ensure_bulk_program(self, ex, fu, fnames, scan_names, k,
@@ -741,7 +733,16 @@ class Module(BaseModule):
                 if eval_metric is not None:
                     self.update_metric(eval_metric, b.label)
             return
-        import time
+        with profiler.scope('module.bulk_step', 'fused_step'):
+            return self._bulk_step_fused(k, batches, batch, scan_dtype,
+                                         eval_metric)
+
+    def _bulk_step_fused(self, k, batches, batch, scan_dtype,
+                         eval_metric):
+        """bulk_step's fused path.  Spans inside 'module.bulk_step':
+        'module.bulk_stack' (the K batches cast and stacked on the
+        device), 'module.host_prep' (optimizer state, schedule columns,
+        program lookup), then the executor's 'executor.dispatch'."""
         self._materialize_fused()
         import jax.numpy as jnp
         eg = self._exec_group
@@ -769,49 +770,49 @@ class Module(BaseModule):
                     self.update_metric(eval_metric, batches[0].label)
                 return ret
             eg.load_data_batch(batches[0])  # dtype/shape checks + cast
-            data_set = set(eg.data_names)
-            per_name = {n: [] for n in scan_names}
-            for b in batches:
-                vals = dict(zip(eg.data_names, b.data))
-                if eg.label_names and b.label:
-                    vals.update(zip(eg.label_names, b.label))
-                for n in scan_names:
-                    v = vals[n]
-                    v = v._data if isinstance(v, nd.NDArray) else \
-                        jnp.asarray(v)
-                    store = scan_dtype if (scan_dtype is not None and
-                                           n in data_set) else \
-                        ex.arg_dict[n].dtype
-                    per_name[n].append(v.astype(store))
-            scan_stacks = {n: jnp.stack(per_name[n])
-                           for n in scan_names}
-            if eg.mesh is not None:
-                from ..parallel import mesh as pmesh
-                scan_stacks = {
-                    n: pmesh.shard_batch(eg.mesh, v, dim=1)
-                    for n, v in scan_stacks.items()}
+            with profiler.scope('module.bulk_stack', 'fused_step'):
+                data_set = set(eg.data_names)
+                per_name = {n: [] for n in scan_names}
+                for b in batches:
+                    vals = dict(zip(eg.data_names, b.data))
+                    if eg.label_names and b.label:
+                        vals.update(zip(eg.label_names, b.label))
+                    for n in scan_names:
+                        v = vals[n]
+                        v = v._data if isinstance(v, nd.NDArray) else \
+                            jnp.asarray(v)
+                        store = scan_dtype \
+                            if (scan_dtype is not None and
+                                n in data_set) else ex.arg_dict[n].dtype
+                        per_name[n].append(v.astype(store))
+                scan_stacks = {n: jnp.stack(per_name[n])
+                               for n in scan_names}
+                if eg.mesh is not None:
+                    from ..parallel import mesh as pmesh
+                    scan_stacks = {
+                        n: pmesh.shard_batch(eg.mesh, v, dim=1)
+                        for n, v in scan_stacks.items()}
         else:
             eg.load_data_batch(batch)
-        weights = [ex.arg_dict[n] for n in fnames]
-        # per-step schedule stacks: counts bump and lr/wd evaluate at
-        # every step index (host scheduler semantics).  ONE (K, n)
-        # array each — a single transfer per dispatch regardless of
-        # parameter count; the per-param split happens in the trace
-        moms, masters, lr_stack, wd_stack = fu.host_prep_steps(
-            weights, k)
-        lrs, wds = jnp.asarray(lr_stack), jnp.asarray(wd_stack)
-        if eg.mesh is not None:
-            import jax
-            from ..parallel import mesh as pmesh
-            repl = pmesh.replicated(eg.mesh)
-            lrs = jax.device_put(lrs, repl)
-            wds = jax.device_put(wds, repl)
-        self._ensure_bulk_program(ex, fu, fnames, scan_names, k,
-                                  stacked=(batches is not None),
-                                  scan_dtype=scan_dtype, fold=fold)
-        from .. import profiler
-        t0 = time.perf_counter()
-        synced = profiler.is_running()   # executor blocks only then
+        with profiler.scope('module.host_prep', 'fused_step'):
+            weights = [ex.arg_dict[n] for n in fnames]
+            # per-step schedule stacks: counts bump and lr/wd evaluate
+            # at every step index (host scheduler semantics).  ONE
+            # (K, n) array each — a single transfer per dispatch
+            # regardless of parameter count; the per-param split
+            # happens in the trace
+            moms, masters, lr_stack, wd_stack = fu.host_prep_steps(
+                weights, k)
+            lrs, wds = jnp.asarray(lr_stack), jnp.asarray(wd_stack)
+            if eg.mesh is not None:
+                import jax
+                from ..parallel import mesh as pmesh
+                repl = pmesh.replicated(eg.mesh)
+                lrs = jax.device_put(lrs, repl)
+                wds = jax.device_put(wds, repl)
+            self._ensure_bulk_program(ex, fu, fnames, scan_names, k,
+                                      stacked=(batches is not None),
+                                      scan_dtype=scan_dtype, fold=fold)
         new_moms, new_masters, mcarry = ex.run_fused_multistep(
             self._bulk_step_fn, fnames, scan_names, scan_stacks,
             moms, masters, lrs, wds, zero=bool(fu.zero))
@@ -821,8 +822,7 @@ class Module(BaseModule):
             # the first metric.get() drains them
             fold.commit(mcarry)
         self._note_step_counters(
-            k, (time.perf_counter() - t0) * 1e3 if synced else 0.0,
-            metric_steps=k if fold is not None else 0)
+            k, metric_steps=k if fold is not None else 0)
         self._params_dirty = True
 
     def _single_step(self, data_batch):

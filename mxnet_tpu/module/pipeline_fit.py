@@ -517,22 +517,19 @@ class ModulePipeTrainer:
         if prog is None:
             prog = self._get_program(hyper, bulk, k, pargs)
             self._programs[local] = prog
-        t0 = time.perf_counter()
-        synced = profiler.is_running()
         with profiler.scope('module_pipe_%s'
                             % ('bulk' if bulk else 'step'),
                             'fused_step'):
             (leaves, self._stage_ws, self._stem_ws, self._head_ws,
              self._opt, self._rng) = prog(*pargs)
-            if synced:
+            if profiler.is_running():
                 jax.block_until_ready(leaves)
-        dt_ms = (time.perf_counter() - t0) * 1e3 if synced else 0.0
         self._synced = False
         self._mod._params_dirty = True
-        self._note_counters(k, dt_ms)
+        self._note_counters(k)
         return leaves[0]
 
-    def _note_counters(self, k, dt_ms):
+    def _note_counters(self, k):
         param_b, state_b = self.state_accounting()
         pipe_mod.note_pipe_counters(
             self._pipe_s, self._pipe_m, k, self._layout, self._dp,

@@ -6,12 +6,18 @@ start/end microseconds dumped as chrome://tracing "traceEvents";
 python/mxnet/profiler.py:27-55 API — SURVEY.md §5.1).  The reference
 tags each engine OprBlock; here device work happens inside whole XLA
 executions, so the recorded spans are the framework's dispatch units:
-executor forward/backward/fused-step (device-synchronized inside the
-span so durations reflect execution, not async enqueue), kvstore
-push/pull, per-op imperative spans under mode='all', and any user
-`profiler.scope`.  For intra-XLA
+executor forward/backward (device-synchronized inside the span while
+profiler_set_state('run') is on, so durations reflect execution, not
+async enqueue), kvstore push/pull, per-op imperative spans under
+mode='all', and any user `profiler.scope`.  For intra-XLA
 kernel timing, `profiler_set_config(profile_xla=True)` additionally
 starts a JAX device trace (PJRT/XPlane) alongside.
+
+`scope` is the one way the program marks time.  Every span is also a
+jax TraceAnnotation named 'mx.<name>', so it lands in whatever jax
+profiler session is open on the device trace's clock, and is kept in
+a bounded in-memory ring per name that `span_tail` reads (always on;
+a span never waits for the device).
 
 Env autostart mirrors the reference: MXNET_PROFILER_AUTOSTART=1.
 """
@@ -19,8 +25,10 @@ import json
 import os
 import threading
 import time
+from collections import deque
 
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 _STATE = {
     'mode': 'symbolic',        # 'symbolic' | 'all'
@@ -41,48 +49,21 @@ _COMM = {
     'bytes_all_gathered': 0,
     'optimizer_state_bytes_per_device': 0,
     # backward-interleaved reduction + epoch-level fusion (round 11):
-    # gradient-bucket collectives issued inside fused steps, the
-    # ESTIMATED wall-clock window those collectives could overlap
-    # backward compute (dispatch time x backward-fraction model — see
-    # add_reduce_stats), and training steps whose metric accumulation
-    # ran device-resident inside the bulk scan
+    # gradient-bucket collectives issued inside fused steps, and
+    # training steps whose metric accumulation ran device-resident
+    # inside the bulk scan
     'reduce_buckets_issued': 0,
-    'overlap_window_ms': 0.0,
     'scan_fused_metric_steps': 0,
 }
 
 
-def add_reduce_stats(buckets_issued=0, overlap_window_ms=0.0,
-                     metric_steps=0):
-    """Accumulate interleaved-reduce / epoch-fusion counters (the
-    fused step paths feed one call per dispatch).  overlap_window_ms
-    is an ESTIMATE: dispatch wall time x 1/2 (the backward's rough
-    share of a training step) x (B-1)/B for B buckets — the window in
-    which all but the last bucket's collective can hide behind
-    remaining wgrad compute.  It bounds the schedulable overlap; XLA's
-    latency-hiding scheduler decides the realized overlap."""
-    with _STATE['lock']:
-        _COMM['reduce_buckets_issued'] += int(buckets_issued)
-        _COMM['overlap_window_ms'] += float(overlap_window_ms)
-        _COMM['scan_fused_metric_steps'] += int(metric_steps)
-
-
-def note_reduce_dispatch(buckets, interleave, k, dt_ms=0.0,
-                         metric_steps=0):
+def note_reduce_dispatch(buckets, k, metric_steps=0):
     """ONE counter model for a fused dispatch of k steps, shared by
     the Module and gluon fused paths: `buckets` gradient-bucket
-    collectives issue per step, and the overlap-window estimate
-    applies the add_reduce_stats formula.  dt_ms must be the wall
-    time of a SYNCHRONIZED dispatch (callers pass 0.0 when the
-    dispatch returned after async enqueue — host return time says
-    nothing about device wall time, so no window is estimated
-    then)."""
-    overlap = dt_ms * 0.5 * (buckets - 1) / buckets \
-        if buckets > 1 and interleave and dt_ms > 0.0 else 0.0
-    if buckets or metric_steps:
-        add_reduce_stats(buckets_issued=buckets * k,
-                         overlap_window_ms=overlap,
-                         metric_steps=metric_steps)
+    collectives issue per step."""
+    with _STATE['lock']:
+        _COMM['reduce_buckets_issued'] += int(buckets) * int(k)
+        _COMM['scan_fused_metric_steps'] += int(metric_steps)
 
 
 # pipeline-parallel counters (round 16: the dp×pipe GPipe training
@@ -234,8 +215,9 @@ def embed_stats():
 
 # host input-pipeline counters (parallel decode pool + device prefetch):
 # decode work done by the workers, time the consumer waited on the pool,
-# ready-chunk queue depth observations, and training-loop-visible input
-# stall (PrefetchToDeviceIter.next blocking time)
+# ready-chunk queue depth observations, training-loop-visible input
+# stall (the 'io.next' spans of PrefetchToDeviceIter) and the bytes
+# io.stage_to_device handed to the device
 _INPUT = {
     'decode_ms': 0.0,
     'decoded_samples': 0,
@@ -244,14 +226,17 @@ _INPUT = {
     'queue_depth_obs': 0,
     'input_stall_ms': 0.0,
     'input_batches': 0,
+    'h2d_bytes': 0,
 }
 
 
 def add_input_stats(decode_ms=0.0, decoded_samples=0, decode_wait_ms=0.0,
-                    queue_depth=None, stall_ms=0.0, batches=0):
+                    queue_depth=None, stall_ms=0.0, batches=0,
+                    h2d_bytes=0):
     """Accumulate host input-pipeline counters (decode workers feed
     decode_ms/decoded_samples; the batch consumer feeds decode_wait_ms
-    + queue_depth; PrefetchToDeviceIter feeds stall_ms/batches)."""
+    + queue_depth; PrefetchToDeviceIter feeds stall_ms/batches;
+    stage_to_device feeds h2d_bytes)."""
     with _STATE['lock']:
         _INPUT['decode_ms'] += decode_ms
         _INPUT['decoded_samples'] += decoded_samples
@@ -261,6 +246,7 @@ def add_input_stats(decode_ms=0.0, decoded_samples=0, decode_wait_ms=0.0,
             _INPUT['queue_depth_obs'] += 1
         _INPUT['input_stall_ms'] += stall_ms
         _INPUT['input_batches'] += batches
+        _INPUT['h2d_bytes'] += h2d_bytes
 
 
 def input_stats():
@@ -379,8 +365,8 @@ def bucketing_stats():
 # elastic-checkpoint counters (elastic.CheckpointManager): snapshots
 # committed, payload bytes written, host-side materialize+write wall
 # time that ran on the background writer WHILE training continued
-# (ckpt_async_overlap_ms — an upper bound on the overlap, like
-# overlap_window_ms; 0 for synchronous/final commits), end-to-end
+# (ckpt_async_overlap_ms — an upper bound on the overlap; 0 for
+# synchronous/final commits), end-to-end
 # commit time, torn/incomplete checkpoints skipped at resume, restores
 # performed, cadence snapshots skipped because a write was in flight,
 # and injected/real write failures survived
@@ -914,7 +900,10 @@ def dump_profile():
     reference's per-op OprExecStat timing, §5.1); on the CPU backend
     the '/host:CPU' XLA runtime lane appears instead.  Python-frame
     spans ('$...' names) from the XLA trace are dropped — the host
-    story is this profiler's own spans."""
+    story is this profiler's own spans, which are in those lanes too
+    ('mx.<name>' TraceAnnotations, on the trace's clock): the pid-0
+    copies on the host's perf_counter clock are written only when no
+    XLA trace was taken."""
     events = [{'ph': 'M', 'name': 'process_name', 'pid': 0,
                'args': {'name': 'mxnet_tpu host spans'}}]
     # compiled-program cache + ZeRO comm/memory counters ride along
@@ -953,12 +942,14 @@ def dump_profile():
                    'args': delta_stats()})
     events.append({'ph': 'M', 'name': 'overlap', 'pid': 0,
                    'args': overlap_stats()})
-    with _STATE['lock']:
-        records = list(_STATE['records'])
-    for name, cat, ts, dur, tid in records:
-        events.append({'name': name, 'cat': cat, 'ph': 'X',
-                       'ts': ts, 'dur': dur, 'pid': 0, 'tid': tid})
-    events.extend(_collect_xla_lanes())
+    lanes = _collect_xla_lanes()
+    if not lanes:
+        with _STATE['lock']:
+            records = list(_STATE['records'])
+        for name, cat, ts, dur, tid in records:
+            events.append({'name': name, 'cat': cat, 'ph': 'X',
+                           'ts': ts, 'dur': dur, 'pid': 0, 'tid': tid})
+    events.extend(lanes)
     with open(_STATE['filename'], 'w') as f:
         json.dump({'traceEvents': events, 'displayTimeUnit': 'ms'}, f)
     return _STATE['filename']
@@ -1036,10 +1027,8 @@ def summary(print_out=True):
                  % (cm['bytes_reduce_scattered'],
                     cm['bytes_all_gathered'],
                     cm['optimizer_state_bytes_per_device']))
-    lines.append('  reduce_buckets_issued=%d overlap_window_ms=%.3f '
-                 'scan_fused_metric_steps=%d'
+    lines.append('  reduce_buckets_issued=%d scan_fused_metric_steps=%d'
                  % (cm['reduce_buckets_issued'],
-                    cm['overlap_window_ms'],
                     cm['scan_fused_metric_steps']))
     ip = input_stats()
     lines.append('  decode_ms=%.3f decoded_samples=%d '
@@ -1261,6 +1250,7 @@ def record(name, category, ts_us, dur_us):
 
 
 def clear():
+    _RING.clear()
     with _STATE['lock']:
         _STATE['records'].clear()
         for k in _COMM:
@@ -1301,24 +1291,100 @@ def clear():
         _SERVE_LAT_POS[0] = 0
 
 
-class scope(object):
-    """Context manager recording one span:
-    `with profiler.scope('forward'): ...`"""
+# The fixed span names at the layer boundaries of the training paths
+# the benchmark runs, with the layer of each as PERF.md section 3 and
+# BENCHMARK.json name it.  In a jax profiler trace each appears as
+# 'mx.' + name on the host plane.
+SPANS = {
+    'fit.step': 'entry (Module.fit, _fit_epochs)',
+    'fit.metric': 'metric fold (metric.EvalMetric.update_dict)',
+    'fit.callback': 'entry (Module.fit, _fit_epochs)',
+    'io.next': 'input (io.NDArrayIter, io.prefetch_to_device)',
+    'io.host_batch': 'input (io.NDArrayIter, io.prefetch_to_device)',
+    'io.stage': 'input (io.NDArrayIter, io.prefetch_to_device)',
+    'module.load_batch':
+        'executor group (DataParallelExecutorGroup.load_data_batch)',
+    'module.host_prep': 'optimizer (optimizer.FusedSGD)',
+    'module.bulk_step': 'entry (Module.bulk_step)',
+    'module.bulk_stack': 'entry (Module.bulk_step)',
+    'executor.dispatch':
+        'compiled step (executor.make_fused_multistep, fused train step)',
+}
 
-    def __init__(self, name, category='operator'):
+_RING_LEN = 4096
+_RING = {}      # name -> deque of (start, end, self_s, parent, step)
+_OPEN = threading.local()       # .stack: this thread's open spans
+
+
+class scope(object):
+    """Context manager marking one span of host time:
+    `with profiler.scope('forward'): ...`
+
+    The span is a jax TraceAnnotation 'mx.<name>' (a StepTraceAnnotation
+    when `step` is given), so an open jax profiler session records it on
+    the device trace's clock; with none open that costs a flag test.
+    On exit (start, end, self seconds, parent's name, step) on the
+    perf_counter clock joins the ring of its name (the newest
+    _RING_LEN; see span_tail), and `seconds` holds the duration.
+    Self time is the duration less what the spans opened inside it on
+    the same thread took; a span given no step inherits its parent's.
+    Under profiler_set_state('run') the span is also appended to the
+    Chrome-trace records.  A span never waits for the device."""
+
+    def __init__(self, name, category='operator', step=None):
         self.name = name
         self.category = category
+        self.step = step
+        self.seconds = 0.0
 
     def __enter__(self):
+        try:
+            stack = _OPEN.stack
+        except AttributeError:
+            stack = _OPEN.stack = []
+        self._parent = stack[-1] if stack else None
+        if self.step is None:
+            if self._parent is not None:
+                self.step = self._parent.step
+            self._ann = TraceAnnotation('mx.' + self.name)
+        else:
+            self._ann = StepTraceAnnotation('mx.' + self.name,
+                                            step_num=self.step)
+        self._child_s = 0.0
+        stack.append(self)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        _OPEN.stack.pop()
+        self.seconds = t1 - self._t0
+        parent = self._parent
+        if parent is not None:
+            parent._child_s += self.seconds
+        ring = _RING.get(self.name)
+        if ring is None:
+            ring = _RING.setdefault(self.name, deque(maxlen=_RING_LEN))
+        ring.append((self._t0, t1, self.seconds - self._child_s,
+                     parent.name if parent is not None else None,
+                     self.step))
         if _STATE['running']:
-            t1 = time.perf_counter()
             record(self.name, self.category,
-                   int(self._t0 * 1e6), int((t1 - self._t0) * 1e6))
+                   int(self._t0 * 1e6), int(self.seconds * 1e6))
         return False
+
+
+def span_tail(name, n):
+    """The newest n completed spans named `name`, oldest first, as
+    (start, end, self_seconds) on the perf_counter clock, or None when
+    fewer than n exist (since the last clear())."""
+    ring = _RING.get(name)
+    if ring is None or len(ring) < n:
+        return None
+    tail = list(ring.copy())    # copy() is atomic under the GIL
+    return [s[:3] for s in tail[len(tail) - n:]]
 
 
 if os.environ.get('MXNET_PROFILER_AUTOSTART', '0') == '1':

@@ -1,0 +1,351 @@
+"""The span primitive (profiler.scope / span_tail / SPANS) and the spans
+and named scopes the training paths carry: one clock with the jax
+profiler, a bounded ring that is always on, and no span that waits for
+the device."""
+import glob
+import os
+import re
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import profiler, sym
+
+BATCH, DIM, STEPS = 4, 10, 3
+
+
+class _Clock:
+    """Hand-set perf_counter, so that intervals are exact."""
+
+    def __init__(self, *ticks):
+        self.ticks = list(ticks)
+
+    def perf_counter(self):
+        return self.ticks.pop(0)
+
+
+def _ring(name):
+    return list(profiler._RING.get(name, ()))
+
+
+def test_nesting_gives_parent_and_self_time(monkeypatch):
+    profiler.clear()
+    # outer 0..10 holds a 1..4 and b 5..7; b holds c 5.5..6.5
+    monkeypatch.setattr(profiler, 'time',
+                        _Clock(0.0, 1.0, 4.0, 5.0, 5.5, 6.5, 7.0, 10.0))
+    with profiler.scope('t.outer') as outer:
+        with profiler.scope('t.a'):
+            pass
+        with profiler.scope('t.b'):
+            with profiler.scope('t.c'):
+                pass
+    assert outer.seconds == 10.0
+    assert _ring('t.outer') == [(0.0, 10.0, 5.0, None, None)]
+    assert _ring('t.a') == [(1.0, 4.0, 3.0, 't.outer', None)]
+    assert _ring('t.b') == [(5.0, 7.0, 1.0, 't.outer', None)]
+    assert _ring('t.c') == [(5.5, 6.5, 1.0, 't.b', None)]
+    assert profiler.span_tail('t.outer', 1) == [(0.0, 10.0, 5.0)]
+
+
+def test_step_number_is_inherited():
+    profiler.clear()
+    with profiler.scope('t.step', step=7):
+        with profiler.scope('t.child'):
+            with profiler.scope('t.grandchild'):
+                pass
+    with profiler.scope('t.child'):
+        pass
+    assert [r[4] for r in _ring('t.step')] == [7]
+    assert [r[4] for r in _ring('t.child')] == [7, None]
+    assert [r[4] for r in _ring('t.grandchild')] == [7]
+
+
+def test_span_tail_none_when_short_else_newest():
+    profiler.clear()
+    assert profiler.span_tail('t.tail', 1) is None
+    for _ in range(5):
+        with profiler.scope('t.tail'):
+            pass
+    assert profiler.span_tail('t.tail', 6) is None
+    assert profiler.span_tail('t.tail', 0) == []
+    ring = _ring('t.tail')
+    assert profiler.span_tail('t.tail', 2) == [r[:3] for r in ring[-2:]]
+    starts = [s for s, _, _ in profiler.span_tail('t.tail', 5)]
+    assert starts == sorted(starts)
+    profiler.clear()
+    assert profiler.span_tail('t.tail', 1) is None
+
+
+def test_ring_is_bounded():
+    profiler.clear()
+    for _ in range(profiler._RING_LEN + 10):
+        with profiler.scope('t.many'):
+            pass
+    assert len(profiler._RING['t.many']) == profiler._RING_LEN
+    assert profiler.span_tail('t.many', profiler._RING_LEN + 1) is None
+    assert len(profiler.span_tail('t.many', profiler._RING_LEN)) == \
+        profiler._RING_LEN
+
+
+def test_threads_do_not_share_a_stack():
+    profiler.clear()
+    inside, release = threading.Event(), threading.Event()
+
+    def other():
+        with profiler.scope('t.other'):
+            inside.set()
+            assert release.wait(10)
+
+    worker = threading.Thread(target=other)
+    worker.start()
+    assert inside.wait(10)
+    with profiler.scope('t.main'):      # opened while t.other is open
+        pass
+    release.set()
+    worker.join(10)
+    assert not worker.is_alive()
+    assert _ring('t.main')[0][3] is None
+    other_rec = _ring('t.other')[0]
+    assert other_rec[3] is None
+    # t.main is no child of t.other: nothing is taken off its self time
+    assert other_rec[2] == other_rec[1] - other_rec[0]
+
+
+def test_span_does_not_wait_for_the_device():
+    """A span around an un-awaited jitted call ends when the call has
+    been enqueued, long before the program has run."""
+    @jax.jit
+    def slow(x):
+        return jax.lax.fori_loop(0, 60, lambda _, a: jnp.tanh(a @ a), x)
+
+    x = jnp.eye(500, dtype=jnp.float32)
+    jax.block_until_ready(slow(x))      # compiled
+    profiler.clear()
+    with profiler.scope('t.enqueue') as span:
+        out = slow(x)
+    t0 = time.perf_counter()
+    jax.block_until_ready(out)
+    waited = time.perf_counter() - t0
+    assert waited > 0.05, 'the program is too fast to tell'
+    assert span.seconds < waited / 5
+
+
+def test_chrome_records_keep_their_names(tmp_path):
+    """Under profiler_set_state('run') a span is still a Chrome-trace
+    record under its own name; with no XLA trace taken it is dumped."""
+    import json
+    profiler.clear()
+    profiler.profiler_set_config(filename=str(tmp_path / 'p.json'))
+    profiler.profiler_set_state('run')
+    with profiler.scope('t.recorded', 'kvstore'):
+        pass
+    profiler.profiler_set_state('stop')
+    with profiler.scope('t.not_recorded'):
+        pass
+    with open(profiler.dump_profile()) as f:
+        events = json.load(f)['traceEvents']
+    spans = {e['name']: e for e in events if e['ph'] == 'X'}
+    assert set(spans) == {'t.recorded'}
+    assert spans['t.recorded']['cat'] == 'kvstore'
+    profiler.profiler_set_config(filename='profile.json')
+    profiler.clear()
+
+
+# ---------------------------------------------------------------------------
+# three fit steps and two bulk dispatches of a tiny network
+# ---------------------------------------------------------------------------
+
+def _net():
+    data = sym.Variable('data')
+    fc1 = sym.FullyConnected(data, name='fc1', num_hidden=8)
+    bn = sym.BatchNorm(fc1, name='bn1')
+    act = sym.Activation(bn, name='relu1', act_type='relu')
+    fc2 = sym.FullyConnected(act, name='fc2', num_hidden=2)
+    return sym.SoftmaxOutput(fc2, name='softmax')
+
+
+def _host_events(trace_dir):
+    """name -> [(start_ns, end_ns)] of the mx.* events on /host:CPU."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, 'plugins', 'profile', '*',
+                                   '*.xplane.pb'))
+    host, = [p for p in ProfileData.from_file(path).planes
+             if p.name == '/host:CPU']
+    events = {}
+    for line in host.lines:
+        for e in line.events:
+            if e.name.startswith('mx.'):
+                events.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns))
+    return events
+
+
+@pytest.fixture(scope='module')
+def fit_run(tmp_path_factory):
+    """One Module.fit epoch of STEPS batches behind the program's own
+    PrefetchToDeviceIter, inside a jax profiler session."""
+    rng = np.random.RandomState(0)
+    x = rng.rand(STEPS * BATCH, DIM).astype(np.float32)
+    y = rng.randint(0, 2, STEPS * BATCH).astype(np.float32)
+    train = mx.io.PrefetchToDeviceIter(
+        mx.io.NDArrayIter(x, y, batch_size=BATCH,
+                          label_name='softmax_label'),
+        size=2, device=mx.cpu(0))
+    mod = mx.mod.Module(_net(), context=mx.cpu(0))
+    trace_dir = str(tmp_path_factory.mktemp('trace'))
+    profiler.clear()
+    jax.profiler.start_trace(trace_dir)
+    try:
+        mod.fit(train, num_epoch=1, optimizer='sgd',
+                optimizer_params={'learning_rate': 0.1, 'momentum': 0.9},
+                batch_end_callback=lambda param: None)
+    finally:
+        jax.profiler.stop_trace()
+    return {'ring': {k: list(v) for k, v in profiler._RING.items()},
+            'input': profiler.input_stats(), 'mod': mod,
+            'bytes': x.nbytes + y.nbytes,
+            'events': _host_events(trace_dir)}
+
+
+@pytest.fixture(scope='module')
+def bulk_run():
+    """Two Module.bulk_step dispatches of K=2 staged batches."""
+    rng = np.random.RandomState(1)
+    mod = mx.mod.Module(_net(), context=mx.cpu(0))
+    mod.bind(data_shapes=[('data', (BATCH, DIM))],
+             label_shapes=[('softmax_label', (BATCH,))])
+    mod.init_params()
+    mod.init_optimizer(optimizer='sgd',
+                       optimizer_params={'learning_rate': 0.1})
+    batches = [mx.io.DataBatch(
+        data=[mx.nd.array(rng.rand(BATCH, DIM).astype(np.float32))],
+        label=[mx.nd.array(rng.randint(0, 2, BATCH).astype(np.float32))])
+        for _ in range(2)]
+    profiler.clear()
+    for _ in range(2):
+        mod.bulk_step(batches=batches)
+    return {k: list(v) for k, v in profiler._RING.items()}
+
+
+@pytest.mark.parametrize('name,count', [
+    ('fit.step', STEPS), ('io.next', STEPS), ('executor.dispatch', STEPS),
+    ('io.stage', STEPS), ('module.load_batch', STEPS),
+    ('module.host_prep', STEPS), ('fit.metric', STEPS),
+    ('fit.callback', STEPS),
+    ('io.host_batch', STEPS + 1),    # the last finds the iterator empty
+])
+def test_fit_steps_leave_one_span_each(fit_run, name, count):
+    assert name in profiler.SPANS
+    assert len(fit_run['ring'][name]) == count
+    # and the same spans are in the profiler's trace, as mx.<name>
+    assert len(fit_run['events']['mx.' + name]) == count
+
+
+@pytest.mark.parametrize('name,parent', [
+    ('fit.step', None), ('io.next', None), ('io.host_batch', 'io.next'),
+    ('io.stage', 'io.next'), ('module.load_batch', 'fit.step'),
+    ('module.host_prep', 'fit.step'), ('executor.dispatch', 'fit.step'),
+    ('fit.metric', 'fit.step'), ('fit.callback', 'fit.step'),
+])
+def test_fit_spans_know_their_parent_and_step(fit_run, name, parent):
+    records = fit_run['ring'][name]
+    assert {r[3] for r in records} == {parent}
+    if parent == 'fit.step' or name == 'fit.step':
+        assert [r[4] for r in records] == [1, 2, 3]
+
+
+def test_top_level_spans_tile_the_fit_loop(fit_run):
+    top = sorted(fit_run['ring']['fit.step'] + fit_run['ring']['io.next'])
+    wall = top[-1][1] - top[0][0]
+    covered = sum(end - start for start, end, *_ in top)
+    assert covered <= wall
+    assert covered >= 0.95 * wall
+    for before, after in zip(top, top[1:]):     # one after the other
+        assert before[1] <= after[0]
+    # a step's self time is what its five children leave
+    for start, end, self_s, _, step in fit_run['ring']['fit.step']:
+        children = sum(r[1] - r[0] for n in (
+            'module.load_batch', 'module.host_prep', 'executor.dispatch',
+            'fit.metric', 'fit.callback') for r in fit_run['ring'][n]
+            if r[4] == step)
+        assert self_s == pytest.approx(end - start - children, abs=1e-9)
+
+
+def test_h2d_bytes_are_the_batches_served(fit_run):
+    assert fit_run['input']['input_batches'] == STEPS
+    assert fit_run['input']['h2d_bytes'] == fit_run['bytes']
+
+
+def test_input_stall_is_the_sum_of_io_next(fit_run):
+    total_ms = sum((end - start) * 1e3
+                   for start, end, *_ in fit_run['ring']['io.next'])
+    assert fit_run['input']['input_stall_ms'] == pytest.approx(
+        total_ms, rel=1e-9)
+
+
+def test_spans_are_on_the_profilers_clock(fit_run):
+    """Read back from the .xplane.pb: the program's spans lie on
+    /host:CPU among the profiler's own events, nested by timestamps."""
+    events = fit_run['events']
+
+    def inside(inner, outer):
+        return all(any(o0 <= i0 and i1 <= o1 for o0, o1 in events[outer])
+                   for i0, i1 in events[inner])
+
+    assert len(events['mx.fit.step']) == STEPS
+    assert inside('mx.io.host_batch', 'mx.io.next')
+    assert inside('mx.io.stage', 'mx.io.next')
+    for child in ('mx.module.load_batch', 'mx.module.host_prep',
+                  'mx.executor.dispatch', 'mx.fit.metric',
+                  'mx.fit.callback'):
+        assert inside(child, 'mx.fit.step')
+    assert not inside('mx.io.next', 'mx.fit.step')
+    # the two clocks agree on every span's length to a fifth of a ms
+    ring = sorted(fit_run['ring']['fit.step'])
+    for (t0, t1), rec in zip(sorted(events['mx.fit.step']), ring):
+        assert (t1 - t0) * 1e-9 == pytest.approx(rec[1] - rec[0],
+                                                 abs=2e-4)
+
+
+def test_fused_step_carries_named_scopes(fit_run):
+    """HLO metadata of the compiled step: forward, its transpose (the
+    backward), the update, and one scope an operator node."""
+    mod = fit_run['mod']
+    ex, fu = mod._exec_group.executor, mod._fused_updater
+    names = ex._diff_names
+    moms, masters, lrs, wds = fu.host_prep(
+        [ex.arg_dict[n] for n in names], advance=False)
+    text = mod._fused_step.lower(
+        tuple(ex.arg_dict[n]._data for n in names), (),
+        tuple(ex.arg_dict[n]._data for n in ex._arg_names
+              if n not in set(names)),
+        tuple(ex.aux_dict[n]._data for n in ex._aux_names),
+        ex._key, moms, masters, lrs, wds).as_text(debug_info=True)
+    scopes = set(re.findall(r'jit\(multistep\)/([^"]*)/[a-z_]+"', text))
+    assert 'jvp(forward)/BatchNorm.bn1' in scopes
+    assert 'transpose(jvp(forward))/BatchNorm.bn1' in scopes
+    assert 'transpose(jvp(forward))/FullyConnected.fc1' in scopes
+    assert 'update' in scopes
+
+
+@pytest.mark.parametrize('name,parent,count', [
+    ('module.bulk_step', None, 2),
+    ('module.bulk_stack', 'module.bulk_step', 2),
+    ('module.host_prep', 'module.bulk_step', 2),
+    ('executor.dispatch', 'module.bulk_step', 2),
+    ('module.load_batch', 'module.bulk_step', 2),
+])
+def test_bulk_dispatches_leave_one_span_each(bulk_run, name, parent,
+                                             count):
+    assert name in profiler.SPANS
+    assert [r[3] for r in bulk_run[name]] == [parent] * count
+
+
+def test_every_fixed_span_name_was_seen(fit_run, bulk_run):
+    assert set(profiler.SPANS) <= set(fit_run['ring']) | set(bulk_run)
+    assert all(profiler.SPANS.values())
