@@ -14,10 +14,11 @@ import time
 from collections import deque, namedtuple, OrderedDict
 from itertools import chain
 
+import jax
 import numpy as np
 
-from . import ndarray as nd
 from . import profiler
+from .context import current_context
 from .ndarray import NDArray
 
 DataDesc = namedtuple('DataDesc', ['name', 'shape', 'dtype', 'layout'])
@@ -86,9 +87,35 @@ class DataIter:
         raise NotImplementedError
 
 
+# XLA's CPU runtime takes a host buffer as it is, without a copy, when
+# its first byte lies on this boundary; any other buffer it copies
+_HOST_ALIGN = 64
+
+
+def _aligned_empty(shape, dtype):
+    """An uninitialised C-contiguous array that starts on a _HOST_ALIGN
+    boundary (numpy's own large allocations start 16 bytes past one)."""
+    dtype = np.dtype(dtype)
+    nbytes = int(np.prod(shape, dtype=np.int64)) * dtype.itemsize
+    raw = np.empty(nbytes + _HOST_ALIGN, np.uint8)
+    start = -raw.ctypes.data % _HOST_ALIGN
+    return raw[start:start + nbytes].view(dtype).reshape(shape)
+
+
+def _served_dtype(dtype):
+    """The dtype a source's batches are served in: float64 as float32,
+    int64 as int32 (what nd.array makes of them), any other as it is."""
+    if dtype == np.float64:
+        return np.dtype(np.float32)
+    return jax.dtypes.canonicalize_dtype(dtype)
+
+
 def _init_data(data, allow_empty, default_name):
     """Normalize input data to list of (name, numpy array)
-    (reference io.py _init_data)."""
+    (reference io.py _init_data).  As in the reference the arrays are
+    copied: the iterator's rows are its own, in the dtype they are served
+    in, aligned and read-only, so a batch can be a view of them that
+    nothing the user writes afterwards reaches."""
     assert (data is not None) or allow_empty
     if data is None:
         data = []
@@ -108,16 +135,26 @@ def _init_data(data, allow_empty, default_name):
                         'them or dict with them as values')
     out = OrderedDict()
     for k, v in data.items():
-        if isinstance(v, NDArray):
-            out[k] = v.asnumpy()
-        else:
-            out[k] = np.asarray(v)
+        src = v.asnumpy() if isinstance(v, NDArray) else np.asarray(v)
+        own = _aligned_empty(src.shape, _served_dtype(src.dtype))
+        np.copyto(own, src, casting='unsafe')
+        own.flags.writeable = False
+        out[k] = own
     return list(out.items())
 
 
 class NDArrayIter(DataIter):
     """Iterator over in-memory arrays with shuffle/pad/discard handling
-    (reference io.py NDArrayIter)."""
+    (reference io.py NDArrayIter).
+
+    The arrays are copied once, when the iterator is made (_init_data).
+    A batch is then made in one pass over its rows at most: in order and
+    inside the data it is a view of that copy, which the CPU device
+    takes as it is and a stager (stage_to_device) sends to its device in
+    one transfer; a batch that wraps is two runs joined, a shuffled one
+    a gather, one copy each.  profiler.input_stats() counts the bytes so
+    copied (host_copy_bytes) and the batches that needed none
+    (view_batches)."""
 
     def __init__(self, data, label=None, batch_size=1, shuffle=False,
                  last_batch_handle='pad', data_name='data',
@@ -168,8 +205,13 @@ class NDArrayIter(DataIter):
 
     def next(self):
         if self.iter_next():
-            return DataBatch(data=self.getdata(), label=self.getlabel(),
-                             pad=self.getpad(), index=None)
+            data, copied = self._getdata(self.data)
+            label, more = self._getdata(self.label)
+            copied += more
+            profiler.add_input_stats(host_copy_bytes=copied,
+                                     view_batches=int(not copied))
+            return DataBatch(data=data, label=label, pad=self.getpad(),
+                             index=None)
         raise StopIteration
 
     def _overrun(self):
@@ -177,21 +219,44 @@ class NDArrayIter(DataIter):
         return max(0, self.cursor + self.batch_size - self.num_data)
 
     def _getdata(self, data_source):
+        """The current batch of each array of `data_source` on the current
+        context's device, and the bytes copied on the host to make them.
+        A batch's rows are read at most once: a view where they are one
+        run (no shuffle, no overrun), else one copy into an aligned
+        buffer, two runs joined or a gather."""
         assert self.cursor < self.num_data, 'DataIter needs reset.'
+        lo, hi = self.cursor, self.cursor + self.batch_size
         overrun = self._overrun()
-        sel = self.idx[self.cursor:self.cursor + self.batch_size]
-        if overrun:
+        if self.shuffle:
             # Wrap around: pad the batch with rows from the epoch start.
-            sel = np.concatenate([sel, self.idx[:overrun]])
-        return [nd.array(arr[sel], dtype=arr.dtype
-                         if arr.dtype != np.float64 else np.float32)
-                for _, arr in data_source]
+            sel = np.concatenate([self.idx[lo:hi], self.idx[:overrun]])
+        ctx = current_context()
+        device = ctx.jax_device()
+        out, copied = [], 0
+        for _, arr in data_source:
+            if not (self.shuffle or overrun):
+                rows = arr[lo:hi]
+            else:
+                rows = _aligned_empty(
+                    (self.batch_size,) + arr.shape[1:], arr.dtype)
+                if self.shuffle:
+                    np.take(arr, sel, axis=0, out=rows, mode='clip')
+                else:
+                    np.concatenate([arr[lo:hi], arr[:overrun]], out=rows)
+                copied += rows.nbytes
+            if device.platform == 'cpu' and rows.ctypes.data % _HOST_ALIGN:
+                copied += rows.nbytes       # the runtime's copy
+            # straight to the context's device: aligned rows become a
+            # CPU-device array without a copy, and no other device is
+            # visited (jnp.asarray would go by jax's default device)
+            out.append(NDArray(jax.device_put(rows, device), ctx))
+        return out, copied
 
     def getdata(self):
-        return self._getdata(self.data)
+        return self._getdata(self.data)[0]
 
     def getlabel(self):
-        return self._getdata(self.label)
+        return self._getdata(self.label)[0]
 
     def getpad(self):
         if self.last_batch_handle == 'pad':
@@ -492,19 +557,21 @@ def stage_to_device(arrays, device=None, mesh=None):
     a Context or a raw jax device; with `mesh` the arrays are
     batch-sharded over it instead.  The bytes handed to either count
     as the profiler's h2d_bytes."""
-    import jax
     if hasattr(device, 'jax_device'):
         device = device.jax_device()
     out = []
     put_bytes = 0
     for a in arrays:
-        data = a._data if isinstance(a, NDArray) else \
-            jax.numpy.asarray(np.asarray(a))
+        # host memory (a CPU-device array, a numpy array) goes to its
+        # target in the one put below, by way of no other device
+        data = a._data if isinstance(a, NDArray) else np.asarray(a)
         if mesh is not None:
             from .parallel import mesh as pmesh
             data = pmesh.shard_batch(mesh, data)
         elif device is not None:
             data = jax.device_put(data, device)
+        else:
+            data = jax.numpy.asarray(data)
         if mesh is not None or device is not None:
             put_bytes += data.nbytes
         out.append(data)

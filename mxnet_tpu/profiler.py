@@ -216,8 +216,9 @@ def embed_stats():
 # host input-pipeline counters (parallel decode pool + device prefetch):
 # decode work done by the workers, time the consumer waited on the pool,
 # ready-chunk queue depth observations, training-loop-visible input
-# stall (the 'io.next' spans of PrefetchToDeviceIter) and the bytes
-# io.stage_to_device handed to the device
+# stall (the 'io.next' spans of PrefetchToDeviceIter), the bytes
+# io.stage_to_device handed to the device, and what NDArrayIter copied
+# on the host to make its batches (none for a batch served as a view)
 _INPUT = {
     'decode_ms': 0.0,
     'decoded_samples': 0,
@@ -227,16 +228,20 @@ _INPUT = {
     'input_stall_ms': 0.0,
     'input_batches': 0,
     'h2d_bytes': 0,
+    'host_copy_bytes': 0,
+    'view_batches': 0,
 }
 
 
 def add_input_stats(decode_ms=0.0, decoded_samples=0, decode_wait_ms=0.0,
                     queue_depth=None, stall_ms=0.0, batches=0,
-                    h2d_bytes=0):
+                    h2d_bytes=0, host_copy_bytes=0, view_batches=0):
     """Accumulate host input-pipeline counters (decode workers feed
     decode_ms/decoded_samples; the batch consumer feeds decode_wait_ms
     + queue_depth; PrefetchToDeviceIter feeds stall_ms/batches;
-    stage_to_device feeds h2d_bytes)."""
+    stage_to_device feeds h2d_bytes; NDArrayIter feeds host_copy_bytes,
+    the bytes it read and wrote again on the host to make a batch, and
+    view_batches, the batches it served without such a copy)."""
     with _STATE['lock']:
         _INPUT['decode_ms'] += decode_ms
         _INPUT['decoded_samples'] += decoded_samples
@@ -247,6 +252,8 @@ def add_input_stats(decode_ms=0.0, decoded_samples=0, decode_wait_ms=0.0,
         _INPUT['input_stall_ms'] += stall_ms
         _INPUT['input_batches'] += batches
         _INPUT['h2d_bytes'] += h2d_bytes
+        _INPUT['host_copy_bytes'] += host_copy_bytes
+        _INPUT['view_batches'] += view_batches
 
 
 def input_stats():

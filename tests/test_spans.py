@@ -281,6 +281,34 @@ def test_h2d_bytes_are_the_batches_served(fit_run):
     assert fit_run['input']['h2d_bytes'] == fit_run['bytes']
 
 
+def test_each_batch_is_staged_exactly_once():
+    """Step by step with an NDArrayIter behind PrefetchToDeviceIter: the
+    stager runs `size` batches ahead of those served, and h2d_bytes is one
+    batch's bytes for each batch staged so far, never twice."""
+    size, batches = 2, 5
+    rng = np.random.RandomState(2)
+    x = rng.rand(batches * BATCH, DIM).astype(np.float32)
+    y = rng.randint(0, 2, batches * BATCH).astype(np.float32)
+    per_batch = BATCH * (DIM + 1) * 4
+    train = mx.io.PrefetchToDeviceIter(
+        mx.io.NDArrayIter(x, y, batch_size=BATCH), size=size,
+        device=mx.cpu(1))
+    profiler.clear()
+    for served in range(1, batches + 1):
+        batch = train.next()
+        stats = profiler.input_stats()
+        assert stats['input_batches'] == served
+        assert stats['h2d_bytes'] == min(served + size, batches) * per_batch
+        lo = (served - 1) * BATCH
+        for arr, rows in ((batch.data[0], x[lo:lo + BATCH]),
+                          (batch.label[0], y[lo:lo + BATCH])):
+            assert arr._data.devices() == {mx.cpu(1).jax_device()}
+            np.testing.assert_array_equal(arr.asnumpy(), rows)
+    with pytest.raises(StopIteration):
+        train.next()
+    assert profiler.input_stats()['h2d_bytes'] == batches * per_batch
+
+
 def test_input_stall_is_the_sum_of_io_next(fit_run):
     total_ms = sum((end - start) * 1e3
                    for start, end, *_ in fit_run['ring']['io.next'])
