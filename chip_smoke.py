@@ -34,9 +34,9 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 SEED = 20260926
 # dispatches of a new program before the steady state: the first
-# compiles it, and the second compiles it once more because the donated
-# outputs it is fed are committed to their device where the freshly
-# initialised weights were not (jax keys executables on that)
+# compiles it; a program whose weights were written past Module
+# (executor.arg_dict[k][:] = v leaves them uncommitted) compiles once
+# more at the second, when it is fed its own donated, committed outputs
 WARM = 2
 
 

@@ -128,7 +128,7 @@ def graph_signature(symbol, ctx, arg_dict, aux_dict, grad_req,
     counters differ between two builds of the same net; the compiled
     math is name-free): variables appear as their positional role in
     the arg/aux lists with shape+dtype+grad_req, ops as (op, sorted
-    attrs, input wiring by topo index, ctx_group)."""
+    attrs, input wiring by topo index, ctx_group, __force_mirroring__)."""
     topo = symbol._topo()
     index = {id(n): i for i, n in enumerate(topo)}
     arg_pos = {n: i for i, n in enumerate(arg_dict)}
@@ -152,7 +152,8 @@ def graph_signature(symbol, ctx, arg_dict, aux_dict, grad_req,
                           for k, v in n.attrs.items()))
             ins = tuple((index[id(s)], oi) for s, oi in n.inputs)
             nodes.append(('op', n.op.name, attrs, ins,
-                          n.user_attrs.get('ctx_group')))
+                          n.user_attrs.get('ctx_group'),
+                          n.user_attrs.get('__force_mirroring__')))
     outs = tuple((index[id(n)], oi) for n, oi in symbol._outputs)
     groups = tuple(sorted((k, str(v))
                    for k, v in (group2ctx or {}).items()))

@@ -126,6 +126,63 @@ def _layout_mode(op, attrs, vals):
     return None
 
 
+def _mirror_segments(topo, node_index, out_entries, aux_pos, skip):
+    """Recomputation marked on the nodes, as the reference's
+    `__force_mirroring__` is (graph_executor.cc:243): every maximal run
+    of marked operator nodes in topological order (variables between
+    them do not break a run; nodes in `skip` are never part of one) is
+    one segment, computed under jax.checkpoint in the training pass.
+    Returns {node idx: None, or for a run's last node its segment}:
+    'nodes' in order, 'reads' the outside entries (node idx, output)
+    it consumes, 'kept' its entries that the rest of the graph or the
+    outputs consume, 'aux' the positions of aux states it advances."""
+    def marked(ni):
+        n = topo[ni]
+        return n.op is not None and ni not in skip and str(
+            n.user_attrs.get('__force_mirroring__', '')).lower() in (
+                'true', '1')
+
+    runs, run = [], []
+    for ni, n in enumerate(topo):
+        if n.op is None:
+            continue
+        if marked(ni):
+            run.append(ni)
+        elif run:
+            runs.append(run)
+            run = []
+    if run:
+        runs.append(run)
+    consumers = {}
+    for ni, n in enumerate(topo):
+        for src, oi in n.inputs:
+            consumers.setdefault((node_index[id(src)], oi), []).append(ni)
+    out = {}
+    for nodes in runs:
+        if len(nodes) < 2:
+            continue
+        inside = set(nodes)
+        reads, kept, aux = [], [], []
+        for ni in nodes:
+            n = topo[ni]
+            for src, oi in n.inputs:
+                key = (node_index[id(src)], oi)
+                if key[0] not in inside and key not in reads:
+                    reads.append(key)
+                if n.op.mutable_aux and src.op is None and \
+                        src.name in aux_pos:
+                    aux.append(aux_pos[src.name])
+            for oi in range(n.op.num_outputs(n.attrs)):
+                if (ni, oi) in out_entries or any(
+                        c not in inside
+                        for c in consumers.get((ni, oi), ())):
+                    kept.append((ni, oi))
+        seg = {'nodes': nodes, 'reads': reads, 'kept': kept, 'aux': aux}
+        out.update({ni: None for ni in nodes})
+        out[nodes[-1]] = seg
+    return out
+
+
 class Executor:
     def __init__(self, symbol, ctx, arg_dict, grad_dict, aux_dict,
                  grad_req_dict, group2ctx=None):
@@ -290,6 +347,17 @@ class Executor:
         # the process-wide cache for the entry's lifetime)
         group2dev = self._group2dev
         remat_mode = self._remat_mode
+        remat_last = _mirror_segments(topo, node_index, out_entries,
+                                      aux_pos, set(split_bn) |
+                                      set(split_conv))
+        self._mirror_segments = [s for s in remat_last.values() if s]
+        # nodes whose aux states are counters kept on the device (the
+        # op declares fold_aux): profiler.fold_device_counters() reads
+        # them through counter_aux() when it is asked
+        self._counter_nodes = [
+            n for n in topo if n.op is not None and n.op.fold_aux]
+        if self._counter_nodes:
+            profiler.watch_device_counters(self)
 
         def run_graph(arg_vals, aux_vals, rng, is_train, collect_all=False):
             """Evaluate the DAG; returns (outputs, new_aux_tuple), plus
@@ -301,14 +369,16 @@ class Executor:
             # so the β-split is disabled for that mode
             do_split = not collect_all
             split_beta = {}                # BN node idx -> β value
-            for ni, node in enumerate(topo):
+
+            def run_node(ni, results, layouts, new_aux):
+                node = topo[ni]
                 if node.op is None:
                     if node.name in arg_pos:
                         results[ni] = [arg_vals[arg_pos[node.name]]]
                     else:
                         results[ni] = [new_aux[aux_pos[node.name]]]
                     layouts[ni] = ['NCHW']
-                    continue
+                    return
                 op = node.op
                 n_aux = op.num_aux
                 in_entries = node.inputs
@@ -396,6 +466,44 @@ class Executor:
                             in_entries[len(vals) - n_aux:], updated):
                         if src.op is None and src.name in aux_pos:
                             new_aux[aux_pos[src.name]] = newv
+
+            def run_segment(seg):
+                """A run of __force_mirroring__ nodes as one
+                jax.checkpoint: what it reads from outside goes in, what
+                the rest of the graph reads of it (and the aux states it
+                advances) comes out and is kept; everything between is
+                recomputed in the backward pass."""
+                seg_layouts = {}
+
+                def inside(ext_vals):
+                    loc_r, loc_l = list(results), list(layouts)
+                    loc_aux = list(new_aux)
+                    for (src, oi), v in zip(seg['reads'], ext_vals):
+                        loc_r[src] = list(loc_r[src])
+                        loc_r[src][oi] = v
+                    for ni in seg['nodes']:
+                        run_node(ni, loc_r, loc_l, loc_aux)
+                        seg_layouts[ni] = loc_l[ni]
+                    return ([loc_r[ni][oi] for ni, oi in seg['kept']],
+                            [loc_aux[p] for p in seg['aux']])
+
+                kept, aux_new = jax.checkpoint(inside)(
+                    [results[src][oi] for src, oi in seg['reads']])
+                for ni in seg['nodes']:
+                    layouts[ni] = seg_layouts[ni]
+                    results[ni] = [None] * len(layouts[ni])
+                for (ni, oi), v in zip(seg['kept'], kept):
+                    results[ni][oi] = v
+                for p, v in zip(seg['aux'], aux_new):
+                    new_aux[p] = v
+
+            mirrored = remat_last if is_train and not collect_all else {}
+            for ni in range(len(topo)):
+                if ni in mirrored:
+                    if mirrored[ni] is not None:    # the run's last node
+                        run_segment(mirrored[ni])
+                else:
+                    run_node(ni, results, layouts, new_aux)
             outputs = tuple(_to_nchw(results[ni][oi], layouts[ni][oi])
                             for ni, oi in out_entries)
             if collect_all:
@@ -492,6 +600,18 @@ class Executor:
             run_graph(arg_vals, aux_vals, rng, False)
         self.raw_forward_train = lambda arg_vals, aux_vals, rng: \
             run_graph(arg_vals, aux_vals, rng, True)
+
+    def counter_aux(self):
+        """[(aux names, their arrays, attrs, the op's fold_aux)] of the
+        nodes that keep counters on the device (see
+        profiler.watch_device_counters).  Reading waits for the
+        dispatches in flight."""
+        out = []
+        for n in self._counter_nodes:
+            names = [src.name for src, _ in n.inputs[-n.op.num_aux:]]
+            out.append((names, [np.asarray(self.aux_dict[a]._data)
+                                for a in names], n.attrs, n.op.fold_aux))
+        return out
 
     # ------------------------------------------------------------------
     def sparse_diff_positions(self):

@@ -103,6 +103,19 @@ class DataParallelExecutorGroup:
         for arr in self.executor.grad_dict.values():
             arr._data = jax.device_put(arr._data, repl)
 
+    def _commit_params(self):
+        """One device: commit weights and aux states to it, as the
+        compiled step's donated outputs are.  copy_params_from leaves
+        them uncommitted, which gives the first step another jit
+        signature than the second: the whole program compiled twice."""
+        dev = self.contexts[0].jax_device()
+        input_names = set(self.data_names) | set(self.label_names)
+        for name, arr in self.executor.arg_dict.items():
+            if name not in input_names:
+                arr._data = jax.device_put(arr._data, dev)
+        for arr in self.executor.aux_dict.values():
+            arr._data = jax.device_put(arr._data, dev)
+
     def _place_input(self, name, value):
         dst = self.executor.arg_dict[name]
         data = value._data if isinstance(value, nd.NDArray) else \
@@ -171,6 +184,8 @@ class DataParallelExecutorGroup:
              if k in self.executor.aux_dict})
         if self.mesh is not None:
             self._apply_shardings()
+        else:
+            self._commit_params()
 
     def reshape(self, data_shapes, label_shapes=None):
         """Rebind to new input shapes (reference executor_group.py reshape):

@@ -8,6 +8,7 @@ public API and KVStore interplay (update_on_kvstore, optimizer state
 save/load) match the reference.
 """
 import logging
+import weakref
 
 from .. import context as ctx_mod
 from .. import initializer as init_mod
@@ -568,8 +569,11 @@ class Module(BaseModule):
                 (plan.key, self._mesh_fp()) if plan is not None
                 else None,
                 fold.key if fold is not None else None, 'lrstack')
-        cache_key = ((ex, fu, 'stacked', k, str(scan_dtype))
-                     if stacked else (ex, fu, 'repeat', k)) + (fkey,)
+        # weak: the key outlives a released executor and updater, and
+        # must not keep their weights and optimizer state on the device
+        cache_key = (weakref.ref(ex), weakref.ref(fu)) + (
+            ('stacked', k, str(scan_dtype)) if stacked
+            else ('repeat', k)) + (fkey,)
         if getattr(self, '_bulk_cache_key', None) != cache_key:
             mesh = eg.mesh
             gr = (lambda grads: plan.apply(grads, mesh)) \
@@ -762,6 +766,8 @@ class Module(BaseModule):
                     % (getattr(eval_metric, 'name', eval_metric),))
         scan_names = [n for n in eg.data_names + eg.label_names
                       if n in ex.arg_dict and n not in set(fnames)]
+        if scan_dtype is not None:
+            self._check_scan_dtype_holds_ids(ex, scan_dtype)
         scan_stacks = None
         if batches is not None:
             if k == 1:
@@ -824,6 +830,40 @@ class Module(BaseModule):
         self._note_step_counters(
             k, metric_steps=k if fold is not None else 0)
         self._params_dirty = True
+
+    def _check_scan_dtype_holds_ids(self, ex, scan_dtype):
+        """Token ids are carried as floats: a data input that feeds an
+        Embedding cannot be staged in a type with fewer mantissa bits
+        than its bound type (bfloat16 is exact up to 256 only) without
+        training on other ids than were given."""
+        import jax.numpy as jnp
+
+        def exact_bits(dtype):
+            dtype = jnp.dtype(dtype)
+            if jnp.issubdtype(dtype, jnp.floating):
+                return jnp.finfo(dtype).nmant + 1
+            return jnp.iinfo(dtype).bits
+
+        data_names = tuple(self._exec_group.data_names)
+        cached = getattr(self, '_embedding_id_inputs', None)
+        if cached is None or cached[0] != data_names:
+            # once a binding: bulk_step calls this at every dispatch
+            cached = self._embedding_id_inputs = (data_names, [
+                (node.inputs[0][0].name, node.name)
+                for node in self._symbol._topo()
+                if node.op is not None and node.op.name == 'Embedding'
+                and node.inputs[0][0].op is None
+                and node.inputs[0][0].name in data_names])
+        for data_name, node_name in cached[1]:
+            bound = ex.arg_dict[data_name].dtype
+            if exact_bits(scan_dtype) < exact_bits(bound):
+                raise MXNetError(
+                    'bulk_step: scan_dtype %s is narrower than %s, the '
+                    'bound type of %r, which feeds Embedding %r: ids '
+                    'above %d are not exact in it; stage the ids in %s'
+                    % (jnp.dtype(scan_dtype).name, jnp.dtype(bound).name,
+                       data_name, node_name, 2 ** exact_bits(scan_dtype),
+                       jnp.dtype(bound).name))
 
     def _single_step(self, data_batch):
         self.forward_backward(data_batch)

@@ -13,6 +13,7 @@ from . import extra
 from . import rnn_op
 from . import contrib_ops
 from . import optimizer_ops
+from . import lm
 
 from .registry import get, exists, list_ops, register, OpDef, OpContext
 

@@ -1,0 +1,625 @@
+"""Operators of hybrid sparse language models (Qwen3-Next's layer kinds).
+
+Every operator takes tokens as rows, `(N, C)` with `N = sequences x
+seq_len`, as `Embedding` gives them and `FullyConnected` takes them; the
+operators that look along a sequence carry `seq_len` as an attribute and
+fold the rows to `(N / seq_len, seq_len, ...)` themselves.  All are plain
+XLA: pure JAX functions differentiated by jax.vjp inside the one compiled
+step, like every other operator of the registry.
+
+  RMSNorm          x * rsqrt(mean x^2 + eps) * gamma, or * (1 + gamma)
+  GatedAttention   per-head q/k RMS norm, partial rotary, grouped-head
+                   causal softmax attention in blocks of query rows, and
+                   the sigmoid gate on the output
+  CausalConv1D     depthwise causal convolution along the sequence
+  GatedDeltaRule   the gated delta rule in chunks (WY form): a unit
+                   lower triangular solve inside a chunk, the state
+                   carried between chunks by lax.scan
+  SparseMoE        top-k routing over all experts, the held experts'
+                   part of the result by a grouped product over the
+                   sorted (token, expert) pairs; nothing is dropped
+"""
+import functools
+import math
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .registry import register, asbool, asfloat, asint
+
+F32 = jnp.float32
+HIGHEST = lax.Precision.HIGHEST
+ATTN_BLOCK = 512            # query rows a block of GatedAttention
+CHUNK = 64                  # tokens a chunk of GatedDeltaRule
+KEY_HEADS_PER_BLOCK = 4     # key heads GatedDeltaRule takes at a time
+EXPERT_TILE = 256           # rows a tile of SparseMoE's grouped product
+
+
+def _data_dtype(in_dtypes):
+    return np.dtype(in_dtypes[0]) if in_dtypes[0] is not None \
+        else np.dtype(np.float32)
+
+
+def _infer_dtype(f32_inputs=(), int_aux=0):
+    """Inputs follow the data's type, except the small vectors named in
+    `f32_inputs` (norm scales, decay rates), which stay float32 under a
+    low-precision graph as BatchNorm's do; trailing aux are int32."""
+    def infer(attrs, in_dtypes):
+        d = _data_dtype(in_dtypes)
+        n = len(in_dtypes) - int_aux
+        ins = [np.dtype(np.float32) if i in f32_inputs else d
+               for i in range(n)] + [np.dtype(np.int32)] * int_aux
+        return ins, [d]
+    return infer
+
+
+def _fold(x, seq_len):
+    """(N, ...) rows of tokens -> (N / seq_len, seq_len, ...)."""
+    if x.shape[0] % seq_len:
+        raise ValueError('%d rows are no whole number of sequences of '
+                         '%d tokens' % (x.shape[0], seq_len))
+    return x.reshape((x.shape[0] // seq_len, seq_len) + x.shape[1:])
+
+
+# ---------------------------------------------------------------------------
+# RMSNorm
+# ---------------------------------------------------------------------------
+
+def rms_norm(x, gamma, eps, zero_centered=False):
+    """Over the last axis, in float32; the result in x's type."""
+    xf = x.astype(F32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    w = gamma.astype(F32)
+    return (y * (1.0 + w if zero_centered else w)).astype(x.dtype)
+
+
+def _rms_infer_shape(attrs, in_shapes):
+    if in_shapes[0] is not None and in_shapes[1] is None \
+            and in_shapes[0][-1] != 0:
+        in_shapes[1] = (in_shapes[0][-1],)
+    return in_shapes
+
+
+@register('RMSNorm', input_names=('data', 'gamma'),
+          infer_shape=_rms_infer_shape, infer_dtype=_infer_dtype((1,)),
+          hint='rmsnorm')
+def _rms_norm(attrs, data, gamma):
+    return rms_norm(data, gamma, asfloat(attrs.get('eps', 1e-6)),
+                    asbool(attrs.get('zero_centered', False)))
+
+
+# ---------------------------------------------------------------------------
+# GatedAttention
+# ---------------------------------------------------------------------------
+
+def rotary(x, rotary_dim, theta):
+    """Rotate-half rotary embedding on the first `rotary_dim` of the
+    head dimension of x (B, T, heads, head_dim); position = index in
+    the sequence."""
+    t = x.shape[1]
+    half = rotary_dim // 2
+    inv_freq = 1.0 / (theta ** (np.arange(0, rotary_dim, 2,
+                                          dtype=np.float64) / rotary_dim))
+    ang = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
+    cos = jnp.asarray(np.cos(ang), F32)[None, :, None, :]
+    sin = jnp.asarray(np.sin(ang), F32)[None, :, None, :]
+    x1, x2, rest = x[..., :half], x[..., half:rotary_dim], \
+        x[..., rotary_dim:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                            rest], axis=-1)
+
+
+def _block_scores(qb, kb, scale, first_row):
+    """Masked scores (kv, group, rows, keys) of a block of query rows
+    against the keys it can see, in float32."""
+    s = jnp.einsum('qghd,kgd->ghqk', qb, kb,
+                   preferred_element_type=F32) * scale
+    rows = first_row + jnp.arange(qb.shape[0])[:, None]
+    return jnp.where(jnp.arange(kb.shape[0])[None, :] <= rows, s, -jnp.inf)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _attention_block(scale, first_row, qb, kb, vb):
+    return _attention_block_fwd(scale, first_row, qb, kb, vb)[0]
+
+
+def _attention_block_fwd(scale, first_row, qb, kb, vb):
+    s = _block_scores(qb, kb, scale, first_row)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.exp(s - lse[..., None]).astype(vb.dtype)
+    o = jnp.einsum('ghqk,kgd->qghd', p, vb,
+                   preferred_element_type=F32).astype(qb.dtype)
+    return o, (qb, kb, vb, o, lse)
+
+
+def _attention_block_bwd(scale, first_row, res, do):
+    """The scores are made again, not kept; the softmax's row term is
+    sum(dO * O) over the head (as in flash attention), not a product
+    along the keys."""
+    qb, kb, vb, o, lse = res
+    p = jnp.exp(_block_scores(qb, kb, scale, first_row) - lse[..., None])
+    dv = jnp.einsum('ghqk,qghd->kgd', p.astype(do.dtype), do,
+                    preferred_element_type=F32)
+    dp = jnp.einsum('qghd,kgd->ghqk', do, vb, preferred_element_type=F32)
+    row = jnp.sum(do.astype(F32) * o.astype(F32), axis=-1)   # (q, g, h)
+    ds = (p * (dp - jnp.moveaxis(row, 0, -1)[..., None]) * scale
+          ).astype(qb.dtype)
+    dq = jnp.einsum('ghqk,kgd->qghd', ds, kb, preferred_element_type=F32)
+    dk = jnp.einsum('ghqk,qghd->kgd', ds, qb, preferred_element_type=F32)
+    return dq.astype(qb.dtype), dk.astype(kb.dtype), dv.astype(vb.dtype)
+
+
+_attention_block.defvjp(_attention_block_fwd, _attention_block_bwd)
+
+
+def causal_attention(q, k, v, scale, block_q=ATTN_BLOCK):
+    """softmax(q k^T * scale + causal) v with grouped heads: q
+    (B, T, kv, group, d), k and v (B, T, kv, d).  One sequence at a
+    time and query rows in blocks, each block against the keys it can
+    see; a block keeps its output and its rows' log-sum-exp and makes
+    its scores again in the backward pass, so no T x T score matrix is
+    ever stored."""
+    t = q.shape[1]
+    block_q = min(block_q, t)
+
+    def one_sequence(args):
+        qs, ks, vs = args
+        return jnp.concatenate([
+            _attention_block(scale, r0, qs[r0:r0 + block_q],
+                             ks[:r0 + block_q], vs[:r0 + block_q])
+            for r0 in range(0, t, block_q)], axis=0)
+
+    return lax.map(one_sequence, (q, k, v))
+
+
+def _attn_infer_shape(attrs, in_shapes):
+    d = asint(attrs['head_dim'])
+    for i in (3, 4):
+        if in_shapes[i] is None:
+            in_shapes[i] = (d,)
+    return in_shapes
+
+
+@register('GatedAttention',
+          input_names=('query_gate', 'key', 'value', 'q_norm_gamma',
+                       'k_norm_gamma'),
+          infer_shape=_attn_infer_shape,
+          infer_dtype=_infer_dtype((3, 4)), hint='gatedattention')
+def _gated_attention(attrs, qg, k, v, q_gamma, k_gamma):
+    heads, kv = asint(attrs['num_heads']), asint(attrs['num_kv_heads'])
+    d, seq_len = asint(attrs['head_dim']), asint(attrs['seq_len'])
+    rotary_dim = asint(attrs.get('rotary_dim', d))
+    theta = asfloat(attrs.get('rope_theta', 10000.0))
+    eps = asfloat(attrs.get('eps', 1e-6))
+    if heads % kv:
+        raise ValueError('%d query heads over %d key-value heads'
+                         % (heads, kv))
+    n = qg.shape[0]
+    qg = qg.reshape(n, heads, 2 * d)        # [q, gate] split per head
+    q, gate = qg[..., :d], qg[..., d:].reshape(n, heads * d)
+    q = rms_norm(q, q_gamma, eps, zero_centered=True)
+    k = rms_norm(k.reshape(n, kv, d), k_gamma, eps, zero_centered=True)
+    q = rotary(_fold(q, seq_len).astype(F32), rotary_dim, theta)
+    k = rotary(_fold(k, seq_len).astype(F32), rotary_dim, theta)
+    b = q.shape[0]
+    o = causal_attention(
+        q.astype(v.dtype).reshape(b, seq_len, kv, heads // kv, d),
+        k.astype(v.dtype), _fold(v.reshape(n, kv, d), seq_len),
+        1.0 / math.sqrt(d))
+    o = o.reshape(n, heads * d)
+    return (o.astype(F32) * jax.nn.sigmoid(gate.astype(F32))
+            ).astype(o.dtype)
+
+
+# ---------------------------------------------------------------------------
+# CausalConv1D
+# ---------------------------------------------------------------------------
+
+def _conv_infer_shape(attrs, in_shapes):
+    if in_shapes[0] is not None and in_shapes[1] is None \
+            and in_shapes[0][-1] != 0:
+        in_shapes[1] = (in_shapes[0][-1], asint(attrs['kernel']))
+    return in_shapes
+
+
+@register('CausalConv1D', input_names=('data', 'weight'),
+          infer_shape=_conv_infer_shape, hint='causalconv1d')
+def _causal_conv1d(attrs, data, weight):
+    """Depthwise: y[t, c] = sum_j w[c, j] * x[t - (kernel-1) + j, c],
+    positions before the sequence's start read as zero."""
+    width, seq_len = asint(attrs['kernel']), asint(attrs['seq_len'])
+    x = _fold(data, seq_len).astype(F32)
+    xp = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    w = weight.astype(F32)
+    y = sum(xp[:, j:j + seq_len] * w[:, j] for j in range(width))
+    return y.reshape(data.shape).astype(data.dtype)
+
+
+# ---------------------------------------------------------------------------
+# GatedDeltaRule
+# ---------------------------------------------------------------------------
+
+def _unit_lower_inverse(a):
+    """(I + a)^-1 for strictly lower triangular a (..., C, C): a is
+    nilpotent, so the Neumann series ends, and its C terms are the
+    product (I - a)(I + a^2)(I + a^4)... of log2(C) factors."""
+    c = a.shape[-1]
+    eye = jnp.eye(c, dtype=a.dtype)
+    mm = functools.partial(jnp.matmul, precision=HIGHEST)
+    inv, power = eye - a, a
+    for _ in range(max(0, (c - 1).bit_length() - 1)):
+        power = mm(power, power)
+        inv = mm(inv, eye + power)
+    return inv
+
+
+# the chain's powers are cheap to make again and as large as the
+# chunk's other tensors together: the backward pass keeps only `a`
+_unit_lower_inverse = jax.checkpoint(_unit_lower_inverse)
+
+
+def chunk_gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
+    """Per head, token by token: S <- exp(g_t) S; d = beta_t (v_t -
+    S^T k_t); S <- S + k_t (x) d; o_t = S^T q_t.  Computed a chunk at a
+    time: inside a chunk the rule is a unit lower triangular system
+    (the WY form), between chunks the state S (dk x dv) is carried.
+    q, k (B, H, T, dk), v (B, H, T, dv), g (log decay <= 0) and beta
+    (B, H, T), all float32.  Returns o (B, H, T, dv) in float32."""
+    bsz, h, t, dk = q.shape
+    dv = v.shape[-1]
+    pad = (-t) % chunk
+    if pad:     # beta = 0 and g = 0: tokens that leave the state alone
+        q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                   for a in (q, k, v))
+        g, beta = (jnp.pad(a, ((0, 0), (0, 0), (0, pad)))
+                   for a in (g, beta))
+    nc = (t + pad) // chunk
+    q, k, v = (a.reshape(bsz, h, nc, chunk, -1) for a in (q, k, v))
+    g = jnp.cumsum(g.reshape(bsz, h, nc, chunk), axis=-1)
+    beta = beta.reshape(bsz, h, nc, chunk)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    # exp(g_i - g_j) for i >= j, masked before exp: the other half of
+    # the difference is positive and can overflow
+    decay = jnp.exp(jnp.where(lower, g[..., :, None] - g[..., None, :],
+                              -jnp.inf))
+    k_beta = k * beta[..., None]
+    a = jnp.einsum('...ik,...jk->...ij', k_beta, k) * decay
+    inv = _unit_lower_inverse(jnp.where(jnp.tril(lower, -1), a, 0.0))
+    u = jnp.matmul(inv, v * beta[..., None])            # (.., C, dv)
+    w = jnp.matmul(inv, k_beta * jnp.exp(g)[..., None])  # (.., C, dk)
+    intra = jnp.einsum('...ik,...jk->...ij', q, k) * decay
+    q_in = q * jnp.exp(g)[..., None]
+    g_last = g[..., -1]
+    k_out = k * jnp.exp(g_last[..., None] - g)[..., None]
+
+    def step(state, xs):
+        u_c, w_c, intra_c, q_c, k_c, decay_c = xs
+        v_new = u_c - jnp.matmul(w_c, state)
+        o_c = jnp.matmul(q_c, state) + jnp.matmul(intra_c, v_new)
+        state = state * decay_c[..., None, None] + jnp.einsum(
+            '...ck,...cv->...kv', k_c, v_new)
+        return state, o_c
+
+    def chunks_first(x):
+        return jnp.moveaxis(x, 2, 0)
+
+    _, o = lax.scan(step, jnp.zeros((bsz, h, dk, dv), F32),
+                    tuple(chunks_first(x) for x in
+                          (u, w, intra, q_in, k_out, jnp.exp(g_last))))
+    o = jnp.moveaxis(o, 0, 2).reshape(bsz, h, nc * chunk, dv)
+    return o[:, :, :t]
+
+
+def _gdr_infer_shape(attrs, in_shapes):
+    hv = asint(attrs['num_v_heads'])
+    for i in (3, 4):
+        if in_shapes[i] is None:
+            in_shapes[i] = (hv,)
+    return in_shapes
+
+
+@register('GatedDeltaRule',
+          input_names=('data', 'a', 'b', 'a_log', 'dt_bias'),
+          infer_shape=_gdr_infer_shape,
+          infer_dtype=_infer_dtype((3, 4)), hint='gateddeltarule')
+def _gated_delta_rule(attrs, qkv, a, b, a_log, dt_bias):
+    """data: [q | k | v] rows after the causal convolution and silu;
+    a, b: the decay's and the write strength's pre-activations, one a
+    value head.  Returns (N, num_v_heads * head_v_dim).
+
+    One sequence and KEY_HEADS_PER_BLOCK key heads (with their value
+    heads) at a time, each block recomputed in the backward pass: what
+    a chunked pass keeps in float32 for its gradient is many times its
+    inputs, and all heads of all sequences at once do not fit."""
+    hk, hv = asint(attrs['num_k_heads']), asint(attrs['num_v_heads'])
+    dk, dv = asint(attrs['head_k_dim']), asint(attrs['head_v_dim'])
+    seq_len = asint(attrs['seq_len'])
+    per_block = min(KEY_HEADS_PER_BLOCK, hk)
+    if hv % hk or hk % per_block:
+        raise ValueError('%d value heads on %d key heads in blocks of %d'
+                         % (hv, hk, per_block))
+    groups, rep = hk // per_block, hv // hk
+    n = qkv.shape[0]
+    bsz = n // seq_len
+
+    def blocks(x, heads):
+        """(N, heads * d) -> (sequences * groups, T, heads / groups, d)"""
+        x = _fold(x, seq_len).reshape(bsz, seq_len, groups,
+                                      heads // groups, -1)
+        return jnp.moveaxis(x, 2, 1).reshape(
+            (bsz * groups, seq_len) + x.shape[3:])
+
+    def per_group(x):
+        """(heads,) -> (sequences * groups, heads / groups)"""
+        return jnp.tile(x.astype(F32).reshape(groups, -1), (bsz, 1))
+
+    def l2norm(y):
+        return y * lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6)
+
+    def one_block(xs):
+        q, k, v, a_, b_, a_log_, dt_bias_ = xs
+        q = jnp.repeat(l2norm(q.astype(F32)) / math.sqrt(dk), rep, axis=1)
+        k = jnp.repeat(l2norm(k.astype(F32)), rep, axis=1)
+        g = -jnp.exp(a_log_) * jax.nn.softplus(
+            a_[..., 0].astype(F32) + dt_bias_)
+        beta = jax.nn.sigmoid(b_[..., 0].astype(F32))
+        o = chunk_gated_delta_rule(*(jnp.moveaxis(x, 1, 0)[None] for x in
+                                     (q, k, v.astype(F32), g, beta)))
+        return jnp.moveaxis(o[0], 0, 1).astype(qkv.dtype)
+
+    o = lax.map(jax.checkpoint(one_block), (
+        blocks(qkv[:, :hk * dk], hk), blocks(qkv[:, hk * dk:2 * hk * dk], hk),
+        blocks(qkv[:, 2 * hk * dk:], hv), blocks(a, hv), blocks(b, hv),
+        per_group(a_log), per_group(dt_bias)))
+    o = o.reshape(bsz, groups, seq_len, hv // groups, dv)
+    return jnp.moveaxis(o, 1, 2).reshape(n, hv * dv)
+
+
+# ---------------------------------------------------------------------------
+# SparseMoE
+# ---------------------------------------------------------------------------
+
+def _tile_plan(group_sizes, tile, max_tiles):
+    """The sorted pairs of each expert cut into tiles of `tile` rows: a
+    tile never spans two experts.  Returns (expert, first row, valid
+    rows) of every tile slot, and how many slots are in use."""
+    tiles = (group_sizes + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles)
+    row_start = jnp.cumsum(group_sizes) - group_sizes
+    t = jnp.arange(max_tiles, dtype=jnp.int32)
+    e = jnp.minimum(jnp.searchsorted(tile_end, t, side='right'),
+                    group_sizes.shape[0] - 1).astype(jnp.int32)
+    j = t - (tile_end - tiles)[e]
+    valid = jnp.where(t < tile_end[-1],
+                      jnp.clip(group_sizes[e] - j * tile, 0, tile), 0)
+    return e, row_start[e] + j * tile, valid, tile_end[-1]
+
+
+def _expert_mlp(xt, wg, wu, wd):
+    """down(silu(gate x) * up x) of one expert on a tile of rows, with
+    what the backward pass needs of it."""
+    g = jnp.dot(xt, wg.T, preferred_element_type=F32)
+    u = jnp.dot(xt, wu.T, preferred_element_type=F32)
+    h = (jax.nn.silu(g) * u).astype(xt.dtype)
+    return jnp.dot(h, wd.T, preferred_element_type=F32), g, u, h
+
+
+def _put_rows(buffer, rows, row0):
+    return lax.dynamic_update_slice(buffer, rows.astype(buffer.dtype),
+                                    (row0, 0))
+
+
+def _add_at(acc, e, value):
+    """acc[e] += value, in place."""
+    index = (e,) + (0,) * value.ndim
+    return lax.dynamic_update_slice(
+        acc, lax.dynamic_slice(acc, index, (1,) + value.shape) + value[None],
+        index)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def grouped_experts(tile, x, wg, wu, wd, token_of, weight_of, pair_weight,
+                    position, group_sizes):
+    """y[n] = sum over token n's pairs (n, e) of weight * mlp_e(x[n]).
+    x (N, H); wg, wu (E, I, H); wd (E, H, I).  The pairs are sorted by
+    expert: `group_sizes` of them for each expert held here, the rest
+    (pairs of experts held elsewhere) behind them.  token_of (M,): the
+    token and (not differentiated) the routing weight of each sorted
+    pair; position (N, k): where each token's pairs stand in that
+    order; pair_weight (N, k), through which the weights' gradient goes.
+
+    The loop runs over the tiles in use only and writes each tile's
+    rows where they stand in the sorted order; a gather by `position`
+    brings them back to their tokens.  The arrays are sized for the
+    worst case (every pair lands here); the work is what was routed
+    here, and rows no tile wrote stay zero."""
+    return _grouped_fwd(tile, x, wg, wu, wd, token_of, weight_of,
+                        pair_weight, position, group_sizes)[0]
+
+
+def _max_tiles(pairs, tile, experts):
+    return -(-pairs // tile) + experts
+
+
+def _grouped_plan(tile, token_of, wg, group_sizes):
+    return _tile_plan(group_sizes, tile,
+                      _max_tiles(token_of.shape[0], tile, wg.shape[0]))
+
+
+def _tile(plan, t, tile, token_of):
+    """Tile t's expert, first sorted row, live rows and their tokens
+    (rows past the expert's last pair read token_of's row 0 and are
+    written as zeros; the next tile overwrites them)."""
+    expert, row0, valid, _ = plan
+    live = jnp.arange(tile) < valid[t]
+    rows = jnp.where(live, row0[t] + jnp.arange(tile), 0)
+    return expert[t], row0[t], live[:, None], token_of[rows]
+
+
+def _sorted_rows(tile, x, wg, wu, wd, token_of, plan):
+    """mlp_e(x[token]) of every sorted pair held here, (M + tile, H)."""
+    def body(t, ys):
+        e, row0, live, tok = _tile(plan, t, tile, token_of)
+        yt = _expert_mlp(x[tok], wg[e], wu[e], wd[e])[0]
+        return _put_rows(ys, jnp.where(live, yt, 0.0), row0)
+
+    return lax.fori_loop(
+        0, plan[3], body,
+        jnp.zeros((token_of.shape[0] + tile, x.shape[1]), x.dtype))
+
+
+def _combine(rows, position, weight=None):
+    """sum_j weight[:, j] * rows[position[:, j]] in float32, one of a
+    token's k pairs at a time: (N, k, H) at once is ten times x."""
+    total = 0.0
+    for j in range(position.shape[1]):
+        part = rows[position[:, j]].astype(F32)
+        total = total + (part if weight is None else part * weight[:, j, None])
+    return total
+
+
+def _grouped_fwd(tile, x, wg, wu, wd, token_of, weight_of, pair_weight,
+                 position, group_sizes):
+    plan = _grouped_plan(tile, token_of, wg, group_sizes)
+    ys = _sorted_rows(tile, x, wg, wu, wd, token_of, plan)
+    y = _combine(ys, position, pair_weight.astype(F32))
+    return y.astype(x.dtype), (x, wg, wu, wd, token_of, weight_of,
+                               position, group_sizes)
+
+
+def _grouped_bwd(tile, res, dy):
+    x, wg, wu, wd, token_of, weight_of, position, group_sizes = res
+    plan = _grouped_plan(tile, token_of, wg, group_sizes)
+    m = token_of.shape[0]
+    weight_of = jnp.pad(weight_of.astype(F32), (0, tile))
+
+    def body(t, carry):
+        dxs, dws, dwg, dwu, dwd = carry
+        e, row0, live, tok = _tile(plan, t, tile, token_of)
+        xt = x[tok]
+        yt, g, u, h = _expert_mlp(xt, wg[e], wu[e], wd[e])
+        dyt = dy[tok].astype(F32)
+        dws = _put_rows(dws, jnp.where(
+            live, jnp.sum(dyt * yt, axis=-1, keepdims=True), 0.0), row0)
+        wt = lax.dynamic_slice(weight_of, (row0,), (tile,))[:, None]
+        dyt = jnp.where(live, dyt * wt, 0.0).astype(x.dtype)
+        dh = jnp.dot(dyt, wd[e], preferred_element_type=F32)
+        sig = jax.nn.sigmoid(g)
+        du = (dh * g * sig).astype(x.dtype)
+        dg = (dh * u * sig * (1.0 + g * (1.0 - sig))).astype(x.dtype)
+        dxt = jnp.dot(dg, wg[e], preferred_element_type=F32) + \
+            jnp.dot(du, wu[e], preferred_element_type=F32)
+        return (_put_rows(dxs, dxt, row0), dws,
+                _add_at(dwg, e, jnp.dot(dg.T, xt,
+                                        preferred_element_type=F32)),
+                _add_at(dwu, e, jnp.dot(du.T, xt,
+                                        preferred_element_type=F32)),
+                _add_at(dwd, e, jnp.dot(dyt.T, h,
+                                        preferred_element_type=F32)))
+
+    dxs, dws, dwg, dwu, dwd = lax.fori_loop(
+        0, plan[3], body,
+        (jnp.zeros((m + tile, x.shape[1]), x.dtype),
+         jnp.zeros((m + tile, 1), F32), jnp.zeros(wg.shape, F32),
+         jnp.zeros(wu.shape, F32), jnp.zeros(wd.shape, F32)))
+    dx = _combine(dxs, position)
+    return (dx.astype(x.dtype), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
+            dwd.astype(wd.dtype), None, None,
+            dws[:, 0][position].astype(weight_of.dtype), None, None)
+
+
+grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def route(x, router_weight, top_k, normalize):
+    """softmax over all experts in float32, the top k and their weights
+    (over the chosen k, whether held here or not)."""
+    logits = jnp.dot(x, router_weight.T, preferred_element_type=F32)
+    vals, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if normalize:
+        vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    return vals, idx
+
+
+def sparse_moe(x, router_weight, wg, wu, wd, top_k, expert_offset,
+               normalize=True, tile=EXPERT_TILE):
+    """The held experts' part of a top-k expert layer.  wg, wu
+    (held, I, H) and wd (held, H, I) are experts expert_offset ..
+    expert_offset + held of router_weight.shape[0].  Returns (y,
+    assigned, computed): per-expert counts of the pairs the router
+    made (all experts) and of those computed here."""
+    n_exp, held = router_weight.shape[0], wg.shape[0]
+    vals, idx = route(x, router_weight, top_k, normalize)
+    local = idx.reshape(-1) - expert_offset
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True)       # held pairs first
+    starts = jnp.searchsorted(key[order], jnp.arange(held + 1),
+                              side='left')
+    group_sizes = (starts[1:] - starts[:-1]).astype(jnp.int32)
+    position = jnp.argsort(order).astype(jnp.int32).reshape(idx.shape)
+    y = grouped_experts(tile, x, wg, wu, wd,
+                        (order // top_k).astype(jnp.int32),
+                        lax.stop_gradient(vals).reshape(-1)[order], vals,
+                        position, group_sizes)
+    assigned = jnp.sum(idx[..., None] == jnp.arange(n_exp), axis=(0, 1),
+                       dtype=jnp.int32)
+    # what the grouped product's loop ran over: the tiles' valid rows
+    e, _, valid, _ = _grouped_plan(tile, key, wg, group_sizes)
+    computed = jnp.zeros((n_exp,), jnp.int32).at[e + expert_offset].add(
+        valid.astype(jnp.int32))
+    return y, assigned, computed
+
+
+def _moe_infer_shape(attrs, in_shapes):
+    if in_shapes[0] is None or in_shapes[0][-1] == 0:
+        return in_shapes
+    hidden = in_shapes[0][-1]
+    n_exp, held = asint(attrs['num_experts']), asint(
+        attrs['num_experts_held'])
+    inter = asint(attrs['intermediate_size'])
+    wanted = [(n_exp, hidden), (held * inter, hidden),
+              (held * inter, hidden), (held * hidden, inter), (2, n_exp)]
+    for i, s in enumerate(wanted, start=1):
+        if in_shapes[i] is None:
+            in_shapes[i] = s
+    return in_shapes
+
+
+def _sparse_moe(attrs, inputs, auxs, op_ctx):
+    x, router_weight, wg, wu, wd = inputs
+    held = asint(attrs['num_experts_held'])
+    offset = asint(attrs.get('expert_offset', 0))
+    if not 0 <= offset <= router_weight.shape[0] - held:
+        raise ValueError('experts %d..%d of %d' % (
+            offset, offset + held, router_weight.shape[0]))
+    hidden = x.shape[-1]
+    y, assigned, computed = sparse_moe(
+        x, router_weight, wg.reshape(held, -1, hidden),
+        wu.reshape(held, -1, hidden), wd.reshape(held, hidden, -1),
+        asint(attrs['top_k']), offset,
+        asbool(attrs.get('normalize', True)))
+    counts = auxs[0] + lax.stop_gradient(jnp.stack([assigned, computed]))
+    return [y], [counts]
+
+
+def _fold_counts(attrs, deltas):
+    """`counts`' growth into profiler.moe_stats(): row 0 the pairs the
+    router assigned to each expert, row 1 those computed here."""
+    from .. import profiler
+    assigned, computed = deltas[0]
+    first = asint(attrs.get('expert_offset', 0))
+    here = slice(first, first + asint(attrs['num_experts_held']))
+    profiler.add_moe_stats(
+        routed=computed.sum(),
+        dropped=(assigned[here] - computed[here]).sum(),
+        per_expert_routed=computed, assignments=assigned.sum())
+
+
+register('SparseMoE',
+         input_names=('data', 'router_weight', 'gate_weight', 'up_weight',
+                      'down_weight', 'counts'),
+         num_aux=1, mutable_aux=True, infer_shape=_moe_infer_shape,
+         infer_dtype=_infer_dtype(int_aux=1), hint='sparsemoe',
+         simple=False, fold_aux=_fold_counts)(_sparse_moe)

@@ -88,6 +88,7 @@ _ACTS = {
     'tanh': jnp.tanh,
     'softrelu': jax.nn.softplus,
     'softsign': jax.nn.soft_sign,
+    'silu': jax.nn.silu,
 }
 
 
@@ -151,11 +152,12 @@ def _softmax_activation(attrs, data):
 
 def _softmax_out_fwd_impl(params, data, label):
     multi_output, preserve_shape = params[3], params[5]
-    if preserve_shape:
-        return jax.nn.softmax(data, axis=-1)
-    if multi_output or data.ndim > 2:
-        return jax.nn.softmax(data, axis=1)
-    return jax.nn.softmax(data, axis=-1)
+    axis = 1 if not preserve_shape and (multi_output or data.ndim > 2) \
+        else -1
+    # exponentials and their sum in float32 whatever the logits' type
+    # (one fusion: nothing wider is stored); the output keeps the type
+    return jax.nn.softmax(data.astype(jnp.float32),
+                          axis=axis).astype(data.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -202,8 +204,17 @@ _softmax_output_fn.defvjp(
     _softmax_output_bwd)
 
 
+def _softmax_output_infer_dtype(attrs, in_dtypes):
+    """The label does not follow a low-precision graph's type: class
+    indices above 256 are not exact in bfloat16."""
+    f32 = np.dtype(np.float32)
+    d = np.dtype(in_dtypes[0]) if in_dtypes[0] is not None else f32
+    return [d, in_dtypes[1] if in_dtypes[1] is not None else f32], [d]
+
+
 @register('SoftmaxOutput', input_names=('data', 'label'),
           aliases=('Softmax',), hint='softmaxoutput',
+          infer_dtype=_softmax_output_infer_dtype,
           infer_shape=lambda attrs, s: (
               s if s[0] is None or s[1] is not None
               else [s[0], _softmax_label_shape(attrs, s[0])]))

@@ -98,7 +98,7 @@ class OpDef:
                  infer_dtype=None, needs_rng=False, mode_dependent=False,
                  mutable_aux=False, hint=None, shape_rule=None,
                  needs_out_shapes=False, infer_shape_bwd=None,
-                 aux_always=False):
+                 aux_always=False, fold_aux=None):
         self.name = name
         self.fcompute = fcompute
         self._input_names = input_names
@@ -113,6 +113,11 @@ class OpDef:
         # aux states mutate regardless of train mode (optimizer update
         # ops: momentum/mean/var states advance on every call)
         self.aux_always = aux_always
+        # the op's aux states are running counters kept on the device:
+        # fold_aux(attrs, deltas) takes what each has grown by since it
+        # was last read (numpy) into the profiler's counters; called by
+        # profiler.fold_device_counters(), never inside a step
+        self.fold_aux = fold_aux
         self.hint = hint or name.lstrip('_').lower()
         # 'same': all (non-aux) inputs and outputs share one shape —
         # enables bidirectional unification (nnvm ElemwiseShape)
@@ -253,7 +258,7 @@ def register(name, input_names=('data',), num_aux=0, num_outputs=1,
              needs_rng=False, mode_dependent=False, mutable_aux=False,
              aliases=(), hint=None, simple=True, shape_rule=None,
              needs_out_shapes=False, infer_shape_bwd=None,
-             aux_always=False):
+             aux_always=False, fold_aux=None):
     """Decorator registering an op.
 
     With simple=True (default) the decorated function has signature
@@ -280,7 +285,8 @@ def register(name, input_names=('data',), num_aux=0, num_outputs=1,
                    mutable_aux=mutable_aux, hint=hint,
                    shape_rule=shape_rule,
                    needs_out_shapes=needs_out_shapes,
-                   infer_shape_bwd=infer_shape_bwd, aux_always=aux_always)
+                   infer_shape_bwd=infer_shape_bwd, aux_always=aux_always,
+                   fold_aux=fold_aux)
         _OP_REGISTRY[name] = op
         for alias in aliases:
             _OP_ALIASES[alias] = name

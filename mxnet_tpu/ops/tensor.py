@@ -563,8 +563,21 @@ def _embedding_infer_shape(attrs, in_shapes):
 _embed_hook = None
 
 
+def _embedding_infer_dtype(attrs, in_dtypes):
+    """The table's type is the output's; `dtype` names it where nothing
+    upstream does (the ids are float32 whatever the table holds)."""
+    f32 = np.dtype(np.float32)
+    if attrs.get('dtype') is not None:
+        table = _dtype(attrs)
+    else:
+        table = in_dtypes[1] if in_dtypes[1] is not None else f32
+    return [in_dtypes[0] if in_dtypes[0] is not None else f32, table], \
+        [table]
+
+
 @register('Embedding', input_names=('data', 'weight'),
-          infer_shape=_embedding_infer_shape)
+          infer_shape=_embedding_infer_shape,
+          infer_dtype=_embedding_infer_dtype)
 def _embedding(attrs, data, weight):
     if _embed_hook is not None:
         out = _embed_hook(attrs, data, weight)

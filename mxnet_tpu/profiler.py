@@ -25,6 +25,7 @@ import json
 import os
 import threading
 import time
+import weakref
 from collections import deque
 
 import numpy as np
@@ -109,26 +110,36 @@ def pipe_stats():
         return dict(_PIPE)
 
 
-# expert-parallel MoE counters (gluon.nn.MoE through the fused step):
-# tokens routed to experts vs dropped at capacity (overflow is
-# otherwise SILENT — the residual passes them through), plus the
-# per-expert table for load-balance reading
+# expert-parallel MoE counters: tokens routed to experts vs dropped at
+# capacity (overflow is otherwise SILENT — the residual passes them
+# through), plus the per-expert table for load-balance reading.  Fed by
+# gluon.nn.MoE through the fused step (add_moe_stats once a dispatch)
+# and by the SparseMoE operator's device-resident counts, which an
+# executor offers through watch_device_counters and
+# fold_device_counters() folds in when it is called: no step ever waits
+# for them, and moe_stats() itself reads host counters only
 _MOE = {
     'moe_routed_tokens': 0,
     'moe_dropped_tokens': 0,
     'moe_dispatches': 0,
+    'moe_assignments': 0,
 }
 _MOE_EXPERTS = {}       # 'e<i>' -> {'routed': n, 'dropped': n}
+# owner -> {aux name: the count last read}: fold_device_counters()
+_WATCHED = weakref.WeakKeyDictionary()
 
 
 def add_moe_stats(routed=0, dropped=0, per_expert_routed=None,
-                  per_expert_dropped=None, dispatches=0):
+                  per_expert_dropped=None, dispatches=0, assignments=0):
     """Accumulate MoE routing counters (the fused step feeds one call
-    per dispatch from the block's device-resident count deltas)."""
+    per dispatch from the block's device-resident count deltas).
+    assignments: (token, expert) pairs the router made over ALL experts,
+    held here or not."""
     with _STATE['lock']:
         _MOE['moe_routed_tokens'] += int(routed)
         _MOE['moe_dropped_tokens'] += int(dropped)
         _MOE['moe_dispatches'] += int(dispatches)
+        _MOE['moe_assignments'] += int(assignments)
         for key, vals in (('routed', per_expert_routed),
                           ('dropped', per_expert_dropped)):
             if vals is None:
@@ -137,6 +148,32 @@ def add_moe_stats(routed=0, dropped=0, per_expert_routed=None,
                 e = _MOE_EXPERTS.setdefault('e%d' % i,
                                             {'routed': 0, 'dropped': 0})
                 e[key] += int(v)
+
+
+def watch_device_counters(owner):
+    """`owner.counter_aux()` -> [(aux names, arrays, attrs, fold_aux)]:
+    running totals an operator keeps on the device as auxiliary state,
+    with the operator's own fold (ops.registry.OpDef.fold_aux).  Held
+    weakly; read only by fold_device_counters()."""
+    _WATCHED.setdefault(owner, {})
+
+
+def fold_device_counters():
+    """Read every watched counter from the device and hand what it has
+    grown by since the last read to its operator's fold (SparseMoE:
+    add_moe_stats).  Waits for the dispatches in flight: call it where
+    a wait costs nothing, not inside a step."""
+    for owner, seen in list(_WATCHED.items()):
+        for names, arrays, attrs, fold in owner.counter_aux():
+            deltas = []
+            for name, now in zip(names, arrays):
+                now = np.asarray(now, np.int64)
+                delta = now - seen.get(name, 0)
+                if (delta < 0).any():       # the state was set anew
+                    delta = now
+                seen[name] = now
+                deltas.append(delta)
+            fold(attrs, deltas)
 
 
 def moe_stats():

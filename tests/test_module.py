@@ -274,6 +274,47 @@ def test_bulk_step_matches_per_step_loop(n_ctx, kvstore):
                                    rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize('given', [False, True])
+def test_module_weights_are_committed_and_the_step_compiles_once(given):
+    """set_params commits every weight to the executor's device, as
+    the compiled step's donated outputs are: the second dispatch sees
+    the first's jit signature and nothing compiles again; the module's
+    own copies outlive the step's donation.  `given`: weights handed
+    in, or made by the initializer."""
+    import jax
+    rng = np.random.RandomState(2)
+    batches = [mx.io.DataBatch(
+        data=[nd.array(rng.rand(16, 8).astype(np.float32))],
+        label=[nd.array((rng.rand(16) * 4).astype(np.float32))])
+        for _ in range(2)]
+    ap = ax = None
+    if given:
+        ap, ax = _bulk_mod([mx.cpu(0)], kvstore=None).get_params()
+    mod = _bulk_mod([mx.cpu(0)], ap, ax, kvstore=None)
+    ex = mod._exec_group.executor
+    for name in ex._diff_names:
+        assert ex.arg_dict[name]._data._committed, name
+    before = {n: mod._arg_params[n].asnumpy().copy()
+              for n in ex._diff_names}
+    compiles = []
+
+    def on_duration(event, duration, **_):
+        if event == '/jax/core/compile/backend_compile_duration':
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        mod.bulk_step(batches=batches)
+        first = len(compiles)
+        mod.bulk_step(batches=batches)
+        jax.block_until_ready(mod.get_outputs()[0]._data)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert len(compiles) == first    # the first may be served by exec_cache
+    for n, w in before.items():
+        np.testing.assert_array_equal(mod._arg_params[n].asnumpy(), w)
+
+
 def test_bulk_step_scan_dtype_storage():
     """bulk_step(scan_dtype=...) stores the stacked data batches in a
     narrower dtype and the fused step casts back before the graph

@@ -258,6 +258,21 @@ CASES = {
                          grad_nodes=['data'], rtol=5e-2, atol=5e-3,
                          wrap='square', eps=1e-2),
     'L2Normalization': Case([(2, 6)], low=0.5, high=1.5),
+    'RMSNorm': Case([(3, 8), (8,)], attrs={'eps': 1e-6,
+                                           'zero_centered': True},
+                    low=0.5, high=1.5),
+    'CausalConv1D': Case([(12, 5), (5, 4)],
+                         attrs={'kernel': 4, 'seq_len': 6}),
+    'GatedAttention': Case([(12, 32), (12, 8), (12, 8), (8,), (8,)],
+                           attrs={'num_heads': 2, 'num_kv_heads': 1,
+                                  'head_dim': 8, 'rotary_dim': 4,
+                                  'seq_len': 6},
+                           rtol=5e-2, atol=5e-3, wrap='square', eps=1e-2),
+    'GatedDeltaRule': Case([(10, 20), (10, 2), (10, 2), (2,), (2,)],
+                           attrs={'num_k_heads': 1, 'num_v_heads': 2,
+                                  'head_k_dim': 4, 'head_v_dim': 6,
+                                  'seq_len': 5, 'chunk_size': 4},
+                           rtol=5e-2, atol=5e-3, wrap='square', eps=1e-2),
     'LRN': Case([(1, 4, 3, 3)], attrs={'nsize': 3}, low=0.5, high=1.5),
     'LSoftmax': Case([(3, 4), (5, 4), (3,)],
                      attrs={'num_hidden': 5, 'margin': 2},
@@ -432,6 +447,10 @@ SKIP = {
     '_crop_assign_scalar': 'covered by tests/test_missing_ops.py',
     'MultiProposal': 'batch variant of Proposal (same kernel), '
                      'covered by tests/test_contrib.py',
+    'SparseMoE': 'top-k routing is piecewise (a finite difference can '
+                 'cross a choice) and the counts are aux state: values, '
+                 'gradients, shares and counters against the plain '
+                 'reference in tests/test_qwen3_next.py',
     '_NoGradient': 'zero-input placeholder node (reference '
                    'init_op.cc); nothing to gradient-check',
 }
