@@ -728,7 +728,7 @@ class Executor:
 
     def make_fused_multistep(self, step_math, scan_names, repeat=None,
                              step_key=None, grad_reduce=None,
-                             metric=None, lr_stacked=False):
+                             metric=None):
         """K whole training steps (fwd+bwd+update) in ONE donated XLA
         dispatch, looping on-device with lax.scan.
 
@@ -741,9 +741,10 @@ class Executor:
         scan_names: args fed per-step (data/label).  In stacked mode
         the caller passes them stacked on a leading K axis; with
         `repeat=K` the currently bound batch is reused K times
-        (xs=None scan).  step_key: see make_fused_train_step; it MUST
-        also identify grad_reduce/metric (both bake into the traced
-        program but are opaque callables here).
+        (only the schedule rows are scanned).  step_key: see
+        make_fused_train_step; it MUST also identify grad_reduce/metric
+        (both bake into the traced program but are opaque callables
+        here).
 
         grad_reduce: optional callable list->list applied to the
         gradients before step_math — the backward-interleaved bucketed
@@ -756,13 +757,12 @@ class Executor:
         final carry comes back from run_fused_multistep so per-batch
         metric host syncs stop breaking the bulk.
 
-        lr_stacked: lrs/wds arrive as ONE (K, n_params) schedule
-        array each, scanned alongside the batches so each step sees
-        ITS row (FactorScheduler boundaries crossed mid-dispatch
-        decay at the right step) instead of loop-invariant scalars —
-        one host->device transfer per dispatch regardless of
-        parameter count; the per-param split happens inside the
-        trace.
+        lrs/wds arrive as ONE (K, n_params) float32 schedule array
+        each (K = 1 for the single step), scanned alongside the
+        batches so each step sees ITS row (FactorScheduler boundaries
+        crossed mid-dispatch decay at the right step) — one
+        host->device transfer per dispatch regardless of parameter
+        count; the per-param split happens inside the trace.
         """
         if self._grouped:
             return None
@@ -811,7 +811,7 @@ class Executor:
                               for e in sparse_rt) if sparse_rt else None
             cache_key = (self._sig, 'multistep', tuple(scan_idx), repeat,
                          tuple(str(d) for d in scan_dt),
-                         bool(lr_stacked), embed_tok, step_key)
+                         embed_tok, step_key)
             fn = exec_cache.get(cache_key)
             if fn is not None:
                 return fn
@@ -820,10 +820,9 @@ class Executor:
                       moms, masters, lrs, wds):
             def run_one(diff_vals, aux_vals, moms, masters, key, sv,
                         lr_t, wd_t, mc):
-                if lr_stacked:
-                    # (n,) schedule row -> per-param traced scalars
-                    lr_t = [lr_t[j] for j in range(len(diff_idx))]
-                    wd_t = [wd_t[j] for j in range(len(diff_idx))]
+                # (n,) schedule row -> per-param traced scalars
+                lr_t = [lr_t[j] for j in range(len(diff_idx))]
+                wd_t = [wd_t[j] for j in range(len(diff_idx))]
                 key, sub = jax.random.split(key)
 
                 def merge(dv):
@@ -921,19 +920,16 @@ class Executor:
                         key, outs, mc)
 
             mc0 = metric[0]() if metric is not None else ()
+            lr0, wd0 = lrs[0], wds[0]
             if repeat == 1:
                 # single step: no scan wrapper (keeps the whole body in
                 # one fusion scope and avoids a trip-count-1 while loop)
-                lr1 = lrs[0] if lr_stacked else lrs
-                wd1 = wds[0] if lr_stacked else wds
                 (new_ws, new_aux, new_moms, new_masters, key, outs,
                  mc) = run_one(tuple(diff_vals), aux_vals, moms,
-                               masters, key, scan_vals, lr1, wd1, mc0)
+                               masters, key, scan_vals, lr0, wd0, mc0)
                 return (outs, new_aux, new_ws, new_moms, new_masters,
                         key, mc)
 
-            lr0 = lrs[0] if lr_stacked else lrs
-            wd0 = wds[0] if lr_stacked else wds
             out_shapes = jax.eval_shape(
                 lambda dv: run_one(dv, aux_vals, moms, masters, key,
                                    jax.tree_util.tree_map(
@@ -945,14 +941,10 @@ class Executor:
 
             def body(carry, xs):
                 diff_vals, aux_vals, moms, masters, key, _, mc = carry
-                if lr_stacked:
-                    if repeat is None:
-                        sv, lr_t, wd_t = xs
-                    else:
-                        (lr_t, wd_t), sv = xs, scan_vals
+                if repeat is None:
+                    sv, lr_t, wd_t = xs
                 else:
-                    sv = scan_vals if xs is None else xs
-                    lr_t, wd_t = lrs, wds
+                    (lr_t, wd_t), sv = xs, scan_vals
                 (new_ws, new_aux, new_moms, new_masters, key, outs,
                  mc) = run_one(diff_vals, aux_vals, moms, masters,
                                key, sv, lr_t, wd_t, mc)
@@ -962,16 +954,10 @@ class Executor:
             init = (tuple(diff_vals), aux_vals, moms, masters, key,
                     outs0, mc0)
             if repeat is not None:
-                if lr_stacked:
-                    carry, _ = jax.lax.scan(body, init, (lrs, wds))
-                else:
-                    carry, _ = jax.lax.scan(body, init, None,
-                                            length=repeat)
-            elif lr_stacked:
+                carry, _ = jax.lax.scan(body, init, (lrs, wds))
+            else:
                 carry, _ = jax.lax.scan(body, init,
                                         (tuple(scan_vals), lrs, wds))
-            else:
-                carry, _ = jax.lax.scan(body, init, tuple(scan_vals))
             (new_ws, new_aux, new_moms, new_masters, key, outs,
              mc) = carry
             return (outs, new_aux, new_ws, new_moms, new_masters, key,
@@ -1113,8 +1099,9 @@ class Executor:
     def run_fused_train_step(self, step, diff_names, moms, masters,
                              lrs, wds, zero=False):
         """Execute a step from make_fused_train_step over the bound
-        arrays and write everything back.  Returns (new_moms,
-        new_masters) for the optimizer to reclaim."""
+        arrays and write everything back.  lrs/wds: the step's
+        schedule row as (1, n_params) float32 arrays.  Returns
+        (new_moms, new_masters) for the optimizer to reclaim."""
         return self.run_fused_multistep(step, diff_names, (), None,
                                         moms, masters, lrs, wds,
                                         zero=zero)[:2]

@@ -515,6 +515,22 @@ class Module(BaseModule):
             self._fused_step_key = (ex, fu, fkey)
         return self._fused_step
 
+    def _schedule_arrays(self, lrs, wds):
+        """The (K, n_params) float32 learning rates and weight decays
+        of a dispatch as the compiled step takes them: ONE array each
+        — a single transfer regardless of parameter count; the
+        per-param split happens in the trace.  Uncommitted on one
+        device, replicated over the mesh where there is one.  The
+        warm-ups place theirs here too: another kind of array would be
+        another signature, compiled at the first real step."""
+        import jax
+        mesh = self._exec_group.mesh
+        repl = None
+        if mesh is not None:
+            from ..parallel import mesh as pmesh
+            repl = pmesh.replicated(mesh)
+        return jax.device_put((lrs, wds), repl)
+
     def _run_fused_step(self):
         ex = self._exec_group.executor
         fu = self._fused_updater
@@ -524,6 +540,7 @@ class Module(BaseModule):
                 fu.param_names = list(fnames)
             weights = [ex.arg_dict[n] for n in fnames]
             moms, masters, lrs, wds = fu.host_prep(weights)
+            lrs, wds = self._schedule_arrays(lrs[None], wds[None])
             self._ensure_fused_program(ex, fu, fnames)
         new_moms, new_masters = ex.run_fused_train_step(
             self._fused_step, fnames, moms, masters, lrs, wds,
@@ -568,7 +585,7 @@ class Module(BaseModule):
         fkey = (fu.cache_key(),
                 (plan.key, self._mesh_fp()) if plan is not None
                 else None,
-                fold.key if fold is not None else None, 'lrstack')
+                fold.key if fold is not None else None)
         # weak: the key outlives a released executor and updater, and
         # must not keep their weights and optimizer state on the device
         cache_key = (weakref.ref(ex), weakref.ref(fu)) + (
@@ -597,8 +614,7 @@ class Module(BaseModule):
             self._bulk_step_fn = ex.make_fused_multistep(
                 fu.step_math, scan_names,
                 repeat=(None if stacked else k),
-                step_key=fkey, grad_reduce=gr, metric=metric_arg,
-                lr_stacked=True)
+                step_key=fkey, grad_reduce=gr, metric=metric_arg)
             self._bulk_cache_key = cache_key
         return self._bulk_step_fn
 
@@ -634,6 +650,7 @@ class Module(BaseModule):
         if single:
             moms, masters, lrs, wds = fu.host_prep(weights,
                                                    advance=False)
+            lrs, wds = self._schedule_arrays(lrs[None], wds[None])
             step = self._ensure_fused_program(ex, fu, fnames)
             ex.warm_fused_multistep(step, fnames, (), None, moms,
                                     masters, lrs, wds,
@@ -666,13 +683,7 @@ class Module(BaseModule):
                            for n, v in scan_stacks.items()}
         moms, masters, lr_stack, wd_stack = fu.host_prep_steps(
             weights, k, advance=False)
-        lrs, wds = jnp.asarray(lr_stack), jnp.asarray(wd_stack)
-        if eg.mesh is not None:
-            import jax
-            from ..parallel import mesh as pmesh
-            repl = pmesh.replicated(eg.mesh)
-            lrs = jax.device_put(lrs, repl)
-            wds = jax.device_put(wds, repl)
+        lrs, wds = self._schedule_arrays(lr_stack, wd_stack)
         fn = self._ensure_bulk_program(ex, fu, fnames, scan_names, k,
                                        stacked=True,
                                        scan_dtype=scan_dtype, fold=fold)
@@ -803,19 +814,10 @@ class Module(BaseModule):
         with profiler.scope('module.host_prep', 'fused_step'):
             weights = [ex.arg_dict[n] for n in fnames]
             # per-step schedule stacks: counts bump and lr/wd evaluate
-            # at every step index (host scheduler semantics).  ONE
-            # (K, n) array each — a single transfer per dispatch
-            # regardless of parameter count; the per-param split
-            # happens in the trace
+            # at every step index (host scheduler semantics)
             moms, masters, lr_stack, wd_stack = fu.host_prep_steps(
                 weights, k)
-            lrs, wds = jnp.asarray(lr_stack), jnp.asarray(wd_stack)
-            if eg.mesh is not None:
-                import jax
-                from ..parallel import mesh as pmesh
-                repl = pmesh.replicated(eg.mesh)
-                lrs = jax.device_put(lrs, repl)
-                wds = jax.device_put(wds, repl)
+            lrs, wds = self._schedule_arrays(lr_stack, wd_stack)
             self._ensure_bulk_program(ex, fu, fnames, scan_names, k,
                                       stacked=(batches is not None),
                                       scan_dtype=scan_dtype, fold=fold)

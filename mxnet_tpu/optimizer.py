@@ -558,11 +558,12 @@ def sgd_update_math(acc, g, m, lr, wd, momentum=0.0, rescale=1.0,
     the two modes cannot drift.  `g` must already be in `acc`'s dtype;
     returns (new_acc, new_momentum).
 
-    lr/wd may be python floats (weak-typed: the multiply stays in
-    acc's dtype) or traced jax scalars from a per-step schedule stack
-    (epoch-level fusion) — traced values are cast to acc's dtype so a
-    strong float32 scalar cannot silently promote a low-precision
-    update."""
+    lr/wd reach the Module's and the standalone steps as traced
+    float32 scalars (elements of the schedule arrays) and are cast to
+    acc's dtype, so a strong float32 scalar cannot silently promote a
+    low-precision update; the ahead-of-time K=1 programs of
+    gluon/fused.py and module/pipeline_fit.py still pass python floats
+    (weak-typed: the multiply stays in acc's dtype)."""
     import jax.numpy as jnp
     if hasattr(lr, 'dtype') and lr.dtype != acc.dtype:
         lr = lr.astype(acc.dtype)
@@ -661,8 +662,9 @@ class FusedSGD:
         def step(ws, gs, moms, masters, lrs, wds):
             from .parallel.embedding import sparse_row_update
             new_ws, new_moms, new_masters = [], [], []
-            for j, (w, g, m, mw, lr, wd) in enumerate(
-                    zip(ws, gs, moms, masters, lrs, wds)):
+            for j, (w, g, m, mw) in enumerate(
+                    zip(ws, gs, moms, masters)):
+                lr, wd = lrs[j], wds[j]
                 if j in sparse_set:
                     # rows-only update from the (unique_ids, row_grads)
                     # COO pair — same sgd_update_math core on the row
@@ -741,7 +743,9 @@ class FusedSGD:
         update and the whole-step fusion (executor.make_fused_train_step):
         lazily create momenta / fp32 masters, bump update counts, and
         evaluate lr/wd schedules.  Returns (moms, masters, lrs, wds)
-        aligned with param_names.
+        aligned with param_names; lrs/wds are float32 vectors of
+        length n_params (one row of the schedule arrays the compiled
+        steps take).
 
         advance=False (AOT warmup, Module.warmup_fused): states still
         materialize lazily — the warmup call must see exactly the
@@ -778,11 +782,12 @@ class FusedSGD:
                         if mp else None
             moms = [self.states[n] for n in self.param_names]
             masters = [self.masters[n] for n in self.param_names]
-        lrs, wds = [], []
-        for name in self.param_names:
+        lrs = np.empty(len(self.param_names), np.float32)
+        wds = np.empty(len(self.param_names), np.float32)
+        for j, name in enumerate(self.param_names):
             opt._update_count(name)
-            lrs.append(opt._get_lr(name))
-            wds.append(opt._get_wd(name))
+            lrs[j] = opt._get_lr(name)
+            wds[j] = opt._get_wd(name)
         if saved_counts is not None:
             self._restore_schedule_state(saved_counts)
         return moms, masters, lrs, wds

@@ -152,8 +152,8 @@ class ZeroBucketLayout:
 
     def pack_scalars(self, b, scalars):
         """Per-element vector of per-param scalars (lr/wd), built in the
-        accumulation dtype so `vec * bucket` promotes exactly like the
-        replicated path's weak-typed `scalar * tensor`."""
+        accumulation dtype, as the replicated path casts its traced
+        scalars (optimizer.sgd_update_math)."""
         import jax.numpy as jnp
         parts = [jnp.full((n,), s, dtype=b.acc_dtype)
                  for s, n in zip(scalars, b.sizes)]
@@ -214,8 +214,7 @@ def sharded_sgd_step(layout, mesh, hyper, ws, gs, moms, masters, lrs,
     CONSTRUCTION: both call optimizer.sgd_update_math (one definition
     of the rescale/clip/wd/momentum core), here on concatenated 1-D
     buckets with per-element lr/wd vectors built in the accumulation
-    dtype (so `vec * bucket` promotes exactly like the replicated
-    path's weak-typed `scalar * tensor`).
+    dtype (as the replicated path casts its traced scalars).
 
     Reduction schedule: each gradient bucket's reduce-scatter issues as
     soon as its member wgrads exist (backward-interleaved — XLA's

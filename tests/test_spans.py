@@ -348,6 +348,7 @@ def test_fused_step_carries_named_scopes(fit_run):
     names = ex._diff_names
     moms, masters, lrs, wds = fu.host_prep(
         [ex.arg_dict[n] for n in names], advance=False)
+    lrs, wds = mod._schedule_arrays(lrs[None], wds[None])
     text = mod._fused_step.lower(
         tuple(ex.arg_dict[n]._data for n in names), (),
         tuple(ex.arg_dict[n]._data for n in ex._arg_names
