@@ -70,26 +70,6 @@ class CompileLog:
             self.seconds += duration
 
 
-class ArgSpy:
-    """Stands in for a compiled step (exec_cache.TimedJit) and keeps the
-    abstract arguments of its last call, so that the very program the
-    module dispatched can be lowered again for its compiled text."""
-
-    def __init__(self, step):
-        self.step = step
-        self.avals = None
-
-    def __call__(self, *args):
-        self.avals = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=x.sharding)
-            if isinstance(x, jax.Array) else x, args)
-        return self.step(*args)
-
-    def compiled_text(self):
-        return self.step.lower(*self.avals).compile().as_text()
-
-
 # ---------------------------------------------------------------------------
 # Phase A — train, full width, one chip
 # ---------------------------------------------------------------------------
@@ -355,9 +335,6 @@ def phase_c(ctxs, batch=1024, image=(3, 224, 224), num_layers=50,
         'mesh holds %s' % mesh_devs
     step_s = []
     for i in range(WARM + 1):
-        if i == WARM:
-            spy = ArgSpy(mod._fused_step)
-            mod._fused_step = spy
         t0 = time.perf_counter()
         mod.forward_backward(data_batch)
         mod.update()
@@ -379,9 +356,17 @@ def phase_c(ctxs, batch=1024, image=(3, 224, 224), num_layers=50,
             a.devices() == set(devices), \
             '%s is not replicated over the mesh: %s' % (name, a.sharding)
     n_bufs = assert_placed(mod, devices)
-    text = spy.compiled_text()
+    # the program the module dispatched, lowered again for the operands
+    # of its next step
+    fu = mod._fused_updater
+    moms, masters, lrs, wds = fu.host_prep_steps(
+        [ex.arg_dict[name] for name in ex._diff_names], 1, advance=False)
+    text = mod._step_program('single').lower(
+        *ex._step_operands(ex._diff_names, (), None, moms, masters,
+                           zero=bool(fu.zero)),
+        *mod._schedule_arrays(lrs, wds)).compile().as_text()
     assert 'all-reduce' in text, 'compiled step has no all-reduce'
-    del spy
+    del fu, moms, masters, lrs, wds
     gc.collect()
     # nothing piled on the first chip: what this phase added to each
     # (the programs phases A and B loaded onto chip 0 are in `base`;

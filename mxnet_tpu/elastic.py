@@ -578,7 +578,7 @@ def _assemble_optimizer(meta, arrays):
     entries: ZeRO flat buckets are reassembled from their per-rank
     pieces and unpacked with the manifest's layout — independent of
     the dp width / zero stage of either run (re-sharding happens in
-    the restoring updater's own host_prep)."""
+    the restoring updater's own host_prep_steps)."""
     mode = meta.get('mode', 'none')
     if mode == 'none':
         return None

@@ -693,12 +693,21 @@ class Executor:
         self._sparse_entries = entries
         return entries
 
-    def make_fused_train_step(self, step_math, step_key=None,
-                              grad_reduce=None):
-        """Compile forward + backward + optimizer update into ONE donated
-        XLA dispatch (the whole training step — no reference
-        counterpart; the reference pays per-op dispatch on all three
-        phases, graph_executor.cc:1236 + per-key optimizer pushes).
+    def make_fused_multistep(self, step_math, scan_names, repeat=None,
+                             step_key=None, grad_reduce=None,
+                             metric=None):
+        """K whole training steps (forward + backward + optimizer
+        update) in ONE donated XLA dispatch, looping on-device with
+        lax.scan; the single step is repeat=1 with no scan_names, and
+        its program holds no loop.
+
+        TPU-native analog of the reference's bulk-exec segments
+        (MXNET_EXEC_BULK_EXEC_MAX_NODE_TRAIN, graph_executor.cc:1135):
+        where the reference amortizes engine-push overhead by fusing op
+        runs into segments, this amortizes the host->device dispatch
+        latency over K full steps, keeping the MXU busy back-to-back
+        (the reference pays per-op dispatch on all three phases,
+        graph_executor.cc:1236 + per-key optimizer pushes).
 
         step_math(ws, gs, moms, masters, lrs, wds) ->
             (new_ws, new_moms, new_masters)
@@ -709,42 +718,22 @@ class Executor:
         all-gathers updated params inside this same donated dispatch).
         Weights, aux states, momenta, and fp32 masters are donated, so
         params update in place in HBM; the PRNG split happens inside the
-        step so the host issues exactly one dispatch per batch.
+        step so the host issues exactly one dispatch per K batches.
 
         Returns None when this executor cannot fuse (ctx-group eager
         mode).  Caller contract: every differentiable arg is a weight
         updated by step_math (grad_req 'write'), in self._diff_names
-        order.  step_key: canonical identity of step_math (e.g.
-        FusedSGD.cache_key()) — when given, the compiled step is shared
-        through the process-wide executable cache across equivalent
-        executors.
-
-        Implemented as the K=1 case of make_fused_multistep (no scan
-        wrapper, same step body).
-        """
-        return self.make_fused_multistep(step_math, (), repeat=1,
-                                         step_key=step_key,
-                                         grad_reduce=grad_reduce)
-
-    def make_fused_multistep(self, step_math, scan_names, repeat=None,
-                             step_key=None, grad_reduce=None,
-                             metric=None):
-        """K whole training steps (fwd+bwd+update) in ONE donated XLA
-        dispatch, looping on-device with lax.scan.
-
-        TPU-native analog of the reference's bulk-exec segments
-        (MXNET_EXEC_BULK_EXEC_MAX_NODE_TRAIN, graph_executor.cc:1135):
-        where the reference amortizes engine-push overhead by fusing op
-        runs into segments, this amortizes the host->device dispatch
-        latency over K full steps, keeping the MXU busy back-to-back.
+        order.
 
         scan_names: args fed per-step (data/label).  In stacked mode
         the caller passes them stacked on a leading K axis; with
         `repeat=K` the currently bound batch is reused K times
-        (only the schedule rows are scanned).  step_key: see
-        make_fused_train_step; it MUST also identify grad_reduce/metric
-        (both bake into the traced program but are opaque callables
-        here).
+        (only the schedule rows are scanned).  step_key: canonical
+        identity of step_math (e.g. FusedSGD.cache_key()) — when
+        given, the compiled step is shared through the process-wide
+        executable cache across equivalent executors; it MUST also
+        identify grad_reduce/metric (both bake into the traced program
+        but are opaque callables here).
 
         grad_reduce: optional callable list->list applied to the
         gradients before step_math — the backward-interleaved bucketed
@@ -786,21 +775,18 @@ class Executor:
         # backward produces (unique_ids, rows) COO pairs instead of a
         # dense (vocab, dim) cotangent.  Resolved here, once per trace.
         sparse_rt = self._sparse_embed_entries()
-        embed_mod = None
-        sparse_dset = frozenset()
-        if sparse_rt:
-            from .parallel import embedding as embed_mod
-            scan_pos = {i: p for p, i in enumerate(scan_idx)}
-            inv_pos = {i: p for p, i in enumerate(inv_idx)}
-            # ('scan'|'inv', position) per lookup — where run_one finds
-            # each table's traced id values without threading them
-            # through the differentiated region
-            sparse_src = [[('scan', scan_pos[self._arg_pos[n]])
-                           if self._arg_pos[n] in scan_pos
-                           else ('inv', inv_pos[self._arg_pos[n]])
-                           for n in e['ids']]
-                          for e in sparse_rt]
-            sparse_dset = frozenset(e['dpos'] for e in sparse_rt)
+        from .parallel import embedding as embed_mod
+        scan_pos = {i: p for p, i in enumerate(scan_idx)}
+        inv_pos = {i: p for p, i in enumerate(inv_idx)}
+        # ('scan'|'inv', position) per lookup — where run_one finds
+        # each table's traced id values without threading them
+        # through the differentiated region
+        sparse_src = [[('scan', scan_pos[self._arg_pos[n]])
+                       if self._arg_pos[n] in scan_pos
+                       else ('inv', inv_pos[self._arg_pos[n]])
+                       for n in e['ids']]
+                      for e in sparse_rt]
+        sparse_dset = frozenset(e['dpos'] for e in sparse_rt)
         cache_key = None
         if self._sig is not None and step_key is not None:
             # step_key stays the LAST component (tests and tools key
@@ -835,80 +821,60 @@ class Executor:
                         merged[i] = v
                     return merged
 
-                if sparse_rt:
-                    # Pre-pass (outside the differentiated region):
-                    # dedup each sparse table's ids to a static rung
-                    # and gather its touched rows.  The rewrite then
-                    # serves every lookup as rows[inverse] — the vjp of
-                    # that gather IS the segment-sum, so the cotangent
-                    # arriving at `rows` is the per-unique-id summed
-                    # row-gradient, (rung, dim).
-                    uids_l, rows_l, invs_l = [], [], []
-                    for e, src in zip(sparse_rt, sparse_src):
-                        ids_vals = [sv[p] if cat == 'scan'
-                                    else inv_vals[p] for cat, p in src]
-                        uids, invs = embed_mod.dedup_ids(
-                            ids_vals, e['rung'], e['vocab'])
-                        rows = embed_mod.gather_rows(
-                            diff_vals[e['dpos']], uids)
-                        uids_l.append(uids)
-                        rows_l.append(rows)
-                        invs_l.append(invs)
+                # Pre-pass (outside the differentiated region): dedup
+                # each sparse table's ids to a static rung and gather
+                # its touched rows.  The rewrite then serves every
+                # lookup as rows[inverse] — the vjp of that gather IS
+                # the segment-sum, so the cotangent arriving at `rows`
+                # is the per-unique-id summed row-gradient, (rung,
+                # dim).  A dense model has no such table: rows_l is
+                # empty and nothing is overridden.
+                uids_l, rows_l, invs_l = [], [], []
+                for e, src in zip(sparse_rt, sparse_src):
+                    ids_vals = [sv[p] if cat == 'scan'
+                                else inv_vals[p] for cat, p in src]
+                    uids, invs = embed_mod.dedup_ids(
+                        ids_vals, e['rung'], e['vocab'])
+                    rows = embed_mod.gather_rows(
+                        diff_vals[e['dpos']], uids)
+                    uids_l.append(uids)
+                    rows_l.append(rows)
+                    invs_l.append(invs)
 
-                    def f(dv, rv):
-                        merged = merge(dv)
-                        # the full tables stay in dv so donation and
-                        # the carry signature are unchanged; their
-                        # lookups are overridden, so their dense
-                        # cotangent is zero and XLA DCEs it
-                        ov = {id(merged[e['arg_i']]):
-                              embed_mod._Override(r, iv, e['dim'])
-                              for e, r, iv in zip(sparse_rt, rv,
-                                                  invs_l)}
-                        with embed_mod.override_scope(ov), \
-                                jax.named_scope('forward'):
-                            outs, new_aux = run_graph(
-                                tuple(merged), aux_vals, sub, True)
-                        return outs, new_aux
+                def f(dv, rv):
+                    merged = merge(dv)
+                    # the full tables stay in dv so donation and the
+                    # carry signature are unchanged; their lookups are
+                    # overridden, so their dense cotangent is zero and
+                    # XLA DCEs it
+                    ov = {id(merged[e['arg_i']]):
+                          embed_mod._Override(r, iv, e['dim'])
+                          for e, r, iv in zip(sparse_rt, rv, invs_l)}
+                    with embed_mod.override_scope(ov), \
+                            jax.named_scope('forward'):
+                        outs, new_aux = run_graph(
+                            tuple(merged), aux_vals, sub, True)
+                    return outs, new_aux
 
-                    f = _maybe_remat(f, remat_mode)
-                    outs, vjp_fn, new_aux = jax.vjp(
-                        f, tuple(diff_vals), tuple(rows_l),
-                        has_aux=True)
-                    heads = tuple(jnp.ones(o.shape, o.dtype)
-                                  for o in outs)
-                    grads, rgrads = vjp_fn(heads)
-                    grads = list(grads)
-                    for e, uids, dr in zip(sparse_rt, uids_l, rgrads):
-                        grads[e['dpos']] = (uids, dr)
-                    if grad_reduce is not None:
-                        # COO grads skip the bucketed all-reduce: the
-                        # plan was built over the dense complement
-                        # (module._ensure_reduce_plan); GSPMD schedules
-                        # the sparse reduction itself
-                        didx = [j for j in range(len(grads))
-                                if j not in sparse_dset]
-                        with jax.named_scope('grad_reduce'):
-                            red = grad_reduce([grads[j] for j in didx])
-                        for j, g in zip(didx, red):
-                            grads[j] = g
-                else:
-                    def f(dv):
-                        with jax.named_scope('forward'):
-                            outs, new_aux = run_graph(
-                                tuple(merge(dv)), aux_vals, sub, True)
-                        return outs, new_aux
-
-                    f = _maybe_remat(f, remat_mode)
-                    outs, vjp_fn, new_aux = jax.vjp(f, tuple(diff_vals),
-                                                    has_aux=True)
-                    heads = tuple(jnp.ones(o.shape, o.dtype)
-                                  for o in outs)
-                    grads, = vjp_fn(heads)
-                    grads = list(grads)
-                    if grad_reduce is not None:
-                        with jax.named_scope('grad_reduce'):
-                            grads = grad_reduce(grads)
+                f = _maybe_remat(f, remat_mode)
+                outs, vjp_fn, new_aux = jax.vjp(
+                    f, tuple(diff_vals), tuple(rows_l), has_aux=True)
+                heads = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
+                grads, rgrads = vjp_fn(heads)
+                grads = list(grads)
+                for e, uids, dr in zip(sparse_rt, uids_l, rgrads):
+                    grads[e['dpos']] = (uids, dr)
+                if grad_reduce is not None:
+                    # COO grads skip the bucketed all-reduce: the plan
+                    # was built over the dense complement
+                    # (module._ensure_reduce_plan); GSPMD schedules the
+                    # sparse reduction itself
+                    didx = [j for j in range(len(grads))
+                            if j not in sparse_dset]
+                    with jax.named_scope('grad_reduce'):
+                        red = grad_reduce([grads[j] for j in didx])
+                    for j, g in zip(didx, red):
+                        grads[j] = g
                 with jax.named_scope('update'):
                     new_ws, new_moms, new_masters = step_math(
                         list(diff_vals), grads, moms, masters, lr_t,
@@ -979,8 +945,8 @@ class Executor:
         over the weights' mesh and any stale moms/masters to their
         weight's sharding.  moms/masters are aligned with diff_vals —
         except under ZeRO (zero=True), where they are per-BUCKET flat
-        shards that own their dp-axis sharding (FusedSGD host_prep
-        committed them); only the key is aligned then."""
+        shards that own their dp-axis sharding (FusedSGD
+        host_prep_steps committed them); only the key is aligned then."""
         shard = mesh = None
         for v in diff_vals:
             s = getattr(v, 'sharding', None)
@@ -1011,37 +977,47 @@ class Executor:
         masters = [recommit(m, w) for m, w in zip(masters, diff_vals)]
         return moms, masters
 
+    def _step_operands(self, diff_names, scan_names, scan_stacks, moms,
+                       masters, zero=False):
+        """The positional operands of a make_fused_multistep program
+        up to its schedule arrays, over the bound arrays: (diff_vals,
+        scan_vals, inv_vals, aux_vals, key, moms, masters), placed as
+        a donated call needs them (_align_step_placement).
+        scan_stacks: per-name stacked (K, ...) arrays, or None where
+        the bound batch is what the program reads (repeat mode, the
+        single step).  zero=True marks moms/masters as ZeRO bucket
+        shards."""
+        args = self.arg_dict
+        diff_set = set(diff_names)
+        scan_set = set(scan_names) - diff_set
+        scanned = scan_stacks if scan_stacks is not None else \
+            {n: args[n]._data for n in scan_set}
+        diff_vals = tuple(args[n]._data for n in diff_names)
+        scan_vals = tuple(scanned[n] for n in self._arg_names
+                          if n in scan_set)
+        inv_vals = tuple(args[n]._data for n in self._arg_names
+                         if n not in diff_set and n not in scan_set)
+        aux_vals = tuple(self.aux_dict[n]._data for n in self._aux_names)
+        moms, masters = self._align_step_placement(diff_vals, moms,
+                                                   masters, zero=zero)
+        return (diff_vals, scan_vals, inv_vals, aux_vals, self._key,
+                moms, masters)
+
     def run_fused_multistep(self, step, diff_names, scan_names,
                             scan_stacks, moms, masters, lrs, wds,
                             zero=False):
         """Execute a step from make_fused_multistep over the bound
-        arrays.  scan_stacks: per-name stacked (K, ...) arrays, or None
-        in repeat mode (the bound batch is reused).  zero=True marks
-        moms/masters as ZeRO bucket shards (see _align_step_placement).
-        Returns (new_moms, new_masters, metric_carry) — metric_carry
-        is the device-resident metric fold's final carry (() when the
-        program has no metric fold)."""
-        diff_set = set(diff_names)
-        scan_set = set(scan_names)
-        inv_names = [n for n in self._arg_names
-                     if n not in diff_set and n not in scan_set]
-        diff_vals = tuple(self.arg_dict[n]._data for n in diff_names)
-        if scan_stacks is not None:
-            scan_vals = tuple(scan_stacks[n] for n in self._arg_names
-                              if n in scan_set and n not in diff_set)
-        else:
-            scan_vals = tuple(self.arg_dict[n]._data
-                              for n in self._arg_names
-                              if n in scan_set and n not in diff_set)
-        inv_vals = tuple(self.arg_dict[n]._data for n in inv_names)
-        aux_vals = tuple(self.aux_dict[n]._data for n in self._aux_names)
-        moms, masters = self._align_step_placement(diff_vals, moms,
-                                                   masters, zero=zero)
+        arrays and write everything back (operands: _step_operands;
+        lrs/wds: the (K, n_params) float32 schedule arrays).  Returns
+        (new_moms, new_masters, metric_carry) — the states for the
+        optimizer to reclaim, and the device-resident metric fold's
+        final carry (() when the program has no metric fold)."""
+        operands = self._step_operands(diff_names, scan_names,
+                                       scan_stacks, moms, masters, zero)
         self.fused_dispatches += 1
         with profiler.scope('executor.dispatch', 'fused_step'):
             (outs, new_aux, new_ws, new_moms, new_masters, self._key,
-             mcarry) = step(diff_vals, scan_vals, inv_vals, aux_vals,
-                            self._key, moms, masters, lrs, wds)
+             mcarry) = step(*operands, lrs, wds)
             self._maybe_block(outs)
         for n, w in zip(diff_names, new_ws):
             self.arg_dict[n]._data = w
@@ -1067,44 +1043,15 @@ class Executor:
         committed/placement flavor than freshly-created arrays, and jax
         keys executables on it).  Without round 2 the second real step
         would still stall on a compile."""
-        import jax
-        diff_set = set(diff_names)
-        scan_set = set(scan_names)
-        inv_names = [n for n in self._arg_names
-                     if n not in diff_set and n not in scan_set]
-        diff_vals = tuple(self.arg_dict[n]._data for n in diff_names)
-        if scan_stacks is not None:
-            scan_vals = tuple(scan_stacks[n] for n in self._arg_names
-                              if n in scan_set and n not in diff_set)
-        else:
-            scan_vals = tuple(self.arg_dict[n]._data
-                              for n in self._arg_names
-                              if n in scan_set and n not in diff_set)
-        inv_vals = tuple(self.arg_dict[n]._data for n in inv_names)
-        aux_vals = tuple(self.aux_dict[n]._data for n in self._aux_names)
-        moms, masters = self._align_step_placement(diff_vals, moms,
-                                                   masters, zero=zero)
-
-        def clone(tree):
-            return jax.tree_util.tree_map(jnp.copy, tree)
-
-        dv, av = clone(diff_vals), clone(aux_vals)
-        mo, ma = clone(moms), clone(masters)
-        key = jnp.copy(self._key)
+        (diff_vals, scan_vals, inv_vals, aux_vals, key, moms,
+         masters) = self._step_operands(diff_names, scan_names,
+                                        scan_stacks, moms, masters, zero)
+        dv, av, key, mo, ma = jax.tree_util.tree_map(
+            jnp.copy, (diff_vals, aux_vals, key, moms, masters))
         for _ in range(max(1, int(rounds))):
             (_, av, dv, mo, ma, key, _mc) = step(
                 dv, scan_vals, inv_vals, av, key, mo, ma, lrs, wds)
         jax.block_until_ready((dv, av))
-
-    def run_fused_train_step(self, step, diff_names, moms, masters,
-                             lrs, wds, zero=False):
-        """Execute a step from make_fused_train_step over the bound
-        arrays and write everything back.  lrs/wds: the step's
-        schedule row as (1, n_params) float32 arrays.  Returns
-        (new_moms, new_masters) for the optimizer to reclaim."""
-        return self.run_fused_multistep(step, diff_names, (), None,
-                                        moms, masters, lrs, wds,
-                                        zero=zero)[:2]
 
     # ------------------------------------------------------------------
     def _gather(self):

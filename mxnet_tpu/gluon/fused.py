@@ -4,7 +4,7 @@ The early-Gluon imperative path trains op-by-op: `autograd.backward`
 replays the tape with one `jax.vjp` dispatch per node, and
 `Trainer.step` runs a Python loop doing per-parameter reduce + updater
 calls — the dispatch-bound regime this project exists to eliminate.
-The Module path already escaped it (executor.make_fused_train_step:
+The Module path already escaped it (executor.make_fused_multistep:
 fwd+bwd+update as ONE donated XLA dispatch, exec_cache'd, ZeRO-1
 sharded).  This module brings the same whole-program compilation to
 hybrid nets trained imperatively:
@@ -843,8 +843,9 @@ class FusedStep:
             self._ema_state = [jnp.add(w, 0) for w in ws]
         emas = tuple(self._ema_state) if self._ema_decay is not None \
             else ()
-        # host_prep reads shape/dtype/_data (momenta adopt the weight's
-        # sharding) — hand it the replicated parents, not the views
+        # host_prep_steps reads shape/dtype/_data (momenta adopt the
+        # weight's sharding) — hand it the replicated parents, not the
+        # views
         weights = [nd.NDArray(w, self._ctxs[0]) for w in ws]
         # per-step schedule stacks: counts bump and lr/wd schedules
         # evaluate at EVERY step index of the dispatch (host scheduler

@@ -7,6 +7,7 @@ per-GPU executors + KVStore push/pull — see executor_group.py), but the
 public API and KVStore interplay (update_on_kvstore, optimizer state
 save/load) match the reference.
 """
+import contextlib
 import logging
 import weakref
 
@@ -59,8 +60,10 @@ class Module(BaseModule):
         self._data_shapes = self._label_shapes = None
         # whole-step fusion (fwd+bwd+update in one donated XLA dispatch)
         self._pending_fused = False
-        self._fused_step = None
-        self._fused_step_key = None
+        # compiled steps by (form, k, scan_dtype, fkey), and weak
+        # references to the executor and updater they were built for
+        self._step_programs = {}
+        self._step_programs_owner = None
 
     # -- checkpoint (reference module.py:114-173) -------------------------
     @staticmethod
@@ -486,35 +489,6 @@ class Module(BaseModule):
             self._reduce_plan_inputs = (shapes, dtypes)
         return self._reduce_plan
 
-    def _ensure_fused_program(self, ex, fu, fnames):
-        """Build (or fetch) the single-step fused program for this
-        executor/updater pair.  Must run AFTER fu.host_prep (under
-        ZeRO, fu.cache_key() carries the bucket layout host_prep may
-        have just rebuilt).
-
-        Keyed on executor AND updater AND the updater's cache_key:
-        init_optimizer(force_init=True) makes a new FusedSGD whose
-        step_math bakes new hyperparams — a stale program would run
-        old-layout buckets against new state shapes.  The reduce plan
-        (bucketing + schedule) is baked into the traced step, so it
-        joins too — WITH the mesh fingerprint: the grad_reduce closure
-        binds a concrete mesh, so unlike the mesh-free step body it
-        cannot be retraced for a different device set.  (step_key
-        routes the compiled step through the process-wide executable
-        cache, so a mismatch here rarely means a recompile.)"""
-        plan = self._ensure_reduce_plan(ex, fu, fnames)
-        fkey = (fu.cache_key(),
-                (plan.key, self._mesh_fp()) if plan is not None
-                else None)
-        if self._fused_step_key != (ex, fu, fkey):
-            mesh = self._exec_group.mesh
-            gr = (lambda grads: plan.apply(grads, mesh)) \
-                if plan is not None else None
-            self._fused_step = ex.make_fused_train_step(
-                fu.step_math, step_key=fkey, grad_reduce=gr)
-            self._fused_step_key = (ex, fu, fkey)
-        return self._fused_step
-
     def _schedule_arrays(self, lrs, wds):
         """The (K, n_params) float32 learning rates and weight decays
         of a dispatch as the compiled step takes them: ONE array each
@@ -530,23 +504,6 @@ class Module(BaseModule):
             from ..parallel import mesh as pmesh
             repl = pmesh.replicated(mesh)
         return jax.device_put((lrs, wds), repl)
-
-    def _run_fused_step(self):
-        ex = self._exec_group.executor
-        fu = self._fused_updater
-        fnames = ex._diff_names
-        with profiler.scope('module.host_prep', 'fused_step'):
-            if fu.param_names != fnames:
-                fu.param_names = list(fnames)
-            weights = [ex.arg_dict[n] for n in fnames]
-            moms, masters, lrs, wds = fu.host_prep(weights)
-            lrs, wds = self._schedule_arrays(lrs[None], wds[None])
-            self._ensure_fused_program(ex, fu, fnames)
-        new_moms, new_masters = ex.run_fused_train_step(
-            self._fused_step, fnames, moms, masters, lrs, wds,
-            zero=bool(fu.zero))
-        fu.commit(new_moms, new_masters)
-        self._note_step_counters(1)
 
     def _note_step_counters(self, k, metric_steps=0):
         """Feed the profiler's comm/memory counters after k fused
@@ -572,26 +529,42 @@ class Module(BaseModule):
         profiler.note_reduce_dispatch(buckets, k,
                                       metric_steps=metric_steps)
 
-    def _ensure_bulk_program(self, ex, fu, fnames, scan_names, k,
-                             stacked, scan_dtype, fold):
-        """Build (or fetch) the K-step bulk program.  Must run AFTER
-        fu.host_prep/host_prep_steps: under ZeRO, fu.cache_key()
-        carries the bucket layout host_prep may have just rebuilt; the
-        reduce plan (+ the mesh its closure binds) and metric fold
-        bake into the traced scan, so they join too (carry
-        signature)."""
+    def _fused_program(self, form, k, scan_names, scan_dtype, fold):
+        """Build (or fetch) the compiled step of k steps: form
+        'single' (k = 1: the bound batch, no loop), 'stacked' (k
+        batches stacked on a leading axis and scanned) or 'repeat' (the
+        bound batch k times).  Must run AFTER fu.host_prep_steps:
+        under ZeRO, fu.cache_key() carries the bucket layout it may
+        have just rebuilt.
+
+        The table holds what was built for THIS executor and updater
+        (init_optimizer(force_init=True) makes a new FusedSGD whose
+        step_math bakes new hyperparams; a stale program would run
+        old-layout buckets against new state shapes) and is dropped
+        with either; weak references, so that a released module does
+        not keep their weights and optimizer state on the device.  The
+        reduce plan (bucketing + schedule) and the metric fold bake
+        into the traced step, so they join the key — the plan WITH the
+        mesh fingerprint: the grad_reduce closure binds a concrete
+        mesh, so unlike the mesh-free step body it cannot be retraced
+        for a different device set.  (step_key routes the compiled
+        step through the process-wide executable cache, so a miss here
+        rarely means a recompile.)"""
         eg = self._exec_group
+        ex, fu = eg.executor, self._fused_updater
+        fnames = ex._diff_names
+        owner = self._step_programs_owner
+        if owner is None or owner[0]() is not ex or owner[1]() is not fu:
+            self._step_programs = {}
+            self._step_programs_owner = (weakref.ref(ex), weakref.ref(fu))
         plan = self._ensure_reduce_plan(ex, fu, fnames)
         fkey = (fu.cache_key(),
                 (plan.key, self._mesh_fp()) if plan is not None
                 else None,
                 fold.key if fold is not None else None)
-        # weak: the key outlives a released executor and updater, and
-        # must not keep their weights and optimizer state on the device
-        cache_key = (weakref.ref(ex), weakref.ref(fu)) + (
-            ('stacked', k, str(scan_dtype)) if stacked
-            else ('repeat', k)) + (fkey,)
-        if getattr(self, '_bulk_cache_key', None) != cache_key:
+        key = (form, k, str(scan_dtype), fkey)
+        program = self._step_programs.get(key)
+        if program is None:
             mesh = eg.mesh
             gr = (lambda grads: plan.apply(grads, mesh)) \
                 if plan is not None else None
@@ -611,12 +584,63 @@ class Module(BaseModule):
                     return _fold.update(mc, label, pred)
 
                 metric_arg = (fold.init, m_update)
-            self._bulk_step_fn = ex.make_fused_multistep(
+            program = self._step_programs[key] = ex.make_fused_multistep(
                 fu.step_math, scan_names,
-                repeat=(None if stacked else k),
+                repeat=(None if form == 'stacked' else k),
                 step_key=fkey, grad_reduce=gr, metric=metric_arg)
-            self._bulk_cache_key = cache_key
-        return self._bulk_step_fn
+        return program
+
+    def _step_program(self, form='single', k=1):
+        """The compiled step of this form and k that this module
+        built last (None when it has built none): what tests and
+        chip_smoke.py lower again or compare."""
+        for key in reversed(self._step_programs):
+            if key[:2] == (form, k):
+                return self._step_programs[key]
+        return None
+
+    def _dispatch_fused(self, form, k, scan_names=(), scan_stacks=None,
+                        scan_dtype=None, fold=None, warm=False):
+        """The one driver of the compiled step: k whole training steps
+        over the bound batch (form 'single', 'repeat') or over
+        scan_stacks ('stacked') as one dispatch.  Spans:
+        'module.host_prep' (optimizer state, schedule rows and their
+        one put, program lookup), then the executor's
+        'executor.dispatch'.  warm=True compiles the same program for
+        the same operands on cloned buffers instead
+        (executor.warm_fused_multistep): no state, schedule or counter
+        changes and no span opens."""
+        ex = self._exec_group.executor
+        fu = self._fused_updater
+        fnames = ex._diff_names
+        span = contextlib.nullcontext() if warm else \
+            profiler.scope('module.host_prep', 'fused_step')
+        with span:
+            if fu.param_names != fnames:
+                fu.param_names = list(fnames)
+            weights = [ex.arg_dict[n] for n in fnames]
+            # counts bump and lr/wd evaluate at every step index (host
+            # scheduler semantics)
+            moms, masters, lrs, wds = fu.host_prep_steps(
+                weights, k, advance=not warm)
+            lrs, wds = self._schedule_arrays(lrs, wds)
+            program = self._fused_program(form, k, scan_names,
+                                          scan_dtype, fold)
+        if warm:
+            ex.warm_fused_multistep(program, fnames, scan_names,
+                                    scan_stacks, moms, masters, lrs, wds,
+                                    zero=bool(fu.zero))
+            return
+        new_moms, new_masters, mcarry = ex.run_fused_multistep(
+            program, fnames, scan_names, scan_stacks, moms, masters,
+            lrs, wds, zero=bool(fu.zero))
+        fu.commit(new_moms, new_masters)
+        if fold is not None:
+            # device scalars queue on the host metric WITHOUT a sync;
+            # the first metric.get() drains them
+            fold.commit(mcarry)
+        self._note_step_counters(
+            k, metric_steps=k if fold is not None else 0)
 
     def warmup_fused(self, bulk=None, eval_metric=None, scan_dtype=None,
                      single=True):
@@ -639,29 +663,18 @@ class Module(BaseModule):
             self.optimizer_initialized
         if not self._fusable_step():
             return False
-        import jax.numpy as jnp
-        eg = self._exec_group
-        ex = eg.executor
-        fu = self._fused_updater
-        fnames = ex._diff_names
-        if fu.param_names != fnames:
-            fu.param_names = list(fnames)
-        weights = [ex.arg_dict[n] for n in fnames]
         if single:
-            moms, masters, lrs, wds = fu.host_prep(weights,
-                                                   advance=False)
-            lrs, wds = self._schedule_arrays(lrs[None], wds[None])
-            step = self._ensure_fused_program(ex, fu, fnames)
-            ex.warm_fused_multistep(step, fnames, (), None, moms,
-                                    masters, lrs, wds,
-                                    zero=bool(fu.zero))
+            self._dispatch_fused('single', 1, warm=True)
         if bulk is None or int(bulk) <= 1:
             return True
+        import jax
+        import jax.numpy as jnp
         k = int(bulk)
+        eg = self._exec_group
+        ex = eg.executor
         fold = metric_mod.device_fold(eval_metric) \
             if eval_metric is not None else None
-        scan_names = [n for n in eg.data_names + eg.label_names
-                      if n in ex.arg_dict and n not in set(fnames)]
+        scan_names = self._scan_names()
         data_set = set(eg.data_names)
         scan_stacks = {}
         for n in scan_names:
@@ -669,7 +682,6 @@ class Module(BaseModule):
             store = scan_dtype if (scan_dtype is not None and
                                    n in data_set) else bound.dtype
             scan_stacks[n] = jnp.zeros((k,) + tuple(bound.shape), store)
-        import jax
         if eg.mesh is not None:
             from ..parallel import mesh as pmesh
             scan_stacks = {n: pmesh.shard_batch(eg.mesh, v, dim=1)
@@ -681,15 +693,8 @@ class Module(BaseModule):
             dev = self._context[0].jax_device()
             scan_stacks = {n: jax.device_put(v, dev)
                            for n, v in scan_stacks.items()}
-        moms, masters, lr_stack, wd_stack = fu.host_prep_steps(
-            weights, k, advance=False)
-        lrs, wds = self._schedule_arrays(lr_stack, wd_stack)
-        fn = self._ensure_bulk_program(ex, fu, fnames, scan_names, k,
-                                       stacked=True,
-                                       scan_dtype=scan_dtype, fold=fold)
-        ex.warm_fused_multistep(fn, fnames, scan_names, scan_stacks,
-                                moms, masters, lrs, wds,
-                                zero=bool(fu.zero))
+        self._dispatch_fused('stacked', k, scan_names, scan_stacks,
+                             scan_dtype, fold, warm=True)
         return True
 
     def bulk_step(self, batches=None, batch=None, repeat=None,
@@ -756,16 +761,11 @@ class Module(BaseModule):
                          eval_metric):
         """bulk_step's fused path.  Spans inside 'module.bulk_step':
         'module.bulk_stack' (the K batches cast and stacked on the
-        device), 'module.host_prep' (optimizer state, schedule columns,
-        program lookup), then the executor's 'executor.dispatch'."""
+        device), then the driver's (_dispatch_fused)."""
         self._materialize_fused()
         import jax.numpy as jnp
         eg = self._exec_group
         ex = eg.executor
-        fu = self._fused_updater
-        fnames = ex._diff_names
-        if fu.param_names != fnames:
-            fu.param_names = list(fnames)
         fold = None
         if eval_metric is not None:
             fold = metric_mod.device_fold(eval_metric)
@@ -775,8 +775,7 @@ class Module(BaseModule):
                     'metric.device_fold); run the per-step loop for '
                     'host-only metrics'
                     % (getattr(eval_metric, 'name', eval_metric),))
-        scan_names = [n for n in eg.data_names + eg.label_names
-                      if n in ex.arg_dict and n not in set(fnames)]
+        scan_names = self._scan_names()
         if scan_dtype is not None:
             self._check_scan_dtype_holds_ids(ex, scan_dtype)
         scan_stacks = None
@@ -811,27 +810,19 @@ class Module(BaseModule):
                         for n, v in scan_stacks.items()}
         else:
             eg.load_data_batch(batch)
-        with profiler.scope('module.host_prep', 'fused_step'):
-            weights = [ex.arg_dict[n] for n in fnames]
-            # per-step schedule stacks: counts bump and lr/wd evaluate
-            # at every step index (host scheduler semantics)
-            moms, masters, lr_stack, wd_stack = fu.host_prep_steps(
-                weights, k)
-            lrs, wds = self._schedule_arrays(lr_stack, wd_stack)
-            self._ensure_bulk_program(ex, fu, fnames, scan_names, k,
-                                      stacked=(batches is not None),
-                                      scan_dtype=scan_dtype, fold=fold)
-        new_moms, new_masters, mcarry = ex.run_fused_multistep(
-            self._bulk_step_fn, fnames, scan_names, scan_stacks,
-            moms, masters, lrs, wds, zero=bool(fu.zero))
-        fu.commit(new_moms, new_masters)
-        if fold is not None:
-            # device scalars queue on the host metric WITHOUT a sync;
-            # the first metric.get() drains them
-            fold.commit(mcarry)
-        self._note_step_counters(
-            k, metric_steps=k if fold is not None else 0)
+        self._dispatch_fused(
+            'stacked' if batches is not None else 'repeat', k,
+            scan_names, scan_stacks, scan_dtype, fold)
         self._params_dirty = True
+
+    def _scan_names(self):
+        """The data and label inputs a K-step program is fed step by
+        step."""
+        eg = self._exec_group
+        ex = eg.executor
+        diff_set = set(ex._diff_names)
+        return [n for n in eg.data_names + eg.label_names
+                if n in ex.arg_dict and n not in diff_set]
 
     def _check_scan_dtype_holds_ids(self, ex, scan_dtype):
         """Token ids are carried as floats: a data input that feeds an
@@ -894,7 +885,7 @@ class Module(BaseModule):
         self._params_dirty = True
         if self._pending_fused:
             self._pending_fused = False
-            self._run_fused_step()
+            self._dispatch_fused('single', 1)
             return
         if self._fused_updater is not None:
             weights, grads = [], []

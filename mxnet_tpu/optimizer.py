@@ -633,7 +633,7 @@ class FusedSGD:
                 'axis; mesh axes are %s' % (mesh.axis_names,))
         # ZeRO bucket state: layout + per-bucket flat shards (momenta /
         # fp32 masters), plus per-param staged values from set_states
-        # waiting to be re-bucketed at the next host_prep
+        # waiting to be re-bucketed at the next host_prep_steps
         self._layout = None
         self._layout_inputs = None
         self._layout_names = None
@@ -712,8 +712,8 @@ class FusedSGD:
             # step_math / _jit_step are (re)bound in _host_prep_zero,
             # which captures the bucket layout BY VALUE: a step program
             # cached under one layout's key must never read a layout
-            # this object later rebuilt (host_prep always runs before
-            # step_math is handed to the executor or traced)
+            # this object later rebuilt (host_prep_steps always runs
+            # before step_math is handed to the executor or traced)
             self.step_math = None
             self._jit_step = None
         else:
@@ -738,59 +738,64 @@ class FusedSGD:
                      else None, self._mesh_fp, self._interleave),)
         return key
 
-    def host_prep(self, weights, advance=True):
-        """Per-step host-side bookkeeping shared by the standalone
-        update and the whole-step fusion (executor.make_fused_train_step):
-        lazily create momenta / fp32 masters, bump update counts, and
-        evaluate lr/wd schedules.  Returns (moms, masters, lrs, wds)
-        aligned with param_names; lrs/wds are float32 vectors of
-        length n_params (one row of the schedule arrays the compiled
-        steps take).
+    def host_prep_steps(self, weights, k, advance=True):
+        """The host side of a dispatch of k steps, shared by the
+        standalone update (k = 1) and the compiled steps
+        (executor.make_fused_multistep): lazily create momenta / fp32
+        masters (the ZeRO bucket layout under zero=1), then bump the
+        update counts and evaluate the lr/wd schedules at EVERY step
+        index, exactly as the per-step loop would, so a
+        FactorScheduler boundary crossed mid-dispatch decays at the
+        right step.  Returns (moms, masters, lrs, wds): the states
+        aligned with param_names, lrs/wds float32 arrays of shape
+        (k, n_params), one row a step.
 
         advance=False (AOT warmup, Module.warmup_fused): states still
         materialize lazily — the warmup call must see exactly the
         buffers a real step would — but the update counts / schedule
         state are restored afterwards, so warming a ladder of bucket
         programs does not advance the lr schedule."""
+        opt = self.optimizer
+        saved = None if advance else self._snapshot_schedule_state()
+        moms, masters = self._host_prep_zero(weights) if self.zero \
+            else self._host_prep_states(weights)
+        shape = (max(1, k), len(self.param_names))
+        lrs, wds = np.empty(shape, np.float32), np.empty(shape, np.float32)
+        for lr_row, wd_row in zip(lrs, wds):
+            for j, name in enumerate(self.param_names):
+                opt._update_count(name)
+                lr_row[j] = opt._get_lr(name)
+                wd_row[j] = opt._get_wd(name)
+        if saved is not None:
+            self._restore_schedule_state(saved)
+        return moms, masters, lrs, wds
+
+    def _host_prep_states(self, weights):
+        """Replicated lazy state init: a momentum for every parameter
+        and an fp32 master for every low-precision one."""
         import jax
         import jax.numpy as jnp
-        opt = self.optimizer
-        saved_counts = None
-        if not advance:
-            saved_counts = self._snapshot_schedule_state()
-        if self.zero:
-            moms, masters = self._host_prep_zero(weights)
-        else:
-            for name, w in zip(self.param_names, weights):
-                mp = self._is_mp(w)
-                if name not in self.states:
-                    mdtype = np.float32 if mp else w.dtype
-                    # commit fresh state to the weight's placement: an
-                    # uncommitted zeros on call 1 vs a committed donated
-                    # output on call 2 changes the jit sharding
-                    # signature and forces a full recompile of the
-                    # fused step
-                    sharding = getattr(w._data, 'sharding', None)
-                    zeros = jnp.zeros(w.shape, dtype=mdtype)
-                    self.states[name] = jax.device_put(zeros, sharding) \
-                        if sharding is not None else zeros
-                if name not in self.masters:
-                    # backfill (fresh start or restored checkpoint
-                    # without masters): re-derive from the current
-                    # weight
-                    self.masters[name] = w._data.astype(np.float32) \
-                        if mp else None
-            moms = [self.states[n] for n in self.param_names]
-            masters = [self.masters[n] for n in self.param_names]
-        lrs = np.empty(len(self.param_names), np.float32)
-        wds = np.empty(len(self.param_names), np.float32)
-        for j, name in enumerate(self.param_names):
-            opt._update_count(name)
-            lrs[j] = opt._get_lr(name)
-            wds[j] = opt._get_wd(name)
-        if saved_counts is not None:
-            self._restore_schedule_state(saved_counts)
-        return moms, masters, lrs, wds
+        for name, w in zip(self.param_names, weights):
+            mp = self._is_mp(w)
+            if name not in self.states:
+                mdtype = np.float32 if mp else w.dtype
+                # commit fresh state to the weight's placement: an
+                # uncommitted zeros on call 1 vs a committed donated
+                # output on call 2 changes the jit sharding
+                # signature and forces a full recompile of the
+                # fused step
+                sharding = getattr(w._data, 'sharding', None)
+                zeros = jnp.zeros(w.shape, dtype=mdtype)
+                self.states[name] = jax.device_put(zeros, sharding) \
+                    if sharding is not None else zeros
+            if name not in self.masters:
+                # backfill (fresh start or restored checkpoint
+                # without masters): re-derive from the current
+                # weight
+                self.masters[name] = w._data.astype(np.float32) \
+                    if mp else None
+        return ([self.states[n] for n in self.param_names],
+                [self.masters[n] for n in self.param_names])
 
     def _snapshot_schedule_state(self):
         """Everything _get_lr mutates: the update counts AND the
@@ -811,34 +816,6 @@ class FusedSGD:
         if sched_state is not None:
             opt.lr_scheduler.__dict__.clear()
             opt.lr_scheduler.__dict__.update(sched_state)
-
-    def host_prep_steps(self, weights, k, advance=True):
-        """host_prep for a K-step bulk dispatch: states init once, the
-        update counts bump K times, and the lr/wd schedules evaluate at
-        EVERY step index (the host scheduler runs exactly as the
-        per-step loop would, so a FactorScheduler boundary crossed
-        mid-dispatch decays at the right step — schedules no longer
-        advance in bulk-size units).  Returns (moms, masters, lrs,
-        wds) with lrs/wds float32 arrays of shape (k, n_params), fed
-        to the scan as per-step inputs.  advance=False: see host_prep
-        (AOT warmup — schedule state restored afterwards)."""
-        opt = self.optimizer
-        saved_counts = None
-        if not advance:
-            saved_counts = self._snapshot_schedule_state()
-        moms, masters, lrs0, wds0 = self.host_prep(weights)
-        n = len(self.param_names)
-        lrs = np.empty((max(1, k), n), np.float32)
-        wds = np.empty((max(1, k), n), np.float32)
-        lrs[0], wds[0] = lrs0, wds0
-        for s in range(1, k):
-            for j, name in enumerate(self.param_names):
-                opt._update_count(name)
-                lrs[s, j] = opt._get_lr(name)
-                wds[s, j] = opt._get_wd(name)
-        if saved_counts is not None:
-            self._restore_schedule_state(saved_counts)
-        return moms, masters, lrs, wds
 
     def _is_mp(self, w):
         import jax.numpy as jnp
@@ -1068,11 +1045,11 @@ class FusedSGD:
                 'a sparse-table FusedSGD only runs inside the fused '
                 'train step (its sparse gradients are COO pairs the '
                 'step constructs in-trace, not standalone arrays)')
-        moms, masters, lrs, wds = self.host_prep(weights)
+        moms, masters, lrs, wds = self.host_prep_steps(weights, 1)
         ws = [w._data for w in weights]
         gs = [g._data for g in grads]
         new_ws, new_moms, new_masters = self._jit_step(
-            ws, gs, moms, masters, lrs, wds)
+            ws, gs, moms, masters, lrs[0], wds[0])
         for w, nw in zip(weights, new_ws):
             w._data = nw
         self.commit(new_moms, new_masters)
@@ -1102,8 +1079,8 @@ class FusedSGD:
         and [momentum, fp32_master] pairs for multi-precision params,
         while FusedSGD checkpoints carry momenta and masters
         separately.  Missing entries re-materialize lazily in
-        host_prep (zeros momenta / masters re-derived from weights) —
-        the same backfill a fresh start uses."""
+        host_prep_steps (zeros momenta / masters re-derived from
+        weights) — the same backfill a fresh start uses."""
         moms = {}
         out_masters = {n: v for n, v in (masters or {}).items()
                        if v is not None}
@@ -1188,9 +1165,10 @@ class FusedSGD:
         # formats to both paths)
         moms, masters = self._split_updater_states(states, masters)
         if self.zero:
-            # stage per-param values; the next host_prep re-buckets
-            # them into dp-sharded flat buffers (the layout, if already
-            # built, stays valid — only the state buffers rebuild)
+            # stage per-param values; the next host_prep_steps
+            # re-buckets them into dp-sharded flat buffers (the layout,
+            # if already built, stays valid — only the state buffers
+            # rebuild)
             self._staged = (moms, masters)
             self._zero_moms = None
             self._zero_masters = None
@@ -1199,7 +1177,8 @@ class FusedSGD:
             self.states = {n: jnp.asarray(v) for n, v in moms.items()}
             # fp32 masters ride along with the momentum states;
             # checkpoints without them re-derive masters from the
-            # weights at the next host_prep (backfills missing keys)
+            # weights at the next host_prep_steps (backfills missing
+            # keys)
             self.masters = {n: jnp.asarray(v)
                             for n, v in masters.items()}
         if counts is not None:

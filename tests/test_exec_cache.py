@@ -151,7 +151,7 @@ def test_fused_train_step_shared_across_modules():
     before = exec_cache.stats()
     mod2 = train_one()
     after = exec_cache.stats()
-    assert mod2._fused_step is mod1._fused_step
+    assert mod2._step_program('single') is mod1._step_program('single')
     assert after['total_compile_s'] == before['total_compile_s']
 
 

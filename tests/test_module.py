@@ -315,6 +315,33 @@ def test_module_weights_are_committed_and_the_step_compiles_once(given):
         np.testing.assert_array_equal(mod._arg_params[n].asnumpy(), w)
 
 
+@pytest.mark.parametrize('k', [1, 4])
+def test_released_module_frees_its_state(k):
+    """A module whose executor group and updater were dropped (as the
+    benchmark's release_module drops them) keeps neither alive through
+    the table of its compiled steps: the single step and K = 4."""
+    import gc
+    import weakref
+    rng = np.random.RandomState(3)
+    batches = [mx.io.DataBatch(
+        data=[nd.array(rng.rand(16, 8).astype(np.float32))],
+        label=[nd.array((rng.rand(16) * 4).astype(np.float32))])
+        for _ in range(k)]
+    mod = _bulk_mod([mx.cpu(0)], kvstore=None)
+    if k == 1:
+        mod.forward_backward(batches[0])
+        mod.update()
+    else:
+        mod.bulk_step(batches=batches)
+    assert mod._step_program('single' if k == 1 else 'stacked', k)
+    alive = [weakref.ref(mod._exec_group.executor),
+             weakref.ref(mod._fused_updater)]
+    mod._exec_group = None
+    mod._fused_updater = None
+    gc.collect()
+    assert [ref() for ref in alive] == [None, None]
+
+
 def test_bulk_step_scan_dtype_storage():
     """bulk_step(scan_dtype=...) stores the stacked data batches in a
     narrower dtype and the fused step casts back before the graph

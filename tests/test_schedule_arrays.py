@@ -74,23 +74,30 @@ def _seed_params(dtype):
 # (a) what the compiled single step is handed
 # ---------------------------------------------------------------------------
 
-class _Spy:
-    """Stands in for Module._fused_step and keeps every call's
-    arguments."""
+def _spy_on_steps(mod, lowered=None):
+    """Every call of a compiled step by the module's executor, as the
+    program's positional arguments; with `lowered`, also the text each
+    program lowers to for them."""
+    ex = mod._exec_group.executor
+    run, calls = ex.run_fused_multistep, []
 
-    def __init__(self, fn):
-        self.fn, self.calls = fn, []
+    def spied(step, *args, **kwargs):
+        def record(*operands):
+            calls.append(operands)
+            if lowered is not None:
+                lowered.append(step.lower(*operands).as_text())
+            return step(*operands)
+        return run(record, *args, **kwargs)
 
-    def __call__(self, *args):
-        self.calls.append(args)
-        return self.fn(*args)
+    ex.run_fused_multistep = spied
+    return calls
 
 
 @pytest.mark.parametrize('entry', ['update', 'fit'])
 def test_single_step_is_handed_two_schedule_arrays(entry):
     mod = _module()
-    mod.warmup_fused()               # builds Module._fused_step
-    spy = mod._fused_step = _Spy(mod._fused_step)
+    mod.warmup_fused()
+    calls = _spy_on_steps(mod)
     if entry == 'update':
         for b in _batches(2):
             mod.forward_backward(b)
@@ -102,9 +109,9 @@ def test_single_step_is_handed_two_schedule_arrays(entry):
             (rng.rand(2 * BATCH) * 4).astype(np.float32), BATCH)
         mod.fit(it, num_epoch=1, eval_metric='acc',
                 batch_end_callback=lambda p: None)
-    assert len(spy.calls) == 2
+    assert len(calls) == 2
     n = len(mod._exec_group.executor._diff_names)
-    for args in spy.calls:
+    for args in calls:
         assert len(args) == 9
         for hyper in args[7:]:
             assert isinstance(hyper, jax.Array)
@@ -120,7 +127,7 @@ def test_single_step_is_handed_two_schedule_arrays(entry):
         assert len(leaves) == n + 2 + 1 + n + 2
     # the rows are the optimizer's own numbers: update 1 at the base
     # rate, each parameter by its multiplier
-    lrs, wds = (np.asarray(a)[0] for a in spy.calls[0][7:])
+    lrs, wds = (np.asarray(a)[0] for a in calls[0][7:])
     names = mod._exec_group.executor._diff_names
     want_lr = {'fc1_weight': 0.1, 'fc2_bias': 0.4}
     want_wd = {'fc1_weight': 1e-2, 'fc2_weight': 3e-2, 'fc1_bias': 5e-3,
@@ -233,21 +240,21 @@ def test_steps_agree_across_paths(arm, dtype):
     assert ref._optimizer.lr_scheduler.base_lr == pytest.approx(0.05)
 
 
-def test_host_prep_returns_float32_vectors():
-    """One row of the schedule arrays; host_prep_steps stacks K of
-    them, and the first is host_prep's."""
+def test_host_prep_returns_float32_rows():
+    """One row of the schedule arrays a step; the single step's is the
+    first of K."""
     mod = _module()
     ex, fu = mod._exec_group.executor, mod._fused_updater
     weights = [ex.arg_dict[n] for n in ex._diff_names]
-    _, _, lrs, wds = fu.host_prep(weights, advance=False)
+    _, _, lrs, wds = fu.host_prep_steps(weights, 1, advance=False)
     _, _, lr_stack, wd_stack = fu.host_prep_steps(weights, 3,
                                                   advance=False)
     n = len(weights)
     for row, stack in ((lrs, lr_stack), (wds, wd_stack)):
         assert isinstance(row, np.ndarray)
-        assert row.dtype == np.float32 and row.shape == (n,)
+        assert row.dtype == np.float32 and row.shape == (1, n)
         assert stack.dtype == np.float32 and stack.shape == (3, n)
-        np.testing.assert_array_equal(stack[0], row)
+        np.testing.assert_array_equal(stack[:1], row)
     np.testing.assert_array_equal(lr_stack[2], lr_stack[0] * 0.5)
     assert mod._optimizer.num_update == 0          # advance=False
 
@@ -256,32 +263,82 @@ def test_host_prep_returns_float32_vectors():
 # (c) a warmed step compiles nothing more
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize('bulk', [None, 4])
 @pytest.mark.parametrize('n_ctx,zero', [(1, 0), (4, 0), (4, 1)])
-def test_warmed_single_step_compiles_nothing_more(n_ctx, zero):
-    """warmup_fused hands the step the same kind of schedule array as
-    the real step (uncommitted on one device, replicated over the
-    mesh): jax sees one signature."""
+def test_warmed_step_compiles_nothing_more(n_ctx, zero, bulk):
+    """warmup_fused hands the step the same kind of operands as the
+    real dispatch (the schedule arrays uncommitted on one device,
+    replicated over the mesh; the stacks placed as staged batches
+    are): jax sees one signature, for the single step and for K=4."""
     mod = _module(ctxs=[mx.cpu(i) for i in range(n_ctx)], zero=zero)
-    batches = _batches(3)
     compiles = []
 
     def on_duration(event, duration, **_):
         if event == '/jax/core/compile/backend_compile_duration':
             compiles.append(duration)
 
-    mod.warmup_fused()
-    step = mod._fused_step.fn
+    mod.warmup_fused(bulk=bulk)
+    form = ('single', 1) if bulk is None else ('stacked', bulk)
+    step = mod._step_program(*form).fn
     sizes = step._cache_size()
     billed = exec_cache.stats()['total_compile_s']
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     try:
-        for b in batches:
-            mod.forward_backward(b)
-            mod.update()
+        if bulk is None:
+            for b in _batches(3):
+                mod.forward_backward(b)
+                mod.update()
+        else:
+            for seed in range(2):
+                mod.bulk_step(batches=_batches(bulk, seed))
         jax.block_until_ready(mod.get_outputs()[0]._data)
     finally:
         jax.monitoring.unregister_event_duration_listener(on_duration)
-    assert mod._fused_step.fn is step
+    assert mod._step_program(*form).fn is step
     assert step._cache_size() == sizes
     assert exec_cache.stats()['total_compile_s'] == billed
-    assert compiles == []
+    if bulk is None:        # bulk_step's stacking compiles its own ops
+        assert compiles == []
+
+
+# ---------------------------------------------------------------------------
+# (e) one table of programs, one builder
+# ---------------------------------------------------------------------------
+
+def test_a_k_seen_before_builds_nothing():
+    """K = 4, 2, 4 in turn: two programs, each built once."""
+    mod = _module()
+    ex = mod._exec_group.executor
+    make, built = ex.make_fused_multistep, []
+
+    def spied(*args, **kwargs):
+        built.append(args)
+        return make(*args, **kwargs)
+
+    ex.make_fused_multistep = spied
+    for k in (4, 2, 4):
+        mod.bulk_step(batches=_batches(k))
+    assert len(built) == 2
+
+
+@pytest.mark.parametrize('form,k,loops', [
+    ('single', 1, 0), ('stacked', 4, 1), ('repeat', 4, 1)])
+def test_only_k_steps_make_a_loop(form, k, loops):
+    """The single step is the K-step builder's program without the
+    scan: no loop in its main function (the random key's split keeps
+    one in a function of its own), one for K = 4."""
+    mod = _module()
+    texts = []
+    calls = _spy_on_steps(mod, texts)
+    batches = _batches(k)
+    if form == 'single':
+        mod.forward_backward(batches[0])
+        mod.update()
+    elif form == 'stacked':
+        mod.bulk_step(batches=batches)
+    else:
+        mod.bulk_step(batch=batches[0], repeat=k)
+    assert len(calls) == 1 and calls[0][7].shape[0] == k
+    main = texts[0][texts[0].index('func.func public @main'):]
+    main = main[:main.index('func.func private')]
+    assert main.count('stablehlo.while') == loops

@@ -212,16 +212,21 @@ def fit_run(tmp_path_factory):
             'events': _host_events(trace_dir)}
 
 
-@pytest.fixture(scope='module')
-def bulk_run():
-    """Two Module.bulk_step dispatches of K=2 staged batches."""
-    rng = np.random.RandomState(1)
+def _bound_module():
     mod = mx.mod.Module(_net(), context=mx.cpu(0))
     mod.bind(data_shapes=[('data', (BATCH, DIM))],
              label_shapes=[('softmax_label', (BATCH,))])
     mod.init_params()
     mod.init_optimizer(optimizer='sgd',
                        optimizer_params={'learning_rate': 0.1})
+    return mod
+
+
+@pytest.fixture(scope='module')
+def bulk_run():
+    """Two Module.bulk_step dispatches of K=2 staged batches."""
+    rng = np.random.RandomState(1)
+    mod = _bound_module()
     batches = [mx.io.DataBatch(
         data=[mx.nd.array(rng.rand(BATCH, DIM).astype(np.float32))],
         label=[mx.nd.array(rng.randint(0, 2, BATCH).astype(np.float32))])
@@ -346,15 +351,11 @@ def test_fused_step_carries_named_scopes(fit_run):
     mod = fit_run['mod']
     ex, fu = mod._exec_group.executor, mod._fused_updater
     names = ex._diff_names
-    moms, masters, lrs, wds = fu.host_prep(
-        [ex.arg_dict[n] for n in names], advance=False)
-    lrs, wds = mod._schedule_arrays(lrs[None], wds[None])
-    text = mod._fused_step.lower(
-        tuple(ex.arg_dict[n]._data for n in names), (),
-        tuple(ex.arg_dict[n]._data for n in ex._arg_names
-              if n not in set(names)),
-        tuple(ex.aux_dict[n]._data for n in ex._aux_names),
-        ex._key, moms, masters, lrs, wds).as_text(debug_info=True)
+    moms, masters, lrs, wds = fu.host_prep_steps(
+        [ex.arg_dict[n] for n in names], 1, advance=False)
+    text = mod._step_program('single').lower(
+        *ex._step_operands(names, (), None, moms, masters),
+        *mod._schedule_arrays(lrs, wds)).as_text(debug_info=True)
     scopes = set(re.findall(r'jit\(multistep\)/([^"]*)/[a-z_]+"', text))
     assert 'jvp(forward)/BatchNorm.bn1' in scopes
     assert 'transpose(jvp(forward))/BatchNorm.bn1' in scopes
@@ -373,6 +374,18 @@ def test_bulk_dispatches_leave_one_span_each(bulk_run, name, parent,
                                              count):
     assert name in profiler.SPANS
     assert [r[3] for r in bulk_run[name]] == [parent] * count
+
+
+@pytest.mark.parametrize('bulk', [None, 2])
+def test_a_warm_up_opens_no_span(bulk):
+    """The readers divide a span's time by dispatches: a warm-up runs
+    the driver's code and is none."""
+    mod = _bound_module()
+    profiler.clear()
+    assert mod.warmup_fused(bulk=bulk)
+    assert mod._step_program(*(('single', 1) if bulk is None
+                               else ('stacked', bulk)))
+    assert not any(profiler._RING.get(name) for name in profiler.SPANS)
 
 
 def test_every_fixed_span_name_was_seen(fit_run, bulk_run):

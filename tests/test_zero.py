@@ -265,12 +265,12 @@ def test_fused_sgd_cache_key_carries_zero_and_layout():
     assert fr.cache_key() != fz.cache_key()
     # layout joins the key once built
     w = mx.nd.array(np.zeros((4, 4), np.float32))
-    fz.host_prep([w])
+    fz.host_prep_steps([w], 1)
     k1 = fz.cache_key()
     assert any('zero' in str(part) for part in k1)
     o3 = opt_mod.create('sgd', learning_rate=0.1, momentum=0.9)
     fz2 = opt_mod.FusedSGD(o3, ['w'], zero=1, mesh=None)
-    fz2.host_prep([mx.nd.array(np.zeros((8, 4), np.float32))])
+    fz2.host_prep_steps([mx.nd.array(np.zeros((8, 4), np.float32))], 1)
     assert fz2.cache_key() != k1           # different bucket layout
 
 

@@ -18,8 +18,7 @@ framework executor within ~5%, so findings transfer.
 
 Timing: K dependent steps ride a lax.scan inside ONE dispatch (params
 thread the carry, so the chain serializes for free); the per-dispatch
-floor is removed two-point (long minus short chain), per
-tools/bench_conv_bn.py.
+floor is removed two-point (long minus short chain).
 """
 import argparse
 import functools
