@@ -6,8 +6,10 @@ One process.  Drives `mx.mod.Module` over `mxnet_tpu.models` ResNet-50
 at full width (batch 256, 3x224x224, bfloat16, random weights from a
 seed) through the entry points a user calls — `Module.fit` and
 `Module.bulk_step` — then compiles and runs the Pallas flash-attention
-kernels at four lengths, then, on a host with four chips, runs the same
-network data-parallel over them.  It fails (non-zero exit, no result
+kernels at four lengths and the gated delta rule's kernels at one block
+of the language model's cell (against the XLA loop they replaced), then,
+on a host with four chips, runs the same network data-parallel over
+them.  It fails (non-zero exit, no result
 line) when JAX finds no TPU, when any phase raises, and when run
 without the rest of the checkout.  It sets no JAX_PLATFORMS and no
 compile-cache directory: both are placed from outside.
@@ -222,6 +224,26 @@ def phase_a(clog, ctx, batch=256, image=(3, 224, 224), num_layers=50,
             'bulk_steady_step_ms': round(bulk_ms[-1] / bulk, 2)}
 
 
+def compile_and_time(fn, args, n_kernels, calls=1):
+    """(result, compile seconds, milliseconds a call) of jit(fn) on
+    args, after one warm call; the lowered text must hold n_kernels
+    Mosaic custom calls."""
+    lowered = jax.jit(fn).lower(*args)
+    found = lowered.as_text().count('tpu_custom_call')
+    assert found >= n_kernels, \
+        'lowered text has %d tpu_custom_call, wanted %d: the kernel ' \
+        'did not take the Mosaic path' % (found, n_kernels)
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    compile_s = time.perf_counter() - t0
+    jax.block_until_ready(compiled(*args))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = compiled(*args)
+    jax.block_until_ready(out)
+    return out, compile_s, (time.perf_counter() - t0) * 1e3 / calls
+
+
 # ---------------------------------------------------------------------------
 # Phase B — the Pallas flash-attention kernels compile
 # ---------------------------------------------------------------------------
@@ -240,20 +262,9 @@ def phase_b(lengths=(2048, 12288, 16384, 32768), parity_at=2048, bh=8,
         return pallas_ops._dense_attention_lse(
             q, k, v, True, 1.0 / d ** 0.5)[0].astype(jnp.float32).sum()
 
-    def timed(fn, n_calls, *args):
-        lowered = jax.jit(fn).lower(*args)
-        calls = lowered.as_text().count('tpu_custom_call')
-        if expect_custom_call:
-            assert calls >= n_calls, \
-                'lowered text has %d tpu_custom_call, wanted %d: the ' \
-                'kernel did not take the Mosaic path' % (calls, n_calls)
-        t0 = time.perf_counter()
-        compiled = lowered.compile()
-        compile_s = time.perf_counter() - t0
-        jax.block_until_ready(compiled(*args))
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(compiled(*args))
-        return out, compile_s, (time.perf_counter() - t0) * 1e3
+    def timed(fn, n_kernels, *args):
+        return compile_and_time(fn, args, n_kernels if expect_custom_call
+                                else 0)
 
     result = {}
     for t in lengths:
@@ -288,6 +299,91 @@ def phase_b(lengths=(2048, 12288, 16384, 32768), parity_at=2048, bh=8,
         log(line)
         result['T=%d' % t] = {'forward_ms': round(fwd_ms, 2),
                               'forward_backward_ms': round(bwd_ms, 2)}
+    return result
+
+
+# ---------------------------------------------------------------------------
+# Phase D — the gated delta rule's kernels compile, and agree with the
+# loop they replaced
+# ---------------------------------------------------------------------------
+
+def scan_delta_rule(q, k, v, g, beta, chunk=64):
+    """The chunk loop as a lax.scan of XLA operations, as ops/lm.py had
+    it before the kernels (PR 29): kept here, and only here, as what
+    phase D compares the kernels with.  T a whole number of chunks."""
+    from jax import lax
+    from mxnet_tpu.ops import lm
+    bsz, h, t, dk = q.shape
+    nc = t // chunk
+    u, w, intra, q_in, k_out, gamma = lm.chunk_local(*(
+        a.reshape((bsz, h, nc, chunk) + a.shape[3:])
+        for a in (q, k, v, g, beta)))
+
+    def step(state, xs):
+        u_c, w_c, intra_c, q_c, k_c, decay_c = xs
+        v_new = u_c - jnp.matmul(w_c, state)
+        o_c = jnp.matmul(q_c, state) + jnp.matmul(intra_c, v_new)
+        state = state * decay_c[..., None, None] + jnp.einsum(
+            '...ck,...cv->...kv', k_c, v_new)
+        return state, o_c
+
+    _, o = lax.scan(step, jnp.zeros((bsz, h, dk, v.shape[-1]), jnp.float32),
+                    tuple(jnp.moveaxis(x, 2, 0)
+                          for x in (u, w, intra, q_in, k_out, gamma)))
+    return jnp.moveaxis(o, 0, 2).reshape(v.shape)
+
+
+def phase_d(shape=(1, 8, 8192, 128), calls=5, expect_custom_call=True):
+    """Forward and gradient of chunk_gated_delta_rule at one block of
+    the language model's cell, against the scan above, to 1e-3 of each
+    tensor's norm."""
+    from mxnet_tpu.ops import lm
+    bsz, h, t, d = shape
+    keys = jax.random.split(jax.random.PRNGKey(SEED + 4), 6)
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    q, k = (unit(jax.random.normal(kk, shape, jnp.float32))
+            for kk in keys[:2])
+    v = jax.random.normal(keys[2], shape, jnp.float32)
+    g = -jax.nn.softplus(jax.random.normal(keys[3], shape[:3], jnp.float32))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], shape[:3], jnp.float32))
+    weight = jax.random.normal(keys[5], shape, jnp.float32)
+    args = (q / d ** 0.5, k, v, g, beta)
+
+    def grad_of(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
+                        argnums=(0, 1, 2, 3, 4))
+
+    def timed(fn, n_kernels):
+        return compile_and_time(fn, args, n_kernels if expect_custom_call
+                                else 0, calls)
+
+    result, outs = {}, {}
+    for name, fn, kernels in (
+            # the gradient alone needs no o: the states again and the
+            # loop backward
+            ('kernel', lm.chunk_gated_delta_rule, (1, 2)),
+            ('scan', scan_delta_rule, (0, 0))):
+        o, fwd_s, fwd_ms = timed(fn, kernels[0])
+        grads, bwd_s, bwd_ms = timed(grad_of(fn), kernels[1])
+        outs[name] = (o,) + tuple(grads)
+        log('phase D delta rule %s %s: forward compile %.1f s run %.2f ms, '
+            'forward+backward compile %.1f s run %.2f ms'
+            % (name, shape, fwd_s, fwd_ms, bwd_s, bwd_ms))
+        result[name] = {'forward_ms': round(fwd_ms, 2),
+                        'forward_backward_ms': round(bwd_ms, 2)}
+    worst = 0.0
+    for name, a, b in zip(('o', 'dq', 'dk', 'dv', 'dg', 'dbeta'),
+                          outs['kernel'], outs['scan']):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert np.isfinite(a).all(), 'delta rule: %s not finite' % name
+        err = np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30)
+        assert err < 1e-3, 'delta rule: %s differs from the scan by ' \
+            '%.3g of its norm' % (name, err)
+        worst = max(worst, err)
+    log('phase D: kernels against the scan, worst %.2g of a norm' % worst)
     return result
 
 
@@ -450,6 +546,7 @@ def main():
     result = {'A': phase_a(clog, mx.tpu(0))}
     gc.collect()
     result['B'] = phase_b()
+    result['D'] = phase_d()
     if len(devices) >= 4:
         result['C'] = phase_c([mx.tpu(i) for i in range(4)])
     else:

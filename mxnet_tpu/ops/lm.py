@@ -3,9 +3,11 @@
 Every operator takes tokens as rows, `(N, C)` with `N = sequences x
 seq_len`, as `Embedding` gives them and `FullyConnected` takes them; the
 operators that look along a sequence carry `seq_len` as an attribute and
-fold the rows to `(N / seq_len, seq_len, ...)` themselves.  All are plain
-XLA: pure JAX functions differentiated by jax.vjp inside the one compiled
-step, like every other operator of the registry.
+fold the rows to `(N / seq_len, seq_len, ...)` themselves.  All are pure
+JAX functions differentiated by jax.vjp inside the one compiled step,
+like every other operator of the registry, and plain XLA but for the
+loop over GatedDeltaRule's chunks, which is three Pallas kernels
+(pallas_ops.delta_rule_*) under a custom gradient rule.
 
   RMSNorm          x * rsqrt(mean x^2 + eps) * gamma, or * (1 + gamma)
   GatedAttention   per-head q/k RMS norm, partial rotary, grouped-head
@@ -13,8 +15,9 @@ step, like every other operator of the registry.
                    the sigmoid gate on the output
   CausalConv1D     depthwise causal convolution along the sequence
   GatedDeltaRule   the gated delta rule in chunks (WY form): a unit
-                   lower triangular solve inside a chunk, the state
-                   carried between chunks by lax.scan
+                   lower triangular solve inside a chunk (XLA, all
+                   chunks at once), the state carried between chunks
+                   in VMEM by a kernel, forward and backward
   SparseMoE        top-k routing over all experts, the held experts'
                    part of the result by a grouped product over the
                    sorted (token, expert) pairs; nothing is dropped
@@ -28,11 +31,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from .registry import register, asbool, asfloat, asint
+from .. import pallas_ops
 
 F32 = jnp.float32
 HIGHEST = lax.Precision.HIGHEST
 ATTN_BLOCK = 512            # query rows a block of GatedAttention
 CHUNK = 64                  # tokens a chunk of GatedDeltaRule
+LANES = 128                 # head widths GatedDeltaRule's kernels take
 KEY_HEADS_PER_BLOCK = 4     # key heads GatedDeltaRule takes at a time
 EXPERT_TILE = 256           # rows a tile of SparseMoE's grouped product
 
@@ -255,30 +260,16 @@ def _unit_lower_inverse(a):
     return inv
 
 
-# the chain's powers are cheap to make again and as large as the
-# chunk's other tensors together: the backward pass keeps only `a`
-_unit_lower_inverse = jax.checkpoint(_unit_lower_inverse)
-
-
-def chunk_gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
-    """Per head, token by token: S <- exp(g_t) S; d = beta_t (v_t -
-    S^T k_t); S <- S + k_t (x) d; o_t = S^T q_t.  Computed a chunk at a
-    time: inside a chunk the rule is a unit lower triangular system
-    (the WY form), between chunks the state S (dk x dv) is carried.
-    q, k (B, H, T, dk), v (B, H, T, dv), g (log decay <= 0) and beta
-    (B, H, T), all float32.  Returns o (B, H, T, dv) in float32."""
-    bsz, h, t, dk = q.shape
-    dv = v.shape[-1]
-    pad = (-t) % chunk
-    if pad:     # beta = 0 and g = 0: tokens that leave the state alone
-        q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)))
-                   for a in (q, k, v))
-        g, beta = (jnp.pad(a, ((0, 0), (0, 0), (0, pad)))
-                   for a in (g, beta))
-    nc = (t + pad) // chunk
-    q, k, v = (a.reshape(bsz, h, nc, chunk, -1) for a in (q, k, v))
-    g = jnp.cumsum(g.reshape(bsz, h, nc, chunk), axis=-1)
-    beta = beta.reshape(bsz, h, nc, chunk)
+def chunk_local(q, k, v, g, beta):
+    """The half of the rule that stays inside a chunk, every chunk at
+    once: the unit lower triangular system of the WY form solved, and
+    what the loop over the chunks takes from each.  q, k (..., chunks,
+    C, dk), v (..., chunks, C, dv), g and beta (..., chunks, C).
+    Returns u (C, dv), w (C, dk), intra (C, C), q_in (C, dk), k_out
+    (C, dk) of every chunk and gamma (..., chunks), the decay over a
+    whole chunk."""
+    chunk = q.shape[-2]
+    g = jnp.cumsum(g, axis=-1)
     lower = jnp.tril(jnp.ones((chunk, chunk), bool))
     # exp(g_i - g_j) for i >= j, masked before exp: the other half of
     # the difference is positive and can overflow
@@ -293,23 +284,77 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
     q_in = q * jnp.exp(g)[..., None]
     g_last = g[..., -1]
     k_out = k * jnp.exp(g_last[..., None] - g)[..., None]
+    return u, w, intra, q_in, k_out, jnp.exp(g_last)
 
-    def step(state, xs):
-        u_c, w_c, intra_c, q_c, k_c, decay_c = xs
-        v_new = u_c - jnp.matmul(w_c, state)
-        o_c = jnp.matmul(q_c, state) + jnp.matmul(intra_c, v_new)
-        state = state * decay_c[..., None, None] + jnp.einsum(
-            '...ck,...cv->...kv', k_c, v_new)
-        return state, o_c
 
-    def chunks_first(x):
-        return jnp.moveaxis(x, 2, 0)
+def _heads_flat(xs):
+    """(B, H, chunks, ...) -> (B * H, chunks, ...), as the kernels take."""
+    return [x.reshape((-1,) + x.shape[2:]) for x in xs]
 
-    _, o = lax.scan(step, jnp.zeros((bsz, h, dk, dv), F32),
-                    tuple(chunks_first(x) for x in
-                          (u, w, intra, q_in, k_out, jnp.exp(g_last))))
-    o = jnp.moveaxis(o, 0, 2).reshape(bsz, h, nc * chunk, dv)
-    return o[:, :, :t]
+
+@jax.custom_vjp
+def _delta_rule_chunked(q, k, v, g, beta):
+    """o (B, H, chunks, C, dv) of inputs already cut into chunks, dk
+    and dv whole lanes.  Between chunks: v_new = u_c - w_c S; o_c =
+    q_in_c S + intra_c v_new; S <- gamma_c S + k_out_c^T v_new, in one
+    kernel that keeps S in VMEM (pallas_ops.delta_rule_chunks)."""
+    o = pallas_ops.delta_rule_chunks(*_heads_flat(chunk_local(q, k, v, g,
+                                                              beta)))
+    return o.reshape(v.shape)
+
+
+def _delta_rule_chunked_fwd(q, k, v, g, beta):
+    return _delta_rule_chunked(q, k, v, g, beta), (q, k, v, g, beta)
+
+
+def _delta_rule_chunked_bwd(inputs, do):
+    """Only the inputs were kept: the chunk-local tensors and every
+    chunk's state are made again, the loop runs last chunk to first in
+    its kernel, and the chunk-local half is differentiated by jax."""
+    local, local_vjp = jax.vjp(chunk_local, *inputs)
+    u, w, intra, q_in, k_out, gamma = _heads_flat(local)
+    s0, v_new = pallas_ops.delta_rule_states(u, w, k_out, gamma)
+    grads = pallas_ops.delta_rule_chunks_bwd(
+        do.reshape(u.shape), w, intra, q_in, k_out, gamma, s0, v_new)
+    return local_vjp(tuple(d.reshape(x.shape)
+                           for d, x in zip(grads, local)))
+
+
+_delta_rule_chunked.defvjp(_delta_rule_chunked_fwd, _delta_rule_chunked_bwd)
+
+
+def _pad_axis(x, axis, multiple):
+    pad = (-x.shape[axis]) % multiple
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths)
+
+
+def chunk_gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
+    """Per head, token by token: S <- exp(g_t) S; d = beta_t (v_t -
+    S^T k_t); S <- S + k_t (x) d; o_t = S^T q_t.  Computed a chunk at a
+    time: inside a chunk the rule is a unit lower triangular system
+    (the WY form, `chunk_local`), between chunks the state S (dk x dv)
+    is carried, in VMEM by a Pallas kernel, forward and backward (off
+    the TPU the same kernels in interpret mode).
+    q, k (B, H, T, dk), v (B, H, T, dv), g (log decay <= 0) and beta
+    (B, H, T), all float32.  Returns o (B, H, T, dv) in float32.
+
+    T is padded to whole chunks with beta = 0 and g = 0, tokens that
+    leave the state alone; dk and dv to whole lanes with zeros: a zero
+    column of q and k adds nothing to any product and a zero column of
+    v gives a zero column of o."""
+    bsz, h, t, _ = q.shape
+    dv = v.shape[-1]
+    q, k, v = (_pad_axis(_pad_axis(a, 2, chunk), 3, LANES)
+               for a in (q, k, v))
+    g, beta = (_pad_axis(a, 2, chunk) for a in (g, beta))
+    nc = q.shape[2] // chunk
+    o = _delta_rule_chunked(*(a.reshape((bsz, h, nc, chunk) + a.shape[3:])
+                              for a in (q, k, v, g, beta)))
+    return o.reshape(bsz, h, nc * chunk, -1)[:, :, :t, :dv]
 
 
 def _gdr_infer_shape(attrs, in_shapes):
@@ -330,9 +375,14 @@ def _gated_delta_rule(attrs, qkv, a, b, a_log, dt_bias):
     value head.  Returns (N, num_v_heads * head_v_dim).
 
     One sequence and KEY_HEADS_PER_BLOCK key heads (with their value
-    heads) at a time, each block recomputed in the backward pass: what
-    a chunked pass keeps in float32 for its gradient is many times its
-    inputs, and all heads of all sequences at once do not fit."""
+    heads) at a time: the chunk-local tensors of a block in float32
+    are many times its inputs, and all heads of all sequences at once
+    do not fit.  A block keeps its bfloat16 inputs for the backward
+    pass and no more (jax.checkpoint): the rule's own gradient keeps
+    the float32 q, k, v it was called with, which are the block's
+    normalised and repeated inputs over again, and makes everything
+    else again; the forward kernel is not run a second time (nothing
+    reads its result there)."""
     hk, hv = asint(attrs['num_k_heads']), asint(attrs['num_v_heads'])
     dk, dv = asint(attrs['head_k_dim']), asint(attrs['head_v_dim'])
     seq_len = asint(attrs['seq_len'])

@@ -840,3 +840,152 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         interpret = default_interpret(q, k, v)
     return _flash(q, k, v, bool(causal), float(scale), int(block_q),
                   bool(interpret))
+
+
+# ---------------------------------------------------------------------------
+# Gated delta rule: the loop over a head's chunks with the state in VMEM.
+# ops/lm.py makes the chunk-local tensors (batched XLA over all chunks)
+# and calls these; what is left is a recurrence, S <- gamma S + k^T v_new
+# with v_new = u - w S, whose state (dk x dv, float32) would cross HBM
+# between every two operations as the carry of a lax.scan.  Here the grid
+# is (blocks of heads: parallel, chunks: arbitrary), the state is a VMEM
+# scratch zeroed at the first chunk of a head, and BlockSpecs stream one
+# chunk's tensors a grid step.  The heads of a grid step are independent
+# chains of small products, unrolled so the scheduler may interleave them.
+# Every operand, accumulator and stored tensor is float32; the products
+# take Mosaic's default for float32 operands.
+# ---------------------------------------------------------------------------
+
+# heads a grid step of the three kernels: 1, 2, 4 and 8 time the same on
+# a v5e (the kernels wait for HBM, not for the chains), 2 holds least VMEM
+DELTA_HEADS_PER_STEP = 2
+
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _mm(a, b, dims=_NN):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _delta_fwd_kernel(*refs, heads, states):
+    """One chunk of `heads` heads.  states=False: o_c = q_in S + intra
+    v_new.  states=True (the backward rule's second make): S_c, the
+    state the chunk starts from, and v_new; o and its operands are
+    left out."""
+    if states:
+        u_ref, w_ref, k_ref, gamma_ref, s0_ref, vnew_ref, s_ref = refs
+    else:
+        (u_ref, w_ref, intra_ref, q_ref, k_ref, gamma_ref, o_ref,
+         s_ref) = refs
+
+    @pl.when(pl.program_id(1) == 0)
+    def _first_chunk():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    for h in range(heads):
+        s = s_ref[h]
+        v_new = u_ref[h, 0] - _mm(w_ref[h, 0], s)
+        if states:
+            s0_ref[h, 0] = s
+            vnew_ref[h, 0] = v_new
+        else:
+            o_ref[h, 0] = _mm(q_ref[h, 0], s) + _mm(intra_ref[h, 0], v_new)
+        s_ref[h] = s * gamma_ref[h, 0] + _mm(k_ref[h, 0], v_new, _TN)
+
+
+def _delta_bwd_kernel(do_ref, w_ref, intra_ref, q_ref, k_ref, gamma_ref,
+                      s0_ref, vnew_ref, du_ref, dw_ref, dintra_ref, dq_ref,
+                      dk_ref, dgamma_ref, ds_ref, *, heads):
+    """One chunk of `heads` heads, the chunks taken last to first: dS is
+    the cotangent of the state the chunk leaves behind."""
+    @pl.when(pl.program_id(1) == 0)
+    def _last_chunk():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    for h in range(heads):
+        ds, do = ds_ref[h], do_ref[h, 0]
+        s0, v_new = s0_ref[h, 0], vnew_ref[h, 0]
+        dv_new = _mm(intra_ref[h, 0], do, _TN) + _mm(k_ref[h, 0], ds)
+        du_ref[h, 0] = dv_new
+        dw_ref[h, 0] = -_mm(dv_new, s0, _NT)
+        dintra_ref[h, 0] = _mm(do, v_new, _NT)
+        dq_ref[h, 0] = _mm(do, s0, _NT)
+        dk_ref[h, 0] = _mm(v_new, ds, _NT)
+        # <dS, S_c> summed over dk here, over dv by the caller
+        dgamma_ref[h, 0] = jnp.sum(ds * s0, axis=0, keepdims=True)
+        ds_ref[h] = (ds * gamma_ref[h, 0] + _mm(q_ref[h, 0], do, _TN)
+                     - _mm(w_ref[h, 0], dv_new, _TN))
+
+
+def _delta_call(name, kernel, operands, outs, state, reverse=False):
+    """pallas_call of a delta rule kernel over (heads, chunks, rows,
+    cols) operands; `outs` are (rows, cols) of each result and `state`
+    (dk, dv) of the scratch a head's state lives in.  `name` is the
+    custom call's in the compiled program and in a trace."""
+    bh, nc = operands[0].shape[:2]
+    heads = max(d for d in range(1, DELTA_HEADS_PER_STEP + 1) if bh % d == 0)
+
+    def spec(rows, cols):
+        return pl.BlockSpec(
+            (heads, 1, rows, cols),
+            (lambda i, j: (i, nc - 1 - j, 0, 0)) if reverse
+            else (lambda i, j: (i, j, 0, 0)))
+
+    return pl.pallas_call(
+        functools.partial(kernel, heads=heads),
+        grid=(bh // heads, nc),
+        in_specs=[spec(*x.shape[2:]) for x in operands],
+        out_specs=[spec(*s) for s in outs],
+        out_shape=[jax.ShapeDtypeStruct((bh, nc) + s, jnp.float32)
+                   for s in outs],
+        scratch_shapes=[pltpu.VMEM((heads,) + state, jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary')),
+        interpret=default_interpret(*operands),
+        name=name,
+    )(*operands)
+
+
+def _gamma_rows(gamma, dv):
+    """(heads, chunks) -> (heads, chunks, 1, dv): a chunk's decay as a
+    row the kernel multiplies the state by."""
+    return jnp.broadcast_to(gamma[..., None, None], gamma.shape + (1, dv))
+
+
+def delta_rule_chunks(u, w, intra, q_in, k_out, gamma):
+    """o of every chunk.  u (heads, chunks, C, dv); w, q_in, k_out
+    (heads, chunks, C, dk); intra (heads, chunks, C, C); gamma (heads,
+    chunks); dk and dv multiples of 128, C of 8, all float32."""
+    c, dv = u.shape[2:]
+    return _delta_call(
+        'delta_rule_chunks',
+        functools.partial(_delta_fwd_kernel, states=False),
+        (u, w, intra, q_in, k_out, _gamma_rows(gamma, dv)), [(c, dv)],
+        (w.shape[-1], dv))[0]
+
+
+def delta_rule_states(u, w, k_out, gamma):
+    """(S_c, v_new) of every chunk: the state each chunk starts from
+    (heads, chunks, dk, dv) and u - w S_c (heads, chunks, C, dv)."""
+    c, dv = u.shape[2:]
+    dk = w.shape[-1]
+    return _delta_call(
+        'delta_rule_states',
+        functools.partial(_delta_fwd_kernel, states=True),
+        (u, w, k_out, _gamma_rows(gamma, dv)), [(dk, dv), (c, dv)], (dk, dv))
+
+
+def delta_rule_chunks_bwd(do, w, intra, q_in, k_out, gamma, s0, v_new):
+    """Cotangents (du, dw, dintra, dq_in, dk_out, dgamma) of
+    delta_rule_chunks' operands for the cotangent `do` of its result,
+    given delta_rule_states' (s0, v_new)."""
+    c, dv = do.shape[2:]
+    dk = w.shape[-1]
+    du, dw, dintra, dq, dkk, dgamma = _delta_call(
+        'delta_rule_chunks_bwd', _delta_bwd_kernel,
+        (do, w, intra, q_in, k_out, _gamma_rows(gamma, dv), s0, v_new),
+        [(c, dv), (c, dk), (c, c), (c, dk), (c, dk), (1, dv)], (dk, dv),
+        reverse=True)
+    return du, dw, dintra, dq, dkk, jnp.sum(dgamma, axis=(2, 3))

@@ -2,6 +2,8 @@
 of the expert layer and a whole tiny model through Module.bulk_step,
 against the plain float32 reference the benchmark compares with
 (benchmark/reference/qwen3_next.py, loaded from where it lives)."""
+import collections
+import functools
 import os
 import sys
 
@@ -11,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu import models, profiler
+from mxnet_tpu import models, pallas_ops, profiler
 from mxnet_tpu.ops import lm
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -45,10 +47,13 @@ def rand(key, *shape):
 
 # -- the chunked gated delta rule against the recurrence ---------------------
 
-def _rule_inputs(t, h=3, dk=8, dv=4):
+def _rule_inputs(t, h=3, dk=8, dv=4, rep=1):
+    """`rep` value heads share each key head's q and k, as the
+    operator's jnp.repeat hands them over."""
     def unit(x):
-        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-    q, k = unit(rand(1, t, h, dk)), unit(rand(2, t, h, dk))
+        return jnp.repeat(x / jnp.linalg.norm(x, axis=-1, keepdims=True),
+                          rep, axis=1)
+    q, k = unit(rand(1, t, h // rep, dk)), unit(rand(2, t, h // rep, dk))
     v = rand(3, t, h, dv)
     g = -jax.nn.softplus(rand(4, t, h))
     beta = jax.nn.sigmoid(rand(5, t, h))
@@ -63,30 +68,114 @@ def _chunked(q, k, v, g, beta, chunk):
     return jnp.moveaxis(o[0], 0, 1)
 
 
-@pytest.mark.parametrize('t,chunk', [(64, 64), (100, 64), (37, 16),
-                                     (130, 64)])
-def test_chunked_delta_rule_is_the_recurrence(t, chunk):
-    args = _rule_inputs(t)
+# (t, chunk, heads, dk, dv, value heads a key head): tier-1's narrow
+# heads (padded to the lanes), the cell's head shape with T whole
+# chunks and not, and widths past one lane that need the padding
+TINY_HEADS = (3, 8, 4, 1)
+CELL_HEADS = (4, 128, 128, 2)
+WIDE_HEADS = (2, 136, 200, 1)
+RULE_CASES = [(64, 64) + TINY_HEADS, (100, 64) + TINY_HEADS,
+              (37, 16) + TINY_HEADS, (130, 64) + TINY_HEADS,
+              (256, 64) + CELL_HEADS, (200, 64) + CELL_HEADS,
+              (100, 64) + WIDE_HEADS]
+
+
+@pytest.mark.parametrize('t,chunk,h,dk,dv,rep', RULE_CASES)
+def test_chunked_delta_rule_is_the_recurrence(t, chunk, h, dk, dv, rep):
+    args = _rule_inputs(t, h, dk, dv, rep)
     net = convnet.Net({})
     close(_chunked(*args, chunk), ref.delta_rule_recurrence(net, *args))
 
 
-@pytest.mark.parametrize('wrt', range(5))
-def test_chunked_delta_rule_gradients(wrt):
-    args = _rule_inputs(100)
-    weight = rand(9, 100, 3, 4)
+@functools.lru_cache(maxsize=None)
+def _rule_gradients(t, chunk, h, dk, dv, rep):
+    """All five gradients of the chunked rule and of the recurrence."""
+    args = _rule_inputs(t, h, dk, dv, rep)
+    weight = rand(9, t, h, dv)
     net = convnet.Net({})
 
-    def loss(fn, x):
-        a = list(args)
-        a[wrt] = x
-        return jnp.sum(fn(*a) * weight)
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
+                        argnums=range(5))(*args)
 
-    got = jax.grad(lambda x: loss(
-        lambda *a: _chunked(*a, 64), x))(args[wrt])
-    want = jax.grad(lambda x: loss(
-        lambda *a: ref.delta_rule_recurrence(net, *a), x))(args[wrt])
-    close(got, want, 1e-4)
+    return (grads(lambda *a: _chunked(*a, chunk)),
+            grads(lambda *a: ref.delta_rule_recurrence(net, *a)))
+
+
+@pytest.mark.parametrize('wrt', range(5))
+@pytest.mark.parametrize('t,chunk,h,dk,dv,rep', [
+    (100, 64) + TINY_HEADS, (256, 64) + CELL_HEADS, (200, 64) + CELL_HEADS,
+    (100, 64) + WIDE_HEADS])
+def test_chunked_delta_rule_gradients(t, chunk, h, dk, dv, rep, wrt):
+    got, want = _rule_gradients(t, chunk, h, dk, dv, rep)
+    close(got[wrt], want[wrt], 1e-4)
+
+
+def test_the_kernel_keeps_the_state_the_recurrence_has_at_each_chunk():
+    """delta_rule_states' S_c is the state the token-by-token rule has
+    reached when chunk c starts, and v_new what the chunk's o is made of."""
+    t, chunk, h, d = 256, 64, 2, 128
+    q, k, v, g, beta = (jnp.moveaxis(x, 1, 0).reshape(
+        (h, t // chunk, chunk) + x.shape[2:])
+        for x in _rule_inputs(t, h, d, d))
+    u, w, intra, q_in, k_out, gamma = lm.chunk_local(q, k, v, g, beta)
+    s0, v_new = pallas_ops.delta_rule_states(u, w, k_out, gamma)
+
+    def token(state, xs):
+        k_t, v_t, g_t, beta_t = xs
+        decayed = state * jnp.exp(g_t)
+        delta = beta_t * (v_t - k_t @ decayed)
+        return decayed + jnp.outer(k_t, delta), state
+
+    for head in range(h):
+        _, before = jax.lax.scan(token, jnp.zeros((d, d)), tuple(
+            x[head].reshape((t,) + x.shape[3:]) for x in (k, v, g, beta)))
+        close(s0[head], before[::chunk])
+    o = pallas_ops.delta_rule_chunks(u, w, intra, q_in, k_out, gamma)
+    close(o, jnp.matmul(q_in, s0) + jnp.matmul(intra, v_new))
+
+
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+@pytest.mark.parametrize('what', ['forward', 'gradient'])
+def test_the_chunk_loop_is_a_kernel_and_no_scan(what):
+    """The loop over the chunks cannot come back unnoticed: one
+    pallas_call forward, three in the gradient (o; the states made
+    again; the loop backward), and no scan or while in either."""
+    args = _rule_inputs(130)
+    fn = {'forward': lambda *a: _chunked(*a, 64),
+          'gradient': jax.grad(lambda *a: jnp.sum(_chunked(*a, 64)),
+                               argnums=range(5))}[what]
+    found = collections.Counter(_primitives(jax.make_jaxpr(fn)(*args).jaxpr))
+    assert found['pallas_call'] == {'forward': 1, 'gradient': 3}[what]
+    assert not found['scan'] and not found['while'], found
+
+
+def test_gated_delta_rule_gradient_under_checkpoint():
+    """The half layer's recomputation wraps the operator in
+    jax.checkpoint: the custom rule gives the same gradients there."""
+    attrs = dict(num_k_heads=2, num_v_heads=4, head_k_dim=8, head_v_dim=8,
+                 seq_len=SEQ)
+    n = 2 * SEQ
+    args = (rand(1, n, 2 * 2 * 8 + 4 * 8), rand(2, n, 4), rand(3, n, 4),
+            0.1 * rand(4, 4), 0.1 * rand(5, 4))
+    weight = rand(6, n, 4 * 8)
+
+    op = functools.partial(lm._gated_delta_rule, attrs)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
+                        argnums=range(5))(*args)
+
+    plain, again = grads(op), grads(jax.checkpoint(op))
+    for a, b in zip(plain, again):
+        assert np.abs(np.asarray(b)).max() > 0
+        close(a, b, 1e-6)
 
 
 def test_causal_conv_and_rms_norm():

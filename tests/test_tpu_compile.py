@@ -1,0 +1,52 @@
+"""The kernels of the language model's cell compile at the cell's widths
+for a TPU v5e that is described, not attached: what Mosaic refuses (a
+block off the tiling, too much VMEM) it refuses here, at no chip time.
+Nothing runs, so this says nothing about results or speed.
+
+The topology is described inside a fixture, never while a module is
+imported: one process at a time may load the TPU's library, and every
+xdist worker imports every test file."""
+import os
+
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu import pallas_ops
+from mxnet_tpu.ops import lm
+
+
+@pytest.fixture(scope='module')
+def one_chip():
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:      # no libtpu here, or another process has it
+        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize('what,kernels', [('forward', 1), ('gradient', 2)])
+def test_delta_rule_kernels_compile_for_the_chip(one_chip, monkeypatch,
+                                                 what, kernels):
+    """One block of the cell: 8 value heads of 128 at 8,192 tokens.
+    The code asks jax for its backend (the CPU here), so the test
+    steers it onto the Mosaic path."""
+    monkeypatch.setattr(pallas_ops, 'default_interpret', lambda *a: False)
+    h, t, d = 8, 8192, 128
+
+    def shape(*s):
+        return jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+
+    args = (shape(1, h, t, d), shape(1, h, t, d), shape(1, h, t, d),
+            shape(1, h, t), shape(1, h, t))
+    fn = {'forward': lm.chunk_gated_delta_rule,
+          'gradient': jax.grad(
+              lambda *a: jnp.sum(lm.chunk_gated_delta_rule(*a)),
+              argnums=(0, 1, 2, 3, 4))}[what]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('tpu_custom_call') >= kernels
+    assert ' while(' not in text
