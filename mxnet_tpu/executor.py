@@ -380,7 +380,7 @@ class Executor:
                     layouts[ni] = ['NCHW']
                     return
                 op = node.op
-                n_aux = op.num_aux
+                n_aux = op.aux_count(node.attrs)
                 in_entries = node.inputs
                 vals = [results[node_index[id(src)]][idx]
                         for src, idx in in_entries]
@@ -608,7 +608,8 @@ class Executor:
         dispatches in flight."""
         out = []
         for n in self._counter_nodes:
-            names = [src.name for src, _ in n.inputs[-n.op.num_aux:]]
+            names = [src.name for src, _ in
+                     n.inputs[-n.op.aux_count(n.attrs):]]
             out.append((names, [np.asarray(self.aux_dict[a]._data)
                                 for a in names], n.attrs, n.op.fold_aux))
         return out
@@ -1159,7 +1160,7 @@ class Executor:
                 break
             vals = [state['results'][node_index[id(src)]][idx]
                     for src, idx in node.inputs]
-            n_aux = node.op.num_aux
+            n_aux = node.op.aux_count(node.attrs)
             args = vals[:len(vals) - n_aux] if n_aux else vals
             auxs = vals[len(vals) - n_aux:] if n_aux else []
             op_ctx = OpContext(

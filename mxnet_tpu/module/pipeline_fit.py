@@ -66,7 +66,7 @@ def _spine_nodes(symbol, data_set, label_set, param_set):
     node = symbol._outputs[0][0]
     spine = []
     while True:
-        if node.op.num_aux:
+        if node.op.aux_count(node.attrs):
             raise MXNetError(
                 'fit(pipeline): op %r (%s) carries auxiliary state — '
                 'BatchNorm & co are not composed with the pipelined '

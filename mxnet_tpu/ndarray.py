@@ -406,7 +406,7 @@ def invoke(op_name, inputs, attrs, out=None):
     op_ctx = _reg.OpContext(
         is_train=is_train,
         rng=_random.next_key() if op.needs_rng else None)
-    n_aux = op.num_aux
+    n_aux = op.aux_count(attrs)
     args = inputs[:len(inputs) - n_aux] if n_aux else inputs
     auxs = inputs[len(inputs) - n_aux:] if n_aux else []
     in_data = [x._data for x in args]

@@ -1,4 +1,5 @@
-"""Operators of hybrid sparse language models (Qwen3-Next's layer kinds).
+"""Operators of sparse language models (the layer kinds of Qwen3-Next
+and of DeepSeek-V3's family).
 
 Every operator takes tokens as rows, `(N, C)` with `N = sequences x
 seq_len`, as `Embedding` gives them and `FullyConnected` takes them; the
@@ -13,14 +14,21 @@ loop over GatedDeltaRule's chunks, which is three Pallas kernels
   GatedAttention   per-head q/k RMS norm, partial rotary, grouped-head
                    causal softmax attention in blocks of query rows, and
                    the sigmoid gate on the output
+  LatentAttention  the core of multi-head latent attention: rotary by
+                   adjacent pairs on the keys' one shared rotary head
+                   and on each query head's rotary part, causal softmax
+                   attention with keys wider than values, on the same
+                   blocks of query rows
   CausalConv1D     depthwise causal convolution along the sequence
   GatedDeltaRule   the gated delta rule in chunks (WY form): a unit
                    lower triangular solve inside a chunk (XLA, all
                    chunks at once), the state carried between chunks
                    in VMEM by a kernel, forward and backward
-  SparseMoE        top-k routing over all experts, the held experts'
-                   part of the result by a grouped product over the
-                   sorted (token, expert) pairs; nothing is dropped
+  SparseMoE        top-k routing over all experts (softmax scores, or
+                   sigmoid scores with a selection bias and a scaling
+                   factor), the held experts' part of the result by a
+                   grouped product over the sorted (token, expert)
+                   pairs; nothing is dropped
 """
 import functools
 import math
@@ -47,16 +55,14 @@ def _data_dtype(in_dtypes):
         else np.dtype(np.float32)
 
 
-def _infer_dtype(f32_inputs=(), int_aux=0):
+def _infer_dtype(f32_inputs=()):
     """Inputs follow the data's type, except the small vectors named in
     `f32_inputs` (norm scales, decay rates), which stay float32 under a
-    low-precision graph as BatchNorm's do; trailing aux are int32."""
+    low-precision graph as BatchNorm's do."""
     def infer(attrs, in_dtypes):
         d = _data_dtype(in_dtypes)
-        n = len(in_dtypes) - int_aux
-        ins = [np.dtype(np.float32) if i in f32_inputs else d
-               for i in range(n)] + [np.dtype(np.int32)] * int_aux
-        return ins, [d]
+        return [np.dtype(np.float32) if i in f32_inputs else d
+                for i in range(len(in_dtypes))], [d]
     return infer
 
 
@@ -99,17 +105,22 @@ def _rms_norm(attrs, data, gamma):
 # GatedAttention
 # ---------------------------------------------------------------------------
 
-def rotary(x, rotary_dim, theta):
-    """Rotate-half rotary embedding on the first `rotary_dim` of the
-    head dimension of x (B, T, heads, head_dim); position = index in
-    the sequence."""
-    t = x.shape[1]
-    half = rotary_dim // 2
+def _rotary_tables(t, rotary_dim, theta):
+    """cos and sin of position * theta^(-2i / rotary_dim), each
+    (1, t, 1, rotary_dim / 2) in float32; position = index in the
+    sequence."""
     inv_freq = 1.0 / (theta ** (np.arange(0, rotary_dim, 2,
                                           dtype=np.float64) / rotary_dim))
     ang = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
-    cos = jnp.asarray(np.cos(ang), F32)[None, :, None, :]
-    sin = jnp.asarray(np.sin(ang), F32)[None, :, None, :]
+    return (jnp.asarray(np.cos(ang), F32)[None, :, None, :],
+            jnp.asarray(np.sin(ang), F32)[None, :, None, :])
+
+
+def rotary(x, rotary_dim, theta):
+    """Rotate-half rotary embedding on the first `rotary_dim` of the
+    head dimension of x (B, T, heads, head_dim)."""
+    half = rotary_dim // 2
+    cos, sin = _rotary_tables(x.shape[1], rotary_dim, theta)
     x1, x2, rest = x[..., :half], x[..., half:rotary_dim], \
         x[..., rotary_dim:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
@@ -161,7 +172,9 @@ _attention_block.defvjp(_attention_block_fwd, _attention_block_bwd)
 
 def causal_attention(q, k, v, scale, block_q=ATTN_BLOCK):
     """softmax(q k^T * scale + causal) v with grouped heads: q
-    (B, T, kv, group, d), k and v (B, T, kv, d).  One sequence at a
+    (B, T, kv, group, d), k (B, T, kv, d) and v (B, T, kv, dv), whose
+    width is its own (latent attention's keys are wider than its
+    values); the result is (B, T, kv, group, dv).  One sequence at a
     time and query rows in blocks, each block against the keys it can
     see; a block keeps its output and its rows' log-sum-exp and makes
     its scores again in the backward pass, so no T x T score matrix is
@@ -216,6 +229,55 @@ def _gated_attention(attrs, qg, k, v, q_gamma, k_gamma):
     o = o.reshape(n, heads * d)
     return (o.astype(F32) * jax.nn.sigmoid(gate.astype(F32))
             ).astype(o.dtype)
+
+
+# ---------------------------------------------------------------------------
+# LatentAttention
+# ---------------------------------------------------------------------------
+
+def rotary_pairs(x, theta):
+    """Rotary embedding that turns adjacent pairs: dims (2i, 2i + 1) of
+    the last axis of x (B, T, heads, d) by position * theta^(-2i / d),
+    in place (the published weights' own order; DeepSeek-V3's code
+    moves the even dims before the odd ones and turns halves, which
+    gives the same scores since queries and keys share the order)."""
+    d = x.shape[-1]
+    cos, sin = _rotary_tables(x.shape[1], d, theta)
+    pairs = x.reshape(x.shape[:-1] + (d // 2, 2))
+    even, odd = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
+                     axis=-1).reshape(x.shape)
+
+
+@register('LatentAttention', input_names=('query', 'key_value', 'key_rope'),
+          hint='latentattention')
+def _latent_attention(attrs, q, kv, k_pe):
+    """query (N, heads * (nope + rope)): each head [q_nope | q_pe];
+    key_value (N, heads * (nope + v)): each head [k_nope | v], the
+    latent's up-projection; key_rope (N, rope): the keys' one rotary
+    head, shared by all heads.  Head h attends with q_h = [q_nope_h |
+    rot(q_pe_h)] over k_h = [k_nope_h | rot(k_pe)], scaled by
+    1 / sqrt(nope + rope), to values of width v.  Returns
+    (N, heads * v)."""
+    heads, seq_len = asint(attrs['num_heads']), asint(attrs['seq_len'])
+    nope, rope = (asint(attrs['qk_nope_head_dim']),
+                  asint(attrs['qk_rope_head_dim']))
+    dv = asint(attrs['v_head_dim'])
+    theta = asfloat(attrs.get('rope_theta', 10000.0))
+    n = q.shape[0]
+    q = _fold(q.reshape(n, heads, nope + rope), seq_len)
+    kv = _fold(kv.reshape(n, heads, nope + dv), seq_len)
+    k_pe = _fold(k_pe.reshape(n, 1, rope), seq_len)
+    dtype = kv.dtype
+    q_pe = rotary_pairs(q[..., nope:].astype(F32), theta).astype(dtype)
+    k_pe = rotary_pairs(k_pe.astype(F32), theta).astype(dtype)
+    b = q.shape[0]
+    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+        k_pe, (b, seq_len, heads, rope))], axis=-1)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    o = causal_attention(q[:, :, :, None, :], k, kv[..., nope:],
+                         1.0 / math.sqrt(nope + rope))
+    return o.reshape(n, heads * dv)
 
 
 # ---------------------------------------------------------------------------
@@ -583,25 +645,45 @@ def _grouped_bwd(tile, res, dy):
 grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def route(x, router_weight, top_k, normalize):
-    """softmax over all experts in float32, the top k and their weights
-    (over the chosen k, whether held here or not)."""
+def route(x, router_weight, top_k, normalize, scoring='softmax',
+          bias=None, scale=1.0):
+    """Scores over all experts in float32 (`scoring`: softmax, or
+    sigmoid as DeepSeek-V3 has it), the top k and their weights (over
+    the chosen k, whether held here or not).  `bias` (experts,) is
+    added for the choice alone and is not in the weights; a sigmoid's
+    weights are normalised over sum + 1e-20, as published; `scale`
+    multiplies them last."""
     logits = jnp.dot(x, router_weight.T, preferred_element_type=F32)
-    vals, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if scoring == 'softmax':
+        scores, tiny = jax.nn.softmax(logits, axis=-1), 0.0
+    elif scoring == 'sigmoid':
+        scores, tiny = jax.nn.sigmoid(logits), 1e-20
+    else:
+        raise ValueError('scoring %r: softmax or sigmoid' % (scoring,))
+    if bias is None:
+        vals, idx = lax.top_k(scores, top_k)
+    else:
+        _, idx = lax.top_k(scores + lax.stop_gradient(bias.astype(F32)),
+                           top_k)
+        vals = jnp.take_along_axis(scores, idx, axis=-1)
     if normalize:
-        vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+        total = jnp.sum(vals, axis=-1, keepdims=True)
+        vals = vals / (total + tiny) if tiny else vals / total
+    if scale != 1.0:
+        vals = vals * scale
     return vals, idx
 
 
 def sparse_moe(x, router_weight, wg, wu, wd, top_k, expert_offset,
-               normalize=True, tile=EXPERT_TILE):
+               normalize=True, tile=EXPERT_TILE, **routing):
     """The held experts' part of a top-k expert layer.  wg, wu
     (held, I, H) and wd (held, H, I) are experts expert_offset ..
-    expert_offset + held of router_weight.shape[0].  Returns (y,
-    assigned, computed): per-expert counts of the pairs the router
-    made (all experts) and of those computed here."""
+    expert_offset + held of router_weight.shape[0]; `routing` is
+    route()'s scoring, bias and scale.  Returns (y, assigned,
+    computed): per-expert counts of the pairs the router made (all
+    experts) and of those computed here."""
     n_exp, held = router_weight.shape[0], wg.shape[0]
-    vals, idx = route(x, router_weight, top_k, normalize)
+    vals, idx = route(x, router_weight, top_k, normalize, **routing)
     local = idx.reshape(-1) - expert_offset
     key = jnp.where((local >= 0) & (local < held), local, held)
     order = jnp.argsort(key, stable=True)       # held pairs first
@@ -622,6 +704,26 @@ def sparse_moe(x, router_weight, wg, wu, wd, top_k, expert_offset,
     return y, assigned, computed
 
 
+def _has_selection_bias(attrs):
+    """topk_method 'noaux_tc' (DeepSeek-V3's name for it): the choice
+    is made on scores + selection_bias, auxiliary state of the node."""
+    method = str(attrs.get('topk_method', 'greedy'))
+    if method not in ('greedy', 'noaux_tc'):
+        raise ValueError('topk_method %r: greedy or noaux_tc' % method)
+    return method == 'noaux_tc'
+
+
+def _moe_input_names(attrs):
+    names = ('data', 'router_weight', 'gate_weight', 'up_weight',
+             'down_weight', 'counts')
+    return names + ('selection_bias',) if _has_selection_bias(attrs) \
+        else names
+
+
+def _moe_num_aux(attrs):
+    return 2 if _has_selection_bias(attrs) else 1
+
+
 def _moe_infer_shape(attrs, in_shapes):
     if in_shapes[0] is None or in_shapes[0][-1] == 0:
         return in_shapes
@@ -630,11 +732,20 @@ def _moe_infer_shape(attrs, in_shapes):
         attrs['num_experts_held'])
     inter = asint(attrs['intermediate_size'])
     wanted = [(n_exp, hidden), (held * inter, hidden),
-              (held * inter, hidden), (held * hidden, inter), (2, n_exp)]
-    for i, s in enumerate(wanted, start=1):
+              (held * inter, hidden), (held * hidden, inter), (2, n_exp),
+              (n_exp,)]
+    for i, s in enumerate(wanted[:len(in_shapes) - 1], start=1):
         if in_shapes[i] is None:
             in_shapes[i] = s
     return in_shapes
+
+
+def _moe_infer_dtype(attrs, in_dtypes):
+    """Weights follow the data; `counts` is int32 and the selection
+    bias float32 whatever the graph's type."""
+    d = _data_dtype(in_dtypes)
+    aux = [np.dtype(np.int32), np.dtype(np.float32)][:_moe_num_aux(attrs)]
+    return [d] * (len(in_dtypes) - len(aux)) + aux, [d]
 
 
 def _sparse_moe(attrs, inputs, auxs, op_ctx):
@@ -645,18 +756,37 @@ def _sparse_moe(attrs, inputs, auxs, op_ctx):
         raise ValueError('experts %d..%d of %d' % (
             offset, offset + held, router_weight.shape[0]))
     hidden = x.shape[-1]
+    biased = _has_selection_bias(attrs)
     y, assigned, computed = sparse_moe(
         x, router_weight, wg.reshape(held, -1, hidden),
         wu.reshape(held, -1, hidden), wd.reshape(held, hidden, -1),
         asint(attrs['top_k']), offset,
-        asbool(attrs.get('normalize', True)))
-    counts = auxs[0] + lax.stop_gradient(jnp.stack([assigned, computed]))
-    return [y], [counts]
+        asbool(attrs.get('normalize', True)),
+        scoring=str(attrs.get('scoring_func', 'softmax')),
+        bias=auxs[1] if biased else None,
+        scale=asfloat(attrs.get('routed_scaling_factor', 1.0)))
+    new_auxs = [auxs[0] + lax.stop_gradient(jnp.stack([assigned,
+                                                       computed]))]
+    if biased:
+        new_auxs.append(_updated_bias(
+            auxs[1], assigned, asfloat(attrs.get('bias_update_rate', 0.0))))
+    return [y], new_auxs
+
+
+def _updated_bias(bias, assigned, rate):
+    """DeepSeek-V3's rule (arXiv:2412.19437, 2.1.2), once a training
+    pass: b_e += rate * sign(mean load - load_e), the loads this pass's
+    own assignments over all experts.  No gradient reaches the bias."""
+    if not rate:
+        return bias
+    load = lax.stop_gradient(assigned).astype(F32)
+    return bias + rate * jnp.sign(jnp.mean(load) - load).astype(bias.dtype)
 
 
 def _fold_counts(attrs, deltas):
     """`counts`' growth into profiler.moe_stats(): row 0 the pairs the
-    router assigned to each expert, row 1 those computed here."""
+    router assigned to each expert, row 1 those computed here (the
+    selection bias, where there is one, is no counter)."""
     from .. import profiler
     assigned, computed = deltas[0]
     first = asint(attrs.get('expert_offset', 0))
@@ -667,9 +797,7 @@ def _fold_counts(attrs, deltas):
         per_expert_routed=computed, assignments=assigned.sum())
 
 
-register('SparseMoE',
-         input_names=('data', 'router_weight', 'gate_weight', 'up_weight',
-                      'down_weight', 'counts'),
-         num_aux=1, mutable_aux=True, infer_shape=_moe_infer_shape,
-         infer_dtype=_infer_dtype(int_aux=1), hint='sparsemoe',
+register('SparseMoE', input_names=_moe_input_names, num_aux=_moe_num_aux,
+         mutable_aux=True, infer_shape=_moe_infer_shape,
+         infer_dtype=_moe_infer_dtype, hint='sparsemoe',
          simple=False, fold_aux=_fold_counts)(_sparse_moe)

@@ -136,18 +136,22 @@ class OpDef:
             names = names(attrs)
         return list(names)
 
+    def aux_count(self, attrs):
+        """How many trailing inputs are aux states: `num_aux`, or for
+        an operator whose aux states depend on its attributes (SparseMoE's
+        selection bias) what `num_aux(attrs)` says."""
+        n = self.num_aux
+        return n(attrs) if callable(n) else n
+
     def arg_names(self, attrs):
         """Non-aux input names."""
         names = self.input_names(attrs)
-        if self.num_aux:
-            return names[:-self.num_aux]
-        return names
+        n_aux = self.aux_count(attrs)
+        return names[:-n_aux] if n_aux else names
 
     def aux_names(self, attrs):
-        names = self.input_names(attrs)
-        if self.num_aux:
-            return names[-self.num_aux:]
-        return []
+        n_aux = self.aux_count(attrs)
+        return self.input_names(attrs)[-n_aux:] if n_aux else []
 
     def num_outputs(self, attrs):
         n = self._num_outputs
@@ -190,7 +194,7 @@ class OpDef:
                 any(s is not None for s in out_shapes):
             in_shapes = self.infer_shape_bwd_fn(attrs, in_shapes,
                                                 out_shapes)
-        n_arg = len(in_shapes) - self.num_aux
+        n_arg = len(in_shapes) - self.aux_count(attrs)
         if self.shape_rule == 'same':
             unified = None
             cands = in_shapes[:n_arg] + list(out_shapes or [])

@@ -268,6 +268,11 @@ CASES = {
                                   'head_dim': 8, 'rotary_dim': 4,
                                   'seq_len': 6},
                            rtol=5e-2, atol=5e-3, wrap='square', eps=1e-2),
+    'LatentAttention': Case([(12, 24), (12, 28), (12, 4)],
+                            attrs={'num_heads': 2, 'qk_nope_head_dim': 8,
+                                   'qk_rope_head_dim': 4, 'v_head_dim': 6,
+                                   'rope_theta': 100.0, 'seq_len': 6},
+                            rtol=5e-2, atol=5e-3, wrap='square', eps=1e-2),
     'GatedDeltaRule': Case([(10, 20), (10, 2), (10, 2), (2,), (2,)],
                            attrs={'num_k_heads': 1, 'num_v_heads': 2,
                                   'head_k_dim': 4, 'head_v_dim': 6,
@@ -450,7 +455,8 @@ SKIP = {
     'SparseMoE': 'top-k routing is piecewise (a finite difference can '
                  'cross a choice) and the counts are aux state: values, '
                  'gradients, shares and counters against the plain '
-                 'reference in tests/test_qwen3_next.py',
+                 'references in tests/test_qwen3_next.py (softmax) and '
+                 'tests/test_deepseek_v3.py (sigmoid, selection bias)',
     '_NoGradient': 'zero-input placeholder node (reference '
                    'init_op.cc); nothing to gradient-check',
 }
