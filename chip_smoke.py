@@ -6,7 +6,9 @@ One process.  Drives `mx.mod.Module` over `mxnet_tpu.models` ResNet-50
 at full width (batch 256, 3x224x224, bfloat16, random weights from a
 seed) through the entry points a user calls — `Module.fit` and
 `Module.bulk_step` — then compiles and runs the Pallas flash-attention
-kernels at four lengths and the gated delta rule's kernels at one block
+kernels at four lengths and at the latent-attention cell's head shape
+(32 heads, keys of 192 over values of 128, T = 8,192, against dense
+attention) and the gated delta rule's kernels at one block
 of the language model's cell (against the XLA loop they replaced), then,
 on a host with four chips, runs the same network data-parallel over
 them.  It fails (non-zero exit, no result
@@ -249,56 +251,77 @@ def compile_and_time(fn, args, n_kernels, calls=1):
 # ---------------------------------------------------------------------------
 
 def phase_b(lengths=(2048, 12288, 16384, 32768), parity_at=2048, bh=8,
-            d=128, expect_custom_call=True):
+            d=128, latent=(32, 8192, 192, 128), parity_heads=4,
+            expect_custom_call=True):
+    """Flash attention at `bh` heads of `d` over each of `lengths`, and
+    at `latent` = (heads, T, dk, dv): latent attention's shape in the
+    Kanana cell, keys wider than values.  Forward and gradient are
+    compared with dense attention at `parity_at` and at the latent
+    case, `parity_heads` heads at a time (the dense float32 scores of
+    32 heads at T = 8,192 would be 8.6 GB)."""
     from mxnet_tpu import pallas_ops
+    from mxnet_tpu.ops import lm
 
     def fwd(q, k, v):
-        return pallas_ops.flash_attention(q, k, v, causal=True)
+        # the latent case with the tile causal_attention gives it
+        return pallas_ops.flash_attention(
+            q, k, v, causal=True,
+            block_q=None if q.shape[-1] == v.shape[-1] else lm.FLASH_BLOCK)
 
     def loss(q, k, v):
         return fwd(q, k, v).astype(jnp.float32).sum()
 
-    def dense_loss(q, k, v):
+    def dense(q, k, v):
         return pallas_ops._dense_attention_lse(
-            q, k, v, True, 1.0 / d ** 0.5)[0].astype(jnp.float32).sum()
+            q, k, v, True, 1.0 / q.shape[-1] ** 0.5)[0]
+
+    dense_all = jax.jit(lambda q, k, v: (dense(q, k, v),) + jax.grad(
+        lambda *a: dense(*a).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v))
 
     def timed(fn, n_kernels, *args):
         return compile_and_time(fn, args, n_kernels if expect_custom_call
                                 else 0)
 
     result = {}
-    for t in lengths:
-        keys = jax.random.split(jax.random.PRNGKey(SEED + t), 3)
-        q, k, v = (jax.random.normal(kk, (1, bh, t, d), jnp.bfloat16)
-                   for kk in keys)
+    cases = [(t, bh, d, d, t == parity_at) for t in lengths]
+    if latent:
+        heads, t, dk, dv = latent
+        cases.append((t, heads, dk, dv, True))
+    for t, heads, dk, dv, parity in cases:
+        keys = jax.random.split(jax.random.PRNGKey(SEED + t + dk), 3)
+        q, k, v = (jax.random.normal(kk, (1, heads, t, w), jnp.bfloat16)
+                   for kk, w in zip(keys, (dk, dk, dv)))
         out, fwd_s, fwd_ms = timed(fwd, 1, q, k, v)
-        grads, bwd_s, bwd_ms = timed(jax.grad(loss, (0, 1, 2)), 3, q, k, v)
-        assert out.shape == q.shape
-        for name, a in zip(('out', 'dq', 'dk', 'dv'), (out,) + grads):
+        grads, bwd_s, bwd_ms = timed(jax.grad(loss, (0, 1, 2)), 2, q, k, v)
+        assert out.shape == v.shape
+        what = 'T=%d' % t if dk == dv else \
+            'T=%d heads=%d dk=%d dv=%d' % (t, heads, dk, dv)
+        got = dict(zip(('out', 'dq', 'dk', 'dv'), (out,) + grads))
+        for name, a in got.items():
             assert bool(jnp.isfinite(a.astype(jnp.float32)).all()), \
-                'flash T=%d: %s not finite' % (t, name)
-        line = ('phase B flash T=%d: forward compile %.1f s run %.2f ms, '
+                'flash %s: %s not finite' % (what, name)
+        line = ('phase B flash %s: forward compile %.1f s run %.2f ms, '
                 'forward+backward compile %.1f s run %.2f ms'
-                % (t, fwd_s, fwd_ms, bwd_s, bwd_ms))
-        if t == parity_at:
-            ref = pallas_ops._dense_attention_lse(
-                q, k, v, True, 1.0 / d ** 0.5)[0]
-            ref_grads = jax.grad(dense_loss, (0, 1, 2))(q, k, v)
+                % (what, fwd_s, fwd_ms, bwd_s, bwd_ms))
+        if parity:
             worst = 0.0
-            for name, a, b in zip(('out', 'dq', 'dk', 'dv'),
-                                  (out,) + grads, (ref,) + ref_grads):
-                a = np.asarray(a, np.float32)
-                b = np.asarray(b, np.float32)
-                # bf16 carries 8 bits: compare against the tensor's scale
-                err = np.abs(a - b).max() / max(np.abs(b).max(), 1e-6)
-                assert err < 2e-2, \
-                    'flash T=%d: %s differs from dense by %.3g of its ' \
-                    'scale' % (t, name, err)
-                worst = max(worst, err)
+            for h0 in range(0, heads, parity_heads):
+                part = slice(h0, h0 + parity_heads)
+                ref = dense_all(q[:, part], k[:, part], v[:, part])
+                for (name, a), b in zip(got.items(), ref):
+                    a = np.asarray(a[:, part], np.float32)
+                    b = np.asarray(b, np.float32)
+                    # bf16 carries 8 bits: compare against the
+                    # tensor's scale
+                    err = np.abs(a - b).max() / max(np.abs(b).max(), 1e-6)
+                    assert err < 2e-2, \
+                        'flash %s: %s differs from dense by %.3g of ' \
+                        'its scale' % (what, name, err)
+                    worst = max(worst, err)
             line += ', parity with dense %.2g of scale' % worst
         log(line)
-        result['T=%d' % t] = {'forward_ms': round(fwd_ms, 2),
-                              'forward_backward_ms': round(bwd_ms, 2)}
+        result[what] = {'forward_ms': round(fwd_ms, 2),
+                        'forward_backward_ms': round(bwd_ms, 2)}
     return result
 
 

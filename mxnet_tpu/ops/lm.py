@@ -6,19 +6,23 @@ seq_len`, as `Embedding` gives them and `FullyConnected` takes them; the
 operators that look along a sequence carry `seq_len` as an attribute and
 fold the rows to `(N / seq_len, seq_len, ...)` themselves.  All are pure
 JAX functions differentiated by jax.vjp inside the one compiled step,
-like every other operator of the registry, and plain XLA but for the
-loop over GatedDeltaRule's chunks, which is three Pallas kernels
-(pallas_ops.delta_rule_*) under a custom gradient rule.
+like every other operator of the registry, and plain XLA but for two
+things under custom gradient rules: the loop over GatedDeltaRule's
+chunks (three Pallas kernels, pallas_ops.delta_rule_*) and the
+attention core of ungrouped heads (pallas_ops.flash_attention's
+kernels, forward and backward).
 
   RMSNorm          x * rsqrt(mean x^2 + eps) * gamma, or * (1 + gamma)
   GatedAttention   per-head q/k RMS norm, partial rotary, grouped-head
-                   causal softmax attention in blocks of query rows, and
-                   the sigmoid gate on the output
+                   causal softmax attention (causal_attention: grouped
+                   heads take the blocked XLA core, blocks of query
+                   rows), and the sigmoid gate on the output
   LatentAttention  the core of multi-head latent attention: rotary by
                    adjacent pairs on the keys' one shared rotary head
                    and on each query head's rotary part, causal softmax
-                   attention with keys wider than values, on the same
-                   blocks of query rows
+                   attention with keys wider than values
+                   (causal_attention: its ungrouped heads take the
+                   flash kernels at any T they tile, else the blocks)
   CausalConv1D     depthwise causal convolution along the sequence
   GatedDeltaRule   the gated delta rule in chunks (WY form): a unit
                    lower triangular solve inside a chunk (XLA, all
@@ -39,11 +43,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from .registry import register, asbool, asfloat, asint
-from .. import pallas_ops
+from .. import pallas_ops, profiler
 
 F32 = jnp.float32
 HIGHEST = lax.Precision.HIGHEST
-ATTN_BLOCK = 512            # query rows a block of GatedAttention
+ATTN_BLOCK = 512            # query rows a block of the blocked attention core
+FLASH_BLOCK = 1024          # rows and keys a tile of the flash kernels
 CHUNK = 64                  # tokens a chunk of GatedDeltaRule
 LANES = 128                 # head widths GatedDeltaRule's kernels take
 KEY_HEADS_PER_BLOCK = 4     # key heads GatedDeltaRule takes at a time
@@ -170,15 +175,13 @@ def _attention_block_bwd(scale, first_row, res, do):
 _attention_block.defvjp(_attention_block_fwd, _attention_block_bwd)
 
 
-def causal_attention(q, k, v, scale, block_q=ATTN_BLOCK):
-    """softmax(q k^T * scale + causal) v with grouped heads: q
-    (B, T, kv, group, d), k (B, T, kv, d) and v (B, T, kv, dv), whose
-    width is its own (latent attention's keys are wider than its
-    values); the result is (B, T, kv, group, dv).  One sequence at a
-    time and query rows in blocks, each block against the keys it can
-    see; a block keeps its output and its rows' log-sum-exp and makes
-    its scores again in the backward pass, so no T x T score matrix is
-    ever stored."""
+def blocked_causal_attention(q, k, v, scale, block_q=ATTN_BLOCK):
+    """causal_attention in plain XLA, grouped heads and any length: one
+    sequence at a time and query rows in blocks, each block against the
+    keys it can see; a block keeps its output and its rows' log-sum-exp
+    and makes its scores again in the backward pass, so no T x T score
+    matrix is ever stored (a block's float32 scores do cross HBM
+    between its fusions)."""
     t = q.shape[1]
     block_q = min(block_q, t)
 
@@ -190,6 +193,45 @@ def causal_attention(q, k, v, scale, block_q=ATTN_BLOCK):
             for r0 in range(0, t, block_q)], axis=0)
 
     return lax.map(one_sequence, (q, k, v))
+
+
+def causal_attention(q, k, v, scale, block_q=None):
+    """softmax(q k^T * scale + causal) v with grouped heads: q
+    (B, T, kv, group, d), k (B, T, kv, d) and v (B, T, kv, dv), whose
+    width is its own (latent attention's keys are wider than its
+    values); the result is (B, T, kv, group, dv).  The path is chosen
+    from the operands' shapes alone:
+
+      kernel   group == 1 and a T the flash kernels' schedules tile
+               (blocks of whole sublanes: a multiple of 8 rows under
+               `block_q` dividing T): pallas_ops.flash_attention, the
+               whole batch in one call with the heads in front of the
+               rows.  Scores, probabilities and their gradients live
+               in VMEM a tile at a time, forward and backward;
+               residuals are q, k, v, o and the rows' log-sum-exp.
+      blocked  grouped heads (the kernels' dK and dV do not sum over a
+               group) or a ragged T: blocked_causal_attention.
+
+    Both keep bf16 operands with float32 scores, sums and accumulators.
+    `block_q`, where given, is the rows of a block on either path; left
+    out it is FLASH_BLOCK on the kernel (tiles of 1024 x 1024 timed
+    best at T = 8,192 with keys of 192 over values of 128: PERF.md
+    section 6, PR 32) and ATTN_BLOCK on the blocked core.
+    profiler.attention_stats() counts the lowerings by path."""
+    t, group = q.shape[1], q.shape[3]
+    tile = FLASH_BLOCK if block_q is None else block_q
+    kernel = group == 1 and \
+        not pallas_ops._needs_dense_fallback(t, t, tile)
+    profiler.note_attention_lowering(
+        'kernel' if kernel else 'blocked', heads=q.shape[2] * group,
+        group=group, dk=q.shape[4], dv=v.shape[3], t=t)
+    if not kernel:
+        return blocked_causal_attention(
+            q, k, v, scale, ATTN_BLOCK if block_q is None else block_q)
+    o = pallas_ops.flash_attention(
+        jnp.swapaxes(q[:, :, :, 0], 1, 2), jnp.swapaxes(k, 1, 2),
+        jnp.swapaxes(v, 1, 2), causal=True, scale=scale, block_q=tile)
+    return jnp.swapaxes(o, 1, 2)[:, :, :, None]
 
 
 def _attn_infer_shape(attrs, in_shapes):
@@ -258,7 +300,12 @@ def _latent_attention(attrs, q, kv, k_pe):
     head, shared by all heads.  Head h attends with q_h = [q_nope_h |
     rot(q_pe_h)] over k_h = [k_nope_h | rot(k_pe)], scaled by
     1 / sqrt(nope + rope), to values of width v.  Returns
-    (N, heads * v)."""
+    (N, heads * v).  Every query head has its own key head (group 1),
+    so causal_attention runs the flash kernels with a value width of
+    their own wherever blocks of whole sublanes (a multiple of 8 rows)
+    divide seq_len (8,192 in the cell: tiles of 1024 x 1024, float32
+    scores in VMEM only), and the blocked XLA core at a ragged
+    seq_len."""
     heads, seq_len = asint(attrs['num_heads']), asint(attrs['seq_len'])
     nope, rope = (asint(attrs['qk_nope_head_dim']),
                   asint(attrs['qk_rope_head_dim']))
@@ -787,7 +834,6 @@ def _fold_counts(attrs, deltas):
     """`counts`' growth into profiler.moe_stats(): row 0 the pairs the
     router assigned to each expert, row 1 those computed here (the
     selection bias, where there is one, is no counter)."""
-    from .. import profiler
     assigned, computed = deltas[0]
     first = asint(attrs.get('expert_offset', 0))
     here = slice(first, first + asint(attrs['num_experts_held']))

@@ -1,21 +1,48 @@
 """In-tree Pallas TPU kernels for hot ops.
 
 The reference hand-writes CUDA for its hottest kernels; the TPU
-counterpart is Pallas (jax.readthedocs.io/en/latest/pallas).  This module
-ships the first production kernel: flash attention — a 3D
-(batch*head, q-block, k-block) grid streams K/V blocks through VMEM with
-the online-softmax recurrence in fp32 scratch, so neither the T^2 score
-matrix nor the full K/V sequence ever sits in VMEM/HBM at once, and
-causal q-tiles skip their fully-masked k-blocks.  Available directly as
-`pallas_ops.flash_attention` and opt-in via
-`parallel.ring_attention.full_attention(use_flash=True)`.
+counterpart is Pallas (jax.readthedocs.io/en/latest/pallas).  Two
+families live here: flash attention, and (further down) the gated delta
+rule's loop over chunks.
 
-Backward is the fused two-pass FlashAttention recipe in Pallas: the
-forward saves the per-row logsumexp, D = rowsum(dO∘O) is a fused XLA
-preprocess, and two kernels (dK/dV gridded over k-blocks, dQ over
-q-blocks) recompute p = exp(s − lse) tile by tile — nothing O(T^2) is
-materialized.  Sequences too long for the resident-VMEM kernels fall
-back to an XLA-level blocked recompute.
+Flash attention — a (batch*head, q-block, k-block) grid streams K/V
+blocks through VMEM with the online-softmax recurrence in fp32 scratch,
+so neither the T^2 score matrix nor the full K/V sequence ever sits in
+VMEM/HBM at once, and causal q-tiles skip their fully-masked k-blocks
+and mask only the tiles the diagonal crosses.  q and k are
+(batch, heads, T, dk); v and the output (batch, heads, T, dv): the
+values' width is their own (latent attention has keys of 192 over
+values of 128), so q, k, dQ, dK and their blocks are dk wide and v, o,
+dO, dV and the accumulators dv wide.  Heads are ungrouped: every query
+head has its own key and value head (the backward kernel's dK and dV do
+not sum over a group).  Available as `pallas_ops.flash_attention`,
+opt-in via `parallel.ring_attention.full_attention(use_flash=True)`,
+and under `ops.lm.causal_attention` for ungrouped heads (the
+LatentAttention operator).
+
+Backward is ONE Pallas kernel: the forward saves the per-row logsumexp,
+D = rowsum(dO∘O) is a fused XLA preprocess, and the kernel (gridded
+over k-blocks, q-blocks innermost) recomputes p = exp(s − lse) once a
+tile for all three gradients, dK/dV in float32 scratch a k-block, dQ in
+a float32 scratch that holds the head's whole sequence — nothing O(T^2)
+is materialized.  Operands keep their type (bf16 in the cells); scores,
+max, sums and accumulators are float32, p and dS are cast to the
+operands' type for their products.
+
+Which shapes take which path (lanes counted as VMEM holds them, a width
+of 192 as 256).  Forward (`_fwd_resident`): the resident schedule keeps
+one head's K and V in VMEM and loops over their blocks; the streaming
+schedule puts that loop on the grid and holds O(block) for any T.  At
+d = 128 in bf16 the forward is resident up to T = 12,288 under the
+default tiles; keys of 192 over values of 128 at T = 8,192 (a 6 MiB
+pair) with tiles of 1024 x 1024 stream.  Backward
+(`_flash_bwd_shared`): the kernel wherever its dQ accumulator
+(tq x dk float32) is at most 64 MiB, T = 131,072 at d = 128; beyond,
+and for lengths no block of whole sublanes divides, dense attention
+(forward) and an XLA-level blocked recompute (backward).  Every
+pallas_call is named (`flash_attention_fwd_stream`,
+`flash_attention_fwd_resident`, `flash_attention_bwd`): the names are
+the custom calls' in a trace.
 """
 import functools
 
@@ -41,17 +68,34 @@ def default_interpret(*operands):
     return jax.default_backend() != 'tpu'
 
 
-def _online_softmax_step(q, kblk, vblk, m, l, acc, scale, causal,
-                         row0, col0):
-    """One K-block of the online-softmax recurrence — the ONE numerics
-    definition both schedules share."""
+def _lanes(d):
+    """A head width as VMEM holds it: rounded up to whole 128-lane
+    tiles (latent attention's keys of 192 occupy 256)."""
+    return -(-d // 128) * 128
+
+
+def _scores(q, kblk, scale, row0, col0, masked):
+    """Scaled scores (rows, cols) of a q tile against a k tile in
+    float32; `masked` (a Python bool) applies the causal mask of a tile
+    the diagonal crosses: rows from `row0` see columns from `col0` up
+    to their own index.  Tiles wholly under the diagonal skip it: the
+    mask would change nothing there."""
     s = lax.dot_general(
         q, kblk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    if causal:
+    if masked:
         rows = row0 + lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = col0 + lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(rows >= cols, s, -jnp.inf)
+    return s
+
+
+def _online_softmax_step(q, kblk, vblk, m, l, acc, scale, masked,
+                         row0, col0):
+    """One K-block of the online-softmax recurrence — the ONE numerics
+    definition both schedules share.  q, kblk are dk wide, vblk and acc
+    dv wide."""
+    s = _scores(q, kblk, scale, row0, col0, masked)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     correction = jnp.exp(m - m_new)
     p = jnp.exp(s - m_new)
@@ -60,6 +104,32 @@ def _online_softmax_step(q, kblk, vblk, m, l, acc, scale, causal,
         p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     return m_new, l_new, acc * correction + pv
+
+
+def _last_live_kb(qi, block_q, block_k, num_kb, offset):
+    """The last k block a causal q tile sees (diagonal inclusive)."""
+    return jnp.minimum(
+        (qi * block_q + block_q - 1 + offset) // block_k, num_kb - 1)
+
+
+def _first_masked_kb(qi, block_q, block_k, num_kb, offset):
+    """The first k block the diagonal crosses for a causal q tile: the
+    blocks before it lie wholly under the diagonal (their last column
+    is no later than the tile's first row)."""
+    return jnp.minimum((qi * block_q + offset + 1) // block_k, num_kb)
+
+
+def _first_live_qb(kb, block_q, block_k, offset):
+    """The first q block whose rows reach a causal k block's columns."""
+    return jnp.maximum(kb * block_k - offset, 0) // block_q
+
+
+def _first_unmasked_qb(kb, block_q, block_k, num_qb, offset):
+    """The first q block wholly under the diagonal of a causal k block
+    (its first row is no earlier than the block's last column)."""
+    return jnp.clip(
+        (kb * block_k + block_k - 1 - offset + block_q - 1) // block_q,
+        0, num_qb)
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
@@ -74,27 +144,30 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     qi = pl.program_id(1)
     kb = pl.program_id(2)
 
-    # causal: this q tile's last live k block (diagonal inclusive)
-    last_kb = num_kb - 1
-    if causal:
-        last_kb = jnp.minimum(
-            (qi * block_q + block_q - 1 + offset) // block_k, num_kb - 1)
-
     @pl.when(kb == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(jnp.logical_not(causal) | (kb <= last_kb))
-    def _compute():
+    def compute(masked):
         m_new, l_new, acc_new = _online_softmax_step(
             q_ref[0], k_ref[0], v_ref[0], m_ref[...], l_ref[...],
-            acc_ref[...], scale, causal, qi * block_q + offset,
+            acc_ref[...], scale, masked, qi * block_q + offset,
             kb * block_k)
         m_ref[...] = m_new
         l_ref[...] = l_new
         acc_ref[...] = acc_new
+
+    if causal:
+        first_masked = _first_masked_kb(qi, block_q, block_k, num_kb,
+                                        offset)
+        pl.when(kb < first_masked)(lambda: compute(False))
+        pl.when((kb >= first_masked) &
+                (kb <= _last_live_kb(qi, block_q, block_k, num_kb,
+                                     offset)))(lambda: compute(True))
+    else:
+        compute(False)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
@@ -109,27 +182,31 @@ def _attn_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
     online-softmax recurrence, and causal q-tiles stop at the diagonal
     (skipping both compute AND reads of the masked tail).  Fastest when
     K/V fit in VMEM."""
-    q = q_ref[0]                          # (block_q, D)
+    q = q_ref[0]                          # (block_q, dk)
     qi = pl.program_id(1)
-    d = q.shape[-1]
-    m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
+    carry = (jnp.full((block_q, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32),
+             jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32))
 
-    def body(kb, carry):
-        m, l, acc = carry
+    def body(masked, kb, carry):
         kblk = k_ref[0, pl.ds(kb * block_k, block_k), :]
         vblk = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        return _online_softmax_step(q, kblk, vblk, m, l, acc, scale,
-                                    causal, qi * block_q + offset,
-                                    kb * block_k)
+        return _online_softmax_step(q, kblk, vblk, *carry, scale, masked,
+                                    qi * block_q + offset, kb * block_k)
 
     if causal:
-        upper = jnp.minimum(
-            (qi * block_q + block_q - 1 + offset) // block_k + 1, num_kb)
+        first_masked = _first_masked_kb(qi, block_q, block_k, num_kb,
+                                        offset)
+        carry = lax.fori_loop(0, first_masked,
+                              functools.partial(body, False), carry)
+        carry = lax.fori_loop(
+            first_masked,
+            _last_live_kb(qi, block_q, block_k, num_kb, offset) + 1,
+            functools.partial(body, True), carry)
     else:
-        upper = num_kb
-    m, l, acc = lax.fori_loop(0, upper, body, (m0, l0, acc0))
+        carry = lax.fori_loop(0, num_kb, functools.partial(body, False),
+                              carry)
+    m, l, acc = carry
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(l)
 
@@ -141,19 +218,39 @@ def _attn_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
 # (measured: a 10 MB threshold OOMs at 2x), hence ~6 MB.
 _VMEM_RESIDENT_BYTES = 6 * 1024 * 1024
 
-# backward tile edge (see _flash_bwd_impl); 1024 measured best on
-# v5e-class — 2048 OOMs the 16 MB VMEM with double buffering
+# backward tile edge (see _bwd_blocks); 1024 measured best on
+# v5e-class among 256 to 2048
 _BWD_BLOCK = 1024
 
+# scoped VMEM the backward kernel asks for beside its dQ accumulator:
+# the double-buffered blocks and a tile's float32 temporaries (scores,
+# p, dP, dS and three products).  Tiles of 1024 x 1024 over heads of
+# 256 need more than Mosaic's default 16 MiB and compile in 32
+_BWD_TILE_VMEM_BYTES = 32 * 1024 * 1024
 
-def _bwd_resident_bytes():
-    """Resident budget of the BACKWARD kernels: two thirds of the
-    forward's.  They hold the same double-buffered sequence pair next
-    to the f32 score temporaries of a _BWD_BLOCK-edge tile, which the
-    forward's smaller tiles do not have: at a 6 MB pair (T=12288,
-    d=128, bf16) Mosaic asks 19.5 MB of the 16 MB scoped VMEM and
-    refuses; at 4 MB (T=8192) it compiles (v5e, libtpu 0.0.34)."""
-    return _VMEM_RESIDENT_BYTES * 2 // 3
+# the largest dQ accumulator (tq x dk float32, lanes as VMEM holds
+# them) the kernel takes: with the tiles' share it stays inside a
+# v5e's 128 MiB of VMEM (T = 131,072 at heads of 128 compiles for it);
+# longer sequences take the XLA-level blocked recompute
+_BWD_ACC_BYTES = 64 * 1024 * 1024
+
+
+def _pair_bytes(t, dk, dv, itemsize):
+    """Bytes of VMEM one head's K and V take, dk + dv wide, lanes
+    counted as VMEM holds them."""
+    return t * (_lanes(dk) + _lanes(dv)) * itemsize
+
+
+def _fwd_resident(tk, dk, dv, itemsize, block_q, block_k):
+    """Whether the forward keeps one head's K and V in VMEM.  Mosaic
+    holds the pair twice (its double-buffered window) beside a tile's
+    float32 scores and probabilities, and the sum has to leave q, o and
+    the accumulators their room in the 16 MB scoped VMEM: a 6 MiB pair
+    compiles with tiles of 384 x 384 (T=12288, d=128) and is refused,
+    20.3 MB of 16, with tiles of 1024 x 1024 (T=8192, keys of 192 over
+    values of 128; v5e, libtpu 0.0.34)."""
+    return 2 * _pair_bytes(tk, dk, dv, itemsize) + \
+        2 * 4 * block_q * block_k <= _VMEM_RESIDENT_BYTES * 7 // 3
 
 
 def _try_fit(t, cap):
@@ -166,17 +263,26 @@ def _try_fit(t, cap):
     return b
 
 
+def _tiles(t, block):
+    """Whether blocks of `block` rows are ones Mosaic takes for a
+    sequence of t: whole sublanes (8 rows; it cannot place a block of 33
+    even where that is the whole sequence), or a sequence of at most
+    one."""
+    return block % 8 == 0 or t <= 8
+
+
 def _fit_block(t, block_q):
     """_try_fit, raising on degenerate results.  Sequence lengths with
     no small power-of-two factor (e.g. prime T) would degenerate to
     1-row blocks that Mosaic rejects or runs pathologically — raise
     with guidance instead."""
     b = _try_fit(t, block_q)
-    if b < 8 and t > 8:
+    if not _tiles(t, b):
         raise ValueError(
-            'flash_attention: sequence length %d has no power-of-two '
-            'block factor >= 8; pad the sequence to a multiple of 128 '
-            'or use full_attention for unaligned lengths' % t)
+            'flash_attention: sequence length %d has no block of whole '
+            'sublanes (a multiple of 8 rows dividing it); pad the '
+            'sequence to a multiple of 128 or use full_attention for '
+            'unaligned lengths' % t)
     return b
 
 
@@ -197,47 +303,46 @@ def _schedule_caps(tq, tk, block_q):
 
 def _flash_fwd_impl(q, k, v, causal, scale, block_q, interpret,
                     return_lse=False):
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
+    b, h, tq, dk = q.shape
+    tk, dv = k.shape[2], v.shape[3]
     offset = tk - tq          # causal rows suffix-align to the keys
     bh = b * h
-    qf = q.reshape(bh, tq, d)
-    kf = k.reshape(bh, tk, d)
-    vf = v.reshape(bh, tk, d)
+    qf = q.reshape(bh, tq, dk)
+    kf = k.reshape(bh, tk, dk)
+    vf = v.reshape(bh, tk, dv)
     block_q = _fit_block(tq, block_q)
     block_k = _fit_block(tk, block_q if tq == tk else max(block_q, 256))
     num_kb = tk // block_k
-    itemsize = jnp.dtype(q.dtype).itemsize
-    resident = 2 * tk * d * itemsize <= _VMEM_RESIDENT_BYTES
+    resident = _fwd_resident(tk, dk, dv, jnp.dtype(q.dtype).itemsize,
+                             block_q, block_k)
     # lse rides along as (bh, tq, 1): the trailing singleton keeps the
     # row axis on the sublane dim so (block_q, 1) kernel views
     # broadcast directly against (block_q, block_k) scores
-    out_shapes = [jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+    out_shapes = [jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
                   jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32)]
+    static = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k, num_kb=num_kb, offset=offset)
 
     if resident:
         out, lse = pl.pallas_call(
-            functools.partial(_attn_kernel_resident, scale=scale,
-                              causal=causal, block_q=block_q,
-                              block_k=block_k, num_kb=num_kb,
-                              offset=offset),
+            functools.partial(_attn_kernel_resident, **static),
             grid=(bh, tq // block_q),
             in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
-                pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
+                pl.BlockSpec((1, block_q, dk), lambda i, j: (i, j, 0)),
+                pl.BlockSpec((1, tk, dk), lambda i, j: (i, 0, 0)),
+                pl.BlockSpec((1, tk, dv), lambda i, j: (i, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+                pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
                 pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
             ],
             out_shape=out_shapes,
             interpret=interpret,
+            name='flash_attention_fwd_resident',
         )(qf, kf, vf)
-        out = out.reshape(b, h, tq, d)
+        out = out.reshape(b, h, tq, dv)
         return (out, lse) if return_lse else out
 
-    grid = (bh, tq // block_q, num_kb)
     if causal:
         # clamp masked k-blocks to the diagonal: repeated block indices
         # skip the HBM->VMEM fetch (compute is gated by pl.when)
@@ -247,42 +352,42 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, interpret,
     else:
         kv_index = lambda i, j, n: (i, n, 0)
     out, lse = pl.pallas_call(
-        functools.partial(_attn_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          num_kb=num_kb, offset=offset),
-        grid=grid,
+        functools.partial(_attn_kernel, **static),
+        grid=(bh, tq // block_q, num_kb),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, n: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), kv_index),
-            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_q, dk), lambda i, j, n: (i, j, 0)),
+            pl.BlockSpec((1, block_k, dk), kv_index),
+            pl.BlockSpec((1, block_k, dv), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, n: (i, j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, j, n: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j, n: (i, j, 0)),
         ],
         out_shape=out_shapes,
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),     # running max
             pltpu.VMEM((block_q, 1), jnp.float32),     # normalizer
-            pltpu.VMEM((block_q, d), jnp.float32),     # output accum
+            pltpu.VMEM((block_q, dv), jnp.float32),    # output accum
         ],
         interpret=interpret,
+        name='flash_attention_fwd_stream',
     )(qf, kf, vf)
-    out = out.reshape(b, h, tq, d)
+    out = out.reshape(b, h, tq, dv)
     return (out, lse) if return_lse else out
 
 
 def _blocked_backward(q, k, v, g, causal, scale, block_q, glse=None):
     """Recompute-based gradients, q-block at a time: live memory is
-    O(block_q * T) instead of the dense O(T^2).  glse: optional
-    logsumexp cotangent, folded into the softmax vjp."""
-    bh, t, d = q.shape
+    O(block_q * T) instead of the dense O(T^2).  q, k (bh, t, dk); v,
+    g (bh, t, dv).  glse: optional logsumexp cotangent, folded into
+    the softmax vjp."""
+    bh, t, dk_w = q.shape
     tk = k.shape[1]
     offset = tk - t
     block_q = _fit_block(t, block_q)
     nq = t // block_q
-    qb = q.reshape(bh, nq, block_q, d)
-    gb = g.reshape(bh, nq, block_q, d)
+    qb = q.reshape(bh, nq, block_q, dk_w)
+    gb = g.reshape(bh, nq, block_q, g.shape[-1])
     lb = (jnp.zeros((bh, nq, block_q, 1), jnp.float32) if glse is None
           else glse.astype(jnp.float32).reshape(bh, nq, block_q, 1))
 
@@ -315,356 +420,168 @@ def _blocked_backward(q, k, v, g, causal, scale, block_q, glse=None):
         (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)),
         (idx, qb.transpose(1, 0, 2, 3), gb.transpose(1, 0, 2, 3),
          lb.transpose(1, 0, 2, 3)))
-    dq = dq_blocks.transpose(1, 0, 2, 3).reshape(bh, t, d)
+    dq = dq_blocks.transpose(1, 0, 2, 3).reshape(bh, t, dk_w)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 # ---------------------------------------------------------------------------
-# Fused Pallas backward: the FlashAttention two-pass recipe.  Pass 0 is
-# the (fused, XLA-level) preprocess D = rowsum(dO * O); pass 1 is two
-# kernels — dK/dV with k-blocks as the parallel grid dim, dQ with
-# q-blocks — each recomputing p = exp(s - lse) from the saved
-# logsumexp, so nothing O(T^2) is ever materialized and both kernels
-# stream their counterpart sequence through a fori_loop with causal
-# skipping.  (Reference analog: the hand-tuned cuDNN-class backward
-# kernels, cudnn_convolution-inl.h-level effort, done the Mosaic way.)
+# Fused Pallas backward: ONE kernel.  Pass 0 is the (fused, XLA-level)
+# preprocess D = rowsum(dO * O); the kernel's grid is (head, k-block,
+# q-block) with the q-blocks innermost, and each live (q tile, k tile)
+# pair recomputes p = exp(s - lse) from the saved logsumexp ONCE for all
+# three gradients: dV += p^T dO and dK += dS^T Q accumulate in float32
+# scratch over the q-blocks of a k-block and are written at its last;
+# dQ += dS K accumulates in a float32 scratch that holds the head's
+# whole (tq, dk) and is written, block by block, during the last
+# k-block.  Nothing O(T^2) is ever materialized; causal tiles above the
+# diagonal are fetch-clamped and compute-gated.  It replaced a dK/dV
+# kernel and a dQ kernel that each made the scores and dP again (7
+# products a pair against 5): 23.5 -> 16.0 ms at 32 heads, T = 8,192,
+# keys 192 over values 128, and a quarter to a third less at every
+# other shape timed (T 2,048 to 32,768, heads of 64, 128 and 256; v5e,
+# PERF.md section 6, PR 32), bit for bit the same gradients.
+# (Reference analog: the hand-tuned cuDNN-class backward kernels,
+# cudnn_convolution-inl.h-level effort, done the Mosaic way.)
 # ---------------------------------------------------------------------------
 
-def _bwd_dkdv_kernel(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
-                     dk_ref, dv_ref, *, scale, causal, block_q, block_k,
-                     num_qb, offset):
+def _bwd_kernel(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref, dq_ref,
+                dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, scale, causal,
+                block_q, block_k, num_qb, num_kb, offset):
+    """One (bh, kb, qi) grid step: q/dO/lse/D arrive one q-block a
+    step, k/v one k-block a kb.  q, k, dQ, dK are dk wide; v, dO, dV
+    dv wide."""
     kb = pl.program_id(1)
-    kblk = k_ref[0]                       # (block_k, D)
-    vblk = v_ref[0]
-    d = kblk.shape[-1]
-    dk0 = jnp.zeros((block_k, d), jnp.float32)
-    dv0 = jnp.zeros((block_k, d), jnp.float32)
+    qi = pl.program_id(2)
+    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
 
-    def body(qi, carry):
-        dk, dv = carry
-        qblk = q_ref[0, pl.ds(qi * block_q, block_q), :]
-        doblk = do_ref[0, pl.ds(qi * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(qi * block_q, block_q), :]   # (bq, 1)
-        dd = dd_ref[0, pl.ds(qi * block_q, block_q), :]     # (bq, 1)
-        s = lax.dot_general(
-            qblk, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = qi * block_q + offset + lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            cols = kb * block_k + lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, -jnp.inf)
-        p = jnp.exp(s - lse)                                # (bq, bk)
+    @pl.when(qi == 0)
+    def _new_k_block():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(kb == 0)
+    def _first_visit():
+        dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[-1]),
+                                    jnp.float32)
+
+    def compute(masked):
+        qblk, doblk, kblk, vblk = q_ref[0], do_ref[0], k_ref[0], v_ref[0]
+        p = jnp.exp(_scores(qblk, kblk, scale, qi * block_q + offset,
+                            kb * block_k, masked) - lse_ref[0])
         # p/ds matmuls run in the input dtype: a f32xf32 MXU pass is
         # several times slower than bf16 and the f32 accumulate
         # (preferred_element_type) already carries the precision
-        dv = dv + lax.dot_general(
+        dv_acc[...] += lax.dot_general(
             p.astype(doblk.dtype), doblk, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # p^T @ dO
         dp = lax.dot_general(
             doblk, vblk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # dO @ V^T
-        ds = p * (dp - dd)
-        dk = dk + lax.dot_general(
-            ds.astype(qblk.dtype), qblk, (((0,), (0,)), ((), ())),
+        ds = (p * (dp - dd_ref[0])).astype(qblk.dtype)
+        dk_acc[...] += lax.dot_general(
+            ds, qblk, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # ds^T @ Q
-        return dk, dv
-
-    # causal: the first q-block whose rows reach this k-block's columns
-    lower = jnp.maximum(kb * block_k - offset, 0) // block_q \
-        if causal else 0
-    dk, dv = lax.fori_loop(lower, num_qb, body, (dk0, dv0))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-
-
-def _bwd_dq_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dd_ref, dq_ref,
-                   *, scale, causal, block_q, block_k, num_kb, offset):
-    qi = pl.program_id(1)
-    qblk = q_ref[0]                       # (block_q, D)
-    doblk = do_ref[0]
-    lse = lse_ref[0]                      # (block_q, 1)
-    dd = dd_ref[0]
-    d = qblk.shape[-1]
-    dq0 = jnp.zeros((block_q, d), jnp.float32)
-
-    def body(kb, dq):
-        kblk = k_ref[0, pl.ds(kb * block_k, block_k), :]
-        vblk = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        s = lax.dot_general(
-            qblk, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = qi * block_q + offset + lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            cols = kb * block_k + lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, -jnp.inf)
-        p = jnp.exp(s - lse)
-        dp = lax.dot_general(
-            doblk, vblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - dd)
-        return dq + lax.dot_general(
-            ds.astype(kblk.dtype), kblk, (((1,), (0,)), ((), ())),
+        dq_acc[rows, :] += lax.dot_general(
+            ds, kblk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # ds @ K
 
     if causal:
-        upper = jnp.minimum(
-            (qi * block_q + block_q - 1 + offset) // block_k + 1,
-            num_kb)
+        # from the first q-block whose rows reach this k-block's
+        # columns; the diagonal crosses the first few of them
+        unmasked = _first_unmasked_qb(kb, block_q, block_k, num_qb, offset)
+        pl.when((qi >= _first_live_qb(kb, block_q, block_k, offset)) &
+                (qi < unmasked))(lambda: compute(True))
+        pl.when(qi >= unmasked)(lambda: compute(False))
     else:
-        upper = num_kb
-    dq = lax.fori_loop(0, upper, body, dq0)
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-
-
-def _bwd_dkdv_stream_kernel(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
-                            dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                            causal, block_q, block_k, num_qb, offset):
-    """Streaming dK/dV: grid (bh, kb, qi) with the q-block axis
-    innermost; q/dO/lse/D arrive one block per grid step (O(block)
-    VMEM regardless of T), dk/dv accumulate in f32 scratch and write
-    once on the final q-block.  Causal q-blocks below the diagonal are
-    fetch-clamped and compute-gated, matching the resident schedule's
-    FLOP skipping."""
-    kb = pl.program_id(1)
-    qi = pl.program_id(2)
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    lower = jnp.maximum(kb * block_k - offset, 0) // block_q \
-        if causal else 0
-
-    @pl.when(qi >= lower)
-    def _compute():
-        qblk = q_ref[0]
-        doblk = do_ref[0]
-        lse = lse_ref[0]
-        dd = dd_ref[0]
-        kblk = k_ref[0]
-        vblk = v_ref[0]
-        s = lax.dot_general(
-            qblk, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = qi * block_q + offset + lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            cols = kb * block_k + lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, -jnp.inf)
-        p = jnp.exp(s - lse)
-        dv_acc[:] = dv_acc[:] + lax.dot_general(
-            p.astype(doblk.dtype), doblk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(
-            doblk, vblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - dd)
-        dk_acc[:] = dk_acc[:] + lax.dot_general(
-            ds.astype(qblk.dtype), qblk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        compute(False)
 
     @pl.when(qi == num_qb - 1)
-    def _store():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
-
-
-def _bwd_dq_stream_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dd_ref,
-                          dq_ref, dq_acc, *, scale, causal, block_q,
-                          block_k, num_kb, offset):
-    """Streaming dQ: grid (bh, qi, kb) with the k-block axis innermost;
-    k/v stream one block per step, dq accumulates in f32 scratch."""
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
-
-    @pl.when(kb == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    if causal:
-        upper = (qi * block_q + block_q - 1 + offset) // block_k + 1
-    else:
-        upper = num_kb
-
-    @pl.when(kb < upper)
-    def _compute():
-        qblk = q_ref[0]
-        doblk = do_ref[0]
-        lse = lse_ref[0]
-        dd = dd_ref[0]
-        kblk = k_ref[0]
-        vblk = v_ref[0]
-        s = lax.dot_general(
-            qblk, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = qi * block_q + offset + lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            cols = kb * block_k + lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, -jnp.inf)
-        p = jnp.exp(s - lse)
-        dp = lax.dot_general(
-            doblk, vblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - dd)
-        dq_acc[:] = dq_acc[:] + lax.dot_general(
-            ds.astype(kblk.dtype), kblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+    def _k_block_done():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
     @pl.when(kb == num_kb - 1)
-    def _store():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+    def _q_block_done():
+        dq_ref[0] = dq_acc[rows, :].astype(dq_ref.dtype)
 
 
-def _flash_bwd_stream_impl(q, k, v, g, o, lse, causal, scale, block_q,
-                           interpret, glse=None):
-    """HBM-streaming backward: same math as _flash_bwd_impl but no
-    operand is sequence-resident — VMEM stays O(block) for any T.
-    glse: optional cotangent on the logsumexp output — it folds exactly
-    into the D preprocess (ds = p*(dp - (D - glse)))."""
-    bh, t, d = q.shape
-    tk = k.shape[1]
-    offset = tk - t
+def _bwd_blocks(t, tk, block_q):
+    """(block_q, block_k) of the backward kernel.  It wants larger
+    tiles than the forward: the per-tile matmul chain (5 MXU passes)
+    amortizes the grid step better."""
     block_q = _fit_block(t, max(block_q, _BWD_BLOCK))
-    block_k = block_q if t == tk else _fit_block(
+    return block_q, block_q if t == tk else _fit_block(
         tk, max(block_q, _BWD_BLOCK))
-    num_qb = t // block_q
-    num_kb = tk // block_k
-    dd = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                 axis=-1, keepdims=True)
-    if glse is not None:
-        dd = dd - glse.astype(jnp.float32)
 
-    if causal:
-        # fetch-clamp skipped diagonal blocks (compute is pl.when-gated)
-        q_index = lambda i, n, j: (
-            i, jnp.maximum(
-                j, jnp.maximum(n * block_k - offset, 0) // block_q), 0)
-        k_index_dq = lambda i, j, n: (
-            i, jnp.minimum(
-                n, (j * block_q + block_q - 1 + offset) // block_k), 0)
-    else:
-        q_index = lambda i, n, j: (i, j, 0)
-        k_index_dq = lambda i, j, n: (i, n, 0)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkdv_stream_kernel, scale=scale,
-                          causal=causal, block_q=block_q,
-                          block_k=block_k, num_qb=num_qb,
-                          offset=offset),
-        grid=(bh, num_kb, num_qb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), q_index),            # q
-            pl.BlockSpec((1, block_q, d), q_index),            # dO
-            pl.BlockSpec((1, block_q, 1), q_index),            # lse
-            pl.BlockSpec((1, block_q, 1), q_index),            # D
-            pl.BlockSpec((1, block_k, d), lambda i, n, j: (i, n, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, n, j: (i, n, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda i, n, j: (i, n, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, n, j: (i, n, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, tk, d), v.dtype)],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, g, lse, dd, k, v)
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_stream_kernel, scale=scale,
-                          causal=causal, block_q=block_q,
-                          block_k=block_k, num_kb=num_kb,
-                          offset=offset),
-        grid=(bh, num_qb, num_kb),
-        in_specs=[
-            pl.BlockSpec((1, block_k, d), k_index_dq),         # k
-            pl.BlockSpec((1, block_k, d), k_index_dq),         # v
-            pl.BlockSpec((1, block_q, d), lambda i, j, n: (i, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, j, n: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j, n: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j, n: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d),
-                               lambda i, j, n: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(k, v, q, g, lse, dd)
-    return dq, dk, dv
+def _dq_acc_bytes(tq, dk):
+    """Bytes of the backward kernel's dQ accumulator in VMEM."""
+    return tq * _lanes(dk) * 4
 
 
 def _flash_bwd_impl(q, k, v, g, o, lse, causal, scale, block_q,
                     interpret, glse=None):
-    """Fused two-kernel backward over flat (bh, t, d) tensors."""
-    bh, t, d = q.shape
-    tk = k.shape[1]
+    """The one-kernel backward over flat tensors: q, k (bh, t, dk); v,
+    g, o (bh, t, dv); lse (bh, t, 1).  glse: optional cotangent on the
+    logsumexp output — it folds exactly into the D preprocess
+    (ds = p*(dp - (D - glse)))."""
+    bh, t, dk = q.shape
+    tk, dv = k.shape[1], v.shape[2]
     offset = tk - t
-    # the backward wants larger tiles than the forward: its per-tile
-    # matmul chain (5 MXU passes) amortizes loop overhead better, and
-    # VMEM pressure is lower (no online-softmax scratch)
-    block_q = _fit_block(t, max(block_q, _BWD_BLOCK))
-    block_k = block_q if t == tk else _fit_block(
-        tk, max(block_q, _BWD_BLOCK))
+    block_q, block_k = _bwd_blocks(t, tk, block_q)
     num_qb = t // block_q
     num_kb = tk // block_k
-    # pass 0: D_i = dO_i . O_i — one fused elementwise+reduce XLA pass.
-    # A logsumexp cotangent folds in here: ds = p*(dp - (D - glse)).
+    # pass 0: D_i = dO_i . O_i — one fused elementwise+reduce XLA pass
     dd = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                  axis=-1, keepdims=True)                    # (bh, t, 1)
     if glse is not None:
         dd = dd - glse.astype(jnp.float32)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
+    if causal:
+        # fetch-clamp the q-blocks above a k-block's diagonal (their
+        # compute is pl.when-gated): a repeated index skips the fetch
+        q_index = lambda i, n, j: (
+            i, jnp.maximum(
+                j, jnp.maximum(n * block_k - offset, 0) // block_q), 0)
+    else:
+        q_index = lambda i, n, j: (i, j, 0)
+    k_index = lambda i, n, j: (i, n, 0)
+    # dQ's blocks leave during the last k-block only: until then the
+    # output window stays on block 0 and nothing is written back
+    dq_index = lambda i, n, j: (i, jnp.where(n == num_kb - 1, j, 0), 0)
+
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          num_qb=num_qb, offset=offset),
-        grid=(bh, num_kb),
+                          num_qb=num_qb, num_kb=num_kb, offset=offset),
+        grid=(bh, num_kb, num_qb),
         in_specs=[
-            pl.BlockSpec((1, t, d), lambda i, n: (i, 0, 0)),   # q
-            pl.BlockSpec((1, t, d), lambda i, n: (i, 0, 0)),   # dO
-            pl.BlockSpec((1, t, 1), lambda i, n: (i, 0, 0)),   # lse
-            pl.BlockSpec((1, t, 1), lambda i, n: (i, 0, 0)),   # D
-            pl.BlockSpec((1, block_k, d), lambda i, n: (i, n, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, n: (i, n, 0)),
+            pl.BlockSpec((1, block_q, dk), q_index),           # q
+            pl.BlockSpec((1, block_q, dv), q_index),           # dO
+            pl.BlockSpec((1, block_q, 1), q_index),            # lse
+            pl.BlockSpec((1, block_q, 1), q_index),            # D
+            pl.BlockSpec((1, block_k, dk), k_index),           # k
+            pl.BlockSpec((1, block_k, dv), k_index),           # v
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda i, n: (i, n, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, n: (i, n, 0)),
+            pl.BlockSpec((1, block_q, dk), dq_index),
+            pl.BlockSpec((1, block_k, dk), k_index),
+            pl.BlockSpec((1, block_k, dv), k_index),
         ],
-        out_shape=[jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, tk, d), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((bh, t, dk), q.dtype),
+                   jax.ShapeDtypeStruct((bh, tk, dk), k.dtype),
+                   jax.ShapeDtypeStruct((bh, tk, dv), v.dtype)],
+        scratch_shapes=[
+            pltpu.VMEM((t, dk), jnp.float32),                  # dQ
+            pltpu.VMEM((block_k, dk), jnp.float32),            # dK
+            pltpu.VMEM((block_k, dv), jnp.float32),            # dV
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary', 'arbitrary'),
+            vmem_limit_bytes=_BWD_TILE_VMEM_BYTES + _dq_acc_bytes(t, dk)),
         interpret=interpret,
+        name='flash_attention_bwd',
     )(q, g, lse, dd, k, v)
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          num_kb=num_kb, offset=offset),
-        grid=(bh, num_qb),
-        in_specs=[
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),  # k
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),  # v
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        interpret=interpret,
-    )(k, v, q, g, lse, dd)
-    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -680,35 +597,28 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, interpret):
 
 def _flash_bwd_shared(causal, scale, block_q, interpret, res, g,
                       glse=None):
-    """Schedule-selecting backward shared by the plain and with-lse
-    custom VJPs; glse is the optional logsumexp cotangent."""
+    """The backward shared by the plain and with-lse custom VJPs; glse
+    is the optional logsumexp cotangent.  The kernel wherever its
+    blocks tile both lengths and its dQ accumulator fits VMEM, else
+    the XLA-level blocked recompute."""
     q, k, v, o, lse = res
-    b, h, tq, d = q.shape
+    b, h, tq, dk = q.shape
     tk = k.shape[2]
-    flatq = lambda x: x.reshape(b * h, tq, d)
-    flatk = lambda x: x.reshape(b * h, tk, d)
-    itemsize = jnp.dtype(q.dtype).itemsize
+    flat = lambda x: x.reshape((b * h,) + x.shape[2:])
     glse_flat = None if glse is None else glse.reshape(b * h, tq, 1)
-    args = (flatq(q), flatk(k), flatk(v), flatq(g), flatq(o),
-            lse.reshape(b * h, tq, 1), causal, scale, block_q,
-            interpret)
-    fitted_q = _try_fit(tq, max(block_q, _BWD_BLOCK))
-    fitted_k = _try_fit(tk, max(block_q, _BWD_BLOCK))
-    if 2 * max(tq, tk) * d * itemsize <= _bwd_resident_bytes():
-        # resident schedule: one head's full sequence (q+dO in the
-        # dK/dV kernel, k+v in the dQ kernel) sits in VMEM — BOTH
-        # sides must fit, hence max(tq, tk)
-        dq, dk, dv = _flash_bwd_impl(*args, glse=glse_flat)
-    elif fitted_q >= 8 and fitted_k >= 8:
-        # streaming schedule: O(block) VMEM for any T (the long-context
-        # path — T=32k+ stays on the fused Pallas kernels)
-        dq, dk, dv = _flash_bwd_stream_impl(*args, glse=glse_flat)
+    cap = max(block_q, _BWD_BLOCK)
+    if _tiles(tq, _try_fit(tq, cap)) and _tiles(tk, _try_fit(tk, cap)) \
+            and _dq_acc_bytes(tq, dk) <= _BWD_ACC_BYTES:
+        dq, dk_, dv_ = _flash_bwd_impl(
+            flat(q), flat(k), flat(v), flat(g), flat(o),
+            lse.reshape(b * h, tq, 1), causal, scale, block_q, interpret,
+            glse=glse_flat)
     else:
-        dq, dk, dv = _blocked_backward(flatq(q), flatk(k), flatk(v),
-                                       flatq(g), causal, scale, block_q,
-                                       glse=glse_flat)
-    return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
-            dv.reshape(b, h, tk, d))
+        dq, dk_, dv_ = _blocked_backward(flat(q), flat(k), flat(v),
+                                         flat(g), causal, scale, block_q,
+                                         glse=glse_flat)
+    return (dq.reshape(q.shape), dk_.reshape(k.shape),
+            dv_.reshape(v.shape))
 
 
 def _flash_bwd_rule(causal, scale, block_q, interpret, res, g):
@@ -732,7 +642,7 @@ def _flash_lse_fwd_rule(q, k, v, causal, scale, block_q, interpret):
 
 def _flash_lse_bwd_rule(causal, scale, block_q, interpret, res, cts):
     g, glse = cts
-    b, h, t, d = res[0].shape
+    b, h, t, _ = res[0].shape
     return _flash_bwd_shared(causal, scale, block_q, interpret, res, g,
                              glse=glse.reshape(b, h, t, 1))
 
@@ -741,12 +651,14 @@ _flash_lse.defvjp(_flash_lse_fwd_rule, _flash_lse_bwd_rule)
 
 
 def _validate_attn_shapes(q, k, v, causal, fn):
-    """Rectangular attention contract: same (batch, heads, head_dim),
-    k/v identical, and causal requires tq <= tk (rows suffix-align to
-    the keys — the KV-cache decode convention; tq > tk would leave the
-    leading rows with no visible key)."""
-    if k.shape != v.shape:
-        raise ValueError('%s requires identical k/v shapes; got %s / %s'
+    """Rectangular attention contract: q and k share (batch, heads,
+    head_dim), k and v share everything but the head width (the values'
+    and the output's is v's own), and causal requires tq <= tk (rows
+    suffix-align to the keys — the KV-cache decode convention; tq > tk
+    would leave the leading rows with no visible key)."""
+    if k.ndim != v.ndim or k.shape[:-1] != v.shape[:-1]:
+        raise ValueError('%s requires identical k/v shapes up to the '
+                         'head width; got %s / %s'
                          % (fn, k.shape, v.shape))
     if q.ndim != 4 or k.ndim != 4 or \
             q.shape[:2] != k.shape[:2] or q.shape[-1] != k.shape[-1]:
@@ -766,12 +678,16 @@ def _needs_dense_fallback(tq, tk, block_q):
     of the device: the check runs _try_fit with exactly the caps the
     forward AND backward schedules will use (_schedule_caps), so the
     predicate and the kernels can never disagree."""
-    return any(_try_fit(t, cap) < 8 and t > 8
-               for t, cap in _schedule_caps(tq, tk, block_q))
+    return not all(_tiles(t, _try_fit(t, cap))
+                   for t, cap in _schedule_caps(tq, tk, block_q))
+
+
+def _default_block_q(tq):
+    return max(256, min(1024, tq // 32))
 
 
 def _dense_attention_lse(q, k, v, causal, scale):
-    b, h, tq, d = q.shape
+    b, h, tq, _ = q.shape
     tk = k.shape[2]
     s = jnp.einsum('bhqd,bhkd->bhqk', q, k).astype(jnp.float32) * scale
     if causal:
@@ -793,14 +709,13 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     lse cotangent folds into the backward's D preprocess).  Lengths
     no schedule can tile take the dense jnp computation."""
     _validate_attn_shapes(q, k, v, causal, 'flash_attention_with_lse')
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tq, tk = q.shape[2], k.shape[2]
     if scale is None:
-        scale = 1.0 / (d ** 0.5)
+        scale = 1.0 / (q.shape[-1] ** 0.5)
     if block_q is None:
-        block_q = max(256, min(1024, tq // 32))
-    # dense route: a sequence length with no usable power-of-two
-    # block factor (natively differentiable either way)
+        block_q = _default_block_q(tq)
+    # dense route: a sequence length no block of whole sublanes
+    # divides (natively differentiable either way)
     if _needs_dense_fallback(tq, tk, block_q):
         return _dense_attention_lse(q, k, v, causal, scale)
     if interpret is None:
@@ -813,13 +728,15 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     interpret=None):
     """Streaming Pallas attention.
 
-    q: (batch, heads, q_len, head_dim); k, v: (batch, heads, kv_len,
-    head_dim).  q_len == kv_len is self-attention; q_len != kv_len
-    covers cross-attention and KV-cache decode, where causal rows are
-    SUFFIX-aligned to the keys (query row i sees keys up to
-    kv_len - q_len + i — the standard decode convention).  Returns
-    q's shape.  On non-TPU backends runs in Pallas interpret mode
-    (slow but correct) unless `interpret` is passed explicitly.
+    q: (batch, heads, q_len, dk); k: (batch, heads, kv_len, dk); v:
+    (batch, heads, kv_len, dv), a width of its own (latent attention:
+    keys of 192 over values of 128).  q_len == kv_len is
+    self-attention; q_len != kv_len covers cross-attention and
+    KV-cache decode, where causal rows are SUFFIX-aligned to the keys
+    (query row i sees keys up to kv_len - q_len + i — the standard
+    decode convention).  Returns (batch, heads, q_len, dv).  On non-TPU
+    backends runs in Pallas interpret mode (slow but correct) unless
+    `interpret` is passed explicitly.
 
     block_q: row-tile edge.  Default (None) auto-scales with the
     sequence — 256 for short T, up to 1024 for long T, where the
@@ -832,7 +749,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if block_q is None:
-        block_q = max(256, min(1024, tq // 32))
+        block_q = _default_block_q(tq)
     if _needs_dense_fallback(tq, tk, block_q):
         from .parallel.ring_attention import full_attention
         return full_attention(q, k, v, causal=causal, scale=scale)

@@ -189,6 +189,35 @@ def moe_stats():
     return out
 
 
+# causal_attention's lowerings by path (ops/lm.py): 'kernel' is the
+# Pallas flash kernel, 'blocked' the XLA core, and the key beside each
+# count is the shape that decided.  Taken while the operator is traced
+# into a program (a step's forward, its recomputation and each
+# re-trace count one apiece), never inside a step
+_ATTENTION = {}         # (path, heads, group, dk, dv, t) -> lowerings
+
+
+def note_attention_lowering(path, heads, group, dk, dv, t):
+    key = (path, int(heads), int(group), int(dk), int(dv), int(t))
+    with _STATE['lock']:
+        _ATTENTION[key] = _ATTENTION.get(key, 0) + 1
+
+
+def attention_stats():
+    """causal_attention's lowerings: {'kernel': n, 'blocked': n,
+    'shapes': [{'path', 'heads', 'group', 'dk', 'dv', 't',
+    'lowerings'}, ...]}."""
+    with _STATE['lock']:
+        seen = sorted(_ATTENTION.items())
+    out = {'kernel': 0, 'blocked': 0, 'shapes': []}
+    for key, n in seen:
+        out[key[0]] += n
+        out['shapes'].append(dict(
+            zip(('path', 'heads', 'group', 'dk', 'dv', 't'), key),
+            lowerings=n))
+    return out
+
+
 # sparse embedding counters (Embedding(sparse_grad=True) through the
 # fused step, plus the serving hot-row cache): the touched-bytes
 # ledger is THE quantity this tier exists to shrink — the dense
@@ -1312,6 +1341,7 @@ def clear():
         for k in _MOE:
             _MOE[k] = 0
         _MOE_EXPERTS.clear()
+        _ATTENTION.clear()
         for k in _EMBED:
             _EMBED[k] = 0
         for k in _CKPT:

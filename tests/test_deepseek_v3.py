@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu import models, profiler
+from mxnet_tpu import models, pallas_ops, profiler
 from mxnet_tpu.ops import lm
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -119,24 +119,108 @@ def test_blocked_core_takes_a_value_width_of_its_own(t):
     weight = rand(4, t, HEADS, 6)
 
     def program(q, k, v):
-        return lm.causal_attention(q[None, :, :, None, :], k[None], v[None],
-                                   1.0 / np.sqrt(12), block_q=16)[0, :, :, 0]
+        return lm.blocked_causal_attention(
+            q[None, :, :, None, :], k[None], v[None], 1.0 / np.sqrt(12),
+            block_q=16)[0, :, :, 0]
 
+    _core_against_the_reference(net, program, q, k, v, weight, nope=8)
+
+
+def _core_against_the_reference(net, program, q, k, v, weight, nope,
+                                tol=1e-4):
+    """Values and all three gradients of `program(q, k, v)` (q, k
+    (T, heads, nope + rope), v (T, heads, dv)) against the reference's
+    core, which takes the keys' rotary part as one shared head."""
     def reference(q, k, v):
-        return ref.causal_attention(net, q[..., :8], q[..., 8:],
-                                    k[..., :8], k[:, 0, 8:], v)
+        return ref.causal_attention(net, q[..., :nope], q[..., nope:],
+                                    k[..., :nope], k[:, 0, nope:], v)
 
     # the reference takes one rotary head: give every head the same
-    k = k.at[..., 8:].set(k[:, :1, 8:])
-    close(program(q, k, v), reference(q, k, v))
+    k = k.at[..., nope:].set(k[:, :1, nope:])
+    close(program(q, k, v), reference(q, k, v), tol)
     for wrt in range(3):
         got, want = (jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
                               argnums=wrt)(q, k, v)
                      for fn in (program, reference))
         if wrt == 1:    # the reference's gradient of the one shared head
-            got = got.at[:, 0, 8:].set(got[..., 8:].sum(axis=1))
-            got = got.at[:, 1:, 8:].set(0.0)
+            got = got.at[:, 0, nope:].set(got[..., nope:].sum(axis=1))
+            got = got.at[:, 1:, nope:].set(0.0)
+        close(got, want, tol)
+
+
+@pytest.fixture
+def attention_paths():
+    """profiler.attention_stats() counted from here on."""
+    profiler._ATTENTION.clear()
+    yield profiler.attention_stats
+    profiler._ATTENTION.clear()
+
+
+# the flash kernels under causal_attention (interpret mode off the TPU):
+# (T, rows a block) so that the forward and, with the backward's tile
+# edge cut to the same, the backward kernel walk one block or several
+KERNEL_SHAPES = {'one-block': (16, 16), 'four-blocks': (64, 16)}
+
+
+@pytest.mark.parametrize('schedule', ['resident', 'streaming'])
+@pytest.mark.parametrize('shape', sorted(KERNEL_SHAPES))
+@pytest.mark.parametrize('nope,rope,dv,heads', [(8, 4, 6, HEADS),
+                                                (128, 64, 128, 2)])
+def test_kernel_core_takes_a_value_width_of_its_own(
+        monkeypatch, attention_paths, nope, rope, dv, heads, shape,
+        schedule):
+    """Ungrouped heads at a T the kernels tile go to the flash kernels,
+    keys 12 over values 6 and keys 192 over values 128: values and all
+    three gradients against the reference's core and against the
+    blocked core, on the resident and the streaming schedule of the
+    forward, through the one backward kernel."""
+    t, block = KERNEL_SHAPES[shape]
+    monkeypatch.setattr(pallas_ops, '_BWD_BLOCK', block)
+    if schedule == 'streaming':
+        monkeypatch.setattr(pallas_ops, '_VMEM_RESIDENT_BYTES', 1)
+    dk = nope + rope
+    q, k, v = rand(1, t, heads, dk), rand(2, t, heads, dk), \
+        rand(3, t, heads, dv)
+    weight = rand(4, t, heads, dv)
+    scale = 1.0 / np.sqrt(dk)
+
+    def core(fn):
+        return lambda q, k, v: fn(q[None, :, :, None, :], k[None], v[None],
+                                  scale, block_q=block)[0, :, :, 0]
+
+    kernel, blocked = core(lm.causal_attention), \
+        core(lm.blocked_causal_attention)
+    _core_against_the_reference(convnet.Net({}), kernel, q, k, v, weight,
+                                nope)
+    stats = attention_paths()
+    assert stats['blocked'] == 0 and stats['kernel'] > 0
+    assert {(s['heads'], s['group'], s['dk'], s['dv'], s['t'])
+            for s in stats['shapes']} == {(heads, 1, dk, dv, t)}
+    close(kernel(q, k, v), blocked(q, k, v), 1e-5)
+    for wrt in range(3):
+        got, want = (jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
+                              argnums=wrt)(q, k, v)
+                     for fn in (kernel, blocked))
         close(got, want, 1e-4)
+
+
+@pytest.mark.parametrize('t,group,why', [(33, 1, 'a ragged T'),
+                                         (40, 8, 'grouped heads'),
+                                         (33, 8, 'both')])
+def test_what_the_kernel_refuses_takes_the_blocked_core(attention_paths, t,
+                                                        group, why):
+    """The path is chosen from the operands' shapes: a T with no block
+    factor of 8 and heads that share their keys keep the blocked core,
+    and the counter says which shape decided."""
+    q, k, v = rand(1, 1, t, 2, group, 12), rand(2, 1, t, 2, 12), \
+        rand(3, 1, t, 2, 6)
+    got = lm.causal_attention(q, k, v, 0.3, block_q=16)
+    close(got, lm.blocked_causal_attention(q, k, v, 0.3, block_q=16), 0)
+    stats = attention_paths()
+    assert (stats['kernel'], stats['blocked']) == (0, 1)
+    assert stats['shapes'] == [dict(path='blocked', heads=2 * group,
+                                    group=group, dk=12, dv=6, t=t,
+                                    lowerings=1)]
 
 
 def test_adjacent_pair_rotary_gives_the_published_scores():

@@ -475,10 +475,11 @@ def test_pallas_flash_streaming_schedule():
 
 @pytest.mark.slow
 def test_pallas_flash_streaming_backward():
-    """The streaming (non-resident) Pallas backward matches the dense
-    oracle's gradients and is bitwise-identical to the resident
-    schedule; forced by shrinking the residency threshold so the
-    elif-branch (not the XLA blocked recompute) runs."""
+    """The Pallas backward behind the streaming (non-resident) forward
+    matches the dense oracle's gradients and is bitwise-identical to
+    the one behind the resident forward (the backward is one kernel on
+    either; the forwards' log-sum-exp must agree to the bit); forced
+    by shrinking the residency threshold."""
     from mxnet_tpu import pallas_ops
     rs = np.random.RandomState(5)
     shape = (1, 2, 256, 32)
@@ -613,6 +614,91 @@ def test_pallas_flash_rectangular(tq, tk):
                                        rtol=5e-3, atol=5e-4)
 
 
+@pytest.mark.parametrize('schedule', ['resident', 'streaming',
+                                      'xla-backward'])
+@pytest.mark.parametrize('tq,tk', [(64, 64), (32, 128), (8, 64)])
+@pytest.mark.parametrize('dk,dv', [(12, 6), (192, 128)])
+def test_pallas_flash_value_width_of_its_own(monkeypatch, dk, dv, tq, tk,
+                                             schedule):
+    """Keys wider than values (latent attention: 192 over 128), square
+    and with tq < tk, causal and not: forward and all three gradients
+    match the dense oracle on the forward's resident and streaming
+    schedule, through the backward kernel with several blocks a side
+    and through the XLA-level blocked recompute that takes over where
+    the kernel's dQ accumulator would not fit."""
+    from mxnet_tpu import pallas_ops
+    monkeypatch.setattr(pallas_ops, '_BWD_BLOCK', 16)
+    if schedule == 'streaming':
+        monkeypatch.setattr(pallas_ops, '_VMEM_RESIDENT_BYTES', 1)
+    if schedule == 'xla-backward':
+        monkeypatch.setattr(pallas_ops, '_BWD_ACC_BYTES', 1)
+    rs = np.random.RandomState(11)
+    B, H = 1, 2
+    q = jnp.asarray(rs.randn(B, H, tq, dk).astype(np.float32) * 0.3)
+    k = jnp.asarray(rs.randn(B, H, tk, dk).astype(np.float32) * 0.3)
+    v = jnp.asarray(rs.randn(B, H, tk, dv).astype(np.float32) * 0.3)
+    g = jnp.asarray(rs.randn(B, H, tq, dv).astype(np.float32))
+    for causal in (False, True):
+        def loss_flash(q, k, v, causal=causal):
+            return jnp.sum(pallas_ops.flash_attention(
+                q, k, v, causal=causal, block_q=16) * g)
+
+        def loss_ref(q, k, v, causal=causal):
+            return jnp.sum(full_attention(q, k, v, causal=causal) * g)
+
+        out = pallas_ops.flash_attention(q, k, v, causal=causal, block_q=16)
+        assert out.shape == (B, H, tq, dv)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(full_attention(q, k, v,
+                                                       causal=causal)),
+            rtol=2e-3, atol=2e-4)
+        got = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+        oracle = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+        for a, o in zip(got, oracle):
+            assert a.shape == o.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(o),
+                                       rtol=5e-3, atol=5e-4)
+
+
+@pytest.mark.parametrize('dk,dv', [(12, 6), (192, 128)])
+def test_flash_attention_with_lse_value_width_of_its_own(dk, dv):
+    """The with-lse entry at dk != dv: out and lse match the dense
+    formulas, and the cotangent of the SECOND output (the log-sum-exp)
+    reaches q and k (v's gradient does not depend on it)."""
+    from mxnet_tpu import pallas_ops
+    rs = np.random.RandomState(12)
+    B, H, T = 1, 2, 32
+    q = jnp.asarray(rs.randn(B, H, T, dk).astype(np.float32) * 0.4)
+    k = jnp.asarray(rs.randn(B, H, T, dk).astype(np.float32) * 0.4)
+    v = jnp.asarray(rs.randn(B, H, T, dv).astype(np.float32) * 0.4)
+    wo = jnp.asarray(rs.randn(B, H, T, dv).astype(np.float32))
+    wl = jnp.asarray(rs.randn(B * H, T, 1).astype(np.float32) * 0.3)
+
+    def loss_flash(q, k, v):
+        o, l = pallas_ops.flash_attention_with_lse(
+            q, k, v, causal=True, block_q=16, interpret=True)
+        return (o * wo).sum() + (l * wl).sum()
+
+    def loss_dense(q, k, v):
+        o, l = pallas_ops._dense_attention_lse(q, k, v, True, dk ** -0.5)
+        return (o * wo).sum() + (l * wl).sum()
+
+    out, lse = pallas_ops.flash_attention_with_lse(
+        q, k, v, causal=True, block_q=16, interpret=True)
+    ref, lse_ref = pallas_ops._dense_attention_lse(q, k, v, True,
+                                                   dk ** -0.5)
+    assert out.shape == (B, H, T, dv) and lse.shape == (B * H, T, 1)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
+                               rtol=2e-4, atol=2e-5)
+    got = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-4)
+
+
 def test_flash_rectangular_validation():
     from mxnet_tpu import pallas_ops
     q = jnp.zeros((1, 1, 64, 16))
@@ -620,8 +706,14 @@ def test_flash_rectangular_validation():
     v = jnp.zeros((1, 1, 32, 16))
     with pytest.raises(ValueError, match='q_len <= kv_len'):
         pallas_ops.flash_attention(q, k, v, causal=True)
+    # a v of another LENGTH than k's is refused; of another width it
+    # is not (the values' width is their own)
     with pytest.raises(ValueError, match='identical k/v'):
         pallas_ops.flash_attention(q, k, jnp.zeros((1, 1, 16, 16)))
+    with pytest.raises(ValueError, match='identical k/v'):
+        pallas_ops.flash_attention_with_lse(q, k, jnp.zeros((1, 1, 16, 8)))
+    out = pallas_ops.flash_attention(q, k, jnp.zeros((1, 1, 32, 8)))
+    assert out.shape == (1, 1, 64, 8)
     # the dense fallback enforces the same convention
     with pytest.raises(ValueError, match='q_len <= kv_len'):
         full_attention(q, k, v, causal=True)

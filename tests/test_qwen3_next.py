@@ -4,6 +4,7 @@ against the plain float32 reference the benchmark compares with
 (benchmark/reference/qwen3_next.py, loaded from where it lives)."""
 import collections
 import functools
+import hashlib
 import os
 import sys
 
@@ -456,6 +457,53 @@ def test_whole_model_two_bulk_steps_against_the_reference():
     # layer's leaves: 2e-3 was the worst leaf over the seeds tried
     assert max(gaps.values()) < 1e-2, max(gaps, key=gaps.get)
     assert np.median(list(gaps.values())) < 2e-3
+
+
+def _bulk_step_text(mod, batches):
+    """The text the module's bulk step program lowers to for its
+    operands (nothing runs)."""
+    ex = mod._exec_group.executor
+    run, texts = ex.run_fused_multistep, []
+
+    class Lowered(Exception):
+        pass
+
+    def spied(step, *args, **kwargs):
+        def record(*operands):
+            texts.append(step.lower(*operands).as_text())
+            raise Lowered()
+        return run(record, *args, **kwargs)
+
+    ex.run_fused_multistep = spied
+    with pytest.raises(Lowered):
+        mod.bulk_step(batches=batches, scan_dtype='float32')
+    return texts[0]
+
+
+# sha256 of the tiny model's bulk step program as PR 31 lowered it
+# (jax 0.9.0, the CPU backend, tests/conftest.py's eight devices).  A
+# PR that changes an operator of this model on purpose replaces it; a
+# PR that says it leaves Qwen3-Next's program alone keeps it.
+STEP_TEXT_SHA256 = (
+    '44e47a3e042ff446eaa779a315bb0b3d0aea53c0a5fa3a24d5341b773211ae06')
+
+
+def test_grouped_heads_keep_the_blocked_core_and_the_program_its_text(
+        monkeypatch):
+    """GatedAttention's 8 query heads a key-value head are not the
+    flash kernel's: every lowering of causal_attention in the whole
+    model's step takes the blocked core, and the step program is, to
+    the byte, the one it was before causal_attention chose a path."""
+    profiler._ATTENTION.clear()
+    text = _bulk_step_text(*_tiny_module()[:2])
+    stats = profiler.attention_stats()
+    assert stats['kernel'] == 0 and stats['blocked'] > 0
+    assert {(s['group'], s['dk'], s['dv'], s['t'])
+            for s in stats['shapes']} == {(8, 16, 16, SEQ)}
+    monkeypatch.setattr(lm, 'causal_attention',
+                        lm.blocked_causal_attention)
+    assert _bulk_step_text(*_tiny_module()[:2]) == text
+    assert hashlib.sha256(text.encode()).hexdigest() == STEP_TEXT_SHA256
 
 
 def test_fit_trains_on_the_normal_path():

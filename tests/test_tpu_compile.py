@@ -50,3 +50,32 @@ def test_delta_rule_kernels_compile_for_the_chip(one_chip, monkeypatch,
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert text.count('tpu_custom_call') >= kernels
     assert ' while(' not in text
+
+
+@pytest.mark.parametrize('what,kernels', [('forward', 1), ('gradient', 2)])
+def test_latent_attention_core_compiles_for_the_chip(one_chip, monkeypatch,
+                                                     what, kernels):
+    """The Kanana cell's core through causal_attention: one sequence of
+    8,192 tokens, 32 heads, keys of 192 over values of 128, bfloat16.
+    The forward is one flash kernel, the gradient that and the one of
+    the backward; the blocked core's loop over sequences is gone."""
+    monkeypatch.setattr(pallas_ops, 'default_interpret', lambda *a: False)
+    t, h, dk, dv = 8192, 32, 192, 128
+
+    def shape(*s):
+        return jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+
+    args = (shape(1, t, h, 1, dk), shape(1, t, h, dk), shape(1, t, h, dv))
+
+    def core(q, k, v):
+        return lm.causal_attention(q, k, v, dk ** -0.5)
+
+    fn = {'forward': core,
+          'gradient': jax.grad(
+              lambda *a: jnp.sum(core(*a).astype(jnp.float32)),
+              argnums=(0, 1, 2))}[what]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('tpu_custom_call') == kernels
+    assert 'flash_attention_fwd' in text
+    assert ('flash_attention_bwd' in text) == (what == 'gradient')
+    assert ' while(' not in text
