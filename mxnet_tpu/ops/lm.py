@@ -1,5 +1,5 @@
-"""Operators of sparse language models (the layer kinds of Qwen3-Next
-and of DeepSeek-V3's family).
+"""Operators of sparse language models (the layer kinds of Qwen3-Next,
+of DeepSeek-V3's family and of AFMoE).
 
 Every operator takes tokens as rows, `(N, C)` with `N = sequences x
 seq_len`, as `Embedding` gives them and `FullyConnected` takes them; the
@@ -13,10 +13,13 @@ attention core of ungrouped heads (pallas_ops.flash_attention's
 kernels, forward and backward).
 
   RMSNorm          x * rsqrt(mean x^2 + eps) * gamma, or * (1 + gamma)
-  GatedAttention   per-head q/k RMS norm, partial rotary, grouped-head
-                   causal softmax attention (causal_attention: grouped
-                   heads take the blocked XLA core, blocks of query
-                   rows), and the sigmoid gate on the output
+  GatedAttention   per-head q/k RMS norm, partial or no rotary, grouped-
+                   head causal softmax attention over every earlier key
+                   or a sliding window of them (causal_attention:
+                   grouped heads and a window take the blocked XLA
+                   core, blocks of query rows against their band of
+                   keys), and the sigmoid gate on the output, packed
+                   beside the query or an input of its own
   LatentAttention  the core of multi-head latent attention: rotary by
                    adjacent pairs on the keys' one shared rotary head
                    and on each query head's rotary part, causal softmax
@@ -132,22 +135,30 @@ def rotary(x, rotary_dim, theta):
                             rest], axis=-1)
 
 
-def _block_scores(qb, kb, scale, first_row):
+def _block_scores(qb, kb, scale, first_row, band=None):
     """Masked scores (kv, group, rows, keys) of a block of query rows
-    against the keys it can see, in float32."""
+    against the keys it was given, in float32: all keys from the
+    sequence's first, or with `band` = (first key, window) the keys
+    from `first key` on, of which a row sees the last `window` up to
+    itself."""
     s = jnp.einsum('qghd,kgd->ghqk', qb, kb,
                    preferred_element_type=F32) * scale
     rows = first_row + jnp.arange(qb.shape[0])[:, None]
-    return jnp.where(jnp.arange(kb.shape[0])[None, :] <= rows, s, -jnp.inf)
+    if band is None:
+        return jnp.where(jnp.arange(kb.shape[0])[None, :] <= rows, s,
+                         -jnp.inf)
+    first_key, window = band
+    keys = first_key + jnp.arange(kb.shape[0])[None, :]
+    return jnp.where((keys <= rows) & (rows - keys < window), s, -jnp.inf)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _attention_block(scale, first_row, qb, kb, vb):
-    return _attention_block_fwd(scale, first_row, qb, kb, vb)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _attention_block(scale, first_row, band, qb, kb, vb):
+    return _attention_block_fwd(scale, first_row, band, qb, kb, vb)[0]
 
 
-def _attention_block_fwd(scale, first_row, qb, kb, vb):
-    s = _block_scores(qb, kb, scale, first_row)
+def _attention_block_fwd(scale, first_row, band, qb, kb, vb):
+    s = _block_scores(qb, kb, scale, first_row, band)
     lse = jax.nn.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None]).astype(vb.dtype)
     o = jnp.einsum('ghqk,kgd->qghd', p, vb,
@@ -155,12 +166,13 @@ def _attention_block_fwd(scale, first_row, qb, kb, vb):
     return o, (qb, kb, vb, o, lse)
 
 
-def _attention_block_bwd(scale, first_row, res, do):
+def _attention_block_bwd(scale, first_row, band, res, do):
     """The scores are made again, not kept; the softmax's row term is
     sum(dO * O) over the head (as in flash attention), not a product
     along the keys."""
     qb, kb, vb, o, lse = res
-    p = jnp.exp(_block_scores(qb, kb, scale, first_row) - lse[..., None])
+    p = jnp.exp(_block_scores(qb, kb, scale, first_row, band) -
+                lse[..., None])
     dv = jnp.einsum('ghqk,qghd->kgd', p.astype(do.dtype), do,
                     preferred_element_type=F32)
     dp = jnp.einsum('qghd,kgd->ghqk', do, vb, preferred_element_type=F32)
@@ -175,59 +187,103 @@ def _attention_block_bwd(scale, first_row, res, do):
 _attention_block.defvjp(_attention_block_fwd, _attention_block_bwd)
 
 
-def blocked_causal_attention(q, k, v, scale, block_q=ATTN_BLOCK):
-    """causal_attention in plain XLA, grouped heads and any length: one
-    sequence at a time and query rows in blocks, each block against the
-    keys it can see; a block keeps its output and its rows' log-sum-exp
-    and makes its scores again in the backward pass, so no T x T score
-    matrix is ever stored (a block's float32 scores do cross HBM
-    between its fusions)."""
-    t = q.shape[1]
+def block_bands(t, block_q, window=None):
+    """(first row, first key, keys' end) of each block of query rows of
+    a sequence of t: a block reads the keys up to its last row, from
+    the sequence's first or, with a window, from the start of the block
+    of keys that holds the first key its first row sees."""
     block_q = min(block_q, t)
+    return [(r0, 0 if window is None else
+             max(0, (r0 - window + 1) // block_q * block_q),
+             min(r0 + block_q, t)) for r0 in range(0, t, block_q)]
+
+
+def blocked_causal_attention(q, k, v, scale, block_q=ATTN_BLOCK,
+                             window=None):
+    """causal_attention in plain XLA, grouped heads, any length and a
+    sliding window: one sequence at a time and query rows in blocks,
+    each block against the keys it can see (block_bands: with a window
+    the band of key blocks its rows reach, so a windowed layer's time
+    and temporaries grow with T and not with its square; rows see
+    their last `window` keys, themselves among them); a block keeps
+    its output and its rows' log-sum-exp and makes its scores again in
+    the backward pass, so no T x T score matrix is ever stored (a
+    block's float32 scores do cross HBM between its fusions).  The
+    gradients of a block's keys and values go back into its band."""
+    t = q.shape[1]
+    bands = block_bands(t, block_q, window)
 
     def one_sequence(args):
         qs, ks, vs = args
         return jnp.concatenate([
-            _attention_block(scale, r0, qs[r0:r0 + block_q],
-                             ks[:r0 + block_q], vs[:r0 + block_q])
-            for r0 in range(0, t, block_q)], axis=0)
+            _attention_block(scale, r0,
+                             None if window is None else (k0, window),
+                             qs[r0:k1], ks[k0:k1], vs[k0:k1])
+            for r0, k0, k1 in bands], axis=0)
 
     return lax.map(one_sequence, (q, k, v))
 
 
-def causal_attention(q, k, v, scale, block_q=None):
+def _positions(t, window, tile, rows):
+    """(visited, needed) query-key positions of one head over one
+    sequence: those a path scores going forward (the kernel's square
+    tiles of edge `tile` on and under the diagonal, or the blocked
+    core's blocks of `rows` rows against their bands) and those the
+    mask lets through (row i sees min(i + 1, window) keys)."""
+    if tile is not None:
+        visited = tile * tile * (t // tile) * (t // tile + 1) // 2
+    else:
+        visited = sum((k1 - r0) * (k1 - k0)
+                      for r0, k0, k1 in block_bands(t, rows, window))
+    reach = t if window is None else window
+    return visited, reach * (reach + 1) // 2 + (t - reach) * reach
+
+
+def causal_attention(q, k, v, scale, block_q=None, window=None):
     """softmax(q k^T * scale + causal) v with grouped heads: q
     (B, T, kv, group, d), k (B, T, kv, d) and v (B, T, kv, dv), whose
     width is its own (latent attention's keys are wider than its
-    values); the result is (B, T, kv, group, dv).  The path is chosen
-    from the operands' shapes alone:
+    values); the result is (B, T, kv, group, dv).  With `window` row i
+    sees key j iff 0 <= i - j < window (a window that reaches the
+    sequence's first key from its last row is no window).  The path is
+    chosen from the operands' shapes and `window` alone:
 
-      kernel   group == 1 and a T the flash kernels' schedules tile
-               (blocks of whole sublanes: a multiple of 8 rows under
-               `block_q` dividing T): pallas_ops.flash_attention, the
-               whole batch in one call with the heads in front of the
-               rows.  Scores, probabilities and their gradients live
-               in VMEM a tile at a time, forward and backward;
-               residuals are q, k, v, o and the rows' log-sum-exp.
+      kernel   group == 1, no window and a T the flash kernels'
+               schedules tile (blocks of whole sublanes: a multiple of
+               8 rows under `block_q` dividing T):
+               pallas_ops.flash_attention, the whole batch in one call
+               with the heads in front of the rows.  Scores,
+               probabilities and their gradients live in VMEM a tile at
+               a time, forward and backward; residuals are q, k, v, o
+               and the rows' log-sum-exp.
       blocked  grouped heads (the kernels' dK and dV do not sum over a
-               group) or a ragged T: blocked_causal_attention.
+               group), a window (the kernels walk every tile under the
+               diagonal) or a ragged T: blocked_causal_attention.
 
     Both keep bf16 operands with float32 scores, sums and accumulators.
     `block_q`, where given, is the rows of a block on either path; left
     out it is FLASH_BLOCK on the kernel (tiles of 1024 x 1024 timed
     best at T = 8,192 with keys of 192 over values of 128: PERF.md
     section 6, PR 32) and ATTN_BLOCK on the blocked core.
-    profiler.attention_stats() counts the lowerings by path."""
+    profiler.attention_stats() counts the lowerings by path, with the
+    query-key positions each scores and the mask lets through."""
     t, group = q.shape[1], q.shape[3]
+    if window is not None and window >= t:
+        window = None
     tile = FLASH_BLOCK if block_q is None else block_q
-    kernel = group == 1 and \
+    kernel = group == 1 and window is None and \
         not pallas_ops._needs_dense_fallback(t, t, tile)
+    rows = ATTN_BLOCK if block_q is None else block_q
+    visited, needed = _positions(t, window, pallas_ops._try_fit(t, tile)
+                                 if kernel else None, rows)
+    heads = q.shape[2] * group
     profiler.note_attention_lowering(
-        'kernel' if kernel else 'blocked', heads=q.shape[2] * group,
-        group=group, dk=q.shape[4], dv=v.shape[3], t=t)
+        'kernel' if kernel else 'blocked', heads=heads, group=group,
+        dk=q.shape[4], dv=v.shape[3], t=t, window=window,
+        keys_visited=q.shape[0] * heads * visited,
+        keys_needed=q.shape[0] * heads * needed)
     if not kernel:
-        return blocked_causal_attention(
-            q, k, v, scale, ATTN_BLOCK if block_q is None else block_q)
+        return blocked_causal_attention(q, k, v, scale, rows, window)
     o = pallas_ops.flash_attention(
         jnp.swapaxes(q[:, :, :, 0], 1, 2), jnp.swapaxes(k, 1, 2),
         jnp.swapaxes(v, 1, 2), causal=True, scale=scale, block_q=tile)
@@ -242,32 +298,56 @@ def _attn_infer_shape(attrs, in_shapes):
     return in_shapes
 
 
-@register('GatedAttention',
-          input_names=('query_gate', 'key', 'value', 'q_norm_gamma',
-                       'k_norm_gamma'),
+def _attn_input_names(attrs):
+    """The gate comes packed beside each head's query, or (attribute
+    `separate_gate`) as an input of its own, heads * head_dim wide."""
+    if asbool(attrs.get('separate_gate', False)):
+        return ('query', 'key', 'value', 'q_norm_gamma', 'k_norm_gamma',
+                'gate')
+    return ('query_gate', 'key', 'value', 'q_norm_gamma', 'k_norm_gamma')
+
+
+@register('GatedAttention', input_names=_attn_input_names,
           infer_shape=_attn_infer_shape,
           infer_dtype=_infer_dtype((3, 4)), hint='gatedattention')
-def _gated_attention(attrs, qg, k, v, q_gamma, k_gamma):
+def _gated_attention(attrs, qg, k, v, q_gamma, k_gamma, gate=None):
+    """Attributes beside the heads' counts and sizes, each defaulting
+    to Qwen3-Next's layer: `rotary_dim` (the head's first dims that
+    rotary turns; 0: no rotary, keys carry no position), `window` (a
+    row sees its last `window` keys; left out: every earlier key),
+    `zero_centered` (the q/k norms' scale is 1 + gamma; false: gamma),
+    `separate_gate` (see _attn_input_names)."""
     heads, kv = asint(attrs['num_heads']), asint(attrs['num_kv_heads'])
     d, seq_len = asint(attrs['head_dim']), asint(attrs['seq_len'])
     rotary_dim = asint(attrs.get('rotary_dim', d))
     theta = asfloat(attrs.get('rope_theta', 10000.0))
     eps = asfloat(attrs.get('eps', 1e-6))
+    centered = asbool(attrs.get('zero_centered', True))
+    window = asint(attrs['window']) if 'window' in attrs else None
     if heads % kv:
         raise ValueError('%d query heads over %d key-value heads'
                          % (heads, kv))
+    if window is not None and window < 1:
+        raise ValueError('a window of %d keys' % window)
     n = qg.shape[0]
-    qg = qg.reshape(n, heads, 2 * d)        # [q, gate] split per head
-    q, gate = qg[..., :d], qg[..., d:].reshape(n, heads * d)
-    q = rms_norm(q, q_gamma, eps, zero_centered=True)
-    k = rms_norm(k.reshape(n, kv, d), k_gamma, eps, zero_centered=True)
-    q = rotary(_fold(q, seq_len).astype(F32), rotary_dim, theta)
-    k = rotary(_fold(k, seq_len).astype(F32), rotary_dim, theta)
+    if gate is None:
+        qg = qg.reshape(n, heads, 2 * d)    # [q, gate] split per head
+        q, gate = qg[..., :d], qg[..., d:].reshape(n, heads * d)
+    else:
+        q = qg.reshape(n, heads, d)
+    q = rms_norm(q, q_gamma, eps, zero_centered=centered)
+    k = rms_norm(k.reshape(n, kv, d), k_gamma, eps, zero_centered=centered)
+
+    def positioned(x):
+        x = _fold(x, seq_len)
+        return rotary(x.astype(F32), rotary_dim, theta) if rotary_dim else x
+
+    q, k = positioned(q), positioned(k)
     b = q.shape[0]
     o = causal_attention(
         q.astype(v.dtype).reshape(b, seq_len, kv, heads // kv, d),
         k.astype(v.dtype), _fold(v.reshape(n, kv, d), seq_len),
-        1.0 / math.sqrt(d))
+        1.0 / math.sqrt(d), window=window)
     o = o.reshape(n, heads * d)
     return (o.astype(F32) * jax.nn.sigmoid(gate.astype(F32))
             ).astype(o.dtype)

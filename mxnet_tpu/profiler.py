@@ -191,30 +191,41 @@ def moe_stats():
 
 # causal_attention's lowerings by path (ops/lm.py): 'kernel' is the
 # Pallas flash kernel, 'blocked' the XLA core, and the key beside each
-# count is the shape that decided.  Taken while the operator is traced
-# into a program (a step's forward, its recomputation and each
-# re-trace count one apiece), never inside a step
-_ATTENTION = {}         # (path, heads, group, dk, dv, t) -> lowerings
+# count is the shape that decided, with the query-key positions those
+# lowerings score (`keys_visited`: whole blocks, forward) and the
+# positions their masks let through (`keys_needed`), over all sequences
+# and heads.  Taken from shapes while the operator is traced into a
+# program (a step's forward, its recomputation and each re-trace count
+# one apiece), never inside a step
+_ATTENTION = {}     # (path, heads, group, dk, dv, t, window) ->
+#                     [lowerings, keys visited, keys needed]
+_ATTENTION_KEY = ('path', 'heads', 'group', 'dk', 'dv', 't', 'window')
 
 
-def note_attention_lowering(path, heads, group, dk, dv, t):
-    key = (path, int(heads), int(group), int(dk), int(dv), int(t))
+def note_attention_lowering(path, heads, group, dk, dv, t, window=None,
+                            keys_visited=0, keys_needed=0):
+    key = (path, int(heads), int(group), int(dk), int(dv), int(t),
+           None if window is None else int(window))
     with _STATE['lock']:
-        _ATTENTION[key] = _ATTENTION.get(key, 0) + 1
+        seen = _ATTENTION.setdefault(key, [0, 0, 0])
+        for i, more in enumerate((1, keys_visited, keys_needed)):
+            seen[i] += int(more)
 
 
 def attention_stats():
     """causal_attention's lowerings: {'kernel': n, 'blocked': n,
-    'shapes': [{'path', 'heads', 'group', 'dk', 'dv', 't',
-    'lowerings'}, ...]}."""
+    'shapes': [{'path', 'heads', 'group', 'dk', 'dv', 't', 'window',
+    'lowerings', 'keys_visited', 'keys_needed'}, ...]}; the last two
+    are sums over the lowerings."""
     with _STATE['lock']:
-        seen = sorted(_ATTENTION.items())
+        seen = sorted(_ATTENTION.items(),
+                      key=lambda kv: kv[0][:6] + (kv[0][6] or 0,))
     out = {'kernel': 0, 'blocked': 0, 'shapes': []}
-    for key, n in seen:
+    for key, (n, visited, needed) in seen:
         out[key[0]] += n
         out['shapes'].append(dict(
-            zip(('path', 'heads', 'group', 'dk', 'dv', 't'), key),
-            lowerings=n))
+            zip(_ATTENTION_KEY, key), lowerings=n, keys_visited=visited,
+            keys_needed=needed))
     return out
 
 

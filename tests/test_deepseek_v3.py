@@ -218,9 +218,15 @@ def test_what_the_kernel_refuses_takes_the_blocked_core(attention_paths, t,
     close(got, lm.blocked_causal_attention(q, k, v, 0.3, block_q=16), 0)
     stats = attention_paths()
     assert (stats['kernel'], stats['blocked']) == (0, 1)
-    assert stats['shapes'] == [dict(path='blocked', heads=2 * group,
-                                    group=group, dk=12, dv=6, t=t,
-                                    lowerings=1)]
+    (shape,) = stats['shapes']
+    assert {k: shape[k] for k in ('path', 'heads', 'group', 'dk', 'dv', 't',
+                                  'window', 'lowerings')} == dict(
+        path='blocked', heads=2 * group, group=group, dk=12, dv=6, t=t,
+        window=None, lowerings=1)
+    # blocks of 16 rows against the keys up to their last row
+    assert shape['keys_needed'] == 2 * group * t * (t + 1) // 2
+    assert shape['keys_visited'] == 2 * group * sum(
+        min(16, t - r0) * min(r0 + 16, t) for r0 in range(0, t, 16))
 
 
 def test_adjacent_pair_rotary_gives_the_published_scores():
