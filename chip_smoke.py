@@ -251,51 +251,71 @@ def compile_and_time(fn, args, n_kernels, calls=1):
 # ---------------------------------------------------------------------------
 
 def phase_b(lengths=(2048, 12288, 16384, 32768), parity_at=2048, bh=8,
-            d=128, latent=(32, 8192, 192, 128), parity_heads=4,
-            expect_custom_call=True):
-    """Flash attention at `bh` heads of `d` over each of `lengths`, and
-    at `latent` = (heads, T, dk, dv): latent attention's shape in the
-    Kanana cell, keys wider than values.  Forward and gradient are
-    compared with dense attention at `parity_at` and at the latent
-    case, `parity_heads` heads at a time (the dense float32 scores of
-    32 heads at T = 8,192 would be 8.6 GB)."""
+            d=128, latent=(32, 8192, 192, 128),
+            grouped=((32, 4, 8192, 128, None), (32, 4, 8192, 128, 2048),
+                     (16, 2, 8192, 256, None)),
+            parity_heads=4, expect_custom_call=True):
+    """Flash attention at `bh` heads of `d` over each of `lengths`; at
+    `latent` = (heads, T, dk, dv): latent attention's shape in the
+    Kanana cell, keys wider than values; and at each of `grouped` =
+    (heads, key-value heads, T, d, window): gated attention's shapes
+    in the Trinity-Mini cell, its full and its windowed layers, and in
+    the Qwen3-Next cell.  Forward and gradient are compared with dense
+    attention at `parity_at` and at the latent and grouped cases,
+    `parity_heads` query heads at a time (the dense float32 scores of
+    32 heads at T = 8,192 would be 8.6 GB), dK and dV summed over the
+    heads that share a key-value head."""
     from mxnet_tpu import pallas_ops
     from mxnet_tpu.ops import lm
 
-    def fwd(q, k, v):
-        # the latent case with the tile causal_attention gives it
-        return pallas_ops.flash_attention(
-            q, k, v, causal=True,
-            block_q=None if q.shape[-1] == v.shape[-1] else lm.FLASH_BLOCK)
-
-    def loss(q, k, v):
-        return fwd(q, k, v).astype(jnp.float32).sum()
-
-    def dense(q, k, v):
-        return pallas_ops._dense_attention_lse(
-            q, k, v, True, 1.0 / q.shape[-1] ** 0.5)[0]
-
-    dense_all = jax.jit(lambda q, k, v: (dense(q, k, v),) + jax.grad(
-        lambda *a: dense(*a).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v))
+    def dense_all(window):
+        def dense(q, k, v):
+            return pallas_ops._dense_attention_lse(
+                q, k, v, True, 1.0 / q.shape[-1] ** 0.5, window)[0]
+        return jax.jit(lambda q, k, v: (dense(q, k, v),) + jax.grad(
+            lambda *a: dense(*a).astype(jnp.float32).sum(), (0, 1, 2))(
+                q, k, v))
 
     def timed(fn, n_kernels, *args):
         return compile_and_time(fn, args, n_kernels if expect_custom_call
                                 else 0)
 
+    def scaled_gap(what, name, a, b):
+        """bf16 carries 8 bits: compare against the tensor's scale."""
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        err = np.abs(a - b).max() / max(np.abs(b).max(), 1e-6)
+        assert err < 2e-2, 'flash %s: %s differs from dense by %.3g of ' \
+            'its scale' % (what, name, err)
+        return err
+
     result = {}
-    cases = [(t, bh, d, d, t == parity_at) for t in lengths]
+    cases = [(t, bh, bh, d, d, None, None, t == parity_at) for t in lengths]
     if latent:
         heads, t, dk, dv = latent
-        cases.append((t, heads, dk, dv, True))
-    for t, heads, dk, dv, parity in cases:
+        cases.append((t, heads, heads, dk, dv, None, lm.FLASH_BLOCK, True))
+    for heads, kv, t, width, window in grouped:
+        # the tiles causal_attention gives the latent and grouped cases
+        cases.append((t, heads, kv, width, width, window,
+                      lm.FLASH_BLOCK if window is None else
+                      pallas_ops.window_block(window, lm.FLASH_BLOCK), True))
+    for t, heads, kv, dk, dv, window, tile, parity in cases:
+        def fwd(q, k, v):
+            return pallas_ops.flash_attention(q, k, v, causal=True,
+                                              block_q=tile, window=window)
+
+        def loss(q, k, v):
+            return fwd(q, k, v).astype(jnp.float32).sum()
+
         keys = jax.random.split(jax.random.PRNGKey(SEED + t + dk), 3)
-        q, k, v = (jax.random.normal(kk, (1, heads, t, w), jnp.bfloat16)
-                   for kk, w in zip(keys, (dk, dk, dv)))
+        q, k, v = (jax.random.normal(kk, (1, n, t, w), jnp.bfloat16)
+                   for kk, n, w in zip(keys, (heads, kv, kv), (dk, dk, dv)))
         out, fwd_s, fwd_ms = timed(fwd, 1, q, k, v)
         grads, bwd_s, bwd_ms = timed(jax.grad(loss, (0, 1, 2)), 2, q, k, v)
-        assert out.shape == v.shape
-        what = 'T=%d' % t if dk == dv else \
+        assert out.shape == q.shape[:3] + (dv,)
+        what = 'T=%d' % t if (dk, kv) == (dv, heads) else \
             'T=%d heads=%d dk=%d dv=%d' % (t, heads, dk, dv)
+        if kv != heads:
+            what += ' kv=%d window=%s' % (kv, window)
         got = dict(zip(('out', 'dq', 'dk', 'dv'), (out,) + grads))
         for name, a in got.items():
             assert bool(jnp.isfinite(a.astype(jnp.float32)).all()), \
@@ -304,20 +324,24 @@ def phase_b(lengths=(2048, 12288, 16384, 32768), parity_at=2048, bh=8,
                 'forward+backward compile %.1f s run %.2f ms'
                 % (what, fwd_s, fwd_ms, bwd_s, bwd_ms))
         if parity:
-            worst = 0.0
-            for h0 in range(0, heads, parity_heads):
-                part = slice(h0, h0 + parity_heads)
-                ref = dense_all(q[:, part], k[:, part], v[:, part])
-                for (name, a), b in zip(got.items(), ref):
-                    a = np.asarray(a[:, part], np.float32)
-                    b = np.asarray(b, np.float32)
-                    # bf16 carries 8 bits: compare against the
-                    # tensor's scale
-                    err = np.abs(a - b).max() / max(np.abs(b).max(), 1e-6)
-                    assert err < 2e-2, \
-                        'flash %s: %s differs from dense by %.3g of ' \
-                        'its scale' % (what, name, err)
-                    worst = max(worst, err)
+            worst, group, reference = 0.0, heads // kv, dense_all(window)
+            # key-value heads a time, and of their query heads
+            per = max(1, parity_heads // group)
+            for j0 in range(0, kv, per):
+                mine = slice(j0, j0 + per)
+                sums = [0.0, 0.0]
+                for h0 in range(j0 * group, (j0 + per) * group,
+                                parity_heads):
+                    part = slice(h0, h0 + parity_heads)
+                    ref = reference(q[:, part], k[:, mine], v[:, mine])
+                    for i, name in enumerate(('out', 'dq')):
+                        worst = max(worst, scaled_gap(
+                            what, name, got[name][:, part], ref[i]))
+                    sums = [s + np.asarray(r, np.float32)
+                            for s, r in zip(sums, ref[2:])]
+                for name, ref_sum in zip(('dk', 'dv'), sums):
+                    worst = max(worst, scaled_gap(
+                        what, name, got[name][:, mine], ref_sum))
             line += ', parity with dense %.2g of scale' % worst
         log(line)
         result[what] = {'forward_ms': round(fwd_ms, 2),

@@ -9,23 +9,24 @@ JAX functions differentiated by jax.vjp inside the one compiled step,
 like every other operator of the registry, and plain XLA but for two
 things under custom gradient rules: the loop over GatedDeltaRule's
 chunks (three Pallas kernels, pallas_ops.delta_rule_*) and the
-attention core of ungrouped heads (pallas_ops.flash_attention's
-kernels, forward and backward).
+attention core (pallas_ops.flash_attention's kernels, forward and
+backward: grouped heads and a sliding window inside them).
 
   RMSNorm          x * rsqrt(mean x^2 + eps) * gamma, or * (1 + gamma)
   GatedAttention   per-head q/k RMS norm, partial or no rotary, grouped-
                    head causal softmax attention over every earlier key
                    or a sliding window of them (causal_attention:
-                   grouped heads and a window take the blocked XLA
-                   core, blocks of query rows against their band of
-                   keys), and the sigmoid gate on the output, packed
-                   beside the query or an input of its own
+                   the flash kernels at any T they tile, K and V not
+                   repeated over a group, the tiles left of a window's
+                   band skipped; else the blocked XLA core), and the
+                   sigmoid gate on the output, packed beside the query
+                   or an input of its own
   LatentAttention  the core of multi-head latent attention: rotary by
                    adjacent pairs on the keys' one shared rotary head
                    and on each query head's rotary part, causal softmax
                    attention with keys wider than values
-                   (causal_attention: its ungrouped heads take the
-                   flash kernels at any T they tile, else the blocks)
+                   (causal_attention: the flash kernels at any T
+                   they tile, else the blocks)
   CausalConv1D     depthwise causal convolution along the sequence
   GatedDeltaRule   the gated delta rule in chunks (WY form): a unit
                    lower triangular solve inside a chunk (XLA, all
@@ -209,7 +210,9 @@ def blocked_causal_attention(q, k, v, scale, block_q=ATTN_BLOCK,
     its output and its rows' log-sum-exp and makes its scores again in
     the backward pass, so no T x T score matrix is ever stored (a
     block's float32 scores do cross HBM between its fusions).  The
-    gradients of a block's keys and values go back into its band."""
+    gradients of a block's keys and values go back into its band.
+    causal_attention sends it what the flash kernels refuse: a T that
+    no block of 8 rows divides."""
     t = q.shape[1]
     bands = block_bands(t, block_q, window)
 
@@ -227,11 +230,12 @@ def blocked_causal_attention(q, k, v, scale, block_q=ATTN_BLOCK,
 def _positions(t, window, tile, rows):
     """(visited, needed) query-key positions of one head over one
     sequence: those a path scores going forward (the kernel's square
-    tiles of edge `tile` on and under the diagonal, or the blocked
-    core's blocks of `rows` rows against their bands) and those the
-    mask lets through (row i sees min(i + 1, window) keys)."""
+    tiles of edge `tile` that its grid computes: on and under the
+    diagonal and, with a window, from the band's left edge on; or the
+    blocked core's blocks of `rows` rows against their bands) and those
+    the mask lets through (row i sees min(i + 1, window) keys)."""
     if tile is not None:
-        visited = tile * tile * (t // tile) * (t // tile + 1) // 2
+        visited = pallas_ops.visited_positions(t, tile, window)
     else:
         visited = sum((k1 - r0) * (k1 - k0)
                       for r0, k0, k1 in block_bands(t, rows, window))
@@ -246,36 +250,43 @@ def causal_attention(q, k, v, scale, block_q=None, window=None):
     values); the result is (B, T, kv, group, dv).  With `window` row i
     sees key j iff 0 <= i - j < window (a window that reaches the
     sequence's first key from its last row is no window).  The path is
-    chosen from the operands' shapes and `window` alone:
+    chosen from the operands' shapes alone:
 
-      kernel   group == 1, no window and a T the flash kernels'
-               schedules tile (blocks of whole sublanes: a multiple of
-               8 rows under `block_q` dividing T):
-               pallas_ops.flash_attention, the whole batch in one call
-               with the heads in front of the rows.  Scores,
-               probabilities and their gradients live in VMEM a tile at
-               a time, forward and backward; residuals are q, k, v, o
-               and the rows' log-sum-exp.
-      blocked  grouped heads (the kernels' dK and dV do not sum over a
-               group), a window (the kernels walk every tile under the
-               diagonal) or a ragged T: blocked_causal_attention.
+      kernel   a T the flash kernels' schedules tile (blocks of whole
+               sublanes: a multiple of 8 rows under `block_q` dividing
+               T), any group, any window: pallas_ops.flash_attention,
+               the whole batch in one call with the heads in front of
+               the rows (one transpose each of q, k, v and the result,
+               whatever the group: (B, kv * group, T, d) is the layout
+               the kernels read a group's heads from).  K and V are
+               not repeated; dK and dV sum over a group in float32
+               inside the backward kernel; under a window the grids
+               skip the tiles left of the band.  Scores, probabilities
+               and their gradients live in VMEM a tile at a time,
+               forward and backward; residuals are q, k, v, o and the
+               rows' log-sum-exp.
+      blocked  a ragged T: blocked_causal_attention.
 
     Both keep bf16 operands with float32 scores, sums and accumulators.
     `block_q`, where given, is the rows of a block on either path; left
-    out it is FLASH_BLOCK on the kernel (tiles of 1024 x 1024 timed
+    out it is, on the kernel, FLASH_BLOCK (tiles of 1024 x 1024 timed
     best at T = 8,192 with keys of 192 over values of 128: PERF.md
-    section 6, PR 32) and ATTN_BLOCK on the blocked core.
+    section 6, PR 32) or under a window what pallas_ops.window_block
+    makes of it, and ATTN_BLOCK on the blocked core.
     profiler.attention_stats() counts the lowerings by path, with the
     query-key positions each scores and the mask lets through."""
     t, group = q.shape[1], q.shape[3]
     if window is not None and window >= t:
         window = None
-    tile = FLASH_BLOCK if block_q is None else block_q
-    kernel = group == 1 and window is None and \
-        not pallas_ops._needs_dense_fallback(t, t, tile)
+    if block_q is not None:
+        tile = block_q
+    elif window is None:
+        tile = FLASH_BLOCK
+    else:
+        tile = pallas_ops.window_block(window, FLASH_BLOCK)
+    kernel = not pallas_ops._needs_dense_fallback(t, t, tile)
     rows = ATTN_BLOCK if block_q is None else block_q
-    visited, needed = _positions(t, window, pallas_ops._try_fit(t, tile)
-                                 if kernel else None, rows)
+    visited, needed = _positions(t, window, tile if kernel else None, rows)
     heads = q.shape[2] * group
     profiler.note_attention_lowering(
         'kernel' if kernel else 'blocked', heads=heads, group=group,
@@ -284,10 +295,18 @@ def causal_attention(q, k, v, scale, block_q=None, window=None):
         keys_needed=q.shape[0] * heads * needed)
     if not kernel:
         return blocked_causal_attention(q, k, v, scale, rows, window)
+    b = q.shape[0]
+    # ungrouped heads keep the slice they had (their step lowers to the
+    # program it was); a group's heads follow one another, as the
+    # kernels' index maps read them
     o = pallas_ops.flash_attention(
-        jnp.swapaxes(q[:, :, :, 0], 1, 2), jnp.swapaxes(k, 1, 2),
-        jnp.swapaxes(v, 1, 2), causal=True, scale=scale, block_q=tile)
-    return jnp.swapaxes(o, 1, 2)[:, :, :, None]
+        jnp.swapaxes(q[:, :, :, 0] if group == 1 else
+                     q.reshape(b, t, heads, q.shape[4]), 1, 2),
+        jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), causal=True,
+        scale=scale, block_q=tile, window=window)
+    o = jnp.swapaxes(o, 1, 2)
+    return o[:, :, :, None] if group == 1 else \
+        o.reshape(b, t, q.shape[2], group, v.shape[3])
 
 
 def _attn_infer_shape(attrs, in_shapes):
@@ -380,8 +399,8 @@ def _latent_attention(attrs, q, kv, k_pe):
     head, shared by all heads.  Head h attends with q_h = [q_nope_h |
     rot(q_pe_h)] over k_h = [k_nope_h | rot(k_pe)], scaled by
     1 / sqrt(nope + rope), to values of width v.  Returns
-    (N, heads * v).  Every query head has its own key head (group 1),
-    so causal_attention runs the flash kernels with a value width of
+    (N, heads * v).  Every query head has its own key head (group 1);
+    causal_attention runs the flash kernels with a value width of
     their own wherever blocks of whole sublanes (a multiple of 8 rows)
     divide seq_len (8,192 in the cell: tiles of 1024 x 1024, float32
     scores in VMEM only), and the blocked XLA core at a ragged

@@ -9,40 +9,52 @@ Flash attention — a (batch*head, q-block, k-block) grid streams K/V
 blocks through VMEM with the online-softmax recurrence in fp32 scratch,
 so neither the T^2 score matrix nor the full K/V sequence ever sits in
 VMEM/HBM at once, and causal q-tiles skip their fully-masked k-blocks
-and mask only the tiles the diagonal crosses.  q and k are
-(batch, heads, T, dk); v and the output (batch, heads, T, dv): the
-values' width is their own (latent attention has keys of 192 over
-values of 128), so q, k, dQ, dK and their blocks are dk wide and v, o,
-dO, dV and the accumulators dv wide.  Heads are ungrouped: every query
-head has its own key and value head (the backward kernel's dK and dV do
-not sum over a group).  Available as `pallas_ops.flash_attention`,
-opt-in via `parallel.ring_attention.full_attention(use_flash=True)`,
-and under `ops.lm.causal_attention` for ungrouped heads (the
-LatentAttention operator).
+and mask only the tiles the diagonal crosses.  q is (batch, heads, T,
+dk), k (batch, kv_heads, T, dk); v (batch, kv_heads, T, dv) and the
+output (batch, heads, T, dv): the values' width is their own (latent
+attention has keys of 192 over values of 128), so q, k, dQ, dK and
+their blocks are dk wide and v, o, dO, dV and the accumulators dv wide.
+Grouped heads: kv_heads divides heads, query head i reads key-value
+head i // group through the BlockSpec index maps (K and V are never
+repeated in HBM), and the backward kernel walks the q-blocks of all
+the heads of a group under one k-block, so dK and dV sum over the group
+in their float32 scratch.  A sliding window (causal self-attention:
+row i sees key j iff 0 <= i - j < window) is a second mask on the tiles
+the band's left edge crosses, and the grids' inner dimensions shrink to
+the widest band: the forward's k-blocks count from a q-tile's first
+visible one, the backward's q-blocks stop at the last whose rows still
+see the k-block.  Ungrouped heads without a window lower to the program
+they lowered to before either existed.  Available as
+`pallas_ops.flash_attention`, opt-in via
+`parallel.ring_attention.full_attention(use_flash=True)`, and under
+`ops.lm.causal_attention` (the LatentAttention and GatedAttention
+operators).
 
 Backward is ONE Pallas kernel: the forward saves the per-row logsumexp,
 D = rowsum(dO∘O) is a fused XLA preprocess, and the kernel (gridded
 over k-blocks, q-blocks innermost) recomputes p = exp(s − lse) once a
 tile for all three gradients, dK/dV in float32 scratch a k-block, dQ in
-a float32 scratch that holds the head's whole sequence — nothing O(T^2)
-is materialized.  Operands keep their type (bf16 in the cells); scores,
-max, sums and accumulators are float32, p and dS are cast to the
-operands' type for their products.
+a float32 scratch that holds the whole sequence of every head of the
+group — nothing O(T^2) is materialized.  Operands keep their type (bf16
+in the cells); scores, max, sums and accumulators are float32, p and dS
+are cast to the operands' type for their products.
 
 Which shapes take which path (lanes counted as VMEM holds them, a width
 of 192 as 256).  Forward (`_fwd_resident`): the resident schedule keeps
 one head's K and V in VMEM and loops over their blocks; the streaming
 schedule puts that loop on the grid and holds O(block) for any T.  At
 d = 128 in bf16 the forward is resident up to T = 12,288 under the
-default tiles; keys of 192 over values of 128 at T = 8,192 (a 6 MiB
-pair) with tiles of 1024 x 1024 stream.  Backward
+default tiles; tiles of 1024 x 1024 at T = 8,192 (keys of 192 over
+values of 128, heads of 128 and of 256) stream.  Backward
 (`_flash_bwd_shared`): the kernel wherever its dQ accumulator
-(tq x dk float32) is at most 64 MiB, T = 131,072 at d = 128; beyond,
-and for lengths no block of whole sublanes divides, dense attention
-(forward) and an XLA-level blocked recompute (backward).  Every
-pallas_call is named (`flash_attention_fwd_stream`,
-`flash_attention_fwd_resident`, `flash_attention_bwd`): the names are
-the custom calls' in a trace.
+(group x tq x dk float32) is at most 64 MiB: T = 131,072 at d = 128
+ungrouped, and the cells' 8 heads a group at T = 8,192 (32 MiB at d =
+128, 64 MiB at 256, beside the tiles' 32: both compile for a v5e's
+128 MiB); beyond, and for lengths no block of whole sublanes divides,
+dense attention (forward) and an XLA-level blocked recompute (backward),
+which take groups and a window too.  Every pallas_call is named
+(`flash_attention_fwd_stream`, `flash_attention_fwd_resident`,
+`flash_attention_bwd`): the names are the custom calls' in a trace.
 """
 import functools
 
@@ -74,28 +86,42 @@ def _lanes(d):
     return -(-d // 128) * 128
 
 
-def _scores(q, kblk, scale, row0, col0, masked):
+# what a masked score reads under a window.  There a row's first live
+# tile may hold none of its keys, and -inf as the row's running maximum
+# would make exp(-inf - -inf); with a finite floor the tile's weights
+# are wiped by the correction factor once a visible key arrives (every
+# row sees itself).  Without a window every row sees its first tile's
+# first key, and the mask stays -inf
+_MASKED_SCORE = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+
+def _scores(q, kblk, scale, row0, col0, masked, window=None):
     """Scaled scores (rows, cols) of a q tile against a k tile in
-    float32; `masked` (a Python bool) applies the causal mask of a tile
-    the diagonal crosses: rows from `row0` see columns from `col0` up
-    to their own index.  Tiles wholly under the diagonal skip it: the
-    mask would change nothing there."""
+    float32; `masked` (a Python bool) applies the masks of a tile an
+    edge crosses: rows from `row0` see columns from `col0` up to their
+    own index (the diagonal) and, with `window`, no further back than
+    `window - 1` before it (the band's left edge).  Tiles wholly
+    between the edges skip it: the mask would change nothing there."""
     s = lax.dot_general(
         q, kblk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     if masked:
         rows = row0 + lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = col0 + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(rows >= cols, s, -jnp.inf)
+        if window is None:
+            s = jnp.where(rows >= cols, s, -jnp.inf)
+        else:
+            s = jnp.where((rows >= cols) & (rows - cols < window), s,
+                          _MASKED_SCORE)
     return s
 
 
 def _online_softmax_step(q, kblk, vblk, m, l, acc, scale, masked,
-                         row0, col0):
+                         row0, col0, window=None):
     """One K-block of the online-softmax recurrence — the ONE numerics
     definition both schedules share.  q, kblk are dk wide, vblk and acc
     dv wide."""
-    s = _scores(q, kblk, scale, row0, col0, masked)
+    s = _scores(q, kblk, scale, row0, col0, masked, window)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     correction = jnp.exp(m - m_new)
     p = jnp.exp(s - m_new)
@@ -132,19 +158,80 @@ def _first_unmasked_qb(kb, block_q, block_k, num_qb, offset):
         0, num_qb)
 
 
+# The band of a window (square self-attention, offset 0: row i sees
+# keys i - window + 1 .. i).  Traced forms for the kernels and index
+# maps, and `_band` in Python ints for the grids' extents and the count
+# of positions visited.
+
+def _band_first_kb(qi, block_q, block_k, window):
+    """The k block that holds the first key a q tile's first row sees."""
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
+def _band_inside_kb(qi, block_q, block_k, window):
+    """The first k block wholly right of the band's left edge for a q
+    tile: its first column is within the window of the tile's last
+    row."""
+    return jnp.maximum(
+        qi * block_q + block_q - 1 - window + block_k, 0) // block_k
+
+
+def _band_last_qb(kb, block_q, block_k, num_qb, window):
+    """The last q block with a row that still sees a k block's columns."""
+    return jnp.minimum(
+        (kb * block_k + block_k - 1 + window - 1) // block_q, num_qb - 1)
+
+
+def _band_crossed_qb(kb, block_q, block_k, window):
+    """The first q block the band's left edge crosses for a k block:
+    its last row no longer sees the block's first column."""
+    return (kb * block_k + window) // block_q
+
+
+def _band(t, block, window):
+    """(first, last) k block of every q tile of a windowed square call
+    with tiles of `block` x `block`, in Python ints."""
+    return [(max(r0 - (window - 1), 0) // block, r0 // block)
+            for r0 in range(0, t, block)]
+
+
+def _band_steps(t, block, window):
+    """Tiles of the widest row of the band (a q tile's k blocks) and of
+    its widest column (a k block's q tiles): the extents of the
+    windowed grids' inner dimensions."""
+    band = _band(t, block, window)
+    return (max(last - first + 1 for first, last in band),
+            max(sum(first <= kb <= last for first, last in band)
+                for kb in range(len(band))))
+
+
+def visited_positions(t, block, window=None):
+    """Query-key positions one head's forward scores over a causal
+    square call of length t with tiles of `block` (fitted to t as the
+    kernels fit it): whole tiles on and under the diagonal and, with a
+    window, from the band's left edge on: what the grids compute."""
+    block = _try_fit(t, block)
+    band = _band(t, block, t if window is None else window)
+    return block * block * sum(last - first + 1 for first, last in band)
+
+
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                  acc_ref, *, scale, causal, block_q, block_k, num_kb,
-                 offset):
+                 offset, steps, window=None):
     """One (bh, qi, kb) grid step of the streaming schedule.  kb is the
     minor grid dim: scratch (m, l, acc) carries the online softmax
-    across kb steps; the last live kb writes o_ref and the per-row
+    across kb steps; the last step writes o_ref and the per-row
     logsumexp (saved for the fused backward).  `offset` = tk - tq:
     causal q rows sit suffix-aligned against the keys (KV-decode
-    convention); 0 for square self-attention."""
+    convention); 0 for square self-attention.  The minor dim has
+    `steps` steps: num_kb, or with `window` the widest band's, counted
+    from the q tile's first visible k block."""
     qi = pl.program_id(1)
-    kb = pl.program_id(2)
+    step = pl.program_id(2)
+    kb = step if window is None else \
+        step + _band_first_kb(qi, block_q, block_k, window)
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -154,7 +241,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         m_new, l_new, acc_new = _online_softmax_step(
             q_ref[0], k_ref[0], v_ref[0], m_ref[...], l_ref[...],
             acc_ref[...], scale, masked, qi * block_q + offset,
-            kb * block_k)
+            kb * block_k, window)
         m_ref[...] = m_new
         l_ref[...] = l_new
         acc_ref[...] = acc_new
@@ -162,26 +249,38 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     if causal:
         first_masked = _first_masked_kb(qi, block_q, block_k, num_kb,
                                         offset)
-        pl.when(kb < first_masked)(lambda: compute(False))
-        pl.when((kb >= first_masked) &
-                (kb <= _last_live_kb(qi, block_q, block_k, num_kb,
-                                     offset)))(lambda: compute(True))
+        if window is None:
+            pl.when(kb < first_masked)(lambda: compute(False))
+            pl.when((kb >= first_masked) &
+                    (kb <= _last_live_kb(qi, block_q, block_k, num_kb,
+                                         offset)))(lambda: compute(True))
+        else:
+            # tiles between the band's left edge and the diagonal need
+            # no mask; a tile either edge crosses takes both
+            plain = (kb >= _band_inside_kb(qi, block_q, block_k, window)) \
+                & (kb < first_masked)
+            pl.when(plain)(lambda: compute(False))
+            pl.when(~plain & (kb <= _last_live_kb(
+                qi, block_q, block_k, num_kb, offset)))(
+                    lambda: compute(True))
     else:
         compute(False)
 
-    @pl.when(kb == num_kb - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
         lse_ref[0] = m_ref[...] + jnp.log(l_ref[...])
 
 
 def _attn_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
-                          causal, block_q, block_k, num_kb, offset):
+                          causal, block_q, block_k, num_kb, offset,
+                          window=None):
     """Resident-K schedule: the whole K/V sequence for one head sits in
-    VMEM (fetched once per head); a fori_loop walks k-blocks with the
-    online-softmax recurrence, and causal q-tiles stop at the diagonal
-    (skipping both compute AND reads of the masked tail).  Fastest when
-    K/V fit in VMEM."""
+    VMEM (fetched once per head, once per group of heads that share
+    it); a fori_loop walks k-blocks with the online-softmax recurrence,
+    and causal q-tiles stop at the diagonal and, with `window`, start
+    at the band's left edge (skipping both compute AND reads of what
+    the masks hide).  Fastest when K/V fit in VMEM."""
     q = q_ref[0]                          # (block_q, dk)
     qi = pl.program_id(1)
     carry = (jnp.full((block_q, 1), -jnp.inf, jnp.float32),
@@ -192,17 +291,34 @@ def _attn_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
         kblk = k_ref[0, pl.ds(kb * block_k, block_k), :]
         vblk = v_ref[0, pl.ds(kb * block_k, block_k), :]
         return _online_softmax_step(q, kblk, vblk, *carry, scale, masked,
-                                    qi * block_q + offset, kb * block_k)
+                                    qi * block_q + offset, kb * block_k,
+                                    window)
 
     if causal:
         first_masked = _first_masked_kb(qi, block_q, block_k, num_kb,
                                         offset)
-        carry = lax.fori_loop(0, first_masked,
-                              functools.partial(body, False), carry)
-        carry = lax.fori_loop(
-            first_masked,
-            _last_live_kb(qi, block_q, block_k, num_kb, offset) + 1,
-            functools.partial(body, True), carry)
+        if window is None:
+            carry = lax.fori_loop(0, first_masked,
+                                  functools.partial(body, False), carry)
+            carry = lax.fori_loop(
+                first_masked,
+                _last_live_kb(qi, block_q, block_k, num_kb, offset) + 1,
+                functools.partial(body, True), carry)
+        else:
+            # the tiles the band's left edge crosses, the unmasked ones
+            # between the edges, those on the diagonal; a window under
+            # a tile leaves the middle loop empty
+            stop = _last_live_kb(qi, block_q, block_k, num_kb, offset) + 1
+            inside = jnp.clip(
+                _band_inside_kb(qi, block_q, block_k, window), 0, stop)
+            first_masked = jnp.clip(first_masked, inside, stop)
+            carry = lax.fori_loop(
+                _band_first_kb(qi, block_q, block_k, window), inside,
+                functools.partial(body, True), carry)
+            carry = lax.fori_loop(inside, first_masked,
+                                  functools.partial(body, False), carry)
+            carry = lax.fori_loop(first_masked, stop,
+                                  functools.partial(body, True), carry)
     else:
         carry = lax.fori_loop(0, num_kb, functools.partial(body, False),
                               carry)
@@ -302,14 +418,19 @@ def _schedule_caps(tq, tk, block_q):
 
 
 def _flash_fwd_impl(q, k, v, causal, scale, block_q, interpret,
-                    return_lse=False):
+                    return_lse=False, window=None):
     b, h, tq, dk = q.shape
-    tk, dv = k.shape[2], v.shape[3]
+    kv, tk, dv = k.shape[1], k.shape[2], v.shape[3]
+    group = h // kv
     offset = tk - tq          # causal rows suffix-align to the keys
     bh = b * h
     qf = q.reshape(bh, tq, dk)
-    kf = k.reshape(bh, tk, dk)
-    vf = v.reshape(bh, tk, dv)
+    kf = k.reshape(b * kv, tk, dk)
+    vf = v.reshape(b * kv, tk, dv)
+    # query head i reads key-value head i // group: K and V are never
+    # repeated in HBM, and the heads of a group follow one another, so
+    # the resident schedule fetches their pair once
+    kv_head = (lambda i: i) if group == 1 else (lambda i: i // group)
     block_q = _fit_block(tq, block_q)
     block_k = _fit_block(tk, block_q if tq == tk else max(block_q, 256))
     num_kb = tk // block_k
@@ -321,7 +442,8 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, interpret,
     out_shapes = [jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
                   jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32)]
     static = dict(scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, num_kb=num_kb, offset=offset)
+                  block_k=block_k, num_kb=num_kb, offset=offset,
+                  window=window)
 
     if resident:
         out, lse = pl.pallas_call(
@@ -329,8 +451,8 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, interpret,
             grid=(bh, tq // block_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, dk), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((1, tk, dk), lambda i, j: (i, 0, 0)),
-                pl.BlockSpec((1, tk, dv), lambda i, j: (i, 0, 0)),
+                pl.BlockSpec((1, tk, dk), lambda i, j: (kv_head(i), 0, 0)),
+                pl.BlockSpec((1, tk, dv), lambda i, j: (kv_head(i), 0, 0)),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
@@ -343,17 +465,27 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, interpret,
         out = out.reshape(b, h, tq, dv)
         return (out, lse) if return_lse else out
 
-    if causal:
+    steps = num_kb
+    if window is not None:
+        # the k dimension of the grid is as long as the widest band and
+        # counts from a q tile's first visible block; steps past the
+        # diagonal repeat its block (no fetch) and compute nothing
+        steps = _band_steps(tq, block_q, window)[0]
+        kv_index = lambda i, j, n: (
+            kv_head(i), jnp.minimum(
+                n + _band_first_kb(j, block_q, block_k, window),
+                (j * block_q + block_q - 1) // block_k), 0)
+    elif causal:
         # clamp masked k-blocks to the diagonal: repeated block indices
         # skip the HBM->VMEM fetch (compute is gated by pl.when)
         kv_index = lambda i, j, n: (
-            i, jnp.minimum(
+            kv_head(i), jnp.minimum(
                 n, (j * block_q + block_q - 1 + offset) // block_k), 0)
     else:
-        kv_index = lambda i, j, n: (i, n, 0)
+        kv_index = lambda i, j, n: (kv_head(i), n, 0)
     out, lse = pl.pallas_call(
-        functools.partial(_attn_kernel, **static),
-        grid=(bh, tq // block_q, num_kb),
+        functools.partial(_attn_kernel, steps=steps, **static),
+        grid=(bh, tq // block_q, steps),
         in_specs=[
             pl.BlockSpec((1, block_q, dk), lambda i, j, n: (i, j, 0)),
             pl.BlockSpec((1, block_k, dk), kv_index),
@@ -376,15 +508,18 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, interpret,
     return (out, lse) if return_lse else out
 
 
-def _blocked_backward(q, k, v, g, causal, scale, block_q, glse=None):
+def _blocked_backward(q, k, v, g, causal, scale, block_q, glse=None,
+                      group=1, window=None):
     """Recompute-based gradients, q-block at a time: live memory is
-    O(block_q * T) instead of the dense O(T^2).  q, k (bh, t, dk); v,
-    g (bh, t, dv).  glse: optional logsumexp cotangent, folded into
-    the softmax vjp."""
+    O(block_q * T) instead of the dense O(T^2).  q (bh, group * t, dk):
+    the heads of a group, one after another, as more rows of their
+    key-value head; k (bh, tk, dk); v (bh, tk, dv); g like q, dv wide.
+    dK and dV sum over the q blocks, so over the group, in float32.
+    glse: optional logsumexp cotangent, folded into the softmax vjp."""
     bh, t, dk_w = q.shape
     tk = k.shape[1]
-    offset = tk - t
-    block_q = _fit_block(t, block_q)
+    offset = tk - t // group
+    block_q = _fit_block(t // group, block_q)
     nq = t // block_q
     qb = q.reshape(bh, nq, block_q, dk_w)
     gb = g.reshape(bh, nq, block_q, g.shape[-1])
@@ -396,11 +531,16 @@ def _blocked_backward(q, k, v, g, causal, scale, block_q, glse=None):
         qi, qblk, gblk, lblk = blk
         s = jnp.einsum('bqd,bkd->bqk', qblk, k).astype(
             jnp.float32) * scale                       # (bh, bq, Tk)
+        if group > 1:
+            qi = qi % (nq // group)         # the block's place in its head
         if causal:
             rows = qi * block_q + offset + lax.broadcasted_iota(
                 jnp.int32, (block_q, tk), 0)
             cols = lax.broadcasted_iota(jnp.int32, (block_q, tk), 1)
-            s = jnp.where(rows >= cols, s, -jnp.inf)
+            keep = rows >= cols
+            if window is not None:
+                keep &= rows - cols < window
+            s = jnp.where(keep, s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
         pv = p.astype(v.dtype)
         dp = jnp.einsum('bqd,bkd->bqk', gblk, v).astype(jnp.float32)
@@ -446,20 +586,38 @@ def _blocked_backward(q, k, v, g, causal, scale, block_q, glse=None):
 
 def _bwd_kernel(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref, dq_ref,
                 dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, scale, causal,
-                block_q, block_k, num_qb, num_kb, offset):
-    """One (bh, kb, qi) grid step: q/dO/lse/D arrive one q-block a
-    step, k/v one k-block a kb.  q, k, dQ, dK are dk wide; v, dO, dV
-    dv wide."""
+                block_q, block_k, num_qb, num_kb, offset, group, steps,
+                window=None):
+    """One (bh, kb, j) grid step: q/dO/lse/D arrive one q-block a step,
+    k/v one k-block a kb.  q, k, dQ, dK are dk wide; v, dO, dV dv wide.
+    bh counts key-value heads: the `group` query heads that share one
+    lie behind one another along the rows of q, dO, lse, D and dQ, and
+    the minor dim walks all their q-blocks, so dK and dV sum over the
+    group in their float32 scratch.  j = head * steps + r: without a
+    window `steps` is num_qb and r the q-block; with one r counts
+    q-blocks from the k-block's own (the first whose rows reach it,
+    square tiles), `steps` the widest band's count, and a q-block's dQ
+    is whole once its diagonal tile is done, r == 0, and leaves then."""
     kb = pl.program_id(1)
-    qi = pl.program_id(2)
-    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+    j = pl.program_id(2)
+    # qi: the q-block's place in its sequence; slot: among all rows
+    if window is None:
+        qi, slot = (j if group == 1 else j % num_qb), j
+    else:
+        r = j if group == 1 else j % steps
+        qi = _first_live_qb(kb, block_q, block_k, offset) + r
+        live = qi <= _band_last_qb(kb, block_q, block_k, num_qb, window)
+        qi = jnp.minimum(qi, num_qb - 1)
+        slot = qi if group == 1 else j // steps * num_qb + qi
+    rows = pl.ds(pl.multiple_of(slot * block_q, block_q), block_q)
 
-    @pl.when(qi == 0)
+    @pl.when(j == 0)
     def _new_k_block():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(kb == 0)
+    @pl.when(kb == 0 if window is None else live & (
+        kb == _band_first_kb(qi, block_q, block_k, window)))
     def _first_visit():
         dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[-1]),
                                     jnp.float32)
@@ -467,7 +625,7 @@ def _bwd_kernel(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref, dq_ref,
     def compute(masked):
         qblk, doblk, kblk, vblk = q_ref[0], do_ref[0], k_ref[0], v_ref[0]
         p = jnp.exp(_scores(qblk, kblk, scale, qi * block_q + offset,
-                            kb * block_k, masked) - lse_ref[0])
+                            kb * block_k, masked, window) - lse_ref[0])
         # p/ds matmuls run in the input dtype: a f32xf32 MXU pass is
         # several times slower than bf16 and the f32 accumulate
         # (preferred_element_type) already carries the precision
@@ -489,46 +647,59 @@ def _bwd_kernel(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref, dq_ref,
         # from the first q-block whose rows reach this k-block's
         # columns; the diagonal crosses the first few of them
         unmasked = _first_unmasked_qb(kb, block_q, block_k, num_qb, offset)
-        pl.when((qi >= _first_live_qb(kb, block_q, block_k, offset)) &
-                (qi < unmasked))(lambda: compute(True))
-        pl.when(qi >= unmasked)(lambda: compute(False))
+        if window is None:
+            pl.when((qi >= _first_live_qb(kb, block_q, block_k, offset)) &
+                    (qi < unmasked))(lambda: compute(True))
+            pl.when(qi >= unmasked)(lambda: compute(False))
+        else:
+            # ... and the band's left edge the last few
+            plain = (qi >= unmasked) & (
+                qi < _band_crossed_qb(kb, block_q, block_k, window))
+            pl.when(live & ~plain)(lambda: compute(True))
+            pl.when(live & plain)(lambda: compute(False))
     else:
         compute(False)
 
-    @pl.when(qi == num_qb - 1)
+    @pl.when(j == group * steps - 1)
     def _k_block_done():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
-    @pl.when(kb == num_kb - 1)
+    @pl.when(kb == num_kb - 1 if window is None else r == 0)
     def _q_block_done():
         dq_ref[0] = dq_acc[rows, :].astype(dq_ref.dtype)
 
 
-def _bwd_blocks(t, tk, block_q):
+def _bwd_blocks(t, tk, block_q, window=None):
     """(block_q, block_k) of the backward kernel.  It wants larger
     tiles than the forward: the per-tile matmul chain (5 MXU passes)
-    amortizes the grid step better."""
-    block_q = _fit_block(t, max(block_q, _BWD_BLOCK))
+    amortizes the grid step better.  Under a window the tile follows
+    it (window_block), square, and an explicit block_q still raises
+    it."""
+    cap = _BWD_BLOCK if window is None else window_block(window, _BWD_BLOCK)
+    block_q = _fit_block(t, max(block_q, cap))
     return block_q, block_q if t == tk else _fit_block(
         tk, max(block_q, _BWD_BLOCK))
 
 
 def _dq_acc_bytes(tq, dk):
-    """Bytes of the backward kernel's dQ accumulator in VMEM."""
+    """Bytes of the backward kernel's dQ accumulator in VMEM: tq rows,
+    those of every head of a group."""
     return tq * _lanes(dk) * 4
 
 
 def _flash_bwd_impl(q, k, v, g, o, lse, causal, scale, block_q,
-                    interpret, glse=None):
-    """The one-kernel backward over flat tensors: q, k (bh, t, dk); v,
-    g, o (bh, t, dv); lse (bh, t, 1).  glse: optional cotangent on the
-    logsumexp output — it folds exactly into the D preprocess
-    (ds = p*(dp - (D - glse)))."""
+                    interpret, glse=None, group=1, window=None):
+    """The one-kernel backward over flat tensors: q (bh, group * t,
+    dk), the heads of a group one after another as more rows of their
+    key-value head; k (bh, tk, dk); v (bh, tk, dv); g, o, lse like q,
+    dv and 1 wide.  glse: optional cotangent on the logsumexp output —
+    it folds exactly into the D preprocess (ds = p*(dp - (D - glse)))."""
     bh, t, dk = q.shape
+    t //= group
     tk, dv = k.shape[1], v.shape[2]
     offset = tk - t
-    block_q, block_k = _bwd_blocks(t, tk, block_q)
+    block_q, block_k = _bwd_blocks(t, tk, block_q, window)
     num_qb = t // block_q
     num_kb = tk // block_k
     # pass 0: D_i = dO_i . O_i — one fused elementwise+reduce XLA pass
@@ -537,24 +708,44 @@ def _flash_bwd_impl(q, k, v, g, o, lse, causal, scale, block_q,
     if glse is not None:
         dd = dd - glse.astype(jnp.float32)
 
-    if causal:
-        # fetch-clamp the q-blocks above a k-block's diagonal (their
-        # compute is pl.when-gated): a repeated index skips the fetch
-        q_index = lambda i, n, j: (
-            i, jnp.maximum(
-                j, jnp.maximum(n * block_k - offset, 0) // block_q), 0)
+    steps = num_qb if window is None else \
+        _band_steps(t, block_q, window)[1]
+
+    def q_block(j, pick):
+        """The q block of step j along the rows of all the group's
+        heads: pick(r) within its head, r the step's count there."""
+        if group == 1:
+            return pick(j)
+        return j // steps * num_qb + pick(j % steps)
+
+    if window is not None:
+        # the minor dim is as long as the widest band and counts q
+        # blocks from the k block's own; those past the sequence's end
+        # repeat its last (no fetch) and compute nothing.  A q block's
+        # dQ leaves when its diagonal tile is done: the output window
+        # follows the k block
+        q_index = lambda i, n, j: (i, q_block(
+            j, lambda r: jnp.minimum(n + r, num_qb - 1)), 0)
+        dq_index = lambda i, n, j: (i, q_block(j, lambda r: n), 0)
     else:
-        q_index = lambda i, n, j: (i, j, 0)
+        if causal:
+            # fetch-clamp the q-blocks above a k-block's diagonal (their
+            # compute is pl.when-gated): a repeated index skips the fetch
+            q_index = lambda i, n, j: (i, q_block(j, lambda r: jnp.maximum(
+                r, jnp.maximum(n * block_k - offset, 0) // block_q)), 0)
+        else:
+            q_index = lambda i, n, j: (i, j, 0)
+        # dQ's blocks leave during the last k-block only: until then the
+        # output window stays on block 0 and nothing is written back
+        dq_index = lambda i, n, j: (i, jnp.where(n == num_kb - 1, j, 0), 0)
     k_index = lambda i, n, j: (i, n, 0)
-    # dQ's blocks leave during the last k-block only: until then the
-    # output window stays on block 0 and nothing is written back
-    dq_index = lambda i, n, j: (i, jnp.where(n == num_kb - 1, j, 0), 0)
 
     return pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          num_qb=num_qb, num_kb=num_kb, offset=offset),
-        grid=(bh, num_kb, num_qb),
+                          num_qb=num_qb, num_kb=num_kb, offset=offset,
+                          group=group, steps=steps, window=window),
+        grid=(bh, num_kb, group * steps),
         in_specs=[
             pl.BlockSpec((1, block_q, dk), q_index),           # q
             pl.BlockSpec((1, block_q, dv), q_index),           # dO
@@ -568,116 +759,137 @@ def _flash_bwd_impl(q, k, v, g, o, lse, causal, scale, block_q,
             pl.BlockSpec((1, block_k, dk), k_index),
             pl.BlockSpec((1, block_k, dv), k_index),
         ],
-        out_shape=[jax.ShapeDtypeStruct((bh, t, dk), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((bh, group * t, dk), q.dtype),
                    jax.ShapeDtypeStruct((bh, tk, dk), k.dtype),
                    jax.ShapeDtypeStruct((bh, tk, dv), v.dtype)],
         scratch_shapes=[
-            pltpu.VMEM((t, dk), jnp.float32),                  # dQ
+            pltpu.VMEM((group * t, dk), jnp.float32),          # dQ
             pltpu.VMEM((block_k, dk), jnp.float32),            # dK
             pltpu.VMEM((block_k, dv), jnp.float32),            # dV
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary', 'arbitrary'),
-            vmem_limit_bytes=_BWD_TILE_VMEM_BYTES + _dq_acc_bytes(t, dk)),
+            vmem_limit_bytes=_BWD_TILE_VMEM_BYTES +
+            _dq_acc_bytes(group * t, dk)),
         interpret=interpret,
         name='flash_attention_bwd',
     )(q, g, lse, dd, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, scale, block_q, interpret):
-    return _flash_fwd_impl(q, k, v, causal, scale, block_q, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, scale, block_q, interpret, window):
+    return _flash_fwd_impl(q, k, v, causal, scale, block_q, interpret,
+                           window=window)
 
 
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, interpret):
+def _flash_fwd_rule(q, k, v, causal, scale, block_q, interpret, window):
     out, lse = _flash_fwd_impl(q, k, v, causal, scale, block_q,
-                               interpret, return_lse=True)
+                               interpret, return_lse=True, window=window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_shared(causal, scale, block_q, interpret, res, g,
+def _flash_bwd_shared(causal, scale, block_q, interpret, window, res, g,
                       glse=None):
     """The backward shared by the plain and with-lse custom VJPs; glse
     is the optional logsumexp cotangent.  The kernel wherever its
-    blocks tile both lengths and its dQ accumulator fits VMEM, else
-    the XLA-level blocked recompute."""
+    blocks tile both lengths and its dQ accumulator (the rows of all
+    the heads of a group) fits VMEM, else the XLA-level blocked
+    recompute.  Both take the heads of a group as more rows of their
+    key-value head: (batch, kv * group, t, d) is (batch * kv, group *
+    t, d) without a copy."""
     q, k, v, o, lse = res
     b, h, tq, dk = q.shape
-    tk = k.shape[2]
-    flat = lambda x: x.reshape((b * h,) + x.shape[2:])
-    glse_flat = None if glse is None else glse.reshape(b * h, tq, 1)
+    kv, tk = k.shape[1], k.shape[2]
+    group = h // kv
+    flat = lambda x: x.reshape((b * kv, -1) + x.shape[3:])
+    glse_flat = None if glse is None else glse.reshape(b * kv, group * tq, 1)
     cap = max(block_q, _BWD_BLOCK)
     if _tiles(tq, _try_fit(tq, cap)) and _tiles(tk, _try_fit(tk, cap)) \
-            and _dq_acc_bytes(tq, dk) <= _BWD_ACC_BYTES:
+            and _dq_acc_bytes(group * tq, dk) <= _BWD_ACC_BYTES:
         dq, dk_, dv_ = _flash_bwd_impl(
             flat(q), flat(k), flat(v), flat(g), flat(o),
-            lse.reshape(b * h, tq, 1), causal, scale, block_q, interpret,
-            glse=glse_flat)
+            lse.reshape(b * kv, group * tq, 1), causal, scale, block_q,
+            interpret, glse=glse_flat, group=group, window=window)
     else:
         dq, dk_, dv_ = _blocked_backward(flat(q), flat(k), flat(v),
                                          flat(g), causal, scale, block_q,
-                                         glse=glse_flat)
+                                         glse=glse_flat, group=group,
+                                         window=window)
     return (dq.reshape(q.shape), dk_.reshape(k.shape),
             dv_.reshape(v.shape))
 
 
-def _flash_bwd_rule(causal, scale, block_q, interpret, res, g):
-    return _flash_bwd_shared(causal, scale, block_q, interpret, res, g)
+def _flash_bwd_rule(causal, scale, block_q, interpret, window, res, g):
+    return _flash_bwd_shared(causal, scale, block_q, interpret, window,
+                             res, g)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_lse(q, k, v, causal, scale, block_q, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_lse(q, k, v, causal, scale, block_q, interpret, window):
     return _flash_fwd_impl(q, k, v, causal, scale, block_q, interpret,
-                           return_lse=True)
+                           return_lse=True, window=window)
 
 
-def _flash_lse_fwd_rule(q, k, v, causal, scale, block_q, interpret):
+def _flash_lse_fwd_rule(q, k, v, causal, scale, block_q, interpret,
+                        window):
     out, lse = _flash_fwd_impl(q, k, v, causal, scale, block_q,
-                               interpret, return_lse=True)
+                               interpret, return_lse=True, window=window)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_lse_bwd_rule(causal, scale, block_q, interpret, res, cts):
+def _flash_lse_bwd_rule(causal, scale, block_q, interpret, window, res,
+                        cts):
     g, glse = cts
     b, h, t, _ = res[0].shape
-    return _flash_bwd_shared(causal, scale, block_q, interpret, res, g,
-                             glse=glse.reshape(b, h, t, 1))
+    return _flash_bwd_shared(causal, scale, block_q, interpret, window,
+                             res, g, glse=glse.reshape(b, h, t, 1))
 
 
 _flash_lse.defvjp(_flash_lse_fwd_rule, _flash_lse_bwd_rule)
 
 
-def _validate_attn_shapes(q, k, v, causal, fn):
-    """Rectangular attention contract: q and k share (batch, heads,
-    head_dim), k and v share everything but the head width (the values'
+def _validate_attn_shapes(q, k, v, causal, fn, window=None):
+    """Rectangular attention contract: q and k share (batch, head_dim)
+    and k's heads divide q's (query head i reads key-value head i //
+    group), k and v share everything but the head width (the values'
     and the output's is v's own), and causal requires tq <= tk (rows
     suffix-align to the keys — the KV-cache decode convention; tq > tk
-    would leave the leading rows with no visible key)."""
+    would leave the leading rows with no visible key).  A window is a
+    causal one over a square call: row i sees keys i - window + 1 to
+    i."""
     if k.ndim != v.ndim or k.shape[:-1] != v.shape[:-1]:
         raise ValueError('%s requires identical k/v shapes up to the '
                          'head width; got %s / %s'
                          % (fn, k.shape, v.shape))
-    if q.ndim != 4 or k.ndim != 4 or \
-            q.shape[:2] != k.shape[:2] or q.shape[-1] != k.shape[-1]:
+    if q.ndim != 4 or k.ndim != 4 or q.shape[0] != k.shape[0] or \
+            q.shape[1] % k.shape[1] or q.shape[-1] != k.shape[-1]:
         raise ValueError(
             '%s wants (batch, heads, seq, head_dim) with matching '
-            'batch/heads/head_dim; got q %s vs k %s'
-            % (fn, q.shape, k.shape))
+            'batch/head_dim and key-value heads that divide the query '
+            'heads; got q %s vs k %s' % (fn, q.shape, k.shape))
     if causal and q.shape[2] > k.shape[2]:
         raise ValueError(
             '%s: causal masking needs q_len <= kv_len (suffix '
             'alignment); got q_len=%d kv_len=%d'
             % (fn, q.shape[2], k.shape[2]))
+    if window is not None and (
+            window < 1 or not causal or q.shape[2] != k.shape[2]):
+        raise ValueError(
+            '%s: a window is one of at least 1 key, causal, over q_len '
+            '== kv_len; got window=%d causal=%s q_len=%d kv_len=%d'
+            % (fn, window, bool(causal), q.shape[2], k.shape[2]))
 
 
 def _needs_dense_fallback(tq, tk, block_q):
     """A length no schedule can tile — a property of the shape, never
     of the device: the check runs _try_fit with exactly the caps the
     forward AND backward schedules will use (_schedule_caps), so the
-    predicate and the kernels can never disagree."""
+    predicate and the kernels can never disagree.  (Under a window the
+    backward's cap is a smaller power of two: what the larger one
+    tiles it tiles.)"""
     return not all(_tiles(t, _try_fit(t, cap))
                    for t, cap in _schedule_caps(tq, tk, block_q))
 
@@ -686,13 +898,36 @@ def _default_block_q(tq):
     return max(256, min(1024, tq // 32))
 
 
-def _dense_attention_lse(q, k, v, causal, scale):
+def window_block(window, cap=1024):
+    """The tile edge of a windowed call, from `window` (and, fitted to
+    it by the kernels, T) alone: the largest power of two no larger
+    than the window, between 128 (a whole lane tile) and `cap`.  The
+    MXU pays for visited positions, about 1 + tile / window of the
+    needed ones once T is several windows, and the grid and the masks
+    for tiles.  Timed at window = 2,048 over T = 8,192, 32 heads of
+    128 over 4 (v5e, PERF.md section 6, PR 35), forward / backward ms a
+    call: tiles of 1024 2.90 / 5.72 (1.50 of the needed positions), 512
+    3.19 / 5.96 (1.25), 256 4.96 / 11.4 (1.125): a position costs a
+    third more in a tile of 512 than in one of 1024, which is what a
+    tile half as large saves once the window is as small as the
+    tile."""
+    block = min(128, cap)
+    while block * 2 <= min(cap, window):
+        block *= 2
+    return block
+
+
+def _dense_attention_lse(q, k, v, causal, scale, window=None):
     b, h, tq, _ = q.shape
-    tk = k.shape[2]
+    kv, tk = k.shape[1], k.shape[2]
+    if h != kv:
+        k, v = (jnp.repeat(x, h // kv, axis=1) for x in (k, v))
     s = jnp.einsum('bhqd,bhkd->bhqk', q, k).astype(jnp.float32) * scale
     if causal:
         mask = ((tk - tq) + jnp.arange(tq)[:, None] >=
                 jnp.arange(tk)[None, :])
+        if window is not None:
+            mask &= jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :] < window
         s = jnp.where(mask, s, -jnp.inf)
     lse = jax.scipy.special.logsumexp(s, axis=-1)
     out = jnp.einsum('bhqk,bhkd->bhqd',
@@ -702,61 +937,72 @@ def _dense_attention_lse(q, k, v, causal, scale):
 
 
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,
-                             block_q=None, interpret=None):
+                             block_q=None, interpret=None, window=None):
     """flash_attention variant that ALSO returns the per-row logsumexp
     (bh, tq, 1) — the merge currency for ring attention / partial
     softmax combination — and is differentiable in BOTH outputs (the
     lse cotangent folds into the backward's D preprocess).  Lengths
     no schedule can tile take the dense jnp computation."""
-    _validate_attn_shapes(q, k, v, causal, 'flash_attention_with_lse')
+    _validate_attn_shapes(q, k, v, causal, 'flash_attention_with_lse',
+                          window)
     tq, tk = q.shape[2], k.shape[2]
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if block_q is None:
-        block_q = _default_block_q(tq)
+        block_q = _default_block_q(tq) if window is None else \
+            window_block(window)
     # dense route: a sequence length no block of whole sublanes
     # divides (natively differentiable either way)
     if _needs_dense_fallback(tq, tk, block_q):
-        return _dense_attention_lse(q, k, v, causal, scale)
+        return _dense_attention_lse(q, k, v, causal, scale, window)
     if interpret is None:
         interpret = default_interpret(q, k, v)
     return _flash_lse(q, k, v, bool(causal), float(scale), int(block_q),
-                      bool(interpret))
+                      bool(interpret), window)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    interpret=None):
+                    interpret=None, window=None):
     """Streaming Pallas attention.
 
-    q: (batch, heads, q_len, dk); k: (batch, heads, kv_len, dk); v:
-    (batch, heads, kv_len, dv), a width of its own (latent attention:
-    keys of 192 over values of 128).  q_len == kv_len is
+    q: (batch, heads, q_len, dk); k: (batch, kv_heads, kv_len, dk); v:
+    (batch, kv_heads, kv_len, dv), a width of its own (latent
+    attention: keys of 192 over values of 128).  kv_heads divides
+    heads: query head i reads key-value head i // (heads // kv_heads),
+    K and V are never repeated, and dK and dV sum over the group in
+    float32 inside the backward kernel.  q_len == kv_len is
     self-attention; q_len != kv_len covers cross-attention and
     KV-cache decode, where causal rows are SUFFIX-aligned to the keys
     (query row i sees keys up to kv_len - q_len + i — the standard
-    decode convention).  Returns (batch, heads, q_len, dv).  On non-TPU
-    backends runs in Pallas interpret mode (slow but correct) unless
-    `interpret` is passed explicitly.
+    decode convention).  `window` (causal self-attention only): row i
+    sees key j iff 0 <= i - j < window; the grids skip the tiles left
+    of the band as they skip those above the diagonal.  Returns
+    (batch, heads, q_len, dv).  On non-TPU backends runs in Pallas
+    interpret mode (slow but correct) unless `interpret` is passed
+    explicitly.
 
     block_q: row-tile edge.  Default (None) auto-scales with the
     sequence — 256 for short T, up to 1024 for long T, where the
-    smaller grid measures 170 -> 117 ms at T=32k (docs/PERF.md).  An
-    explicit value is honored exactly (e.g. to bound VMEM for large
-    head_dim).
+    smaller grid measures 170 -> 117 ms at T=32k (docs/PERF.md) — or,
+    under a window, follows the window (window_block).  An explicit
+    value is honored exactly (e.g. to bound VMEM for large head_dim).
     """
-    _validate_attn_shapes(q, k, v, causal, 'flash_attention')
+    _validate_attn_shapes(q, k, v, causal, 'flash_attention', window)
     tq, tk = q.shape[2], k.shape[2]
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if block_q is None:
-        block_q = _default_block_q(tq)
+        block_q = _default_block_q(tq) if window is None else \
+            window_block(window)
     if _needs_dense_fallback(tq, tk, block_q):
-        from .parallel.ring_attention import full_attention
-        return full_attention(q, k, v, causal=causal, scale=scale)
+        if window is None and q.shape[1] == k.shape[1]:
+            from .parallel.ring_attention import full_attention
+            return full_attention(q, k, v, causal=causal, scale=scale)
+        return _dense_attention_lse(q, k, v, causal, scale, window)[0]
     if interpret is None:
         interpret = default_interpret(q, k, v)
     return _flash(q, k, v, bool(causal), float(scale), int(block_q),
-                  bool(interpret))
+                  bool(interpret), window)
 
 
 # ---------------------------------------------------------------------------

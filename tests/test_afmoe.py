@@ -1,5 +1,5 @@
 """AFMoE's layer kinds (Trinity-Mini's) on the CPU at a small size: the
-sliding window of the blocked attention core, GatedAttention's
+sliding window of the attention core on either path, GatedAttention's
 attributes one at a time, the shares of the expert layer and a whole
 tiny model through Module.bulk_step and fit, against the plain float32
 reference the benchmark compares with (benchmark/reference/afmoe.py,
@@ -124,8 +124,9 @@ def test_windowed_core_against_a_dense_masked_softmax(case, what):
 @pytest.mark.parametrize('window', [40, 41, 4096])
 def test_a_window_that_reaches_every_key_is_no_window(attention_paths,
                                                       window):
-    """The causal result, bit for bit, on the causal path: grouped heads
-    on the blocked core and ungrouped ones on the flash kernels."""
+    """The causal result, bit for bit, on the causal path: grouped and
+    ungrouped heads alike on the flash kernels, and the counter keeps
+    no window."""
     q, k, v = rand(1, 1, 40, 2, 4, 12), rand(2, 1, 40, 2, 12), \
         rand(3, 1, 40, 2, 6)
     close(lm.causal_attention(q, k, v, 0.3, block_q=16, window=window),
@@ -134,24 +135,28 @@ def test_a_window_that_reaches_every_key_is_no_window(attention_paths,
                               window=window),
           lm.causal_attention(q[:, :, :, :1], k, v, 0.3, block_q=8), 0)
     stats = attention_paths()
-    assert (stats['kernel'], stats['blocked']) == (2, 2)
+    assert (stats['kernel'], stats['blocked']) == (4, 0)
     assert {s['window'] for s in stats['shapes']} == {None}
     # two lowerings of each shape, 8 heads and 2
     assert {s['keys_needed'] for s in stats['shapes']} == {
         2 * 8 * 40 * 41 // 2, 2 * 2 * 40 * 41 // 2}
 
 
-def test_a_window_keeps_ungrouped_heads_off_the_kernels(attention_paths):
-    """The flash kernels walk every tile under the diagonal: a window
-    goes to the blocked core whatever the heads, and the counter says
-    which window decided."""
+def test_a_window_takes_ungrouped_heads_to_the_kernels_too(attention_paths):
+    """The flash kernels skip the tiles left of the band: a window goes
+    to them whatever the heads, the counter says which window decided,
+    and the result is the dense masked softmax's."""
     q, k, v = rand(1, 1, 64, 2, 1, 12), rand(2, 1, 64, 2, 12), \
         rand(3, 1, 64, 2, 6)
     lm.causal_attention(q, k, v, 0.3, block_q=16)
-    lm.causal_attention(q, k, v, 0.3, block_q=16, window=24)
+    got = lm.causal_attention(q, k, v, 0.3, block_q=16, window=24)
     shapes = attention_paths()['shapes']
     assert [(s['path'], s['window']) for s in shapes] == [
-        ('blocked', 24), ('kernel', None)]
+        ('kernel', None), ('kernel', 24)]
+    windowed, full = shapes[1], shapes[0]
+    assert windowed['keys_needed'] < full['keys_needed']
+    assert windowed['keys_visited'] < full['keys_visited']
+    close(got[0], dense_attention(q[0], k[0], v[0], 0.3, 24), 1e-5)
 
 
 def positions(t, block, window):
@@ -181,30 +186,38 @@ def test_the_counter_of_positions_visited_and_needed(attention_paths, t,
           ((3, t, kv, group, 16), (3, t, kv, 16), (3, t, kv, 16))))
     (shape,) = attention_paths()['shapes']
     visited, needed = positions(t, block, window)
-    assert shape == dict(path='blocked', heads=kv * group, group=group,
+    # a T that blocks of 8 rows divide takes the kernels, whose square
+    # tiles of `block` are the same whole blocks of keys a block of rows
+    assert shape == dict(path='blocked' if t % 8 else 'kernel',
+                         heads=kv * group, group=group,
                          dk=16, dv=16, t=t, window=window, lowerings=1,
                          keys_visited=3 * kv * group * visited,
                          keys_needed=3 * kv * group * needed)
     assert needed <= visited
     if (t, block) == (8192, 512):
-        # the cell's layers: 1.25 and 1.06 of what the mask lets through
+        # the cell's layers at tiles of 512: 1.25 and 1.06 of what the
+        # mask lets through
         assert round(visited / needed, 2) == (1.25 if window else 1.06)
         assert needed == (14681088 if window else 33558528)
 
 
-def test_a_windowed_layers_work_grows_with_T_and_not_its_square():
-    """The operations of the compiled forward and backward at twice the
-    length: twice with a window, four times without."""
-    def flops(t, window):
-        shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in
-                  ((1, t, 2, 4, 16), (1, t, 2, 16), (1, t, 2, 16))]
-        fn = jax.grad(lambda q, k, v: jnp.sum(lm.causal_attention(
-            q, k, v, 0.25, block_q=64, window=window)), argnums=(0, 1, 2))
-        cost = jax.jit(fn).lower(*shapes).compile().cost_analysis()
-        return (cost[0] if isinstance(cost, (list, tuple)) else cost)['flops']
+def test_a_windowed_layers_work_grows_with_T_and_not_its_square(
+        attention_paths):
+    """The positions the kernels' grids score at twice the length: twice
+    with a window, four times without."""
+    def visited(t, window):
+        jax.eval_shape(
+            lambda q, k, v: lm.causal_attention(q, k, v, 0.25, block_q=64,
+                                                window=window),
+            *(jax.ShapeDtypeStruct(s, jnp.float32) for s in
+              ((1, t, 2, 4, 16), (1, t, 2, 16), (1, t, 2, 16))))
+        (shape,) = attention_paths()['shapes']
+        profiler._ATTENTION.clear()
+        assert shape['path'] == 'kernel'
+        return shape['keys_visited']
 
-    assert flops(1024, 128) / flops(512, 128) < 2.2
-    assert flops(1024, None) / flops(512, None) > 3.5
+    assert visited(1024, 128) / visited(512, 128) < 2.2
+    assert visited(1024, None) / visited(512, None) > 3.5
 
 
 # -- GatedAttention's attributes -------------------------------------------------
@@ -604,7 +617,8 @@ def test_whole_model_two_bulk_steps_against_the_reference(leaf):
 def test_the_embedding_is_scaled_only_with_mup(attention_paths):
     """mup_enabled multiplies the embedding by sqrt(hidden_size); the
     step's lowerings are three windowed layers and one full, all on
-    the blocked core, forward and recomputed."""
+    the flash kernels (one tile holds the sequence), forward and
+    recomputed."""
     def logits(**extra):
         mod, batches, _ = _tiny_module(steps=1, **extra)
         mod.forward(batches[0], is_train=False)
@@ -612,7 +626,7 @@ def test_the_embedding_is_scaled_only_with_mup(attention_paths):
 
     assert np.abs(logits() - logits(mup_enabled=False)).max() > 1e-3
     stats = attention_paths()
-    assert stats['kernel'] == 0
+    assert stats['blocked'] == 0
     by_window = {s['window']: s for s in stats['shapes']}
     assert set(by_window) == {None, 12}
     assert by_window[12]['lowerings'] == 3 * by_window[None]['lowerings']

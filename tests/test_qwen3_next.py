@@ -480,29 +480,27 @@ def _bulk_step_text(mod, batches):
     return texts[0]
 
 
-# sha256 of the tiny model's bulk step program as PR 31 lowered it
-# (jax 0.9.0, the CPU backend, tests/conftest.py's eight devices).  A
-# PR that changes an operator of this model on purpose replaces it; a
-# PR that says it leaves Qwen3-Next's program alone keeps it.
+# sha256 of the tiny model's bulk step program as PR 35 lowered it
+# (jax 0.9.0, the CPU backend, tests/conftest.py's eight devices; PR 35
+# sent GatedAttention's grouped heads to the flash kernels, interpreted
+# here; until then it was PR 31's, on the blocked core).  A PR that
+# changes an operator of this model on purpose replaces it; a PR that
+# says it leaves Qwen3-Next's program alone keeps it.
 STEP_TEXT_SHA256 = (
-    '44e47a3e042ff446eaa779a315bb0b3d0aea53c0a5fa3a24d5341b773211ae06')
+    '5dc7eee402a3267413b0a87a207b4c0eb88ab00f1e135d46d572c77a511cdde9')
 
 
-def test_grouped_heads_keep_the_blocked_core_and_the_program_its_text(
-        monkeypatch):
-    """GatedAttention's 8 query heads a key-value head are not the
-    flash kernel's: every lowering of causal_attention in the whole
-    model's step takes the blocked core, and the step program is, to
-    the byte, the one it was before causal_attention chose a path."""
+def test_grouped_heads_take_the_kernels_and_the_program_keeps_its_text():
+    """GatedAttention's 8 query heads a key-value head are the flash
+    kernels' since PR 35: every lowering of causal_attention in the
+    whole model's step takes them, none the blocked core, and the step
+    program is, to the byte, the one PR 35 lowered."""
     profiler._ATTENTION.clear()
     text = _bulk_step_text(*_tiny_module()[:2])
     stats = profiler.attention_stats()
-    assert stats['kernel'] == 0 and stats['blocked'] > 0
-    assert {(s['group'], s['dk'], s['dv'], s['t'])
-            for s in stats['shapes']} == {(8, 16, 16, SEQ)}
-    monkeypatch.setattr(lm, 'causal_attention',
-                        lm.blocked_causal_attention)
-    assert _bulk_step_text(*_tiny_module()[:2]) == text
+    assert stats['blocked'] == 0 and stats['kernel'] > 0
+    assert {(s['group'], s['dk'], s['dv'], s['t'], s['window'])
+            for s in stats['shapes']} == {(8, 16, 16, SEQ, None)}
     assert hashlib.sha256(text.encode()).hexdigest() == STEP_TEXT_SHA256
 
 

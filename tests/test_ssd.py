@@ -42,6 +42,9 @@ def _mini_ssd_train(num_classes=2):
 
 
 def test_mini_ssd_trains():
+    # Xavier draws from the process's stream, which the tests a worker
+    # ran before have advanced: one draw in some dozens diverges
+    mx.random.seed(0)
     net = _mini_ssd_train()
     mod = mx.mod.Module(net, data_names=('data',), label_names=('label',))
     B = 2

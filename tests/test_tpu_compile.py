@@ -82,37 +82,60 @@ def test_latent_attention_core_compiles_for_the_chip(one_chip, monkeypatch,
     assert ' while(' not in text
 
 
+# (sequences, key-value heads, head width, window) of gated attention's
+# core in the cells: 8 query heads a key-value head at 8,192 tokens
+GATED_CORES = {'trinity-mini-full': (1, 4, 128, None),
+               'trinity-mini-windowed': (1, 4, 128, 2048),
+               'qwen3-next': (2, 2, 256, None)}
+
+
 @pytest.fixture(scope='module')
-def gated_core_memory(one_chip):
-    """Temporaries of the Trinity-Mini cell's attention core, forward
-    and gradient, compiled for the chip: one sequence of `t` tokens, 32
-    query heads over 4 key-value heads of 128, bfloat16."""
+def gated_core(one_chip):
+    """The gradient of a cell's attention core through causal_attention,
+    compiled for the chip at `t` tokens."""
     @functools.lru_cache(maxsize=None)
-    def temporaries(t, window):
+    def compiled(case, t=8192):
+        b, kv, d, window = GATED_CORES[case]
+
         def shape(*s):
             return jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
 
-        args = (shape(1, t, 4, 8, 128), shape(1, t, 4, 128),
-                shape(1, t, 4, 128))
+        args = (shape(b, t, kv, 8, d), shape(b, t, kv, d), shape(b, t, kv, d))
         fn = jax.grad(lambda *a: jnp.sum(lm.causal_attention(
-            *a, 128 ** -0.5, window=window).astype(jnp.float32)),
+            *a, d ** -0.5, window=window).astype(jnp.float32)),
             argnums=(0, 1, 2))
-        compiled = jax.jit(fn).lower(*args).compile()
-        assert 'tpu_custom_call' not in compiled.as_text()
-        return compiled.memory_analysis().temp_size_in_bytes
+        return jax.jit(fn).lower(*args).compile()
 
-    return temporaries
+    return compiled
 
 
-@pytest.mark.parametrize('window', [2048, 512])
-def test_windowed_core_compiles_for_the_chip_in_memory_linear_in_t(
-        gated_core_memory, window):
-    """Grouped heads under a window stay plain XLA (no kernel takes
-    them), and the blocks' bands make the temporaries of a sequence
-    twice as long about twice as large (PR 34: 232 -> 328 MB under a
-    window of 2,048, 42 -> 99 MB under one of 512; 977 MB without):
-    a mask over the causal triangle would make them four times."""
-    short, long = gated_core_memory(4096, window), \
-        gated_core_memory(8192, window)
+@pytest.mark.parametrize('case', sorted(GATED_CORES))
+def test_gated_attention_core_compiles_for_the_chip(gated_core, monkeypatch,
+                                                    case):
+    """Grouped heads, with and without a window, are one forward and
+    one backward flash kernel over the whole batch: Mosaic takes the
+    backward's dQ accumulator of a whole group (8 heads x 8,192 rows in
+    float32: 32 MiB at heads of 128, 64 MiB at 256) beside its tiles,
+    and no loop of XLA blocks is left."""
+    monkeypatch.setattr(pallas_ops, 'default_interpret', lambda *a: False)
+    text = gated_core(case).as_text()
+    assert text.count('tpu_custom_call') == 2
+    assert 'flash_attention_fwd' in text and 'flash_attention_bwd' in text
+    assert ' while(' not in text
+
+
+@pytest.mark.parametrize('case', ['trinity-mini-full',
+                                  'trinity-mini-windowed'])
+def test_gated_core_compiles_for_the_chip_in_memory_linear_in_t(
+        gated_core, monkeypatch, case):
+    """On the kernels no score leaves VMEM: the temporaries of a
+    sequence twice as long are under three times as large (134 -> 353
+    MB) with a window and without: the head-major copies of q, o and
+    their gradients and the rows' float32 sums.  PR 34's blocked core
+    held 328 MB at 8,192 under a window of 2,048 and 977 MB without
+    one, the blocks' scores."""
+    monkeypatch.setattr(pallas_ops, 'default_interpret', lambda *a: False)
+    short, long = (gated_core(case, t).memory_analysis().temp_size_in_bytes
+                   for t in (4096, 8192))
     assert long < 3 * short
-    assert long < gated_core_memory(8192, None)
+    assert long < 400e6
