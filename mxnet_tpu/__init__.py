@@ -7,6 +7,9 @@ KVStore-style distribution over XLA collectives, Gluon-style imperative
 blocks — with compute expressed as pure JAX so whole graphs compile into
 single XLA modules instead of per-op kernel dispatch.
 """
+import time
+_import_start = time.perf_counter()     # the first statement: see import_s
+
 __version__ = '0.1.0'
 
 from . import base
@@ -68,3 +71,7 @@ from . import recordio
 from . import image
 from . import gluon
 from . import test_utils
+
+# seconds this package took to import (profiler.setup_stats reads it):
+# what was imported before it, jax in a process that had it, is not in
+import_s = time.perf_counter() - _import_start

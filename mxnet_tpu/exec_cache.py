@@ -31,17 +31,53 @@ Env knobs (documented in docs/PERF.md):
 Counters (exposed via profiler.exec_cache_stats / profiler.summary):
   hits / misses        signature lookups at bind time
   total_compile_s      wall time spent tracing+compiling XLA programs
+                       (TimedJit: trace, compile and the first run,
+                       undivided)
+and, as jax itself reports them (jax.monitoring; see _JAX_SECONDS and
+_JAX_COUNTS below): trace_s, lower_s, backend_compile_s, cache_load_s,
+persistent_requests, persistent_hits, persistent_misses.
+compile_log() holds the newest backend compiles one by one.
 """
 import os
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 
+import jax.monitoring
 import numpy as np
+
+from . import profiler
 
 _LOCK = threading.RLock()
 _CACHE = OrderedDict()          # signature-scoped key -> cached object
-_STATS = {'hits': 0, 'misses': 0, 'total_compile_s': 0.0}
+# What jax reports of every trace, lowering and backend compile, and of
+# every request to its persistent cache, by the key it is summed under.
+# backend_compile_s is the compiler's time on a miss and the cache's
+# read on a hit (cache_load_s is that read alone, so the difference is
+# the compiler proper); jax counts a persistent miss only where it
+# writes an entry; trace_s counts outermost traces only (a jit traced
+# inside another's trace is in the outer one's time).  jax emits these
+# from its compile path only: no listener runs in a steady step.
+_TRACE_EVENT = '/jax/core/compile/jaxpr_trace_duration'
+_TRACING = threading.local()    # .depth: traces open on this thread
+_JAX_SECONDS = {
+    _TRACE_EVENT: 'trace_s',
+    '/jax/core/compile/jaxpr_to_mlir_module_duration': 'lower_s',
+    '/jax/core/compile/backend_compile_duration': 'backend_compile_s',
+    '/jax/compilation_cache/cache_retrieval_time_sec': 'cache_load_s',
+}
+_JAX_COUNTS = {
+    '/jax/compilation_cache/compile_requests_use_cache':
+        'persistent_requests',
+    '/jax/compilation_cache/cache_hits': 'persistent_hits',
+    '/jax/compilation_cache/cache_misses': 'persistent_misses',
+}
+_STATS = {'hits': 0, 'misses': 0, 'total_compile_s': 0.0,
+          **{k: 0.0 for k in _JAX_SECONDS.values()},
+          **{k: 0 for k in _JAX_COUNTS.values()}}
+# the newest backend compiles: (end on the perf_counter clock, seconds,
+# fun_name, the innermost open profiler span's name or None)
+_COMPILE_LOG = deque(maxlen=64)
 _PERSISTENT_DIR = None          # set once by setup_persistent_cache
 
 # Every env knob whose value is baked into the TRACED program must be
@@ -211,6 +247,52 @@ def stats():
         return dict(_STATS)
 
 
+def _on_jax_event(event, **_):
+    key = _JAX_COUNTS.get(event)
+    if key is not None:
+        with _LOCK:
+            _STATS[key] += 1
+
+
+def _on_jax_scalar(event, _start, **_):
+    """jax marks the start of a trace with a scalar event of the trace's
+    name: the depth of this thread's open traces."""
+    if event == _TRACE_EVENT:
+        _TRACING.depth = getattr(_TRACING, 'depth', 0) + 1
+
+
+def _on_jax_duration(event, seconds, fun_name=None, **_):
+    key = _JAX_SECONDS.get(event)
+    if key is None:
+        return
+    if event == _TRACE_EVENT:
+        # a jit traced inside another's trace reports its own duration,
+        # which the outer one's holds already: count the outermost
+        depth = _TRACING.depth = getattr(_TRACING, 'depth', 1) - 1
+        if depth > 0:
+            return
+    with _LOCK:
+        _STATS[key] += seconds
+        if key == 'backend_compile_s':
+            _COMPILE_LOG.append((time.perf_counter(), seconds, fun_name,
+                                 profiler.open_span()))
+
+
+jax.monitoring.register_event_listener(_on_jax_event)
+jax.monitoring.register_scalar_listener(_on_jax_scalar)
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def compile_log():
+    """The newest 64 backend compiles, oldest first, as (end on the
+    perf_counter clock, seconds, fun_name, span): which program jax
+    handed to the compiler or read from its persistent cache
+    ('jit(multistep)' is the fused train step), for how long, and the
+    innermost profiler span open on that thread then, or None."""
+    with _LOCK:
+        return list(_COMPILE_LOG)
+
+
 # ---------------------------------------------------------------------------
 # serving bucket ladder
 # ---------------------------------------------------------------------------
@@ -374,8 +456,9 @@ def clear(reset_stats=True):
     with _LOCK:
         _CACHE.clear()
         if reset_stats:
-            for k in _STATS:
-                _STATS[k] = 0.0 if k == 'total_compile_s' else 0
+            for k, v in _STATS.items():
+                _STATS[k] = type(v)()
+            _COMPILE_LOG.clear()
 
 
 def size():

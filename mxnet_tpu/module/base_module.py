@@ -36,6 +36,20 @@ def _fire(callbacks, *cb_args):
         cb(*cb_args)
 
 
+def _wait_for(outputs):
+    """One wait for all of a step's outputs (NDArrays): fit's
+    'fit.wait' span, so that the metric fold's own time is apart.  The
+    fold reads them on the host next, so their copies are started
+    before the wait, behind the step on the device, as the fold's own
+    read started them when it was what waited."""
+    import jax
+    arrays = [getattr(o, '_data', o) for o in outputs]
+    for a in arrays:
+        if isinstance(a, jax.Array):
+            a.copy_to_host_async()
+    jax.block_until_ready(arrays)
+
+
 def _trim_pad(arrays, pad):
     """Drop the trailing `pad` rows that a padded final batch carries."""
     if not pad:
@@ -314,7 +328,9 @@ class BaseModule:
         Spans: each pass of the per-step loop is one 'fit.step' (with
         the iterator's 'io.next' it tiles the loop); inside it
         'fit.metric' is the metric fold, which waits for the step's
-        outputs, and 'fit.callback' the user's batch_end_callback.
+        outputs ('fit.wait' inside it is that wait alone, so the
+        fold's self time is the fold's own work), and 'fit.callback'
+        the user's batch_end_callback.
 
         Overlapped metric pipeline: XLA dispatch is async, but the
         reference loop's per-batch `update_metric` materializes the
@@ -351,6 +367,8 @@ class BaseModule:
         def _fold_one():
             labels, preds, ep, nb = pending.popleft()
             with profiler.scope('fit.metric', 'fit') as fold:
+                with profiler.scope('fit.wait', 'fit'):
+                    _wait_for(preds.values())
                 eval_metric.update_dict(labels, preds)
             profiler.add_overlap_stats(
                 deferred_metric_folds=1,
@@ -405,6 +423,8 @@ class BaseModule:
                             if ahead else None
                         if snap is None:
                             with profiler.scope('fit.metric', 'fit'):
+                                with profiler.scope('fit.wait', 'fit'):
+                                    _wait_for(self.get_outputs())
                                 self.update_metric(eval_metric,
                                                    data_batch.label)
                         if monitor is not None:

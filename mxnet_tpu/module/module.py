@@ -151,6 +151,14 @@ class Module(BaseModule):
         if self.params_initialized and not force_init:
             return
         assert self.binded, 'call bind before initializing the parameters'
+        with profiler.scope('module.init_params', 'setup'):
+            self._init_params(initializer, arg_params, aux_params,
+                              allow_missing, allow_extra)
+
+    def _init_params(self, initializer, arg_params, aux_params,
+                     allow_missing, allow_extra):
+        """init_params' work.  set_params shares it without the span:
+        weights written again later (fit's epoch end) are no set-up."""
         if self._arg_params is None:
             self._arg_params = {
                 name: nd.zeros(arr.shape, dtype=arr.dtype)
@@ -216,14 +224,13 @@ class Module(BaseModule):
 
     def set_params(self, arg_params, aux_params, allow_missing=False,
                    force_init=True, allow_extra=False):
-        if not allow_missing:
-            self.init_params(initializer=None, arg_params=arg_params,
-                             aux_params=aux_params,
-                             allow_missing=allow_missing,
-                             force_init=force_init,
-                             allow_extra=allow_extra)
-            return
         if self.params_initialized and not force_init:
+            return
+        if not allow_missing:
+            assert self.binded, \
+                'call bind before initializing the parameters'
+            self._init_params(None, arg_params, aux_params, False,
+                              allow_extra)
             return
         if not allow_extra:
             self._check_extra_params(arg_params, aux_params)
@@ -243,6 +250,12 @@ class Module(BaseModule):
         if self.binded:
             self.logger.warning('Already binded, ignoring bind()')
             return
+        with profiler.scope('module.bind', 'setup'):
+            self._bind(data_shapes, label_shapes, for_training,
+                       inputs_need_grad, shared_module, grad_req)
+
+    def _bind(self, data_shapes, label_shapes, for_training,
+              inputs_need_grad, shared_module, grad_req):
         self.for_training = for_training
         self.inputs_need_grad = inputs_need_grad
         self.binded = True
@@ -281,6 +294,11 @@ class Module(BaseModule):
             self.logger.warning('optimizer already initialized, '
                                 'ignoring...')
             return
+        with profiler.scope('module.init_optimizer', 'setup'):
+            self._init_optimizer(kvstore, optimizer, optimizer_params,
+                                 zero)
+
+    def _init_optimizer(self, kvstore, optimizer, optimizer_params, zero):
         (kvstore, update_on_kvstore) = model_mod._create_kvstore(
             kvstore, len(self._context), self._arg_params)
         batch_size = self._exec_group.batch_size

@@ -1079,13 +1079,69 @@ def _collect_xla_lanes():
 
 def exec_cache_stats():
     """Executor compiled-program cache counters: exec_cache_hits /
-    exec_cache_misses (signature lookups at bind) and total_compile_s
-    (wall time spent tracing+compiling XLA programs this process)."""
+    exec_cache_misses (signature lookups at bind), total_compile_s
+    (wall time spent tracing+compiling XLA programs this process), and
+    under exec_cache's own names what jax reported of its traces,
+    lowerings, backend compiles and persistent cache."""
     from . import exec_cache
     st = exec_cache.stats()
-    return {'exec_cache_hits': st['hits'],
-            'exec_cache_misses': st['misses'],
-            'total_compile_s': st['total_compile_s']}
+    st['exec_cache_hits'] = st.pop('hits')
+    st['exec_cache_misses'] = st.pop('misses')
+    return st
+
+
+def setup_stats():
+    """Where the time before the first steady step went, assembled from
+    what the program keeps anyway (this call holds no state): import_s,
+    the package's own import; the summed seconds and the count of the
+    'module.bind', 'module.init_params' and 'module.init_optimizer'
+    spans in the ring (bind_s, bind_n, ...); first_step_s, the oldest
+    'module.bulk_step' span or, with none, the oldest 'fit.step' (the
+    one that traced, lowered and compiled or loaded the step program;
+    None with neither, or once that ring has wrapped); and exec_cache's
+    figures from jax (trace_s ... persistent_misses)."""
+    from . import exec_cache, import_s
+    out = {'import_s': import_s}
+    for name in ('bind', 'init_params', 'init_optimizer'):
+        ring = _RING.get('module.' + name)
+        spans = ring.copy() if ring else ()     # copy() is atomic
+        out[name + '_s'] = sum(s[1] - s[0] for s in spans)
+        out[name + '_n'] = len(spans)
+    first = span_head('module.bulk_step' if _RING.get('module.bulk_step')
+                      else 'fit.step', 1)
+    out['first_step_s'] = first[0][1] - first[0][0] if first else None
+    st = exec_cache.stats()
+    for key in ('trace_s', 'lower_s', 'backend_compile_s', 'cache_load_s',
+                'persistent_requests', 'persistent_hits',
+                'persistent_misses'):
+        out[key] = st[key]
+    return out
+
+
+def _setup_lines():
+    """summary()'s set-up block: setup_stats() and every backend compile
+    of over a second with the span it ran under."""
+    from . import exec_cache
+    st = setup_stats()
+    first = st['first_step_s']
+    lines = ['  set-up: import_s=%.3f bind_s=%.3f (%d) '
+             'init_params_s=%.3f (%d) init_optimizer_s=%.3f (%d) '
+             'first_step_s=%s'
+             % (st['import_s'], st['bind_s'], st['bind_n'],
+                st['init_params_s'], st['init_params_n'],
+                st['init_optimizer_s'], st['init_optimizer_n'],
+                'none' if first is None else '%.3f' % first),
+             '    trace_s=%.3f lower_s=%.3f backend_compile_s=%.3f '
+             'cache_load_s=%.3f persistent_requests=%d '
+             'persistent_hits=%d persistent_misses=%d'
+             % (st['trace_s'], st['lower_s'], st['backend_compile_s'],
+                st['cache_load_s'], st['persistent_requests'],
+                st['persistent_hits'], st['persistent_misses'])]
+    for _end, seconds, fun_name, span in exec_cache.compile_log():
+        if seconds > 1.0:
+            lines.append('    compiled %s in %.3f s under %s'
+                         % (fun_name, seconds, span or 'no span'))
+    return lines
 
 
 def summary(print_out=True):
@@ -1105,6 +1161,7 @@ def summary(print_out=True):
                  'total_compile_s=%.3f'
                  % (st['exec_cache_hits'], st['exec_cache_misses'],
                     st['total_compile_s']))
+    lines.extend(_setup_lines())
     cm = comm_stats()
     lines.append('  bytes_reduce_scattered=%d bytes_all_gathered=%d '
                  'optimizer_state_bytes_per_device=%d'
@@ -1380,6 +1437,8 @@ def clear():
 # the benchmark runs, with the layer of each as PERF.md section 3 and
 # BENCHMARK.json name it.  In a jax profiler trace each appears as
 # 'mx.' + name on the host plane.
+_SETUP_LAYER = \
+    'set-up (Module.bind, init_params, init_optimizer, package import)'
 SPANS = {
     'fit.step': 'entry (Module.fit, _fit_epochs)',
     'fit.metric': 'metric fold (metric.EvalMetric.update_dict)',
@@ -1394,6 +1453,10 @@ SPANS = {
     'module.bulk_stack': 'entry (Module.bulk_step)',
     'executor.dispatch':
         'compiled step (executor.make_fused_multistep, fused train step)',
+    'fit.wait': 'metric fold (metric.EvalMetric.update_dict)',
+    'module.bind': _SETUP_LAYER,
+    'module.init_params': _SETUP_LAYER,
+    'module.init_optimizer': _SETUP_LAYER,
 }
 
 _RING_LEN = 4096
@@ -1470,6 +1533,22 @@ def span_tail(name, n):
         return None
     tail = list(ring.copy())    # copy() is atomic under the GIL
     return [s[:3] for s in tail[len(tail) - n:]]
+
+
+def span_head(name, n):
+    """The oldest n completed spans named `name`, oldest first, as
+    span_tail gives them, or None when fewer than n exist or the ring
+    is full: its oldest may have been dropped by then."""
+    ring = _RING.get(name)
+    if ring is None or len(ring) < n or len(ring) == ring.maxlen:
+        return None
+    return [s[:3] for s in list(ring.copy())[:n]]
+
+
+def open_span():
+    """The name of the innermost span open on this thread, or None."""
+    stack = getattr(_OPEN, 'stack', None)
+    return stack[-1].name if stack else None
 
 
 if os.environ.get('MXNET_PROFILER_AUTOSTART', '0') == '1':

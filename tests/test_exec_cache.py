@@ -9,6 +9,7 @@ PrefetchingIter worker-thread lifecycle."""
 import gc
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -179,12 +180,156 @@ def test_exec_cache_disabled(monkeypatch):
     assert ex1.forward()[0].shape == (8, 3)
 
 
+JAX_KEYS = {'trace_s', 'lower_s', 'backend_compile_s', 'cache_load_s',
+            'persistent_requests', 'persistent_hits', 'persistent_misses'}
+
+
 def test_profiler_counters_exposed():
     st = profiler.exec_cache_stats()
     assert set(st) == {'exec_cache_hits', 'exec_cache_misses',
-                       'total_compile_s'}
+                       'total_compile_s'} | JAX_KEYS
+    assert set(exec_cache.stats()) == {'hits', 'misses',
+                                       'total_compile_s'} | JAX_KEYS
     text = profiler.summary(print_out=False)
     assert 'exec_cache_hits=' in text and 'total_compile_s=' in text
+
+
+# ---------------------------------------------------------------------------
+# what jax reports of its compile path, folded into stats()
+# ---------------------------------------------------------------------------
+
+def _fresh_jit(scale):
+    """A jitted function jax has not seen (the closure is new), whose
+    program differs by `scale` from any other in the persistent cache."""
+    import jax
+
+    def setup_probe(x):
+        return (x * scale).sum()
+
+    return jax.jit(setup_probe)
+
+
+def test_listeners_fold_a_fresh_jit_and_nothing_of_its_second_call():
+    import jax.numpy as jnp
+    f, x = _fresh_jit(1.5), jnp.arange(7.0)
+    st0 = exec_cache.stats()
+    f(x).block_until_ready()
+    st1 = exec_cache.stats()
+    for key in ('trace_s', 'lower_s', 'backend_compile_s'):
+        assert st1[key] > st0[key], key
+    for key in ('hits', 'misses', 'total_compile_s'):   # not jax's
+        assert st1[key] == st0[key], key
+    f(x).block_until_ready()
+    assert exec_cache.stats() == st1
+
+
+def test_a_trace_inside_a_trace_is_counted_once():
+    """jax reports the trace of a jit called inside another's trace on
+    its own and inside the outer one's duration: trace_s holds the
+    outermost only, so it never passes the wall time."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner(x):
+        time.sleep(0.1)         # runs while inner is traced
+        return x * 2.0
+
+    def outer(x):
+        return inner(x) + inner(x + 1.0)    # traced once: jax caches it
+
+    st0 = exec_cache.stats()
+    t0 = time.perf_counter()
+    jax.jit(outer)(jnp.arange(3.0)).block_until_ready()
+    wall = time.perf_counter() - t0
+    traced = exec_cache.stats()['trace_s'] - st0['trace_s']
+    assert 0.1 <= traced < 0.2 and traced < wall
+    assert exec_cache._TRACING.depth == 0
+
+
+def test_listeners_are_registered_once():
+    from jax._src import monitoring
+    assert monitoring.get_event_listeners().count(
+        exec_cache._on_jax_event) == 1
+    assert monitoring.get_event_duration_listeners().count(
+        exec_cache._on_jax_duration) == 1
+    assert monitoring.get_scalar_listeners().count(
+        exec_cache._on_jax_scalar) == 1
+
+
+def test_compile_log_names_the_function_and_the_open_span():
+    import jax.numpy as jnp
+    x = jnp.arange(5.0)
+    t0 = time.perf_counter()
+    with profiler.scope('t.outer'):
+        with profiler.scope('t.compiling'):
+            _fresh_jit(2.5)(x).block_until_ready()
+    _fresh_jit(3.5)(x).block_until_ready()
+    t1 = time.perf_counter()
+    inside, outside = [e for e in exec_cache.compile_log()
+                       if e[2] == 'jit(setup_probe)'][-2:]
+    assert inside[3] == 't.compiling' and outside[3] is None
+    for end, seconds, _, _ in (inside, outside):
+        assert t0 < end < t1 and 0 < seconds < t1 - t0
+    assert inside[0] < outside[0]
+
+
+def test_compile_log_is_bounded_and_cleared_with_the_stats():
+    for i in range(70):
+        exec_cache._on_jax_duration(
+            '/jax/core/compile/backend_compile_duration', 0.25,
+            fun_name='f%d' % i)
+    log = exec_cache.compile_log()
+    assert len(log) == 64
+    assert [e[2] for e in log[-2:]] == ['f68', 'f69']
+    assert exec_cache.stats()['backend_compile_s'] >= 70 * 0.25
+    exec_cache._on_jax_duration('/jax/some/other_duration', 9.0)
+    exec_cache._on_jax_event('/jax/some/other_event')
+    assert len(exec_cache.compile_log()) == 64
+    exec_cache.clear()
+    assert exec_cache.compile_log() == []
+    st = exec_cache.stats()
+    assert all(st[k] == 0 for k in JAX_KEYS)
+    assert isinstance(st['trace_s'], float)
+    assert isinstance(st['persistent_hits'], int)
+
+
+def test_persistent_cache_hit_counts_and_its_read_is_cache_load_s(
+        tmp_path):
+    """A second compile of the same program, as a second process would
+    make it (jax's in-memory caches cleared): the persistent cache
+    answers, and its read is both backend_compile_s and cache_load_s."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src import compilation_cache as cc
+    jax.config.update('jax_compilation_cache_dir', str(tmp_path))
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
+    jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
+    cc.reset_cache()
+    x = jnp.arange(9.0)
+    try:
+        st0 = exec_cache.stats()
+        _fresh_jit(4.5)(x).block_until_ready()
+        st1 = exec_cache.stats()
+        assert st1['persistent_requests'] == st0['persistent_requests'] + 1
+        assert st1['persistent_misses'] == st0['persistent_misses'] + 1
+        assert st1['persistent_hits'] == st0['persistent_hits']
+        assert st1['cache_load_s'] == st0['cache_load_s']
+        _fresh_jit(4.5)(x).block_until_ready()
+        st2 = exec_cache.stats()
+        assert st2['persistent_requests'] == st1['persistent_requests'] + 1
+        assert st2['persistent_hits'] == st1['persistent_hits'] + 1
+        assert st2['persistent_misses'] == st1['persistent_misses']
+        load = st2['cache_load_s'] - st1['cache_load_s']
+        assert load > 0
+        # the backend compile of a hit is the cache's read and a little
+        assert st2['backend_compile_s'] - st1['backend_compile_s'] >= load
+    finally:
+        jax.config.update('jax_compilation_cache_dir', None)
+        jax.config.update('jax_persistent_cache_min_compile_time_secs',
+                          1.0)
+        jax.config.update('jax_persistent_cache_min_entry_size_bytes', 0)
+        cc.reset_cache()
 
 
 def test_persistent_cache_dir_from_jax_env(tmp_path, monkeypatch):

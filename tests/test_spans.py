@@ -7,6 +7,7 @@ import os
 import re
 import threading
 import time
+from collections import deque
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +91,40 @@ def test_ring_is_bounded():
     assert profiler.span_tail('t.many', profiler._RING_LEN + 1) is None
     assert len(profiler.span_tail('t.many', profiler._RING_LEN)) == \
         profiler._RING_LEN
+
+
+def test_span_head_is_the_oldest_until_the_ring_is_full():
+    profiler.clear()
+    assert profiler.span_head('t.head', 1) is None
+    for _ in range(5):
+        with profiler.scope('t.head'):
+            pass
+    ring = _ring('t.head')
+    assert profiler.span_head('t.head', 1) == [ring[0][:3]]
+    assert profiler.span_head('t.head', 3) == [r[:3] for r in ring[:3]]
+    assert profiler.span_head('t.head', 6) is None
+    assert profiler.span_head('t.head', 1) != profiler.span_tail('t.head', 1)
+    for _ in range(profiler._RING_LEN):     # the first five are dropped
+        with profiler.scope('t.head'):
+            pass
+    assert profiler.span_head('t.head', 1) is None
+    assert len(profiler.span_tail('t.head', 1)) == 1
+
+
+def test_open_span_is_the_innermost_of_this_thread():
+    assert profiler.open_span() is None
+    with profiler.scope('t.outer'):
+        assert profiler.open_span() == 't.outer'
+        with profiler.scope('t.inner'):
+            assert profiler.open_span() == 't.inner'
+            seen = []
+            worker = threading.Thread(
+                target=lambda: seen.append(profiler.open_span()))
+            worker.start()
+            worker.join(10)
+            assert seen == [None]
+        assert profiler.open_span() == 't.outer'
+    assert profiler.open_span() is None
 
 
 def test_threads_do_not_share_a_stack():
@@ -222,6 +257,77 @@ def _bound_module():
     return mod
 
 
+@pytest.mark.parametrize('name', ['module.bind', 'module.init_params',
+                                  'module.init_optimizer'])
+def test_set_up_by_hand_leaves_one_span_each(name):
+    profiler.clear()
+    mod = _bound_module()
+    (start, end, self_s, parent, step), = _ring(name)
+    assert parent is None and step is None and end >= start
+    assert profiler.SPANS[name].startswith('set-up (')
+    # what returns at once opens none: a second bind() that is ignored,
+    # weights and an optimizer that are there already
+    mod.bind(data_shapes=[('data', (BATCH, DIM))],
+             label_shapes=[('softmax_label', (BATCH,))])
+    mod.init_params()
+    mod.init_optimizer()
+    assert len(_ring(name)) == 1
+    # set_params writes weights without being set-up
+    mod.set_params(*mod.get_params())
+    assert len(_ring('module.init_params')) == 1
+    mod.init_params(force_init=True)
+    assert len(_ring('module.init_params')) == 2
+
+
+def test_setup_stats_has_every_key():
+    profiler.clear()
+    empty = profiler.setup_stats()
+    assert set(empty) == {
+        'import_s', 'bind_s', 'bind_n', 'init_params_s', 'init_params_n',
+        'init_optimizer_s', 'init_optimizer_n', 'first_step_s', 'trace_s',
+        'lower_s', 'backend_compile_s', 'cache_load_s',
+        'persistent_requests', 'persistent_hits', 'persistent_misses'}
+    assert empty['import_s'] == mx.import_s > 0
+    assert empty['first_step_s'] is None
+    assert (empty['bind_s'], empty['bind_n']) == (0, 0)
+    # from a ring made by hand: a fit's set-up, then its steps
+    for name, spans in (
+            ('module.bind', [(0.0, 2.0)]),
+            ('module.init_params', [(2.0, 2.5), (9.0, 9.25)]),
+            ('module.init_optimizer', [(2.5, 2.75)]),
+            ('fit.step', [(3.0, 7.0), (7.0, 7.5)])):
+        profiler._RING[name] = deque(
+            (t0, t1, t1 - t0, None, None) for t0, t1 in spans)
+    st = profiler.setup_stats()
+    assert (st['bind_s'], st['bind_n']) == (2.0, 1)
+    assert (st['init_params_s'], st['init_params_n']) == (0.75, 2)
+    assert (st['init_optimizer_s'], st['init_optimizer_n']) == (0.25, 1)
+    assert st['first_step_s'] == 4.0
+    # a bulk dispatch is the first step wherever there is one
+    profiler._RING['module.bulk_step'] = deque(
+        [(8.0, 8.5, 0.5, None, None), (8.5, 8.6, 0.1, None, None)],
+        maxlen=profiler._RING_LEN)
+    assert profiler.setup_stats()['first_step_s'] == 0.5
+    text = profiler.summary(print_out=False)
+    assert 'set-up: import_s=' in text and 'first_step_s=0.500' in text
+    assert 'persistent_hits=' in text
+    profiler.clear()
+
+
+def test_summary_names_compiles_over_a_second_with_their_span(
+        monkeypatch):
+    from mxnet_tpu import exec_cache
+    monkeypatch.setattr(exec_cache, '_COMPILE_LOG', deque([
+        (10.0, 48.25, 'jit(multistep)', 'module.bulk_step'),
+        (11.0, 0.5, 'jit(iota)', 'module.bind'),
+        (12.0, 1.5, 'jit(zeros)', None)]))
+    text = profiler.summary(print_out=False)
+    assert 'compiled jit(multistep) in 48.250 s under module.bulk_step' \
+        in text
+    assert 'compiled jit(zeros) in 1.500 s under no span' in text
+    assert 'jit(iota)' not in text
+
+
 @pytest.fixture(scope='module')
 def bulk_run():
     """Two Module.bulk_step dispatches of K=2 staged batches."""
@@ -241,8 +347,12 @@ def bulk_run():
     ('fit.step', STEPS), ('io.next', STEPS), ('executor.dispatch', STEPS),
     ('io.stage', STEPS), ('module.load_batch', STEPS),
     ('module.host_prep', STEPS), ('fit.metric', STEPS),
-    ('fit.callback', STEPS),
+    ('fit.callback', STEPS), ('fit.wait', STEPS),
     ('io.host_batch', STEPS + 1),    # the last finds the iterator empty
+    # set-up, once a fit: the epoch's end writes the weights again
+    # through set_params, which is no 'module.init_params'
+    ('module.bind', 1), ('module.init_params', 1),
+    ('module.init_optimizer', 1),
 ])
 def test_fit_steps_leave_one_span_each(fit_run, name, count):
     assert name in profiler.SPANS
@@ -256,12 +366,43 @@ def test_fit_steps_leave_one_span_each(fit_run, name, count):
     ('io.stage', 'io.next'), ('module.load_batch', 'fit.step'),
     ('module.host_prep', 'fit.step'), ('executor.dispatch', 'fit.step'),
     ('fit.metric', 'fit.step'), ('fit.callback', 'fit.step'),
+    ('fit.wait', 'fit.metric'), ('module.bind', None),
+    ('module.init_params', None), ('module.init_optimizer', None),
 ])
 def test_fit_spans_know_their_parent_and_step(fit_run, name, parent):
     records = fit_run['ring'][name]
     assert {r[3] for r in records} == {parent}
-    if parent == 'fit.step' or name == 'fit.step':
+    if parent in ('fit.step', 'fit.metric') or name == 'fit.step':
         assert [r[4] for r in records] == [1, 2, 3]
+
+
+def test_the_fold_waits_once_before_it_folds(fit_run):
+    """'fit.wait' is the first thing inside each 'fit.metric', and the
+    fold's self time is what the wait leaves of it."""
+    for fold, wait in zip(fit_run['ring']['fit.metric'],
+                          fit_run['ring']['fit.wait']):
+        assert fold[0] <= wait[0] <= wait[1] <= fold[1]
+        assert fold[2] == pytest.approx(
+            (fold[1] - fold[0]) - (wait[1] - wait[0]), abs=1e-9)
+
+
+def test_a_deferred_fold_waits_inside_its_span_too(monkeypatch):
+    """With no batch_end_callback the folds run a step late
+    (_fold_one): the same two spans, the wait inside the fold."""
+    monkeypatch.delenv('MXNET_TPU_TRAIN_STEP_AHEAD', raising=False)
+    rng = np.random.RandomState(3)
+    x = rng.rand(STEPS * BATCH, DIM).astype(np.float32)
+    y = rng.randint(0, 2, STEPS * BATCH).astype(np.float32)
+    mod = mx.mod.Module(_net(), context=mx.cpu(0))
+    profiler.clear()
+    mod.fit(mx.io.NDArrayIter(x, y, batch_size=BATCH,
+                              label_name='softmax_label'),
+            num_epoch=1, optimizer='sgd',
+            optimizer_params={'learning_rate': 0.1})
+    assert profiler.overlap_stats()['overlap_deferred_metric_folds'] == \
+        STEPS
+    assert [r[3] for r in _ring('fit.wait')] == ['fit.metric'] * STEPS
+    assert len(_ring('fit.metric')) == STEPS
 
 
 def test_top_level_spans_tile_the_fit_loop(fit_run):
