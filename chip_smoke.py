@@ -9,7 +9,7 @@ seed) through the entry points a user calls — `Module.fit` and
 kernels at four lengths and at the latent-attention cell's head shape
 (32 heads, keys of 192 over values of 128, T = 8,192, against dense
 attention) and the gated delta rule's kernels at one block
-of the language model's cell (against the XLA loop they replaced), then,
+of the language model's cell (against the XLA code they replaced), then,
 on a host with four chips, runs the same network data-parallel over
 them.  It fails (non-zero exit, no result
 line) when JAX finds no TPU, when any phase raises, and when run
@@ -350,19 +350,81 @@ def phase_b(lengths=(2048, 12288, 16384, 32768), parity_at=2048, bh=8,
 
 
 # ---------------------------------------------------------------------------
-# Phase D — the gated delta rule's kernels compile, and agree with the
-# loop they replaced
+# Phase D — the gated delta rule's kernels compile, and agree with the XLA
+# code they replaced
 # ---------------------------------------------------------------------------
 
-def scan_delta_rule(q, k, v, g, beta, chunk=64):
-    """The chunk loop as a lax.scan of XLA operations, as ops/lm.py had
-    it before the kernels (PR 29): kept here, and only here, as what
-    phase D compares the kernels with.  T a whole number of chunks."""
+def xla_chunk_local(q, k, v, g, beta):
+    """The half of the rule that stays inside a chunk as batched XLA
+    over every chunk at once, as ops/lm.py had it before the kernels
+    (PR 36): kept here, and only here, as what phase D compares
+    pallas_ops.delta_rule_local and its backward with.  q, k (...,
+    chunks, C, dk), v (..., chunks, C, dv), g and beta (..., chunks,
+    C).  Returns u, w, intra, q_in, k_out of every chunk and gamma."""
     from jax import lax
-    from mxnet_tpu.ops import lm
+    chunk = q.shape[-2]
+    g = jnp.cumsum(g, axis=-1)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(lower, g[..., :, None] - g[..., None, :],
+                              -jnp.inf))
+    k_beta = k * beta[..., None]
+    a = jnp.where(jnp.tril(lower, -1), jnp.einsum(
+        '...ik,...jk->...ij', k_beta, k) * decay, 0.0)
+    # (I + a)^-1 = (I - a)(I + a^2)(I + a^4)...: a is nilpotent
+    eye = jnp.eye(chunk, dtype=a.dtype)
+    inv, power = eye - a, a
+    for _ in range(max(0, (chunk - 1).bit_length() - 1)):
+        power = jnp.matmul(power, power, precision=lax.Precision.HIGHEST)
+        inv = jnp.matmul(inv, eye + power, precision=lax.Precision.HIGHEST)
+    u = jnp.matmul(inv, v * beta[..., None])
+    w = jnp.matmul(inv, k_beta * jnp.exp(g)[..., None])
+    intra = jnp.einsum('...ik,...jk->...ij', q, k) * decay
+    q_in = q * jnp.exp(g)[..., None]
+    g_last = g[..., -1]
+    k_out = k * jnp.exp(g_last[..., None] - g)[..., None]
+    return u, w, intra, q_in, k_out, jnp.exp(g_last)
+
+
+def _chunks(xs, chunk):
+    """(B, H, T, ...) -> (B * H, T / chunk, chunk, ...) of each."""
+    return [x.reshape((-1, x.shape[2] // chunk, chunk) + x.shape[3:])
+            for x in xs]
+
+
+@jax.custom_vjp
+def xla_local_delta_rule(q, k, v, g, beta):
+    """The rule as PR 30 to 36 ran it: the chunk-local half in XLA,
+    differentiated by jax.vjp, around the loop's three kernels."""
+    from mxnet_tpu import pallas_ops
+    local = xla_chunk_local(*_chunks((q, k, v, g, beta), 64))
+    return pallas_ops.delta_rule_chunks(*local).reshape(v.shape)
+
+
+def _xla_local_fwd(q, k, v, g, beta):
+    return xla_local_delta_rule(q, k, v, g, beta), (q, k, v, g, beta)
+
+
+def _xla_local_bwd(inputs, do):
+    from mxnet_tpu import pallas_ops
+    local, local_vjp = jax.vjp(xla_chunk_local, *_chunks(inputs, 64))
+    u, w, intra, q_in, k_out, gamma = local
+    s0, v_new = pallas_ops.delta_rule_states(u, w, k_out, gamma)
+    grads = pallas_ops.delta_rule_chunks_bwd(
+        do.reshape(u.shape), w, intra, q_in, k_out, gamma, s0, v_new)
+    return tuple(d.reshape(x.shape)
+                 for d, x in zip(local_vjp(grads), inputs))
+
+
+xla_local_delta_rule.defvjp(_xla_local_fwd, _xla_local_bwd)
+
+
+def scan_delta_rule(q, k, v, g, beta, chunk=64):
+    """The whole rule in XLA, the chunk loop a lax.scan, as ops/lm.py
+    had it before any kernel (PR 29).  T a whole number of chunks."""
+    from jax import lax
     bsz, h, t, dk = q.shape
     nc = t // chunk
-    u, w, intra, q_in, k_out, gamma = lm.chunk_local(*(
+    u, w, intra, q_in, k_out, gamma = xla_chunk_local(*(
         a.reshape((bsz, h, nc, chunk) + a.shape[3:])
         for a in (q, k, v, g, beta)))
 
@@ -382,7 +444,9 @@ def scan_delta_rule(q, k, v, g, beta, chunk=64):
 
 def phase_d(shape=(1, 8, 8192, 128), calls=5, expect_custom_call=True):
     """Forward and gradient of chunk_gated_delta_rule at one block of
-    the language model's cell, against the scan above, to 1e-3 of each
+    the language model's cell (five kernels), against the chunk-local
+    half in XLA around the loop's kernels (what the kernels of the
+    local half replaced) and against the scan above, to 1e-3 of each
     tensor's norm."""
     from mxnet_tpu.ops import lm
     bsz, h, t, d = shape
@@ -409,9 +473,11 @@ def phase_d(shape=(1, 8, 8192, 128), calls=5, expect_custom_call=True):
 
     result, outs = {}, {}
     for name, fn, kernels in (
-            # the gradient alone needs no o: the states again and the
-            # loop backward
-            ('kernel', lm.chunk_gated_delta_rule, (1, 2)),
+            # forward: the local make and the loop; the gradient alone
+            # needs no o: the local make and the states again, the
+            # loop backward, the local half's backward
+            ('kernel', lm.chunk_gated_delta_rule, (2, 4)),
+            ('xla_local', xla_local_delta_rule, (1, 2)),
             ('scan', scan_delta_rule, (0, 0))):
         o, fwd_s, fwd_ms = timed(fn, kernels[0])
         grads, bwd_s, bwd_ms = timed(grad_of(fn), kernels[1])
@@ -422,15 +488,17 @@ def phase_d(shape=(1, 8, 8192, 128), calls=5, expect_custom_call=True):
         result[name] = {'forward_ms': round(fwd_ms, 2),
                         'forward_backward_ms': round(bwd_ms, 2)}
     worst = 0.0
-    for name, a, b in zip(('o', 'dq', 'dk', 'dv', 'dg', 'dbeta'),
-                          outs['kernel'], outs['scan']):
-        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-        assert np.isfinite(a).all(), 'delta rule: %s not finite' % name
-        err = np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30)
-        assert err < 1e-3, 'delta rule: %s differs from the scan by ' \
-            '%.3g of its norm' % (name, err)
-        worst = max(worst, err)
-    log('phase D: kernels against the scan, worst %.2g of a norm' % worst)
+    for other in ('xla_local', 'scan'):
+        for name, a, b in zip(('o', 'dq', 'dk', 'dv', 'dg', 'dbeta'),
+                              outs['kernel'], outs[other]):
+            a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+            assert np.isfinite(a).all(), 'delta rule: %s not finite' % name
+            err = np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30)
+            assert err < 1e-3, 'delta rule: %s differs from %s by %.3g ' \
+                'of its norm' % (name, other, err)
+            worst = max(worst, err)
+    log('phase D: kernels against the XLA local half and the scan, worst '
+        '%.2g of a norm' % worst)
     return result
 
 

@@ -7,8 +7,9 @@ operators that look along a sequence carry `seq_len` as an attribute and
 fold the rows to `(N / seq_len, seq_len, ...)` themselves.  All are pure
 JAX functions differentiated by jax.vjp inside the one compiled step,
 like every other operator of the registry, and plain XLA but for two
-things under custom gradient rules: the loop over GatedDeltaRule's
-chunks (three Pallas kernels, pallas_ops.delta_rule_*) and the
+things under custom gradient rules: GatedDeltaRule's core (five Pallas
+kernels, pallas_ops.delta_rule_*: a chunk's own system and the loop
+over the chunks, each forward and backward) and the
 attention core (pallas_ops.flash_attention's kernels, forward and
 backward: grouped heads and a sliding window inside them).
 
@@ -29,9 +30,9 @@ backward: grouped heads and a sliding window inside them).
                    they tile, else the blocks)
   CausalConv1D     depthwise causal convolution along the sequence
   GatedDeltaRule   the gated delta rule in chunks (WY form): a unit
-                   lower triangular solve inside a chunk (XLA, all
-                   chunks at once), the state carried between chunks
-                   in VMEM by a kernel, forward and backward
+                   lower triangular solve inside a chunk and the state
+                   carried between chunks, both in VMEM by kernels,
+                   forward and backward (the gradients by rule)
   SparseMoE        top-k routing over all experts (softmax scores, or
                    sigmoid scores with a selection bias and a scaling
                    factor), the held experts' part of the result by a
@@ -50,7 +51,6 @@ from .registry import register, asbool, asfloat, asint
 from .. import pallas_ops, profiler
 
 F32 = jnp.float32
-HIGHEST = lax.Precision.HIGHEST
 ATTN_BLOCK = 512            # query rows a block of the blocked attention core
 FLASH_BLOCK = 1024          # rows and keys a tile of the flash kernels
 CHUNK = 64                  # tokens a chunk of GatedDeltaRule
@@ -454,61 +454,31 @@ def _causal_conv1d(attrs, data, weight):
 # GatedDeltaRule
 # ---------------------------------------------------------------------------
 
-def _unit_lower_inverse(a):
-    """(I + a)^-1 for strictly lower triangular a (..., C, C): a is
-    nilpotent, so the Neumann series ends, and its C terms are the
-    product (I - a)(I + a^2)(I + a^4)... of log2(C) factors."""
-    c = a.shape[-1]
-    eye = jnp.eye(c, dtype=a.dtype)
-    mm = functools.partial(jnp.matmul, precision=HIGHEST)
-    inv, power = eye - a, a
-    for _ in range(max(0, (c - 1).bit_length() - 1)):
-        power = mm(power, power)
-        inv = mm(inv, eye + power)
-    return inv
-
-
-def chunk_local(q, k, v, g, beta):
-    """The half of the rule that stays inside a chunk, every chunk at
-    once: the unit lower triangular system of the WY form solved, and
-    what the loop over the chunks takes from each.  q, k (..., chunks,
-    C, dk), v (..., chunks, C, dv), g and beta (..., chunks, C).
-    Returns u (C, dv), w (C, dk), intra (C, C), q_in (C, dk), k_out
-    (C, dk) of every chunk and gamma (..., chunks), the decay over a
-    whole chunk."""
-    chunk = q.shape[-2]
-    g = jnp.cumsum(g, axis=-1)
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-    # exp(g_i - g_j) for i >= j, masked before exp: the other half of
-    # the difference is positive and can overflow
-    decay = jnp.exp(jnp.where(lower, g[..., :, None] - g[..., None, :],
-                              -jnp.inf))
-    k_beta = k * beta[..., None]
-    a = jnp.einsum('...ik,...jk->...ij', k_beta, k) * decay
-    inv = _unit_lower_inverse(jnp.where(jnp.tril(lower, -1), a, 0.0))
-    u = jnp.matmul(inv, v * beta[..., None])            # (.., C, dv)
-    w = jnp.matmul(inv, k_beta * jnp.exp(g)[..., None])  # (.., C, dk)
-    intra = jnp.einsum('...ik,...jk->...ij', q, k) * decay
-    q_in = q * jnp.exp(g)[..., None]
-    g_last = g[..., -1]
-    k_out = k * jnp.exp(g_last[..., None] - g)[..., None]
-    return u, w, intra, q_in, k_out, jnp.exp(g_last)
-
-
 def _heads_flat(xs):
     """(B, H, chunks, ...) -> (B * H, chunks, ...), as the kernels take."""
     return [x.reshape((-1,) + x.shape[2:]) for x in xs]
 
 
+def _note_delta_rule(q, v, **what):
+    """profiler.delta_rule_stats(): taken from shapes while the rule is
+    traced.  q (B, H, chunks, C, dk), v (..., dv)."""
+    profiler.note_delta_rule(heads=q.shape[0] * q.shape[1],
+                             chunks=q.shape[2], chunk=q.shape[3],
+                             dk=q.shape[4], dv=v.shape[4], **what)
+
+
 @jax.custom_vjp
 def _delta_rule_chunked(q, k, v, g, beta):
     """o (B, H, chunks, C, dv) of inputs already cut into chunks, dk
-    and dv whole lanes.  Between chunks: v_new = u_c - w_c S; o_c =
-    q_in_c S + intra_c v_new; S <- gamma_c S + k_out_c^T v_new, in one
-    kernel that keeps S in VMEM (pallas_ops.delta_rule_chunks)."""
-    o = pallas_ops.delta_rule_chunks(*_heads_flat(chunk_local(q, k, v, g,
-                                                              beta)))
-    return o.reshape(v.shape)
+    and dv whole lanes.  Inside a chunk the unit lower triangular system
+    of the WY form is made and solved (pallas_ops.delta_rule_local: u,
+    w, intra, q_in, k_out, gamma of every chunk).  Between chunks:
+    v_new = u_c - w_c S; o_c = q_in_c S + intra_c v_new; S <- gamma_c S
+    + k_out_c^T v_new, in one kernel that keeps S in VMEM
+    (pallas_ops.delta_rule_chunks)."""
+    _note_delta_rule(q, v, local_makes=1)
+    local = pallas_ops.delta_rule_local(*_heads_flat((q, k, v, g, beta)))
+    return pallas_ops.delta_rule_chunks(*local).reshape(v.shape)
 
 
 def _delta_rule_chunked_fwd(q, k, v, g, beta):
@@ -516,16 +486,19 @@ def _delta_rule_chunked_fwd(q, k, v, g, beta):
 
 
 def _delta_rule_chunked_bwd(inputs, do):
-    """Only the inputs were kept: the chunk-local tensors and every
-    chunk's state are made again, the loop runs last chunk to first in
-    its kernel, and the chunk-local half is differentiated by jax."""
-    local, local_vjp = jax.vjp(chunk_local, *inputs)
-    u, w, intra, q_in, k_out, gamma = _heads_flat(local)
+    """Only the inputs were kept: the chunk-local tensors (with T, the
+    solved system) and every chunk's state are made again, the loop
+    runs last chunk to first in its kernel, and the chunk-local half's
+    gradient is a kernel too, written by rule."""
+    _note_delta_rule(inputs[0], inputs[2], local_makes=1, backward_rules=1)
+    flat = _heads_flat(inputs)
+    u, w, intra, q_in, k_out, gamma, inv = pallas_ops.delta_rule_local(
+        *flat, with_inverse=True)
     s0, v_new = pallas_ops.delta_rule_states(u, w, k_out, gamma)
     grads = pallas_ops.delta_rule_chunks_bwd(
         do.reshape(u.shape), w, intra, q_in, k_out, gamma, s0, v_new)
-    return local_vjp(tuple(d.reshape(x.shape)
-                           for d, x in zip(grads, local)))
+    return tuple(d.reshape(x.shape) for d, x in zip(
+        pallas_ops.delta_rule_local_bwd(*flat, inv, grads), inputs))
 
 
 _delta_rule_chunked.defvjp(_delta_rule_chunked_fwd, _delta_rule_chunked_bwd)
@@ -544,9 +517,10 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
     """Per head, token by token: S <- exp(g_t) S; d = beta_t (v_t -
     S^T k_t); S <- S + k_t (x) d; o_t = S^T q_t.  Computed a chunk at a
     time: inside a chunk the rule is a unit lower triangular system
-    (the WY form, `chunk_local`), between chunks the state S (dk x dv)
-    is carried, in VMEM by a Pallas kernel, forward and backward (off
-    the TPU the same kernels in interpret mode).
+    (the WY form), between chunks the state S (dk x dv) is carried;
+    both halves are Pallas kernels that keep a chunk's matrices and the
+    state in VMEM, forward and backward (off the TPU the same kernels
+    in interpret mode).
     q, k (B, H, T, dk), v (B, H, T, dv), g (log decay <= 0) and beta
     (B, H, T), all float32.  Returns o (B, H, T, dv) in float32.
 
@@ -560,8 +534,10 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
                for a in (q, k, v))
     g, beta = (_pad_axis(a, 2, chunk) for a in (g, beta))
     nc = q.shape[2] // chunk
-    o = _delta_rule_chunked(*(a.reshape((bsz, h, nc, chunk) + a.shape[3:])
-                              for a in (q, k, v, g, beta)))
+    q, k, v, g, beta = (a.reshape((bsz, h, nc, chunk) + a.shape[3:])
+                        for a in (q, k, v, g, beta))
+    _note_delta_rule(q, v, lowerings=1)
+    o = _delta_rule_chunked(q, k, v, g, beta)
     return o.reshape(bsz, h, nc * chunk, -1)[:, :, :t, :dv]
 
 

@@ -3,7 +3,7 @@
 The reference hand-writes CUDA for its hottest kernels; the TPU
 counterpart is Pallas (jax.readthedocs.io/en/latest/pallas).  Two
 families live here: flash attention, and (further down) the gated delta
-rule's loop over chunks.
+rule: a chunk's own system and the loop over the chunks.
 
 Flash attention — a (batch*head, q-block, k-block) grid streams K/V
 blocks through VMEM with the online-softmax recurrence in fp32 scratch,
@@ -84,6 +84,11 @@ def _lanes(d):
     """A head width as VMEM holds it: rounded up to whole 128-lane
     tiles (latent attention's keys of 192 occupy 256)."""
     return -(-d // 128) * 128
+
+
+def _sublanes(rows):
+    """Rows of float32 as VMEM holds them: whole tiles of 8."""
+    return -(-rows // 8) * 8
 
 
 # what a masked score reads under a window.  There a row's first live
@@ -1006,30 +1011,224 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 
 
 # ---------------------------------------------------------------------------
-# Gated delta rule: the loop over a head's chunks with the state in VMEM.
-# ops/lm.py makes the chunk-local tensors (batched XLA over all chunks)
-# and calls these; what is left is a recurrence, S <- gamma S + k^T v_new
-# with v_new = u - w S, whose state (dk x dv, float32) would cross HBM
-# between every two operations as the carry of a lax.scan.  Here the grid
-# is (blocks of heads: parallel, chunks: arbitrary), the state is a VMEM
-# scratch zeroed at the first chunk of a head, and BlockSpecs stream one
-# chunk's tensors a grid step.  The heads of a grid step are independent
-# chains of small products, unrolled so the scheduler may interleave them.
-# Every operand, accumulator and stored tensor is float32; the products
-# take Mosaic's default for float32 operands.
+# Gated delta rule: five kernels, every intermediate in VMEM.
+#
+# The chunk-local half (delta_rule_local, delta_rule_local_bwd): what the
+# rule computes inside a chunk of C tokens, one chunk of a few heads a
+# grid step.  The C x C matrices of a chunk (the decay exp(g_i - g_j),
+# a = (k beta) k^T * decay below the diagonal, the powers of the doubling
+# chain, T = (I + a)^-1, and backward dT, dA and the decay's cotangent)
+# never reach HBM; the backward kernel is the gradient written by rule
+# (dA = -T^T dT T^T), not a transpose of the chain.  The chain's
+# products and dA's two run at Precision.HIGHEST, as the XLA code they
+# replaced did, on two heads at a time side by side in the lanes against
+# a block diagonal operand: a product 64 wide fills a quarter of the
+# MXU's array, the pair's half, in the same passes (1.72 -> 1.12 ms a
+# make of the cell's block); every other product takes Mosaic's default
+# for float32 operands.
+#
+# The loop over a head's chunks (delta_rule_chunks, _states,
+# _chunks_bwd): a recurrence, S <- gamma S + k^T v_new with v_new = u -
+# w S, whose state (dk x dv, float32) would cross HBM between every two
+# operations as the carry of a lax.scan.  There the grid is (blocks of
+# heads: parallel, chunks: arbitrary), the state is a VMEM scratch zeroed
+# at the first chunk of a head, and BlockSpecs stream one chunk's tensors
+# a grid step.
+#
+# A grid step takes several heads (fewer, longer steps: a step costs a
+# third of a microsecond whatever it holds) in a loop inside the kernel.
+# Every operand, accumulator and stored tensor is float32.
 # ---------------------------------------------------------------------------
 
-# heads a grid step of the three kernels: 1, 2, 4 and 8 time the same on
-# a v5e (the kernels wait for HBM, not for the chains), 2 holds least VMEM
-DELTA_HEADS_PER_STEP = 2
+# heads a grid step of the five kernels, at most, and the VMEM their
+# blocks (twice: Pallas double-buffers them) and the state may take: in
+# steps of 8 heads a block of the cell (8 heads of 128, one sequence)
+# takes 6.17 ms over its seven calls, of 4 heads 6.33, of 2 6.51
+# (PERF.md section 6, PR 37), in 6.7 MiB at most; wider heads get fewer
+DELTA_HEADS_PER_STEP = 8
+_DELTA_VMEM_BYTES = 8 * 1024 * 1024
 
 _NN = (((1,), (0,)), ((), ()))
 _NT = (((1,), (1,)), ((), ()))
 _TN = (((0,), (0,)), ((), ()))
 
 
-def _mm(a, b, dims=_NN):
-    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+def _mm(a, b, dims=_NN, precision=None):
+    return lax.dot_general(a, b, dims, precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+# the products of the chunk's solve: float32 in full, as the XLA code had
+_mm_solve = functools.partial(_mm, precision=lax.Precision.HIGHEST)
+
+
+def _each(n, body):
+    """body(i) for every i < n as a loop inside the kernel.  Unrolled
+    in Python, 8 heads run a block of the cell 9 % faster (5.64 ms for
+    6.17) but are traced and lowered 8 times over in every trace of the
+    step: a second a gradient of the rule for 0.3, and the cell's warm
+    set-up read 73.8 s for 62.8 (PERF.md section 6, PR 37)."""
+    lax.fori_loop(0, n, lambda i, carry: (body(i), carry)[1], 0)
+
+
+def _pair_scalars(g_row, beta_row):
+    """The local kernels take two heads at a time, side by side in the
+    lanes: a chunk's C x C matrices of both are one (C, 2C) array, whole
+    vector registers at C = 64, and a product of it with a block
+    diagonal (2C, 2C) operand is both heads' products in the passes of
+    one.  From the pair's cumulative log decays and write strengths,
+    rows (1, 2C): `beta`, `grown` = exp(g) and `tail` = exp(g_last - g)
+    of each head as columns (C, 1); `decay`, both heads' exp(g_i - g_j)
+    on and below the diagonal, 0 above, masked before exp: the other
+    half of the difference is positive and can overflow; and the masks
+    `left` (the first head's lanes; `sides`: each head's), `eye`,
+    `strict` (below the diagonal) and `last` (each head's last column).  A row becomes
+    columns through the diagonal of its broadcast: one term a sum, so
+    exact."""
+    c = g_row.shape[-1] // 2
+    row = lax.broadcasted_iota(jnp.int32, (c, 2 * c), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1)
+    left = lane < c
+    col = jnp.where(left, lane, lane - c)
+    eye = row == col
+
+    sides = (left, ~left)
+
+    def columns(x):
+        on = jnp.where(eye, x, 0.0)
+        return [jnp.sum(jnp.where(mine, on, 0.0), axis=1, keepdims=True)
+                for mine in sides]
+
+    g = columns(g_row)
+    decay = jnp.exp(jnp.where(row >= col,
+                              jnp.where(left, g[0], g[1]) - g_row, -jnp.inf))
+    # the decay's last row is exp(g_last - g_j)
+    tail = columns(jnp.sum(jnp.where(row == c - 1, decay, 0.0), axis=0,
+                           keepdims=True))
+    return dict(beta=columns(beta_row), grown=[jnp.exp(x) for x in g],
+                tail=tail, decay=decay, left=left, sides=sides, eye=eye,
+                strict=row > col, last=col == c - 1)
+
+
+def _side_by_side(xs):
+    return jnp.concatenate(xs, axis=1)
+
+
+def _pair_scores(xs, ys):
+    """x y^T (C, C) of each head, side by side."""
+    return _side_by_side([_mm(x, y, _NT) for x, y in zip(xs, ys)])
+
+
+def _halves(x):
+    c = x.shape[1] // 2
+    return x[:, :c], x[:, c:]
+
+
+def _block_diagonal(x, left):
+    """(C, 2C), two heads side by side -> (2C, 2C), one on each block."""
+    return jnp.concatenate([jnp.where(left, x, 0.0),
+                            jnp.where(left, 0.0, x)], axis=0)
+
+
+def _delta_local_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, u_ref, w_ref,
+                        intra_ref, qin_ref, kout_ref, *inv_ref, heads):
+    """One chunk of `heads` heads, a pair at a time: the unit lower
+    triangular system of the WY form made and solved, and what the loop
+    over the chunks takes from the chunk.  T = (I + a)^-1 for the
+    strictly lower a: a is nilpotent, so the Neumann series ends, and
+    its C terms are the product (I - a)(I + a^2)(I + a^4)... of log2(C)
+    factors."""
+    def one_pair(pair):
+        both = (2 * pair, 2 * pair + 1)
+        q, k, v = ([ref[h, 0] for h in both] for ref in (q_ref, k_ref, v_ref))
+        s = _pair_scalars(g_ref[pair, 0], beta_ref[pair, 0])
+        k_beta = [x * beta for x, beta in zip(k, s['beta'])]
+        a = jnp.where(s['strict'], s['decay'] * _pair_scores(k_beta, k), 0.0)
+        unit = s['eye'].astype(jnp.float32)
+        inv, power = unit - a, a
+        for _ in range(max(0, (a.shape[0] - 1).bit_length() - 1)):
+            power = _mm_solve(power, _block_diagonal(power, s['left']))
+            inv = _mm_solve(inv, _block_diagonal(unit + power, s['left']))
+        inv = _halves(inv)
+        intra = _halves(s['decay'] * _pair_scores(q, k))
+        for i, h in enumerate(both):
+            u_ref[h, 0] = _mm(inv[i], v[i] * s['beta'][i])
+            w_ref[h, 0] = _mm(inv[i], k_beta[i] * s['grown'][i])
+            intra_ref[h, 0] = intra[i]
+            qin_ref[h, 0] = q[i] * s['grown'][i]
+            kout_ref[h, 0] = k[i] * s['tail'][i]
+            if inv_ref:
+                inv_ref[0][h, 0] = inv[i]
+
+    _each(heads // 2, one_pair)
+
+
+def _delta_local_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inv_ref,
+                            du_ref, dw_ref, dintra_ref, dqin_ref, dkout_ref,
+                            dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, *,
+                            heads):
+    """One chunk of `heads` heads, a pair at a time: the cotangents of
+    q, k, v, of the cumulative log decay and of beta from those of the
+    local kernel's results, by rule: dT = du (v beta)^T + dw (k beta
+    e^g)^T, dA = -T^T dT T^T below the diagonal, then the product rules
+    of a and intra; the decay exp(g_i - g_j) hands row i the row sums
+    of (dA * a + dintra * intra) and takes the column sums from row
+    j."""
+    def lanes(x):
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    def one_pair(pair):
+        both = (2 * pair, 2 * pair + 1)
+        q, k, v, inv, du, dw, dq_in, dk_out = (
+            [ref[h, 0] for h in both] for ref in (
+                q_ref, k_ref, v_ref, inv_ref, du_ref, dw_ref, dqin_ref,
+                dkout_ref))
+        s = _pair_scalars(g_ref[pair, 0], beta_ref[pair, 0])
+        beta, grown, tail, left = s['beta'], s['grown'], s['tail'], s['left']
+        k_beta = [x * b for x, b in zip(k, beta)]
+        d_inv = _side_by_side([
+            _mm(du[i], v[i] * beta[i], _NT)
+            + _mm(dw[i], k_beta[i] * grown[i], _NT) for i in (0, 1)])
+        da = jnp.where(s['strict'], -_mm_solve(
+            _mm_solve(jnp.concatenate(inv, axis=0),
+                      _block_diagonal(d_inv, left), _TN),
+            _block_diagonal(_side_by_side(inv), left), _NT), 0.0)
+        dkk = da * s['decay']
+        dqk = s['decay'] * _side_by_side([dintra_ref[h, 0] for h in both])
+        # of the decay: (dA * a + dintra * intra), row sums less column
+        # sums; the diagonal (decay 1) is in both and left out of both
+        through = dkk * _pair_scores(k_beta, k) + jnp.where(
+            s['strict'], dqk * _pair_scores(q, k), 0.0)
+        dg, dbeta, d_tails = [], [], []
+        for i, h in enumerate(both):
+            dkk_i, dqk_i = _halves(dkk)[i], _halves(dqk)[i]
+            d_vbeta = _mm(inv[i], du[i], _TN)
+            d_kgrown = _mm(inv[i], dw[i], _TN)
+            d_kbeta = _mm(dkk_i, k[i]) + d_kgrown * grown[i]
+            dq_ref[h, 0] = _mm(dqk_i, k[i]) + dq_in[i] * grown[i]
+            dk_ref[h, 0] = (_mm(dkk_i, k_beta[i], _TN) + _mm(dqk_i, q[i], _TN)
+                            + d_kbeta * beta[i] + dk_out[i] * tail[i])
+            dv_ref[h, 0] = d_vbeta * beta[i]
+            d_tail = lanes(dk_out[i] * k[i]) * tail[i]
+            d_tails.append(jnp.sum(d_tail, axis=0, keepdims=True))
+            dg.append(lanes(jnp.where(s['sides'][i], through, 0.0))
+                      + lanes(d_kgrown * k_beta[i] + dq_in[i] * q[i])
+                      * grown[i] - d_tail)
+            dbeta.append(lanes(d_kbeta * k[i]) + lanes(d_vbeta * v[i]))
+
+        def rows(xs):
+            return jnp.sum(jnp.where(s['eye'], jnp.where(left, *xs), 0.0),
+                           axis=0, keepdims=True)
+
+        dg = _halves(
+            rows(dg) - jnp.sum(through, axis=0, keepdims=True)
+            + jnp.where(s['last'][:1], jnp.where(left[:1], *d_tails), 0.0))
+        dbeta = _halves(rows(dbeta))
+        for i, h in enumerate(both):
+            dg_ref[h, 0] = dg[i]
+            dbeta_ref[h, 0] = dbeta[i]
+
+    _each(heads // 2, one_pair)
 
 
 def _delta_fwd_kernel(*refs, heads, states):
@@ -1047,7 +1246,7 @@ def _delta_fwd_kernel(*refs, heads, states):
     def _first_chunk():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    for h in range(heads):
+    def one_head(h):
         s = s_ref[h]
         v_new = u_ref[h, 0] - _mm(w_ref[h, 0], s)
         if states:
@@ -1056,6 +1255,8 @@ def _delta_fwd_kernel(*refs, heads, states):
         else:
             o_ref[h, 0] = _mm(q_ref[h, 0], s) + _mm(intra_ref[h, 0], v_new)
         s_ref[h] = s * gamma_ref[h, 0] + _mm(k_ref[h, 0], v_new, _TN)
+
+    _each(heads, one_head)
 
 
 def _delta_bwd_kernel(do_ref, w_ref, intra_ref, q_ref, k_ref, gamma_ref,
@@ -1067,7 +1268,7 @@ def _delta_bwd_kernel(do_ref, w_ref, intra_ref, q_ref, k_ref, gamma_ref,
     def _last_chunk():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    for h in range(heads):
+    def one_head(h):
         ds, do = ds_ref[h], do_ref[h, 0]
         s0, v_new = s0_ref[h, 0], vnew_ref[h, 0]
         dv_new = _mm(intra_ref[h, 0], do, _TN) + _mm(k_ref[h, 0], ds)
@@ -1081,31 +1282,48 @@ def _delta_bwd_kernel(do_ref, w_ref, intra_ref, q_ref, k_ref, gamma_ref,
         ds_ref[h] = (ds * gamma_ref[h, 0] + _mm(q_ref[h, 0], do, _TN)
                      - _mm(w_ref[h, 0], dv_new, _TN))
 
+    _each(heads, one_head)
 
-def _delta_call(name, kernel, operands, outs, state, reverse=False):
+
+def _delta_call(name, kernel, operands, outs, state=None, reverse=False,
+                pairs=1):
     """pallas_call of a delta rule kernel over (heads, chunks, rows,
     cols) operands; `outs` are (rows, cols) of each result and `state`
-    (dk, dv) of the scratch a head's state lives in.  `name` is the
-    custom call's in the compiled program and in a trace."""
+    (dk, dv) of the scratch a head's state lives in (the loop's
+    kernels: their chunks follow one another; without one every grid
+    step stands alone).  pairs=2: a grid step takes an even number of
+    heads, and an operand with half as many leading entries holds one
+    for each pair.  A grid step takes as many heads, up to
+    DELTA_HEADS_PER_STEP, as divide the heads and fit _DELTA_VMEM_BYTES.
+    `name` is the custom call's in the compiled program and in a
+    trace."""
     bh, nc = operands[0].shape[:2]
-    heads = max(d for d in range(1, DELTA_HEADS_PER_STEP + 1) if bh % d == 0)
+    a_head = 4 * (2 * sum(
+        _sublanes(rows) * _lanes(cols) for rows, cols in
+        [x.shape[2:] for x in operands if x.shape[0] == bh] + list(outs))
+        + (state[0] * state[1] if state else 0))
+    fit = [d for d in range(pairs, DELTA_HEADS_PER_STEP + 1, pairs)
+           if bh % d == 0]
+    heads = max([d for d in fit if d * a_head <= _DELTA_VMEM_BYTES]
+                or fit[:1])
 
-    def spec(rows, cols):
+    def spec(rows, cols, of=1):
         return pl.BlockSpec(
-            (heads, 1, rows, cols),
+            (heads // of, 1, rows, cols),
             (lambda i, j: (i, nc - 1 - j, 0, 0)) if reverse
             else (lambda i, j: (i, j, 0, 0)))
 
     return pl.pallas_call(
         functools.partial(kernel, heads=heads),
         grid=(bh // heads, nc),
-        in_specs=[spec(*x.shape[2:]) for x in operands],
+        in_specs=[spec(*x.shape[2:], of=bh // x.shape[0]) for x in operands],
         out_specs=[spec(*s) for s in outs],
         out_shape=[jax.ShapeDtypeStruct((bh, nc) + s, jnp.float32)
                    for s in outs],
-        scratch_shapes=[pltpu.VMEM((heads,) + state, jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'arbitrary')),
+        scratch_shapes=[] if state is None else
+        [pltpu.VMEM((heads,) + state, jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            'parallel', 'parallel' if state is None else 'arbitrary')),
         interpret=default_interpret(*operands),
         name=name,
     )(*operands)
@@ -1152,3 +1370,58 @@ def delta_rule_chunks_bwd(do, w, intra, q_in, k_out, gamma, s0, v_new):
         [(c, dv), (c, dk), (c, c), (c, dk), (c, dk), (1, dv)], (dk, dv),
         reverse=True)
     return du, dw, dintra, dq, dkk, jnp.sum(dgamma, axis=(2, 3))
+
+
+def _pair_rows(x):
+    """(heads, chunks, C) -> (heads / 2, chunks, 1, 2C): a chunk's
+    scalars of two heads as one row the local kernels read."""
+    h, nc, c = x.shape
+    return jnp.moveaxis(x.reshape(h // 2, 2, nc, c), 1, 2).reshape(
+        h // 2, nc, 1, 2 * c)
+
+
+def _whole_pairs(xs):
+    """An odd count of heads gets one more, of zeros: it decays nothing
+    (g = 0), writes nothing (beta = 0) and is cut off again."""
+    if xs[0].shape[0] % 2 == 0:
+        return list(xs)
+    return [jnp.pad(x, [(0, 1)] + [(0, 0)] * (x.ndim - 1)) for x in xs]
+
+
+def delta_rule_local(q, k, v, g, beta, with_inverse=False):
+    """The half of the rule that stays inside a chunk.  q, k (heads,
+    chunks, C, dk), v (heads, chunks, C, dv), g (log decay a token) and
+    beta (heads, chunks, C); dk and dv multiples of 128, C of 8, all
+    float32.  Returns what delta_rule_chunks takes: u (C, dv), w (C,
+    dk), intra (C, C), q_in (C, dk), k_out (C, dk) of every chunk and
+    gamma (heads, chunks), the decay over a whole chunk; with_inverse
+    (the backward rule's make): T (C, C) of every chunk after them."""
+    heads, _, c, dk = q.shape
+    dv = v.shape[-1]
+    g = jnp.cumsum(g, axis=-1)
+    q, k, v, g_pairs, beta = _whole_pairs((q, k, v, g, beta))
+    made = _delta_call(
+        'delta_rule_local', _delta_local_kernel,
+        (q, k, v, _pair_rows(g_pairs), _pair_rows(beta)),
+        [(c, dv), (c, dk), (c, c), (c, dk), (c, dk)]
+        + [(c, c)] * with_inverse, pairs=2)
+    made = [x[:heads] for x in made]
+    return tuple(made[:5]) + (jnp.exp(g[..., -1]),) + tuple(made[5:])
+
+
+def delta_rule_local_bwd(q, k, v, g, beta, inv, cotangents):
+    """Cotangents (dq, dk, dv, dg, dbeta) of delta_rule_local's operands
+    for the `cotangents` (du, dw, dintra, dq_in, dk_out, dgamma) of its
+    results, given its T (`inv`)."""
+    du, dw, dintra, dq_in, dk_out, dgamma = cotangents
+    heads, _, c, dk = q.shape
+    g = jnp.cumsum(g, axis=-1)
+    q, k, v, g_pairs, beta, *given = _whole_pairs(
+        (q, k, v, g, beta, inv, du, dw, dintra, dq_in, dk_out))
+    dq, dkk, dv, dg, dbeta = (x[:heads] for x in _delta_call(
+        'delta_rule_local_bwd', _delta_local_bwd_kernel,
+        (q, k, v, _pair_rows(g_pairs), _pair_rows(beta), *given),
+        [(c, dk), (c, dk), (c, v.shape[-1]), (1, c), (1, c)], pairs=2))
+    # gamma = exp(g_last); g the cumulative sum of what the caller gave
+    dg = dg[:, :, 0].at[..., -1].add(dgamma * jnp.exp(g[..., -1]))
+    return dq, dkk, dv, lax.cumsum(dg, axis=2, reverse=True), dbeta[:, :, 0]

@@ -229,6 +229,43 @@ def attention_stats():
     return out
 
 
+# GatedDeltaRule's core by shape (ops/lm.py): how often the rule was
+# traced into a program (`lowerings`), and how many of its chunk-local
+# makes (pallas_ops.delta_rule_local: one a forward, one more in the
+# backward rule) and backward rules went with them.  From shapes while
+# the operator is traced, like the attention counts, never inside a step
+_DELTA_RULE = {}    # (heads, chunks, chunk, dk, dv) ->
+#                     [lowerings, local makes, backward rules]
+_DELTA_RULE_KEY = ('heads', 'chunks', 'chunk', 'dk', 'dv')
+_DELTA_RULE_COUNTS = ('lowerings', 'local_makes', 'backward_rules')
+
+
+def note_delta_rule(heads, chunks, chunk, dk, dv, **counts):
+    key = tuple(int(x) for x in (heads, chunks, chunk, dk, dv))
+    with _STATE['lock']:
+        seen = _DELTA_RULE.setdefault(key, [0] * len(_DELTA_RULE_COUNTS))
+        for name, more in counts.items():
+            seen[_DELTA_RULE_COUNTS.index(name)] += int(more)
+
+
+def delta_rule_stats():
+    """The gated delta rule's lowerings: {'lowerings': n, 'local_makes':
+    n, 'backward_rules': n, 'shapes': [{'heads', 'chunks', 'chunk',
+    'dk', 'dv', 'lowerings', 'local_makes', 'backward_rules'}, ...]};
+    `heads` counts every sequence's (B * H), the widths are the padded
+    ones the kernels see."""
+    with _STATE['lock']:
+        seen = sorted((k, list(v)) for k, v in _DELTA_RULE.items())
+    out = dict.fromkeys(_DELTA_RULE_COUNTS, 0)
+    out['shapes'] = []
+    for key, counts in seen:
+        for name, n in zip(_DELTA_RULE_COUNTS, counts):
+            out[name] += n
+        out['shapes'].append(dict(zip(_DELTA_RULE_KEY + _DELTA_RULE_COUNTS,
+                                      key + tuple(counts))))
+    return out
+
+
 # sparse embedding counters (Embedding(sparse_grad=True) through the
 # fused step, plus the serving hot-row cache): the touched-bytes
 # ledger is THE quantity this tier exists to shrink — the dense
@@ -1410,6 +1447,7 @@ def clear():
             _MOE[k] = 0
         _MOE_EXPERTS.clear()
         _ATTENTION.clear()
+        _DELTA_RULE.clear()
         for k in _EMBED:
             _EMBED[k] = 0
         for k in _CKPT:

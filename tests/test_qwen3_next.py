@@ -119,7 +119,8 @@ def test_the_kernel_keeps_the_state_the_recurrence_has_at_each_chunk():
     q, k, v, g, beta = (jnp.moveaxis(x, 1, 0).reshape(
         (h, t // chunk, chunk) + x.shape[2:])
         for x in _rule_inputs(t, h, d, d))
-    u, w, intra, q_in, k_out, gamma = lm.chunk_local(q, k, v, g, beta)
+    u, w, intra, q_in, k_out, gamma = pallas_ops.delta_rule_local(
+        q, k, v, g, beta)
     s0, v_new = pallas_ops.delta_rule_states(u, w, k_out, gamma)
 
     def token(state, xs):
@@ -137,23 +138,28 @@ def test_the_kernel_keeps_the_state_the_recurrence_has_at_each_chunk():
 
 
 def _primitives(jaxpr):
+    """Every primitive outside the kernels' own bodies (those loop over
+    the heads of a grid step)."""
     for eqn in jaxpr.eqns:
         yield eqn.primitive.name
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _primitives(sub)
+        if eqn.primitive.name != 'pallas_call':
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _primitives(sub)
 
 
 @pytest.mark.parametrize('what', ['forward', 'gradient'])
 def test_the_chunk_loop_is_a_kernel_and_no_scan(what):
-    """The loop over the chunks cannot come back unnoticed: one
-    pallas_call forward, three in the gradient (o; the states made
-    again; the loop backward), and no scan or while in either."""
+    """The loop over the chunks cannot come back unnoticed: two
+    pallas_calls forward (the chunk's own system, then the loop), six
+    in the gradient (those two; the local make and the states again,
+    the loop backward, the local half's backward), and no scan or
+    while in either."""
     args = _rule_inputs(130)
     fn = {'forward': lambda *a: _chunked(*a, 64),
           'gradient': jax.grad(lambda *a: jnp.sum(_chunked(*a, 64)),
                                argnums=range(5))}[what]
     found = collections.Counter(_primitives(jax.make_jaxpr(fn)(*args).jaxpr))
-    assert found['pallas_call'] == {'forward': 1, 'gradient': 3}[what]
+    assert found['pallas_call'] == {'forward': 2, 'gradient': 6}[what]
     assert not found['scan'] and not found['while'], found
 
 
@@ -480,27 +486,38 @@ def _bulk_step_text(mod, batches):
     return texts[0]
 
 
-# sha256 of the tiny model's bulk step program as PR 35 lowered it
-# (jax 0.9.0, the CPU backend, tests/conftest.py's eight devices; PR 35
-# sent GatedAttention's grouped heads to the flash kernels, interpreted
-# here; until then it was PR 31's, on the blocked core).  A PR that
-# changes an operator of this model on purpose replaces it; a PR that
-# says it leaves Qwen3-Next's program alone keeps it.
+# sha256 of the tiny model's bulk step program as PR 37 lowered it
+# (jax 0.9.0, the CPU backend, tests/conftest.py's eight devices; PR 37
+# made GatedDeltaRule's chunk-local half two kernels, interpreted here;
+# until then it was PR 35's, which sent GatedAttention's grouped heads
+# to the flash kernels).  A PR that changes an operator of this model
+# on purpose replaces it; a PR that says it leaves Qwen3-Next's program
+# alone keeps it.
 STEP_TEXT_SHA256 = (
-    '5dc7eee402a3267413b0a87a207b4c0eb88ab00f1e135d46d572c77a511cdde9')
+    '34a775d521e9c4465aea80629617ce4c4b175a8b5dc15de719211b24cf90b756')
 
 
 def test_grouped_heads_take_the_kernels_and_the_program_keeps_its_text():
     """GatedAttention's 8 query heads a key-value head are the flash
     kernels' since PR 35: every lowering of causal_attention in the
-    whole model's step takes them, none the blocked core, and the step
-    program is, to the byte, the one PR 35 lowered."""
+    whole model's step takes them, none the blocked core; the delta
+    rule's lowerings are counted by the one shape its kernels see (4
+    value heads of a sequence's block, one chunk, widths padded to the
+    lanes; the makes and backward rules traced with them depend on what
+    jax has cached of earlier traces: tests/test_delta_rule_local.py
+    counts them on a shape of its own); and the step program is, to the
+    byte, the one PR 37 lowered."""
     profiler._ATTENTION.clear()
+    profiler._DELTA_RULE.clear()
     text = _bulk_step_text(*_tiny_module()[:2])
     stats = profiler.attention_stats()
     assert stats['blocked'] == 0 and stats['kernel'] > 0
     assert {(s['group'], s['dk'], s['dv'], s['t'], s['window'])
             for s in stats['shapes']} == {(8, 16, 16, SEQ, None)}
+    rule = profiler.delta_rule_stats()
+    assert [(s['heads'], s['chunks'], s['chunk'], s['dk'], s['dv'])
+            for s in rule['shapes']] == [(4, 1, 64, 128, 128)]
+    assert rule['lowerings'] > 0
     assert hashlib.sha256(text.encode()).hexdigest() == STEP_TEXT_SHA256
 
 

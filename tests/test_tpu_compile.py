@@ -6,8 +6,10 @@ Nothing runs, so this says nothing about results or speed.
 The topology is described inside a fixture, never while a module is
 imported: one process at a time may load the TPU's library, and every
 xdist worker imports every test file."""
+import base64
 import functools
 import os
+import re
 
 import pytest
 import jax
@@ -30,12 +32,49 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize('what,kernels', [('forward', 1), ('gradient', 2)])
+def _mosaic_kernels(lowered_text):
+    """{kernel name: its Mosaic module as text} of a lowered program's
+    tpu_custom_calls (the modules travel as base64 bytecode)."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    found = {}
+    with ctx:
+        for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                               lowered_text):
+            asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+                enable_debug_info=False)
+            found[re.search(r'module @(\w+)', asm).group(1)] = asm
+    return found
+
+
+# the solve's products in each kernel (its body is one pair of heads,
+# looped over a grid step's pairs): the doubling
+# chain's ten (two heads' 64 x 64 side by side against a block diagonal:
+# five squarings, five factors) and dA = -T^T dT T^T's two
+SOLVE_PRODUCTS = {'delta_rule_local': 10, 'delta_rule_chunks': 0,
+                  'delta_rule_states': 0, 'delta_rule_chunks_bwd': 0,
+                  'delta_rule_local_bwd': 2}
+# forward: the chunk's own system, then the loop; the gradient alone
+# needs no o: the local make again (with T), the states again, the loop
+# backward, the local half's backward
+DELTA_KERNELS = {
+    'forward': ('delta_rule_local', 'delta_rule_chunks'),
+    'gradient': ('delta_rule_local', 'delta_rule_states',
+                 'delta_rule_chunks_bwd', 'delta_rule_local_bwd')}
+
+
+@pytest.mark.parametrize('what', sorted(DELTA_KERNELS))
 def test_delta_rule_kernels_compile_for_the_chip(one_chip, monkeypatch,
-                                                 what, kernels):
+                                                 what):
     """One block of the cell: 8 value heads of 128 at 8,192 tokens.
     The code asks jax for its backend (the CPU here), so the test
-    steers it onto the Mosaic path."""
+    steers it onto the Mosaic path.  Mosaic takes Precision.HIGHEST on
+    the float32 products of the chunk's solve as it stands
+    (`contract_precision<fp32>` on those matmuls and on no other; no
+    split into bfloat16 parts by hand), and XLA is left no product over
+    the chunks' 64 x 64 matrices."""
     monkeypatch.setattr(pallas_ops, 'default_interpret', lambda *a: False)
     h, t, d = 8, 8192, 128
 
@@ -48,9 +87,16 @@ def test_delta_rule_kernels_compile_for_the_chip(one_chip, monkeypatch,
           'gradient': jax.grad(
               lambda *a: jnp.sum(lm.chunk_gated_delta_rule(*a)),
               argnums=(0, 1, 2, 3, 4))}[what]
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    assert text.count('tpu_custom_call') >= kernels
+    lowered = jax.jit(fn).lower(*args)
+    kernels = _mosaic_kernels(lowered.as_text())
+    assert set(DELTA_KERNELS[what]) <= set(kernels)
+    for name, asm in kernels.items():
+        assert asm.count('contract_precision<fp32>') == \
+            SOLVE_PRODUCTS[name], name
+    text = lowered.compile().as_text()
+    assert text.count('tpu_custom_call') == len(DELTA_KERNELS[what])
     assert ' while(' not in text
+    assert not re.search(r'f32\[8,128,64,64\]\S* (dot|convolution)\(', text)
 
 
 @pytest.mark.parametrize('what,kernels', [('forward', 1), ('gradient', 2)])
