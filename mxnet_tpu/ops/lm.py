@@ -6,12 +6,14 @@ seq_len`, as `Embedding` gives them and `FullyConnected` takes them; the
 operators that look along a sequence carry `seq_len` as an attribute and
 fold the rows to `(N / seq_len, seq_len, ...)` themselves.  All are pure
 JAX functions differentiated by jax.vjp inside the one compiled step,
-like every other operator of the registry, and plain XLA but for two
+like every other operator of the registry, and plain XLA but for three
 things under custom gradient rules: GatedDeltaRule's core (five Pallas
 kernels, pallas_ops.delta_rule_*: a chunk's own system and the loop
-over the chunks, each forward and backward) and the
+over the chunks, each forward and backward), the
 attention core (pallas_ops.flash_attention's kernels, forward and
-backward: grouped heads and a sliding window inside them).
+backward: grouped heads and a sliding window inside them) and
+SparseMoE's combine (pallas_ops.add_rows: a tile's rows added to their
+tokens by DMA, forward and backward).
 
   RMSNorm          x * rsqrt(mean x^2 + eps) * gamma, or * (1 + gamma)
   GatedAttention   per-head q/k RMS norm, partial or no rotary, grouped-
@@ -640,11 +642,6 @@ def _expert_mlp(xt, wg, wu, wd):
     return jnp.dot(h, wd.T, preferred_element_type=F32), g, u, h
 
 
-def _put_rows(buffer, rows, row0):
-    return lax.dynamic_update_slice(buffer, rows.astype(buffer.dtype),
-                                    (row0, 0))
-
-
 def _add_at(acc, e, value):
     """acc[e] += value, in place."""
     index = (e,) + (0,) * value.ndim
@@ -664,11 +661,13 @@ def grouped_experts(tile, x, wg, wu, wd, token_of, weight_of, pair_weight,
     pair; position (N, k): where each token's pairs stand in that
     order; pair_weight (N, k), through which the weights' gradient goes.
 
-    The loop runs over the tiles in use only and writes each tile's
-    rows where they stand in the sorted order; a gather by `position`
-    brings them back to their tokens.  The arrays are sized for the
-    worst case (every pair lands here); the work is what was routed
-    here, and rows no tile wrote stay zero."""
+    The loop runs over the tiles in use only and adds each tile's
+    weighted rows to their tokens in a float32 (N, H) sum
+    (pallas_ops.add_rows: a live row's line of the sum is read, added
+    to and written back by DMA): a token's pairs are summed in expert
+    order, and nothing is dropped.  Only token_of and position are
+    sized for the worst case (every pair lands here); the work and the
+    rows moved are what was routed here."""
     return _grouped_fwd(tile, x, wg, wu, wd, token_of, weight_of,
                         pair_weight, position, group_sizes)[0]
 
@@ -683,44 +682,41 @@ def _grouped_plan(tile, token_of, wg, group_sizes):
 
 
 def _tile(plan, t, tile, token_of):
-    """Tile t's expert, first sorted row, live rows and their tokens
-    (rows past the expert's last pair read token_of's row 0 and are
-    written as zeros; the next tile overwrites them)."""
+    """Tile t's expert, first sorted row, how many rows are live (the
+    first ones) and which, and their tokens: unique, since a token
+    picks an expert once (rows past the expert's last pair read
+    token_of's row 0 and add to no token)."""
     expert, row0, valid, _ = plan
     live = jnp.arange(tile) < valid[t]
     rows = jnp.where(live, row0[t] + jnp.arange(tile), 0)
-    return expert[t], row0[t], live[:, None], token_of[rows]
+    return expert[t], row0[t], valid[t], live[:, None], token_of[rows]
 
 
-def _sorted_rows(tile, x, wg, wu, wd, token_of, plan):
-    """mlp_e(x[token]) of every sorted pair held here, (M + tile, H)."""
-    def body(t, ys):
-        e, row0, live, tok = _tile(plan, t, tile, token_of)
-        yt = _expert_mlp(x[tok], wg[e], wu[e], wd[e])[0]
-        return _put_rows(ys, jnp.where(live, yt, 0.0), row0)
-
-    return lax.fori_loop(
-        0, plan[3], body,
-        jnp.zeros((token_of.shape[0] + tile, x.shape[1]), x.dtype))
+def _add_tile(acc, tok, rows, valid):
+    """acc[tok] += rows for a tile's live rows, acc as row_tiles()
+    shapes it."""
+    return pallas_ops.add_rows(acc, tok, pallas_ops.row_tiles(rows), valid)
 
 
-def _combine(rows, position, weight=None):
-    """sum_j weight[:, j] * rows[position[:, j]] in float32, one of a
-    token's k pairs at a time: (N, k, H) at once is ten times x."""
-    total = 0.0
-    for j in range(position.shape[1]):
-        part = rows[position[:, j]].astype(F32)
-        total = total + (part if weight is None else part * weight[:, j, None])
-    return total
+def _row_sum(x):
+    """A float32 zero sum of x's shape, as _add_tile takes it."""
+    return pallas_ops.row_tiles(jnp.zeros(x.shape, F32))
 
 
 def _grouped_fwd(tile, x, wg, wu, wd, token_of, weight_of, pair_weight,
                  position, group_sizes):
     plan = _grouped_plan(tile, token_of, wg, group_sizes)
-    ys = _sorted_rows(tile, x, wg, wu, wd, token_of, plan)
-    y = _combine(ys, position, pair_weight.astype(F32))
-    return y.astype(x.dtype), (x, wg, wu, wd, token_of, weight_of,
-                               position, group_sizes)
+    weights = jnp.pad(weight_of.astype(F32), (0, tile))
+
+    def body(t, y):
+        e, row0, valid, _, tok = _tile(plan, t, tile, token_of)
+        yt = _expert_mlp(x[tok], wg[e], wu[e], wd[e])[0]
+        wt = lax.dynamic_slice(weights, (row0,), (tile,))[:, None]
+        return _add_tile(y, tok, yt * wt, valid)
+
+    y = lax.fori_loop(0, plan[3], body, _row_sum(x))
+    return y.reshape(x.shape).astype(x.dtype), (
+        x, wg, wu, wd, token_of, weight_of, position, group_sizes)
 
 
 def _grouped_bwd(tile, res, dy):
@@ -730,13 +726,13 @@ def _grouped_bwd(tile, res, dy):
     weight_of = jnp.pad(weight_of.astype(F32), (0, tile))
 
     def body(t, carry):
-        dxs, dws, dwg, dwu, dwd = carry
-        e, row0, live, tok = _tile(plan, t, tile, token_of)
+        dx, dws, dwg, dwu, dwd = carry
+        e, row0, valid, live, tok = _tile(plan, t, tile, token_of)
         xt = x[tok]
         yt, g, u, h = _expert_mlp(xt, wg[e], wu[e], wd[e])
         dyt = dy[tok].astype(F32)
-        dws = _put_rows(dws, jnp.where(
-            live, jnp.sum(dyt * yt, axis=-1, keepdims=True), 0.0), row0)
+        dws = lax.dynamic_update_slice(dws, jnp.where(
+            live[:, 0], jnp.sum(dyt * yt, axis=-1), 0.0), (row0,))
         wt = lax.dynamic_slice(weight_of, (row0,), (tile,))[:, None]
         dyt = jnp.where(live, dyt * wt, 0.0).astype(x.dtype)
         dh = jnp.dot(dyt, wd[e], preferred_element_type=F32)
@@ -745,7 +741,7 @@ def _grouped_bwd(tile, res, dy):
         dg = (dh * u * sig * (1.0 + g * (1.0 - sig))).astype(x.dtype)
         dxt = jnp.dot(dg, wg[e], preferred_element_type=F32) + \
             jnp.dot(du, wu[e], preferred_element_type=F32)
-        return (_put_rows(dxs, dxt, row0), dws,
+        return (_add_tile(dx, tok, dxt, valid), dws,
                 _add_at(dwg, e, jnp.dot(dg.T, xt,
                                         preferred_element_type=F32)),
                 _add_at(dwu, e, jnp.dot(du.T, xt,
@@ -753,15 +749,13 @@ def _grouped_bwd(tile, res, dy):
                 _add_at(dwd, e, jnp.dot(dyt.T, h,
                                         preferred_element_type=F32)))
 
-    dxs, dws, dwg, dwu, dwd = lax.fori_loop(
+    dx, dws, dwg, dwu, dwd = lax.fori_loop(
         0, plan[3], body,
-        (jnp.zeros((m + tile, x.shape[1]), x.dtype),
-         jnp.zeros((m + tile, 1), F32), jnp.zeros(wg.shape, F32),
+        (_row_sum(x), jnp.zeros((m + tile,), F32), jnp.zeros(wg.shape, F32),
          jnp.zeros(wu.shape, F32), jnp.zeros(wd.shape, F32)))
-    dx = _combine(dxs, position)
-    return (dx.astype(x.dtype), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
-            dwd.astype(wd.dtype), None, None,
-            dws[:, 0][position].astype(weight_of.dtype), None, None)
+    return (dx.reshape(x.shape).astype(x.dtype), dwg.astype(wg.dtype),
+            dwu.astype(wu.dtype), dwd.astype(wd.dtype), None, None,
+            dws[position].astype(weight_of.dtype), None, None)
 
 
 grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)

@@ -1,9 +1,10 @@
 """In-tree Pallas TPU kernels for hot ops.
 
 The reference hand-writes CUDA for its hottest kernels; the TPU
-counterpart is Pallas (jax.readthedocs.io/en/latest/pallas).  Two
-families live here: flash attention, and (further down) the gated delta
-rule: a chunk's own system and the loop over the chunks.
+counterpart is Pallas (jax.readthedocs.io/en/latest/pallas).  Three
+families live here: flash attention, (further down) the gated delta
+rule: a chunk's own system and the loop over the chunks, and (last)
+rows added to their tokens by DMA, SparseMoE's combine.
 
 Flash attention — a (batch*head, q-block, k-block) grid streams K/V
 blocks through VMEM with the online-softmax recurrence in fp32 scratch,
@@ -1425,3 +1426,68 @@ def delta_rule_local_bwd(q, k, v, g, beta, inv, cotangents):
     # gamma = exp(g_last); g the cumulative sum of what the caller gave
     dg = dg[:, :, 0].at[..., -1].add(dgamma * jnp.exp(g[..., -1]))
     return dq, dkk, dv, lax.cumsum(dg, axis=2, reverse=True), dbeta[:, :, 0]
+
+
+# ---------------------------------------------------------------------------
+# Rows added to their tokens (SparseMoE's combine)
+# ---------------------------------------------------------------------------
+
+def row_tiles(x):
+    """(..., H) -> (..., H / 128, 128) where 128 divides H, else
+    (..., 1, H): a row as whole lines of (8, 128) tiles, which a DMA
+    can address alone (HBM tiles an (N, H) array's rows eight at a
+    time)."""
+    h = x.shape[-1]
+    return x.reshape(x.shape[:-1] + ((h // 128, 128) if h % 128 == 0
+                                     else (1, h)))
+
+
+def _add_rows_kernel(count_ref, dest_ref, rows_ref, _, acc_ref, buf, sem):
+    """acc[dest[i]] += rows[i] for i < count: every live row's line of
+    the sum read at once, added to in VMEM and written back at once."""
+    count = count_ref[0]
+
+    def each(copy):
+        def go(i, carry):
+            copy(i)
+            return carry
+        lax.fori_loop(0, count, go, 0)
+
+    def fetch(i):
+        return pltpu.make_async_copy(acc_ref.at[dest_ref[i]], buf.at[i],
+                                     sem.at[0])
+
+    def store(i):
+        return pltpu.make_async_copy(buf.at[i], acc_ref.at[dest_ref[i]],
+                                     sem.at[1])
+
+    each(lambda i: fetch(i).start())
+    each(lambda i: fetch(i).wait())
+    buf[...] += rows_ref[...]
+    each(lambda i: store(i).start())
+    each(lambda i: store(i).wait())
+
+
+def add_rows(acc, dest, rows, count):
+    """acc[dest[i]] += rows[i] for the first `count` rows, in place (acc
+    is aliased to the result).  acc (N, S, L) and rows (tile, S, L)
+    float32, rows as row_tiles() shapes them; dest (tile,) int32,
+    unique and in range among the first count; the other rows are left
+    out.  Rows move by DMA, so the work is the live rows, whatever N
+    is."""
+    block = rows.shape
+    return pl.pallas_call(
+        _add_rows_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(1,),
+            in_specs=[pl.BlockSpec(block, lambda i, c, d: (0, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM(block, jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
+        input_output_aliases={3: 0},
+        interpret=default_interpret(acc, rows),
+        name='add_rows',
+    )(jnp.reshape(count, (1,)).astype(jnp.int32), dest.astype(jnp.int32),
+      rows, acc)

@@ -6,6 +6,7 @@ import collections
 import functools
 import hashlib
 import os
+import re
 import sys
 
 import numpy as np
@@ -324,31 +325,104 @@ def test_nothing_is_dropped_when_every_token_picks_the_same_experts():
     assert set(np.nonzero(counts[1])[0]) == {9, 10, 12, 15}
 
 
-def test_expert_layer_gradients_against_the_reference():
-    c = dict(TINY, num_experts_held=8, expert_offset=8)
-    p = _layer_params(c)
-    x = rand(14, 150, c['hidden_size'])
-    weight = rand(15, 150, c['hidden_size'])
-    names = ['l0_moe_router_weight', 'l0_moe_gate_weight',
-             'l0_moe_up_weight', 'l0_moe_down_weight']
+def _held_pairs(c, p, x):
+    """(tokens,) how many of each token's pairs go to experts held
+    here, and (held,) how many pairs each held expert gets."""
+    _, idx = lm.route(x, p['l0_moe_router_weight'],
+                      c['num_experts_per_tok'], True)
+    local = np.asarray(idx) - c['expert_offset']
+    held = (local >= 0) & (local < c['num_experts_held'])
+    return held.sum(axis=1), np.bincount(local[held],
+                                         minlength=c['num_experts_held'])
 
+
+def _sparse_moe_loss(c, weight, tile):
     def program(x, *ws):
-        held, hidden = 8, c['hidden_size']
+        held, hidden = c['num_experts_held'], c['hidden_size']
         y, _, _ = lm.sparse_moe(
             x, ws[0], ws[1].reshape(held, -1, hidden),
             ws[2].reshape(held, -1, hidden), ws[3].reshape(held, hidden, -1),
-            c['num_experts_per_tok'], c['expert_offset'], tile=32)
-        return jnp.sum(y * weight)
+            c['num_experts_per_tok'], c['expert_offset'], tile=tile)
+        return jnp.sum(y * weight), y
+    return program
+
+
+MOE_NAMES = ['l0_moe_router_weight', 'l0_moe_gate_weight',
+             'l0_moe_up_weight', 'l0_moe_down_weight']
+
+
+@pytest.mark.parametrize('first_token', ['elsewhere', 'held'])
+@pytest.mark.parametrize('tile', [8, 32])
+def test_expert_layer_gradients_against_the_reference(tile, first_token):
+    """Output and gradients at tiles of 8 and 32 rows, where some token's
+    pairs land in the tiles of different experts and some expert's last
+    tile is partial.  Token 0 is routed to no held expert (its output
+    and input gradient must be the reference's exactly: a partial tile's
+    dead rows add to no token) or to several."""
+    c = dict(TINY, num_experts_held=8, expert_offset=8)
+    p = _layer_params(c)
+    x = rand(14, 150, c['hidden_size'])
+    per_token, per_expert = _held_pairs(c, p, x)
+    if first_token == 'held':
+        j = int(np.argmax(per_token))
+        x = x.at[jnp.array([0, j])].set(x[jnp.array([j, 0])])
+        per_token[[0, j]] = per_token[[j, 0]]
+    assert per_token[0] >= 2 if first_token == 'held' else \
+        per_token[0] == 0
+    assert per_token.max() >= 2 and (per_expert % tile).any()
+    weight = rand(15, 150, c['hidden_size'])
 
     def reference(x, *ws):
-        q = dict(p, **dict(zip(names, ws)))
-        return jnp.sum(_reference_layer(c, q, x, shared=False) * weight)
+        q = dict(p, **dict(zip(MOE_NAMES, ws)))
+        y = _reference_layer(c, q, x, shared=False)
+        return jnp.sum(y * weight), y
 
-    ws = [p[n] for n in names]
-    got = jax.grad(program, argnums=range(5))(x, *ws)
-    want = jax.grad(reference, argnums=range(5))(x, *ws)
+    ws = [p[n] for n in MOE_NAMES]
+    got, got_y = jax.grad(_sparse_moe_loss(c, weight, tile),
+                          argnums=range(5), has_aux=True)(x, *ws)
+    want, want_y = jax.grad(reference, argnums=range(5),
+                            has_aux=True)(x, *ws)
+    close(got_y, want_y, 1e-4)
     for a, b in zip(got, want):
         close(a, b, 1e-4)
+    if first_token == 'elsewhere':
+        assert not np.asarray(got_y[0]).any() and not np.asarray(want_y[0]).any()
+        assert not np.asarray(got[0][0]).any()
+        np.testing.assert_array_equal(got[0][0], want[0][0])
+
+
+@pytest.mark.parametrize('hidden', [32, 256])
+@pytest.mark.parametrize('count', [0, 5, 16])
+def test_add_rows_adds_the_live_rows_once(hidden, count):
+    """pallas_ops.add_rows adds the first `count` rows to their (unique)
+    destinations and leaves every other row of the sum as it was; a
+    hidden width that 128 divides is held as whole lanes, another as one
+    line a row."""
+    acc, rows = rand(21, 64, hidden), rand(22, 16, hidden)
+    dest = jnp.sort(jax.random.permutation(jax.random.PRNGKey(23), 64)[:16])
+    got = jax.jit(pallas_ops.add_rows)(
+        pallas_ops.row_tiles(acc), dest, pallas_ops.row_tiles(rows), count)
+    np.testing.assert_array_equal(got.reshape(acc.shape),
+                                  acc.at[dest[:count]].add(rows[:count]))
+
+
+def test_no_array_is_sized_for_every_pair():
+    """Each tile's rows are added to their tokens inside the loop: at 150
+    tokens, top 4 and tiles of 32, neither the forward nor its gradient
+    holds an array of tokens * k + tile rows (a zero-filled buffer of
+    every pair, gathered back k times), in the jaxpr or the lowering."""
+    c = dict(TINY, num_experts_held=8, expert_offset=8)
+    p = _layer_params(c)
+    x = rand(14, 150, c['hidden_size'])
+    ws = [p[n] for n in MOE_NAMES]
+    program = _sparse_moe_loss(c, rand(15, 150, c['hidden_size']), 32)
+    rows = 150 * c['num_experts_per_tok'] + 32
+    for f in (lambda *a: program(*a)[1],
+              jax.grad(lambda *a: program(*a)[0], argnums=range(5))):
+        jaxpr = str(jax.make_jaxpr(f)(x, *ws))
+        text = jax.jit(f).lower(x, *ws).as_text()
+        assert not re.search(r'(?<!\d)%d,\d' % rows, jaxpr)
+        assert not re.search(r'(?<!\d)%dx\d+x' % rows, text)
 
 
 def test_counters_reach_the_profiler_without_a_sync_in_the_step():
@@ -486,15 +560,15 @@ def _bulk_step_text(mod, batches):
     return texts[0]
 
 
-# sha256 of the tiny model's bulk step program as PR 37 lowered it
-# (jax 0.9.0, the CPU backend, tests/conftest.py's eight devices; PR 37
-# made GatedDeltaRule's chunk-local half two kernels, interpreted here;
-# until then it was PR 35's, which sent GatedAttention's grouped heads
-# to the flash kernels).  A PR that changes an operator of this model
-# on purpose replaces it; a PR that says it leaves Qwen3-Next's program
-# alone keeps it.
+# sha256 of the tiny model's bulk step program (jax 0.9.0, the CPU
+# backend, tests/conftest.py's eight devices): GatedDeltaRule's
+# chunk-local half as two kernels, interpreted here, GatedAttention's
+# grouped heads on the flash kernels, and SparseMoE's tiles added to
+# their tokens inside the tile loop by pallas_ops.add_rows.  A change
+# to an operator of this model on purpose replaces it; a change that
+# says it leaves Qwen3-Next's program alone keeps it.
 STEP_TEXT_SHA256 = (
-    '34a775d521e9c4465aea80629617ce4c4b175a8b5dc15de719211b24cf90b756')
+    'cbf27b112978935d00f78ba0546c1bb4f273337b692911556e4a286b287799cd')
 
 
 def test_grouped_heads_take_the_kernels_and_the_program_keeps_its_text():

@@ -185,3 +185,33 @@ def test_gated_core_compiles_for_the_chip_in_memory_linear_in_t(
                    for t in (4096, 8192))
     assert long < 3 * short
     assert long < 400e6
+
+
+# (tokens, hidden, experts, held, expert width, top k, scoring) of the
+# expert layers of the cells
+EXPERT_LAYERS = {'qwen3-next': (16384, 2048, 512, 32, 512, 10, 'softmax'),
+                 'trinity-mini': (8192, 2048, 128, 16, 1024, 8, 'sigmoid')}
+
+
+@pytest.mark.parametrize('case', sorted(EXPERT_LAYERS))
+def test_expert_layer_compiles_for_the_chip(one_chip, monkeypatch, case):
+    """A cell's expert layer and its gradient: the forward's and the
+    backward's tile loops each add their rows to the tokens with one
+    add_rows kernel, and no array of every pair's rows (tokens x k +
+    tile of them) is left in the program."""
+    monkeypatch.setattr(pallas_ops, 'default_interpret', lambda *a: False)
+    n, h, e, held, inter, k, scoring = EXPERT_LAYERS[case]
+
+    def shape(*s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    def loss(x, router, wg, wu, wd):
+        y = lm.sparse_moe(x, router, wg, wu, wd, k, 0, scoring=scoring)[0]
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        shape(n, h), shape(e, h, dtype=jnp.float32), shape(held, inter, h),
+        shape(held, inter, h), shape(held, h, inter)).compile().as_text()
+    assert text.count('tpu_custom_call') == 2
+    assert 'add_rows' in text
+    assert not re.search(r'\[%d,' % (n * k + lm.EXPERT_TILE), text)
