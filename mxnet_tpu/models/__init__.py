@@ -6,7 +6,8 @@ Same architectures, composed from this framework's symbol API; on TPU
 the whole network compiles to one XLA module per executor.
 """
 from . import (lenet, mlp, resnet, alexnet, vgg, inception_bn, ssd,
-               inception_v3, resnext, qwen3_next, deepseek_v3, afmoe)
+               inception_v3, resnext, qwen3_next, deepseek_v3, afmoe,
+               ouro)
 
 _FACTORY = {
     'lenet': lenet.get_symbol,
@@ -23,6 +24,7 @@ _FACTORY = {
     'qwen3_next': qwen3_next.get_symbol,
     'deepseek_v3': deepseek_v3.get_symbol,
     'afmoe': afmoe.get_symbol,
+    'ouro': ouro.get_symbol,
 }
 
 

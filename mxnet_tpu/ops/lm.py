@@ -1,5 +1,5 @@
-"""Operators of sparse language models (the layer kinds of Qwen3-Next,
-of DeepSeek-V3's family and of AFMoE).
+"""Operators of language models (the layer kinds of Qwen3-Next, of
+DeepSeek-V3's family, of AFMoE and of Ouro's looped stack).
 
 Every operator takes tokens as rows, `(N, C)` with `N = sequences x
 seq_len`, as `Embedding` gives them and `FullyConnected` takes them; the
@@ -40,7 +40,15 @@ tokens by DMA, forward and backward).
                    factor), the held experts' part of the result by a
                    grouped product over the sorted (token, expert)
                    pairs; nothing is dropped
+  LoopedDecoder    a stack of decoder layers (rotary multi-head
+                   attention, a gated feed-forward, four plain RMS
+                   norms a layer) run num_loops times with the same
+                   weights and a final norm after each pass, as one
+                   lax.scan whose body is traced once, each half layer
+                   recomputed in the backward pass; each weight's
+                   gradient summed over the passes in float32
 """
+import collections
 import functools
 import math
 
@@ -916,3 +924,167 @@ register('SparseMoE', input_names=_moe_input_names, num_aux=_moe_num_aux,
          mutable_aux=True, infer_shape=_moe_infer_shape,
          infer_dtype=_moe_infer_dtype, hint='sparsemoe',
          simple=False, fold_aux=_fold_counts)(_sparse_moe)
+
+
+# ---------------------------------------------------------------------------
+# LoopedDecoder
+# ---------------------------------------------------------------------------
+
+# a layer's inputs in order: the attention half's, then the feed-forward
+# half's (each half is one function of the stream and its own weights)
+_ATTENTION_HALF = ('input_norm_gamma', 'q_proj_weight', 'k_proj_weight',
+                   'v_proj_weight', 'o_proj_weight', 'post_attn_norm_gamma')
+_MLP_HALF = ('pre_mlp_norm_gamma', 'mlp_gate_proj_weight',
+             'mlp_up_proj_weight', 'mlp_down_proj_weight',
+             'post_mlp_norm_gamma')
+_LAYER_INPUTS = _ATTENTION_HALF + _MLP_HALF
+
+
+# the static shape of a looped stack
+_Loop = collections.namedtuple(
+    '_Loop', 'loops heads kv d seq_len theta eps')
+
+
+def _rope_attention(loop, x, wq, wk, wv):
+    """Causal softmax attention of x (N, hidden) with rotate-half rotary
+    on every dim of the heads, no q/k norm and no gate: causal_attention
+    with `kv` key-value heads (16 over 16 in Ouro: group 1)."""
+    n, d, kv = x.shape[0], loop.d, loop.kv
+    group = loop.heads // kv
+
+    def heads(w, count):
+        return _fold(jnp.dot(x, w.T).reshape(n, count, d), loop.seq_len)
+
+    q = rotary(heads(wq, loop.heads).astype(F32), d, loop.theta)
+    k = rotary(heads(wk, kv).astype(F32), d, loop.theta)
+    b = q.shape[0]
+    o = causal_attention(
+        q.astype(x.dtype).reshape(b, loop.seq_len, kv, group, d),
+        k.astype(x.dtype), heads(wv, kv), 1.0 / math.sqrt(d))
+    return o.reshape(n, loop.heads * d)
+
+
+def _attention_half(loop, h, w):
+    """h + N2(W_o MHA(N1(h))), the sandwich norms plain RMS norms."""
+    g1, wq, wk, wv, wo, g2 = w
+    with jax.named_scope('norm'):
+        x = rms_norm(h, g1, loop.eps)
+    with jax.named_scope('attention'):
+        o = jnp.dot(_rope_attention(loop, x, wq, wk, wv), wo.T)
+    with jax.named_scope('norm'):
+        return h + rms_norm(o, g2, loop.eps)
+
+
+def _mlp_half(loop, h, w):
+    """h + N4(W_down (silu(W_gate N3(h)) * W_up N3(h)))."""
+    g3, wg, wu, wd, g4 = w
+    with jax.named_scope('norm'):
+        x = rms_norm(h, g3, loop.eps)
+    with jax.named_scope('mlp'):
+        f = jnp.dot(jax.nn.silu(jnp.dot(x, wg.T)) * jnp.dot(x, wu.T), wd.T)
+    with jax.named_scope('norm'):
+        return h + rms_norm(f, g4, loop.eps)
+
+
+def _halves(layers):
+    """[(half function, its weights)] of one pass, in order."""
+    split = len(_ATTENTION_HALF)
+    return [half for w in layers for half in (
+        (_attention_half, w[:split]), (_mlp_half, w[split:]))]
+
+
+def _final_norm(loop, h, gamma):
+    with jax.named_scope('norm'):
+        return rms_norm(h, gamma, loop.eps)
+
+
+def looped_decoder(loop, x, layers, gamma):
+    """x (N, hidden) through the layers `loop.loops` times with the same
+    weights, the final norm after each pass, as one lax.scan whose body,
+    one pass, is traced once.  `layers`: a tuple a layer of its
+    _LAYER_INPUTS; `gamma`: the final norm's scale.
+
+    Each half layer and the final norm run under jax.checkpoint, as
+    `__force_mirroring__` runs the other models' half layers: the scan
+    keeps for the backward only the stream where each takes it,
+    (2 L + 1) x N x hidden a pass.  The weights enter the scan in
+    float32 and the body casts them back to their type: jax's rule for
+    a scan then sums each weight's gradient over the passes in float32,
+    and the cast's own gradient rounds the sum once."""
+    wide = tuple(tuple(w.astype(F32) for w in layer) for layer in layers)
+
+    def one_pass(h, _):
+        for (fn, w), (_, kind) in zip(_halves(wide), _halves(layers)):
+            narrow = tuple(a.astype(k.dtype) for a, k in zip(w, kind))
+            h = jax.checkpoint(functools.partial(fn, loop))(h, narrow)
+        return jax.checkpoint(functools.partial(_final_norm, loop))(
+            h, gamma), None
+
+    return lax.scan(one_pass, x, None, length=loop.loops)[0]
+
+
+def _decoder_input_names(attrs):
+    """data, each layer's inputs as l<i>_<name>, the final norm's scale."""
+    return ('data',) + tuple(
+        'l%d_%s' % (i, name) for i in range(asint(attrs['num_layers']))
+        for name in _LAYER_INPUTS) + ('final_norm_gamma',)
+
+
+def _decoder_infer_shape(attrs, in_shapes):
+    if in_shapes[0] is None or in_shapes[0][-1] == 0:
+        return in_shapes
+    hidden = in_shapes[0][-1]
+    d = asint(attrs['head_dim'])
+    q, kv = asint(attrs['num_heads']) * d, asint(attrs['num_kv_heads']) * d
+    inter = asint(attrs['intermediate_size'])
+    layer = [(hidden,), (q, hidden), (kv, hidden), (kv, hidden), (hidden, q),
+             (hidden,), (hidden,), (inter, hidden), (inter, hidden),
+             (hidden, inter), (hidden,)]
+    wanted = layer * asint(attrs['num_layers']) + [(hidden,)]
+    for i, s in enumerate(wanted, start=1):
+        if in_shapes[i] is None:
+            in_shapes[i] = s
+    return in_shapes
+
+
+def _decoder_infer_dtype(attrs, in_dtypes):
+    """Weights follow the data; every norm scale stays float32."""
+    d = _data_dtype(in_dtypes)
+    names = _decoder_input_names(attrs)
+    return [np.dtype(np.float32) if n.endswith('_gamma') else d
+            for n in names], [d]
+
+
+@register('LoopedDecoder', input_names=_decoder_input_names,
+          infer_shape=_decoder_infer_shape,
+          infer_dtype=_decoder_infer_dtype, hint='loopeddecoder',
+          simple=False)
+def _looped_decoder(attrs, inputs, auxs, op_ctx):
+    """Attributes: num_layers, num_loops, num_heads, num_kv_heads,
+    head_dim, intermediate_size (the weights' shapes say it), rope_theta,
+    eps, seq_len.  The step program holds the layers once: one scan of
+    num_loops passes forward and one backward (looped_decoder).  A
+    training trace is counted (profiler.looped_decoder_stats); shape
+    inference's, in float32 whatever the graph's type, is not."""
+    data, weights = inputs[0], inputs[1:]
+    layers = asint(attrs['num_layers'])
+    heads, kv = asint(attrs['num_heads']), asint(attrs['num_kv_heads'])
+    if heads % kv:
+        raise ValueError('%d query heads over %d key-value heads'
+                         % (heads, kv))
+    loop = _Loop(asint(attrs['num_loops']), heads, kv,
+                 asint(attrs['head_dim']), asint(attrs['seq_len']),
+                 asfloat(attrs.get('rope_theta', 10000.0)),
+                 asfloat(attrs.get('eps', 1e-6)))
+    if loop.loops < 1:
+        raise ValueError('%d passes over the layers' % loop.loops)
+    per = len(_LAYER_INPUTS)
+    stack = tuple(tuple(weights[i * per:(i + 1) * per])
+                  for i in range(layers))
+    if op_ctx.is_train:
+        # the stream where each half layer and the final norm take it
+        profiler.note_looped_decoder(
+            loops=loop.loops, layers=layers, tokens=data.shape[0],
+            hidden=data.shape[1], saved_bytes=loop.loops *
+            (2 * layers + 1) * data.size * data.dtype.itemsize)
+    return [looped_decoder(loop, data, stack, weights[-1])], []

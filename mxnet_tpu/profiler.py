@@ -266,6 +266,41 @@ def delta_rule_stats():
     return out
 
 
+# LoopedDecoder's stacks by shape (ops/lm.py): how often the operator was
+# traced for training (`lowerings`; shape inference's traces are not
+# counted), with the passes, the layers and what its scan keeps for the
+# backward.  From shapes while the operator is traced, never inside a step
+_LOOPED = {}        # (loops, layers, tokens, hidden, saved bytes) ->
+#                     lowerings
+_LOOPED_KEY = ('loops', 'layers', 'tokens', 'hidden', 'saved_bytes')
+
+
+def note_looped_decoder(loops, layers, tokens, hidden, saved_bytes):
+    key = tuple(int(x) for x in (loops, layers, tokens, hidden, saved_bytes))
+    with _STATE['lock']:
+        _LOOPED[key] = _LOOPED.get(key, 0) + 1
+
+
+def looped_decoder_stats():
+    """The looped stacks' lowerings: {'lowerings': n, 'loops', 'layers',
+    'layer_applications', 'saved_bytes' (those of the largest stack: the
+    activations its scan keeps for the backward over all passes, bytes),
+    'shapes': [{'loops', 'layers', 'tokens', 'hidden', 'saved_bytes',
+    'lowerings'}, ...]}; zeros where nothing was traced."""
+    with _STATE['lock']:
+        seen = sorted(_LOOPED.items())
+    out = {'lowerings': sum(n for _, n in seen), 'loops': 0, 'layers': 0,
+           'layer_applications': 0, 'saved_bytes': 0, 'shapes': []}
+    for key, n in seen:
+        shape = dict(zip(_LOOPED_KEY, key), lowerings=n)
+        out['shapes'].append(shape)
+        if shape['saved_bytes'] >= out['saved_bytes']:
+            out.update(loops=shape['loops'], layers=shape['layers'],
+                       layer_applications=shape['loops'] * shape['layers'],
+                       saved_bytes=shape['saved_bytes'])
+    return out
+
+
 # sparse embedding counters (Embedding(sparse_grad=True) through the
 # fused step, plus the serving hot-row cache): the touched-bytes
 # ledger is THE quantity this tier exists to shrink — the dense
@@ -1448,6 +1483,7 @@ def clear():
         _MOE_EXPERTS.clear()
         _ATTENTION.clear()
         _DELTA_RULE.clear()
+        _LOOPED.clear()
         for k in _EMBED:
             _EMBED[k] = 0
         for k in _CKPT:

@@ -278,6 +278,14 @@ CASES = {
                                   'head_k_dim': 4, 'head_v_dim': 6,
                                   'seq_len': 5, 'chunk_size': 4},
                            rtol=5e-2, atol=5e-3, wrap='square', eps=1e-2),
+    'LoopedDecoder': Case([(12, 8), (8,), (8, 8), (8, 8), (8, 8), (8, 8),
+                           (8,), (8,), (6, 8), (6, 8), (8, 6), (8,), (8,)],
+                          attrs={'num_layers': 1, 'num_loops': 2,
+                                 'num_heads': 2, 'num_kv_heads': 2,
+                                 'head_dim': 4, 'intermediate_size': 6,
+                                 'rope_theta': 100.0, 'seq_len': 6},
+                          low=0.5, high=1.5, rtol=5e-2, atol=5e-3,
+                          wrap='square', eps=1e-2),
     'LRN': Case([(1, 4, 3, 3)], attrs={'nsize': 3}, low=0.5, high=1.5),
     'LSoftmax': Case([(3, 4), (5, 4), (3,)],
                      attrs={'num_hidden': 5, 'margin': 2},
