@@ -9,7 +9,8 @@ seed) through the entry points a user calls — `Module.fit` and
 kernels at four lengths and at the latent-attention cell's head shape
 (32 heads, keys of 192 over values of 128, T = 8,192, against dense
 attention) and the gated delta rule's kernels at one block
-of the language model's cell (against the XLA code they replaced), then,
+of the language model's cell (against the XLA code they replaced) and
+its causal convolution's (against two XLA forms), then,
 on a host with four chips, runs the same network data-parallel over
 them.  It fails (non-zero exit, no result
 line) when JAX finds no TPU, when any phase raises, and when run
@@ -503,6 +504,79 @@ def phase_d(shape=(1, 8, 8192, 128), calls=5, expect_custom_call=True):
 
 
 # ---------------------------------------------------------------------------
+# Phase E — CausalConv1D alone at the Qwen3-Next cell's shape: its kernels
+# against the XLA forms
+# ---------------------------------------------------------------------------
+
+def cast_after_shift_conv(data, w, seq_len):
+    """causal_conv in XLA with the shifts in data's own type, each tap
+    cast to float32 after its slice: the best XLA form found before the
+    kernels, kept here, and only here, as what phase E compares them
+    with.  data (N, C) rows of sequences of seq_len, w (C, W)."""
+    width = w.shape[1]
+    x = data.reshape((-1, seq_len) + data.shape[1:])
+    xp = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    w = w.astype(jnp.float32)
+    y = sum(xp[:, j:j + seq_len].astype(jnp.float32) * w[:, j]
+            for j in range(width))
+    return y.astype(data.dtype).reshape(data.shape)
+
+
+def phase_e(rows=16384, channels=8192, seq_len=8192, width=4, calls=10,
+            expect_custom_call=True):
+    """The Qwen3-Next cell's causal convolution alone (16,384 rows of
+    8,192 bfloat16 as 2 sequences, a kernel of 4), forward and forward
+    with its vjp, in three forms: lm.causal_conv_xla (the operator
+    before the kernels), the cast-after-shift XLA form above and
+    lm.causal_conv (the kernels at this shape).  y, dx and dw of the
+    last two against the first, to 1e-2 of the largest element."""
+    import functools
+    from mxnet_tpu.ops import lm
+    keys = jax.random.split(jax.random.PRNGKey(SEED + 5), 3)
+    x = jax.random.normal(keys[0], (rows, channels), jnp.bfloat16)
+    w = (0.5 * jax.random.normal(keys[1], (channels, width))).astype(
+        jnp.bfloat16)
+    dy = jax.random.normal(keys[2], (rows, channels), jnp.bfloat16)
+
+    def with_vjp(fn):
+        def run(x, w, dy):
+            y, vjp = jax.vjp(fn, x, w)
+            return (y,) + vjp(dy)
+        return run
+
+    result, outs = {}, {}
+    for name, fn, kernels in (
+            ('xla', lm.causal_conv_xla, (0, 0)),
+            ('cast_after_shift', cast_after_shift_conv, (0, 0)),
+            ('kernel', lm.causal_conv, (1, 2))):
+        fn = functools.partial(fn, seq_len=seq_len)
+        if not expect_custom_call:
+            kernels = (0, 0)
+        _, fwd_s, fwd_ms = compile_and_time(fn, (x, w), kernels[0], calls)
+        outs[name], bwd_s, bwd_ms = compile_and_time(
+            with_vjp(fn), (x, w, dy), kernels[1], calls)
+        log('phase E causal conv %s (%d, %d) in sequences of %d: forward '
+            'compile %.1f s run %.3f ms, forward+backward compile %.1f s '
+            'run %.3f ms' % (name, rows, channels, seq_len, fwd_s, fwd_ms,
+                             bwd_s, bwd_ms))
+        result[name] = {'forward_ms': round(fwd_ms, 3),
+                        'forward_backward_ms': round(bwd_ms, 3)}
+    worst = 0.0
+    for other in ('cast_after_shift', 'kernel'):
+        for name, a, b in zip(('y', 'dx', 'dw'), outs[other], outs['xla']):
+            a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+            assert np.isfinite(a).all(), 'causal conv: %s not finite' % name
+            err = np.abs(a - b).max() / max(np.abs(b).max(), 1e-30)
+            assert err < 1e-2, 'causal conv: %s of %s differs by %.3g of ' \
+                'the largest element' % (name, other, err)
+            worst = max(worst, err)
+    log('phase E: the other forms against lm.causal_conv_xla, worst %.2g '
+        'of the largest element' % worst)
+    result['worst'] = worst
+    return result
+
+
+# ---------------------------------------------------------------------------
 # Phase C — data parallel over four chips
 # ---------------------------------------------------------------------------
 
@@ -662,6 +736,7 @@ def main():
     gc.collect()
     result['B'] = phase_b()
     result['D'] = phase_d()
+    result['E'] = phase_e()
     if len(devices) >= 4:
         result['C'] = phase_c([mx.tpu(i) for i in range(4)])
     else:

@@ -30,7 +30,7 @@ tokens by DMA, forward and backward).
                    attention with keys wider than values
                    (causal_attention: the flash kernels at any T
                    they tile, else the blocks)
-  CausalConv1D     depthwise causal convolution along the sequence
+  CausalConv1D     depthwise causal convolution along the sequence (2 kernels)
   GatedDeltaRule   the gated delta rule in chunks (WY form): a unit
                    lower triangular solve inside a chunk and the state
                    carried between chunks, both in VMEM by kernels,
@@ -447,17 +447,42 @@ def _conv_infer_shape(attrs, in_shapes):
     return in_shapes
 
 
-@register('CausalConv1D', input_names=('data', 'weight'),
-          infer_shape=_conv_infer_shape, hint='causalconv1d')
-def _causal_conv1d(attrs, data, weight):
-    """Depthwise: y[t, c] = sum_j w[c, j] * x[t - (kernel-1) + j, c],
-    positions before the sequence's start read as zero."""
-    width, seq_len = asint(attrs['kernel']), asint(attrs['seq_len'])
+def causal_conv(data, weight, seq_len):
+    """Depthwise, over every sequence of seq_len rows: y[t, c] = sum_j
+    w[c, j] * x[t - (W-1) + j, c], positions before a sequence's start
+    read as zero, in float32; y in data's type.  data (N, C), weight (C,
+    W).  Where seq_len is whole sublane tiles of data's type, C whole
+    lanes and W - 1 rows fit in one tile (pallas_ops.conv_fits: the
+    Qwen3-Next cell's 2 sequences of 8,192 rows of 8,192 bfloat16 under
+    a width of 4), pallas_ops.causal_conv1d's kernels, which read and
+    write each element once; any other shape causal_conv_xla, which
+    copies x to float32 and shifts it along the sublanes.
+    profiler.causal_conv_stats() counts the lowerings by path."""
+    n, c = data.shape
+    width = weight.shape[1]
+    if pallas_ops.conv_fits(seq_len, c, width, data.dtype):
+        profiler.note_causal_conv('kernel', n // seq_len, seq_len, c, width)
+        return pallas_ops.causal_conv1d(_fold(data, seq_len),
+                                        weight).reshape(data.shape)
+    profiler.note_causal_conv('xla', n // seq_len, seq_len, c, width)
+    return causal_conv_xla(data, weight, seq_len)
+
+
+def causal_conv_xla(data, weight, seq_len):
+    """causal_conv as plain XLA, for any shape."""
+    width = weight.shape[1]
     x = _fold(data, seq_len).astype(F32)
     xp = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
     w = weight.astype(F32)
     y = sum(xp[:, j:j + seq_len] * w[:, j] for j in range(width))
     return y.reshape(data.shape).astype(data.dtype)
+
+
+@register('CausalConv1D', input_names=('data', 'weight'),
+          infer_shape=_conv_infer_shape, hint='causalconv1d')
+def _causal_conv1d(attrs, data, weight):
+    """causal_conv; weight (C, kernel)."""
+    return causal_conv(data, weight, asint(attrs['seq_len']))
 
 
 # ---------------------------------------------------------------------------

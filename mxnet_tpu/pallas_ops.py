@@ -1,10 +1,10 @@
 """In-tree Pallas TPU kernels for hot ops.
 
 The reference hand-writes CUDA for its hottest kernels; the TPU
-counterpart is Pallas (jax.readthedocs.io/en/latest/pallas).  Three
+counterpart is Pallas (jax.readthedocs.io/en/latest/pallas).  Four
 families live here: flash attention, (further down) the gated delta
-rule: a chunk's own system and the loop over the chunks, and (last)
-rows added to their tokens by DMA, SparseMoE's combine.
+rule's chunks and the loop over them, rows added to their tokens by
+DMA (SparseMoE's combine) and (last) CausalConv1D's depthwise pass.
 
 Flash attention — a (batch*head, q-block, k-block) grid streams K/V
 blocks through VMEM with the online-softmax recurrence in fp32 scratch,
@@ -1491,3 +1491,248 @@ def add_rows(acc, dest, rows, count):
         name='add_rows',
     )(jnp.reshape(count, (1,)).astype(jnp.int32), dest.astype(jnp.int32),
       rows, acc)
+
+
+# ---------------------------------------------------------------------------
+# Depthwise causal convolution along the sequence (CausalConv1D): one pass
+# over (rows, lanes) blocks forward and one backward, each block read with
+# a tile of its neighbour's rows (custom calls `causal_conv1d`,
+# `causal_conv1d_bwd`)
+# ---------------------------------------------------------------------------
+
+# rows and lanes of a grid step's block, at most; lanes of a strip
+# (forward, backward); tiles of rows a turn.  A block is worked through
+# a tile of rows by a strip of lanes at a time, each strip's values in
+# registers, in a loop over turns of CONV_TURN tiles unrolled.  Compiled
+# for a v5e at the Qwen3-Next cell's (2, 8192, 8192) bfloat16, a grid
+# step of 512 x 1024 is about 2,300 bundles forward and 4,400 backward,
+# under the 3,840 and 5,760 cycles (1.5 GHz) its bytes take at 819 GB/s;
+# as whole-array operations (each value held in VMEM) 5,187 and 9,810;
+# a turn of one tile 3,400 and 11,000.  Unrolled whole (1,994 and
+# 4,102), a block traced 128 and 256 tile bodies at every call site:
+# 90 s more set-up for that cell on the chip
+CONV_ROWS = 512
+CONV_LANES = 1024
+CONV_STRIPS = (256, 128)
+CONV_TURN = 4
+
+
+def _sublane_tile(dtype):
+    """Rows of one (rows, 128) tile of `dtype` as HBM and VMEM hold it:
+    8 of 32 bits, 16 of bfloat16 (two rows packed in a sublane)."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def conv_fits(t, c, width, dtype):
+    """Whether causal_conv1d takes (B, t, c) of `dtype` under a kernel of
+    `width`: t whole sublane tiles, c whole lanes, and the width - 1
+    rows a block reads of its neighbour inside one tile (the halo)."""
+    tile = _sublane_tile(dtype)
+    return t % tile == 0 and c % 128 == 0 and 0 < width <= tile + 1
+
+
+def _conv_block(n, unit, cap):
+    """The largest multiple of `unit` that divides n and is at most cap
+    (unit divides n)."""
+    return max(b for b in range(unit, min(n, cap) + 1, unit) if n % b == 0)
+
+
+def _rows_from(ext, start, rows):
+    """ext[start:start + rows] of a float32 value: a roll along the
+    sublanes, then the first rows."""
+    shift = -start % ext.shape[0]
+    return (pltpu.roll(ext, shift, 0) if shift else ext)[:rows]
+
+
+def _halo(rows, edge):
+    """A neighbour's rows in float32, zeros where `edge`: no sequence
+    reads the rows of another."""
+    return jnp.where(edge, 0.0, rows.astype(jnp.float32))
+
+
+def _taps(ext, w, first, rows):
+    """sum_j w[j] * ext[first + d_j : first + d_j + rows], d_j = j -
+    (W-1): in the taps' order, in float32."""
+    return functools.reduce(jnp.add, (
+        _rows_from(ext, first + j - (len(w) - 1), rows) * w[j]
+        for j in range(len(w))))
+
+
+def _strips(w_ref, lanes, tile, cap):
+    """(columns, the taps' weights broadcast to a tile) of each strip of
+    a block's lanes: whole lanes, at most cap."""
+    strip = _conv_block(lanes, 128, cap)
+    for k in range(0, lanes, strip):
+        cols = slice(k, k + strip)
+        yield cols, [jnp.broadcast_to(w_ref[j:j + 1, cols], (tile, strip))
+                     for j in range(w_ref.shape[0])]
+
+
+def _turns(rows, turn, body, carry, last=None):
+    """body(first row, carry) over a block's rows in turns of `turn`
+    rows: a loop, each turn unrolled; `last` (a static body, for the
+    last turn) ends the loop."""
+    steps = rows // turn - (last is not None)
+    carry = lax.fori_loop(
+        0, steps, lambda i, c: body(pl.multiple_of(i * turn, turn), c),
+        carry)
+    return carry if last is None else last(rows - turn, carry)
+
+
+def _conv_fwd_kernel(x_ref, prev_ref, w_ref, y_ref, *, strip, tiles):
+    """y of a (rows, lanes) block: sum_j w[j] * x[r - (W-1) + j].  Each
+    tile of rows is read below the tile above it, the first below the
+    last tile of the sequence's previous block (zeros at its start)."""
+    tile = prev_ref.shape[1]
+    rows, lanes = x_ref.shape[1:]
+    for cols, w in _strips(w_ref, lanes, tile, strip):
+        def turn(first, above, cols=cols, w=w):
+            for k in range(tiles):
+                at = pl.ds(first + k * tile, tile)
+                x = x_ref[0, at, cols].astype(jnp.float32)
+                y = _taps(jnp.concatenate([above, x]), w, tile, tile)
+                y_ref[0, at, cols] = y.astype(y_ref.dtype)
+                above = x
+            return above
+
+        _turns(rows, tiles * tile, turn,
+               _halo(prev_ref[0, :, cols], pl.program_id(1) == 0))
+
+
+def _conv_bwd_kernel(x_ref, prev_ref, dy_ref, next_ref, w_ref, dx_ref,
+                     dw_ref, *, strip, tiles):
+    """dx of a block: sum_j w[j] * dy[r + (W-1) - j], each tile of dy
+    read above the tile below it, the last above the first tile of the
+    sequence's next block (zeros at its end); dw: each tap's x[r - (W-1)
+    + j] times dy, summed over the block's rows in float32 and added to
+    the lanes' sums over every block of every sequence (the grid's inner
+    axes)."""
+    t = pl.program_id(2)
+    tile, width = prev_ref.shape[1], w_ref.shape[0]
+    rows, lanes = x_ref.shape[1:]
+
+    @pl.when((pl.program_id(1) == 0) & (t == 0))
+    def _first_block():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    for cols, w in _strips(w_ref, lanes, tile, strip):
+        after = _halo(next_ref[0, :, cols], t == pl.num_programs(2) - 1)
+
+        def turn(first, carry, ends=False, cols=cols, w=w, after=after):
+            dy, x_above, sums = carry
+            for k in range(tiles):
+                at = pl.ds(first + k * tile, tile)
+                below = (after if ends and k == tiles - 1 else
+                         dy_ref[0, pl.ds(first + (k + 1) * tile, tile),
+                                cols].astype(jnp.float32))
+                dx = _taps(jnp.concatenate([dy, below]), w[::-1], width - 1,
+                           tile)
+                dx_ref[0, at, cols] = dx.astype(dx_ref.dtype)
+                x = x_ref[0, at, cols].astype(jnp.float32)
+                ext = jnp.concatenate([x_above, x])
+                sums = tuple(
+                    acc + _rows_from(ext, tile + j - (width - 1), tile) * dy
+                    for j, acc in enumerate(sums))
+                dy, x_above = below, x
+            return dy, x_above, sums
+
+        _, _, sums = _turns(
+            rows, tiles * tile, turn,
+            (dy_ref[0, :tile, cols].astype(jnp.float32),
+             _halo(prev_ref[0, :, cols], t == 0),
+             (jnp.zeros((tile, cols.stop - cols.start), jnp.float32),)
+             * width),
+            functools.partial(turn, ends=True))
+        for j in range(width):
+            dw_ref[j:j + 1, cols] += jnp.sum(sums[j], axis=0, keepdims=True)
+
+
+def _conv_plan(x, *operands):
+    """(rows, lanes) of x's blocks, its halo's rows, the strips'
+    (forward, backward) lanes, the tiles of rows a turn and interpret
+    mode: all a call's trace depends on beside its operands' shapes."""
+    tile = _sublane_tile(x.dtype)
+    rows = _conv_block(x.shape[1], tile, CONV_ROWS)
+    return (rows, _conv_block(x.shape[2], 128, CONV_LANES), tile,
+            CONV_STRIPS, _conv_block(rows // tile, 1, CONV_TURN),
+            default_interpret(*operands))
+
+
+# jitted with the plan static: traced once a shape, not at every call
+# site of every trace of a step (the backward kernel alone traced in
+# 0.4 s, and a step of Qwen3-Next traces its three layers' kernels
+# several times over)
+@functools.partial(jax.jit, static_argnums=2)
+def _conv_fwd_call(x, w_rows, plan):
+    bsz, t, c = x.shape
+    rows, lanes, tile, strips, tiles, interpret = plan
+    per = rows // tile
+    return pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, strip=strips[0], tiles=tiles),
+        grid=(bsz, t // rows, c // lanes),
+        in_specs=[
+            pl.BlockSpec((1, rows, lanes), lambda b, i, k: (b, i, k)),
+            pl.BlockSpec((1, tile, lanes),
+                         lambda b, i, k: (b, jnp.maximum(i * per - 1, 0), k)),
+            pl.BlockSpec((w_rows.shape[0], lanes), lambda b, i, k: (0, k))],
+        out_specs=pl.BlockSpec((1, rows, lanes), lambda b, i, k: (b, i, k)),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel',) * 3),
+        interpret=interpret,
+        name='causal_conv1d',
+    )(x, x, w_rows)
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def _conv_bwd_call(x, w_rows, dy, plan):
+    bsz, t, c = x.shape
+    rows, lanes, tile, strips, tiles, interpret = plan
+    per = rows // tile
+    block = pl.BlockSpec((1, rows, lanes), lambda k, b, i: (b, i, k))
+    return pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, strip=strips[1], tiles=tiles),
+        grid=(c // lanes, bsz, t // rows),
+        in_specs=[
+            block,
+            pl.BlockSpec((1, tile, lanes),
+                         lambda k, b, i: (b, jnp.maximum(i * per - 1, 0), k)),
+            block,
+            pl.BlockSpec((1, tile, lanes), lambda k, b, i: (
+                b, jnp.minimum((i + 1) * per, t // tile - 1), k)),
+            pl.BlockSpec((w_rows.shape[0], lanes), lambda k, b, i: (0, k))],
+        out_specs=[block,
+                   pl.BlockSpec((w_rows.shape[0], lanes),
+                                lambda k, b, i: (0, k))],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(w_rows.shape, jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary', 'arbitrary')),
+        interpret=interpret,
+        name='causal_conv1d_bwd',
+    )(x, x, dy, dy, w_rows)
+
+
+@jax.custom_vjp
+def causal_conv1d(x, w):
+    """Depthwise causal convolution of every sequence: y[b, t, c] =
+    sum_j w[c, j] * x[b, t - (W-1) + j, c], rows before a sequence's
+    start read as zero, in float32, y in x's type.  x (B, T, C), w
+    (C, W), as conv_fits() admits them.  One kernel over (B, T / rows,
+    C / lanes) blocks reads each element once (and one tile of rows of
+    the block before); the gradient is one kernel too."""
+    return _conv_fwd_call(x, w.astype(jnp.float32).T, _conv_plan(x, x))
+
+
+def _causal_conv1d_fwd(x, w):
+    return causal_conv1d(x, w), (x, w)
+
+
+def _causal_conv1d_bwd(res, dy):
+    x, w = res
+    dx, dw = _conv_bwd_call(x, w.astype(jnp.float32).T, dy,
+                            _conv_plan(x, x, dy))
+    return dx, dw.T.astype(w.dtype)
+
+
+causal_conv1d.defvjp(_causal_conv1d_fwd, _causal_conv1d_bwd)

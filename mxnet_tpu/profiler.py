@@ -266,6 +266,33 @@ def delta_rule_stats():
     return out
 
 
+# CausalConv1D's lowerings by path (ops/lm.py causal_conv): 'kernel' is
+# pallas_ops.causal_conv1d, 'xla' the plain statement other shapes keep,
+# by the shape that decided.  From shapes while the operator is traced,
+# like the attention counts, never inside a step
+_CAUSAL_CONV = {}   # (path, sequences, t, channels, width) -> lowerings
+_CAUSAL_CONV_KEY = ('path', 'sequences', 't', 'channels', 'width')
+
+
+def note_causal_conv(path, sequences, t, channels, width):
+    key = (path,) + tuple(int(x) for x in (sequences, t, channels, width))
+    with _STATE['lock']:
+        _CAUSAL_CONV[key] = _CAUSAL_CONV.get(key, 0) + 1
+
+
+def causal_conv_stats():
+    """CausalConv1D's lowerings: {'kernel': n, 'xla': n, 'shapes':
+    [{'path', 'sequences', 't', 'channels', 'width', 'lowerings'},
+    ...]}."""
+    with _STATE['lock']:
+        seen = sorted(_CAUSAL_CONV.items())
+    out = {'kernel': 0, 'xla': 0, 'shapes': []}
+    for key, n in seen:
+        out[key[0]] += n
+        out['shapes'].append(dict(zip(_CAUSAL_CONV_KEY, key), lowerings=n))
+    return out
+
+
 # LoopedDecoder's stacks by shape (ops/lm.py): how often the operator was
 # traced for training (`lowerings`; shape inference's traces are not
 # counted), with the passes, the layers and what its scan keeps for the
@@ -1483,6 +1510,7 @@ def clear():
         _MOE_EXPERTS.clear()
         _ATTENTION.clear()
         _DELTA_RULE.clear()
+        _CAUSAL_CONV.clear()
         _LOOPED.clear()
         for k in _EMBED:
             _EMBED[k] = 0

@@ -215,3 +215,31 @@ def test_expert_layer_compiles_for_the_chip(one_chip, monkeypatch, case):
     assert text.count('tpu_custom_call') == 2
     assert 'add_rows' in text
     assert not re.search(r'\[%d,' % (n * k + lm.EXPERT_TILE), text)
+
+
+@pytest.mark.parametrize('what,kernels', [
+    ('forward', ['causal_conv1d']),
+    ('value_and_gradient', ['causal_conv1d', 'causal_conv1d_bwd'])])
+def test_causal_conv_compiles_for_the_chip(one_chip, monkeypatch, what,
+                                           kernels):
+    """The Qwen3-Next cell's causal convolution ((16,384, 8,192)
+    bfloat16 rows as 2 sequences, a kernel of 4) through causal_conv:
+    Mosaic takes the kernels' blocks within the scoped VMEM, and the
+    program's temporaries hold no float32 copy of x (512 MiB; the XLA
+    statement's gradient held 3 GiB of them)."""
+    monkeypatch.setattr(pallas_ops, 'default_interpret', lambda *a: False)
+    x = jax.ShapeDtypeStruct((16384, 8192), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((8192, 4), jnp.bfloat16, sharding=one_chip)
+
+    def conv(x, w):
+        return lm.causal_conv(x, w, 8192)
+
+    fn = {'forward': conv,
+          'value_and_gradient': jax.value_and_grad(
+              lambda x, w: jnp.sum(conv(x, w).astype(jnp.float32)),
+              argnums=(0, 1))}[what]
+    lowered = jax.jit(fn).lower(x, w)
+    assert sorted(_mosaic_kernels(lowered.as_text())) == kernels
+    compiled = lowered.compile()
+    assert compiled.as_text().count('tpu_custom_call') == len(kernels)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
