@@ -293,6 +293,33 @@ def causal_conv_stats():
     return out
 
 
+# SparseMoE's plan by path (ops/lm.py _pair_plan): 'packed' sorts each
+# pair's slot and index packed into one int32, 'stable' sorts (slot,
+# index) stably where they do not fit one, by the shape that decided.
+# From shapes while the operator is traced, never inside a step
+_MOE_PLAN = {}      # (path, pairs, buckets, top_k) -> lowerings
+_MOE_PLAN_KEY = ('path', 'pairs', 'buckets', 'top_k')
+
+
+def note_moe_plan(path, pairs, buckets, top_k):
+    key = (path,) + tuple(int(x) for x in (pairs, buckets, top_k))
+    with _STATE['lock']:
+        _MOE_PLAN[key] = _MOE_PLAN.get(key, 0) + 1
+
+
+def moe_plan_stats():
+    """SparseMoE's plans: {'packed': n, 'stable': n, 'shapes': [{'path',
+    'pairs', 'buckets', 'top_k', 'lowerings'}, ...]}; `buckets` is the
+    held experts and one for the pairs held elsewhere."""
+    with _STATE['lock']:
+        seen = sorted(_MOE_PLAN.items())
+    out = {'packed': 0, 'stable': 0, 'shapes': []}
+    for key, n in seen:
+        out[key[0]] += n
+        out['shapes'].append(dict(zip(_MOE_PLAN_KEY, key), lowerings=n))
+    return out
+
+
 # LoopedDecoder's stacks by shape (ops/lm.py): how often the operator was
 # traced for training (`lowerings`; shape inference's traces are not
 # counted), with the passes, the layers and what its scan keeps for the
@@ -1511,6 +1538,7 @@ def clear():
         _ATTENTION.clear()
         _DELTA_RULE.clear()
         _CAUSAL_CONV.clear()
+        _MOE_PLAN.clear()
         _LOOPED.clear()
         for k in _EMBED:
             _EMBED[k] = 0

@@ -425,6 +425,136 @@ def test_no_array_is_sized_for_every_pair():
         assert not re.search(r'(?<!\d)%dx\d+x' % rows, text)
 
 
+# (experts, held, top k) of the three expert cells' layers
+PLAN_CELLS = {'qwen3-next': (512, 32, 10), 'trinity-mini': (128, 16, 8),
+              'kanana': (128, 16, 6)}
+PLAN_ROUTES = ['random-first', 'random-last', 'none-held', 'all-held',
+               'one-token-held']
+
+
+def _routed_keys(experts, held, k, route, tokens=37):
+    """(tokens * k,) slots of a routing: a held expert's 0..held-1, or
+    `held` for a pair held elsewhere.  random-first and random-last: a
+    uniform router, expert_offset 0 and experts - held; none-held: no
+    pair lands here; all-held: every pair does; one-token-held: token 0
+    alone has pairs here, several."""
+    rng = np.random.default_rng([experts, held, k, PLAN_ROUTES.index(route)])
+
+    def picks(pool):
+        return np.stack([rng.choice(pool, k, replace=False)
+                         for _ in range(tokens)])
+    offset = experts - held if route == 'random-last' else 0
+    if route.startswith('random'):
+        idx = picks(np.arange(experts))
+    elif route == 'all-held':
+        idx = picks(np.arange(held))
+    else:
+        idx = picks(np.arange(held, experts))
+        if route == 'one-token-held':
+            idx[0, :3] = rng.choice(held, 3, replace=False)
+    local = idx.reshape(-1) - offset
+    return jnp.asarray(np.where((local >= 0) & (local < held), local, held),
+                       jnp.int32)
+
+
+def _argsort_plan(key, weight, held):
+    """The parent's plan: a stable argsort, searchsorted over the sorted
+    keys, gathers, and a second argsort for the inverse."""
+    order = jnp.argsort(key, stable=True)
+    starts = jnp.searchsorted(key[order], jnp.arange(held + 1), side='left')
+    return (order, weight[order], jnp.argsort(order),
+            (starts[1:] - starts[:-1]).astype(jnp.int32))
+
+
+@pytest.mark.parametrize('path', ['packed', 'stable'])
+@pytest.mark.parametrize('route', PLAN_ROUTES)
+@pytest.mark.parametrize('cell', sorted(PLAN_CELLS))
+def test_the_plan_is_the_stable_argsorts(monkeypatch, cell, route, path):
+    """SparseMoE's plan, on both paths, against the stable argsorts it
+    replaced, exactly: every row of the order, the tokens and the
+    weights, the held experts' group sizes, and the backward's return to
+    the pairs' order (the inverse permutation, applied to the pairs'
+    own indices)."""
+    experts, held, k = PLAN_CELLS[cell]
+    if path == 'stable':
+        monkeypatch.setattr(lm, '_key_bits', lambda pairs, held: None)
+    key = _routed_keys(experts, held, k, route)
+    weight = jnp.asarray(np.random.default_rng(5).random(key.shape[0]),
+                         jnp.float32)
+    order, weight_of, group_sizes = lm._pair_plan(key, weight, held, k)
+    want = _argsort_plan(key, weight, held)
+    np.testing.assert_array_equal(order, want[0])
+    np.testing.assert_array_equal(order // k, want[0] // k)
+    np.testing.assert_array_equal(weight_of, want[1])
+    np.testing.assert_array_equal(
+        lm._to_pairs(order, jnp.arange(key.shape[0], dtype=jnp.int32)),
+        want[2])
+    np.testing.assert_array_equal(group_sizes, want[3])
+    held_pairs = {'none-held': 0, 'all-held': key.shape[0],
+                  'one-token-held': 3}.get(route)
+    if held_pairs is not None:
+        assert int(group_sizes.sum()) == held_pairs
+
+
+def test_key_bits_choose_the_path_from_the_shapes():
+    """The packed keys need 2**b >= the pairs and (held + 1) * 2**b
+    within an int32: 18 + 6 bits at Qwen3-Next's 163,840 pairs and 33
+    slots; past the int32 the stable sort."""
+    assert lm._key_bits(16384 * 10, 32) == 18
+    assert lm._key_bits(8192 * 8, 16) == 16
+    assert lm._key_bits(8192 * 6, 16) == 16
+    assert lm._key_bits(1, 0) == 1
+    assert lm._key_bits(2 ** 20, 2047) == 20
+    assert lm._key_bits(2 ** 20 + 1, 2047) is None
+    assert lm._key_bits(2 ** 20, 2048) is None
+
+
+def _ops_over(text, op, elements):
+    """How many `op`s (sort, gather) of a StableHLO text have a result of
+    `elements` elements."""
+    if op == 'sort':
+        found = re.findall(r'"stablehlo\.sort".*?\}\) : \([^)]*\) -> '
+                           r'\((tensor<[^>]*>)', text, re.S)
+    else:
+        found = re.findall(r'"stablehlo\.%s"[^\n]* -> (tensor<[^>]*>)' % op,
+                           text)
+    return sum(int(np.prod([int(d) for d in re.findall(r'(\d+)x', t)]))
+               == elements for t in found)
+
+
+def test_the_plan_sorts_once_and_gathers_no_pair():
+    """At 150 tokens and top 4: the forward sorts the 600 pairs once
+    and gathers none of them (the parent sorted twice and gathered every
+    pair's key and weight); its gradient adds one sort, the weights'
+    gradient back to the pairs' order, and no gather of every pair."""
+    c = dict(TINY, num_experts_held=8, expert_offset=8)
+    p = _layer_params(c)
+    x = rand(14, 150, c['hidden_size'])
+    ws = [p[n] for n in MOE_NAMES]
+    program = _sparse_moe_loss(c, rand(15, 150, c['hidden_size']), 32)
+    pairs = 150 * c['num_experts_per_tok']
+    for f, sorts in ((lambda *a: program(*a)[1], 1),
+                     (jax.grad(lambda *a: program(*a)[0], argnums=range(5)),
+                      2)):
+        text = jax.jit(f).lower(x, *ws).as_text()
+        assert _ops_over(text, 'sort', pairs) == sorts
+        assert _ops_over(text, 'gather', pairs) == 0
+
+
+def test_the_plan_counter_reads_the_packed_path():
+    """profiler.moe_plan_stats() after the tiny model's step lowers:
+    every plan on the packed path, at 80 tokens x top 4, 8 held experts
+    and one slot for the rest."""
+    profiler._MOE_PLAN.clear()
+    _bulk_step_text(*_tiny_module()[:2])
+    stats = profiler.moe_plan_stats()
+    assert stats['packed'] > 0 and stats['stable'] == 0
+    assert [(s['path'], s['pairs'], s['buckets'], s['top_k'])
+            for s in stats['shapes']] == [
+        ('packed', 2 * SEQ * TINY['num_experts_per_tok'],
+         TINY['num_experts_held'] + 1, TINY['num_experts_per_tok'])]
+
+
 def test_counters_reach_the_profiler_without_a_sync_in_the_step():
     """The counts live on the device as auxiliary state;
     fold_device_counters() folds what is new into moe_stats(), and
@@ -564,11 +694,13 @@ def _bulk_step_text(mod, batches):
 # backend, tests/conftest.py's eight devices): GatedDeltaRule's
 # chunk-local half as two kernels, interpreted here, GatedAttention's
 # grouped heads on the flash kernels, and SparseMoE's tiles added to
-# their tokens inside the tile loop by pallas_ops.add_rows.  A change
-# to an operator of this model on purpose replaces it; a change that
-# says it leaves Qwen3-Next's program alone keeps it.
+# their tokens inside the tile loop by pallas_ops.add_rows, and its
+# plan one sort of packed keys, the weights' gradient sorted back to the
+# pairs' order.  A change to an operator of this model on purpose
+# replaces it; a change that says it leaves Qwen3-Next's program alone
+# keeps it.
 STEP_TEXT_SHA256 = (
-    'cbf27b112978935d00f78ba0546c1bb4f273337b692911556e4a286b287799cd')
+    '9962ef280aea8b8ea6a4a4ba24b7db6530170e876d7b5ea76df8416060f657fb')
 
 
 def test_grouped_heads_take_the_kernels_and_the_program_keeps_its_text():
@@ -580,7 +712,7 @@ def test_grouped_heads_take_the_kernels_and_the_program_keeps_its_text():
     lanes; the makes and backward rules traced with them depend on what
     jax has cached of earlier traces: tests/test_delta_rule_local.py
     counts them on a shape of its own); and the step program is, to the
-    byte, the one PR 37 lowered."""
+    byte, the one STEP_TEXT_SHA256 records."""
     profiler._ATTENTION.clear()
     profiler._DELTA_RULE.clear()
     text = _bulk_step_text(*_tiny_module()[:2])

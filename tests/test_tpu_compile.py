@@ -190,7 +190,15 @@ def test_gated_core_compiles_for_the_chip_in_memory_linear_in_t(
 # (tokens, hidden, experts, held, expert width, top k, scoring) of the
 # expert layers of the cells
 EXPERT_LAYERS = {'qwen3-next': (16384, 2048, 512, 32, 512, 10, 'softmax'),
-                 'trinity-mini': (8192, 2048, 128, 16, 1024, 8, 'sigmoid')}
+                 'trinity-mini': (8192, 2048, 128, 16, 1024, 8, 'sigmoid'),
+                 'kanana': (8192, 2048, 128, 16, 768, 6, 'sigmoid')}
+
+
+def _pair_gathers(text, pairs):
+    """The compiled program's fusions of kind kCustom (gathers, on the
+    TPU) whose result is one value for each of the `pairs` pairs."""
+    return re.findall(r'= [a-z0-9]+\[%d\]\S* fusion\([^)]*\), kind=kCustom'
+                      % pairs, text)
 
 
 @pytest.mark.parametrize('case', sorted(EXPERT_LAYERS))
@@ -215,6 +223,34 @@ def test_expert_layer_compiles_for_the_chip(one_chip, monkeypatch, case):
     assert text.count('tpu_custom_call') == 2
     assert 'add_rows' in text
     assert not re.search(r'\[%d,' % (n * k + lm.EXPERT_TILE), text)
+    assert not _pair_gathers(text, n * k)
+
+
+@pytest.mark.parametrize('case', sorted(EXPERT_LAYERS))
+def test_expert_plan_compiles_to_one_sort_and_no_pair_gather(
+        one_chip, monkeypatch, case):
+    """A cell's expert layer forward: its plan is one sort over the
+    tokens x k pairs (each pair's packed slot and index, carrying its
+    weight), and no fusion gathers a value for every pair (the parent's
+    key[order] and weight[order])."""
+    monkeypatch.setattr(pallas_ops, 'default_interpret', lambda *a: False)
+    n, h, e, held, inter, k, scoring = EXPERT_LAYERS[case]
+
+    def shape(*s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    def forward(x, router, wg, wu, wd):
+        return lm.sparse_moe(x, router, wg, wu, wd, k, 0, scoring=scoring)[0]
+
+    text = jax.jit(forward).lower(
+        shape(n, h), shape(e, h, dtype=jnp.float32), shape(held, inter, h),
+        shape(held, inter, h), shape(held, h, inter)).compile().as_text()
+    assert not _pair_gathers(text, n * k)
+    sorts = re.findall(r'^\s*%%\S+ = \((s32\[%d\]\S*), (f32\[%d\]\S*)\) '
+                       r'sort\(' % (n * k, n * k), text, re.M)
+    assert len(sorts) == 1
+    assert not re.search(r'\[%d\]\S*\) sort\(.*is_stable=true' % (n * k),
+                         text)
 
 
 @pytest.mark.parametrize('what,kernels', [
