@@ -88,8 +88,8 @@ def make_module(ctxs, num_layers, num_classes, image):
     return mx.mod.Module(sym, context=ctxs if len(ctxs) > 1 else ctxs[0])
 
 
-# the optimizer bench.py and examples/image_classification/common/fit.py
-# train this model with
+# the optimizer benchmark/configs/resnet50.json and
+# examples/image_classification/common/fit.py train this model with
 OPTIMIZER_PARAMS = {'learning_rate': 0.1, 'momentum': 0.9, 'wd': 1e-4,
                     'multi_precision': True}
 
@@ -187,8 +187,8 @@ def phase_a(clog, ctx, batch=256, image=(3, 224, 224), num_layers=50,
            ' '.join('%.1f' % ms for ms in steady), n_fit, dev))
     del train, x, y
 
-    # (b) bulk_step dispatches of K pre-staged batches — bench.py's
-    # headline configuration
+    # (b) bulk_step dispatches of K pre-staged batches — the device-fed
+    # ResNet-50 cells of BENCHMARK.json
     batches = []
     for _ in range(bulk):
         bx, by = synthetic(rng, batch, image, num_classes)

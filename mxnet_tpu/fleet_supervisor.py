@@ -1889,8 +1889,9 @@ class FleetSupervisor(object):
         # the latency window is request-driven: with ZERO new requests
         # it is frozen at the last busy period's values, and treating
         # that as "hot" would block scale-down FOREVER on an idle
-        # fleet (caught by the BENCH_LOOP diurnal drill: the fleet
-        # stayed at peak size through the idle night phase)
+        # fleet (the fleet stayed at peak size through an idle night:
+        # tests/test_fleet_supervisor.py::
+        # test_autoscale_follows_a_diurnal_load_without_flapping)
         if delta > 0:
             for name, m in list(self._models.items()):
                 d = m.get('deadline_ms')

@@ -52,8 +52,9 @@ Observability: profiler.gluon_fused_stats() (gluon_fused_steps /
 gluon_fused_dispatches), the 'gluon_fused' span category, the
 reduce_buckets_issued / scan_fused_metric_steps
 comm counters, and the ZeRO comm/state counters Module feeds.
-Bench: BENCH_GLUON=1 and BENCH_OVERLAP=1 in bench.py.  Docs:
-docs/PERF.md rounds 10-11.
+Gates: tests/test_gluon_fused.py (fused vs imperative parity) and
+tests/test_overlap_fusion.py (interleaved vs end-of-backward reduce).
+Docs: docs/PERF.md rounds 10-11.
 """
 import hashlib
 import os

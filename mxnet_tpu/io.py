@@ -469,7 +469,8 @@ class PrefetchToDeviceIter(_StagedBatchMixin, DataIter):
     the time the training loop was blocked on input (the 'io.next'
     span; 'io.host_batch' and 'io.stage' split it into the wrapped
     iterator's next() and the enqueueing of the copy) — so callers
-    (bench.py) can report per-step input stall.
+    can report per-step input stall (tests/test_image_pipeline.py:
+    test_prefetch_to_device_feeds_stall_counter).
     """
 
     def __init__(self, data_iter, size=2, device=None, mesh=None):

@@ -19,17 +19,16 @@ tokens by DMA, forward and backward).
   GatedAttention   per-head q/k RMS norm, partial or no rotary, grouped-
                    head causal softmax attention over every earlier key
                    or a sliding window of them (causal_attention:
-                   the flash kernels at any T they tile, K and V not
-                   repeated over a group, the tiles left of a window's
-                   band skipped; else the blocked XLA core), and the
+                   the flash kernels, K and V not repeated over a
+                   group, the tiles left of a window's band skipped,
+                   a ragged T padded to whole sublanes), and the
                    sigmoid gate on the output, packed beside the query
                    or an input of its own
   LatentAttention  the core of multi-head latent attention: rotary by
                    adjacent pairs on the keys' one shared rotary head
                    and on each query head's rotary part, causal softmax
                    attention with keys wider than values
-                   (causal_attention: the flash kernels at any T
-                   they tile, else the blocks)
+                   (causal_attention: the flash kernels)
   CausalConv1D     depthwise causal convolution along the sequence (2 kernels)
   GatedDeltaRule   the gated delta rule in chunks (WY form): a unit
                    lower triangular solve inside a chunk and the state
@@ -61,7 +60,6 @@ from .registry import register, asbool, asfloat, asint
 from .. import pallas_ops, profiler
 
 F32 = jnp.float32
-ATTN_BLOCK = 512            # query rows a block of the blocked attention core
 FLASH_BLOCK = 1024          # rows and keys a tile of the flash kernels
 CHUNK = 64                  # tokens a chunk of GatedDeltaRule
 LANES = 128                 # head widths GatedDeltaRule's kernels take
@@ -146,145 +144,40 @@ def rotary(x, rotary_dim, theta):
                             rest], axis=-1)
 
 
-def _block_scores(qb, kb, scale, first_row, band=None):
-    """Masked scores (kv, group, rows, keys) of a block of query rows
-    against the keys it was given, in float32: all keys from the
-    sequence's first, or with `band` = (first key, window) the keys
-    from `first key` on, of which a row sees the last `window` up to
-    itself."""
-    s = jnp.einsum('qghd,kgd->ghqk', qb, kb,
-                   preferred_element_type=F32) * scale
-    rows = first_row + jnp.arange(qb.shape[0])[:, None]
-    if band is None:
-        return jnp.where(jnp.arange(kb.shape[0])[None, :] <= rows, s,
-                         -jnp.inf)
-    first_key, window = band
-    keys = first_key + jnp.arange(kb.shape[0])[None, :]
-    return jnp.where((keys <= rows) & (rows - keys < window), s, -jnp.inf)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _attention_block(scale, first_row, band, qb, kb, vb):
-    return _attention_block_fwd(scale, first_row, band, qb, kb, vb)[0]
-
-
-def _attention_block_fwd(scale, first_row, band, qb, kb, vb):
-    s = _block_scores(qb, kb, scale, first_row, band)
-    lse = jax.nn.logsumexp(s, axis=-1)
-    p = jnp.exp(s - lse[..., None]).astype(vb.dtype)
-    o = jnp.einsum('ghqk,kgd->qghd', p, vb,
-                   preferred_element_type=F32).astype(qb.dtype)
-    return o, (qb, kb, vb, o, lse)
-
-
-def _attention_block_bwd(scale, first_row, band, res, do):
-    """The scores are made again, not kept; the softmax's row term is
-    sum(dO * O) over the head (as in flash attention), not a product
-    along the keys."""
-    qb, kb, vb, o, lse = res
-    p = jnp.exp(_block_scores(qb, kb, scale, first_row, band) -
-                lse[..., None])
-    dv = jnp.einsum('ghqk,qghd->kgd', p.astype(do.dtype), do,
-                    preferred_element_type=F32)
-    dp = jnp.einsum('qghd,kgd->ghqk', do, vb, preferred_element_type=F32)
-    row = jnp.sum(do.astype(F32) * o.astype(F32), axis=-1)   # (q, g, h)
-    ds = (p * (dp - jnp.moveaxis(row, 0, -1)[..., None]) * scale
-          ).astype(qb.dtype)
-    dq = jnp.einsum('ghqk,kgd->qghd', ds, kb, preferred_element_type=F32)
-    dk = jnp.einsum('ghqk,qghd->kgd', ds, qb, preferred_element_type=F32)
-    return dq.astype(qb.dtype), dk.astype(kb.dtype), dv.astype(vb.dtype)
-
-
-_attention_block.defvjp(_attention_block_fwd, _attention_block_bwd)
-
-
-def block_bands(t, block_q, window=None):
-    """(first row, first key, keys' end) of each block of query rows of
-    a sequence of t: a block reads the keys up to its last row, from
-    the sequence's first or, with a window, from the start of the block
-    of keys that holds the first key its first row sees."""
-    block_q = min(block_q, t)
-    return [(r0, 0 if window is None else
-             max(0, (r0 - window + 1) // block_q * block_q),
-             min(r0 + block_q, t)) for r0 in range(0, t, block_q)]
-
-
-def blocked_causal_attention(q, k, v, scale, block_q=ATTN_BLOCK,
-                             window=None):
-    """causal_attention in plain XLA, grouped heads, any length and a
-    sliding window: one sequence at a time and query rows in blocks,
-    each block against the keys it can see (block_bands: with a window
-    the band of key blocks its rows reach, so a windowed layer's time
-    and temporaries grow with T and not with its square; rows see
-    their last `window` keys, themselves among them); a block keeps
-    its output and its rows' log-sum-exp and makes its scores again in
-    the backward pass, so no T x T score matrix is ever stored (a
-    block's float32 scores do cross HBM between its fusions).  The
-    gradients of a block's keys and values go back into its band.
-    causal_attention sends it what the flash kernels refuse: a T that
-    no block of 8 rows divides."""
-    t = q.shape[1]
-    bands = block_bands(t, block_q, window)
-
-    def one_sequence(args):
-        qs, ks, vs = args
-        return jnp.concatenate([
-            _attention_block(scale, r0,
-                             None if window is None else (k0, window),
-                             qs[r0:k1], ks[k0:k1], vs[k0:k1])
-            for r0, k0, k1 in bands], axis=0)
-
-    return lax.map(one_sequence, (q, k, v))
-
-
-def _positions(t, window, tile, rows):
-    """(visited, needed) query-key positions of one head over one
-    sequence: those a path scores going forward (the kernel's square
-    tiles of edge `tile` that its grid computes: on and under the
-    diagonal and, with a window, from the band's left edge on; or the
-    blocked core's blocks of `rows` rows against their bands) and those
-    the mask lets through (row i sees min(i + 1, window) keys)."""
-    if tile is not None:
-        visited = pallas_ops.visited_positions(t, tile, window)
-    else:
-        visited = sum((k1 - r0) * (k1 - k0)
-                      for r0, k0, k1 in block_bands(t, rows, window))
-    reach = t if window is None else window
-    return visited, reach * (reach + 1) // 2 + (t - reach) * reach
-
-
 def causal_attention(q, k, v, scale, block_q=None, window=None):
     """softmax(q k^T * scale + causal) v with grouped heads: q
     (B, T, kv, group, d), k (B, T, kv, d) and v (B, T, kv, dv), whose
     width is its own (latent attention's keys are wider than its
     values); the result is (B, T, kv, group, dv).  With `window` row i
     sees key j iff 0 <= i - j < window (a window that reaches the
-    sequence's first key from its last row is no window).  The path is
-    chosen from the operands' shapes alone:
+    sequence's first key from its last row is no window).
 
-      kernel   a T the flash kernels' schedules tile (blocks of whole
-               sublanes: a multiple of 8 rows under `block_q` dividing
-               T), any group, any window: pallas_ops.flash_attention,
-               the whole batch in one call with the heads in front of
-               the rows (one transpose each of q, k, v and the result,
-               whatever the group: (B, kv * group, T, d) is the layout
-               the kernels read a group's heads from).  K and V are
-               not repeated; dK and dV sum over a group in float32
-               inside the backward kernel; under a window the grids
-               skip the tiles left of the band.  Scores, probabilities
-               and their gradients live in VMEM a tile at a time,
-               forward and backward; residuals are q, k, v, o and the
-               rows' log-sum-exp.
-      blocked  a ragged T: blocked_causal_attention.
+    One path, pallas_ops.flash_attention's kernels: the whole batch in
+    one call with the heads in front of the rows (one transpose each of
+    q, k, v and the result, whatever the group: (B, kv * group, T, d)
+    is the layout the kernels read a group's heads from).  K and V are
+    not repeated; dK and dV sum over a group in float32 inside the
+    backward kernel; under a window the grids skip the tiles left of
+    the band.  Scores, probabilities and their gradients live in VMEM a
+    tile at a time, forward and backward; residuals are q, k, v, o and
+    the rows' log-sum-exp; operands keep their type, with float32
+    scores, sums and accumulators.
 
-    Both keep bf16 operands with float32 scores, sums and accumulators.
-    `block_q`, where given, is the rows of a block on either path; left
-    out it is, on the kernel, FLASH_BLOCK (tiles of 1024 x 1024 timed
+    A T the kernels' schedules do not tile (no block of whole sublanes,
+    8 rows, divides it) is padded with zero rows at its end up to the
+    next multiple of 8 and the result sliced back, exactly: a real row
+    sees no key after itself, so never a padded one; a padded row sees
+    real keys or itself, so it stays finite, and the slice gives it a
+    zero cotangent, so it adds nothing to dK or dV.  A T they tile is
+    passed as it is.
+
+    `block_q`, a power of two of at least 8 rows where given, is the
+    tile's edge; left out it is FLASH_BLOCK (tiles of 1024 x 1024 timed
     best at T = 8,192 with keys of 192 over values of 128: PERF.md
     section 6, PR 32) or under a window what pallas_ops.window_block
-    makes of it, and ATTN_BLOCK on the blocked core.
-    profiler.attention_stats() counts the lowerings by path, with the
-    query-key positions each scores and the mask lets through."""
+    makes of it.  profiler.attention_stats() counts the lowerings, with
+    the query-key positions the kernels' tiles score (at the padded
+    length) and those the mask lets through (at T)."""
     t, group = q.shape[1], q.shape[3]
     if window is not None and window >= t:
         window = None
@@ -294,27 +187,28 @@ def causal_attention(q, k, v, scale, block_q=None, window=None):
         tile = FLASH_BLOCK
     else:
         tile = pallas_ops.window_block(window, FLASH_BLOCK)
-    kernel = not pallas_ops._needs_dense_fallback(t, t, tile)
-    rows = ATTN_BLOCK if block_q is None else block_q
-    visited, needed = _positions(t, window, tile if kernel else None, rows)
+    pad = -t % 8 if pallas_ops._needs_dense_fallback(t, t, tile) else 0
+    reach = t if window is None else window
     heads = q.shape[2] * group
     profiler.note_attention_lowering(
-        'kernel' if kernel else 'blocked', heads=heads, group=group,
-        dk=q.shape[4], dv=v.shape[3], t=t, window=window,
-        keys_visited=q.shape[0] * heads * visited,
-        keys_needed=q.shape[0] * heads * needed)
-    if not kernel:
-        return blocked_causal_attention(q, k, v, scale, rows, window)
-    b = q.shape[0]
+        'kernel', heads=heads, group=group, dk=q.shape[4], dv=v.shape[3],
+        t=t, window=window, keys_visited=q.shape[0] * heads *
+        pallas_ops.visited_positions(t + pad, tile, window),
+        keys_needed=q.shape[0] * heads *
+        (reach * (reach + 1) // 2 + (t - reach) * reach))
+    if pad:
+        q, k, v = (jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+                   for x in (q, k, v))
+    b, tp = q.shape[:2]
     # ungrouped heads keep the slice they had (their step lowers to the
     # program it was); a group's heads follow one another, as the
     # kernels' index maps read them
     o = pallas_ops.flash_attention(
         jnp.swapaxes(q[:, :, :, 0] if group == 1 else
-                     q.reshape(b, t, heads, q.shape[4]), 1, 2),
+                     q.reshape(b, tp, heads, q.shape[4]), 1, 2),
         jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), causal=True,
         scale=scale, block_q=tile, window=window)
-    o = jnp.swapaxes(o, 1, 2)
+    o = jnp.swapaxes(o, 1, 2)[:, :t]
     return o[:, :, :, None] if group == 1 else \
         o.reshape(b, t, q.shape[2], group, v.shape[3])
 
@@ -411,10 +305,8 @@ def _latent_attention(attrs, q, kv, k_pe):
     1 / sqrt(nope + rope), to values of width v.  Returns
     (N, heads * v).  Every query head has its own key head (group 1);
     causal_attention runs the flash kernels with a value width of
-    their own wherever blocks of whole sublanes (a multiple of 8 rows)
-    divide seq_len (8,192 in the cell: tiles of 1024 x 1024, float32
-    scores in VMEM only), and the blocked XLA core at a ragged
-    seq_len."""
+    their own (seq_len 8,192 in the cell: tiles of 1024 x 1024, float32
+    scores in VMEM only)."""
     heads, seq_len = asint(attrs['num_heads']), asint(attrs['seq_len'])
     nope, rope = (asint(attrs['qk_nope_head_dim']),
                   asint(attrs['qk_rope_head_dim']))

@@ -985,7 +985,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     of the band as they skip those above the diagonal.  Returns
     (batch, heads, q_len, dv).  On non-TPU backends runs in Pallas
     interpret mode (slow but correct) unless `interpret` is passed
-    explicitly.
+    explicitly.  Lengths no schedule can tile take the dense jnp
+    computation (ops.lm.causal_attention pads them to whole sublanes
+    instead).
 
     block_q: row-tile edge.  Default (None) auto-scales with the
     sequence — 256 for short T, up to 1024 for long T, where the
@@ -1000,10 +1002,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     if block_q is None:
         block_q = _default_block_q(tq) if window is None else \
             window_block(window)
+    # dense route, as in flash_attention_with_lse
     if _needs_dense_fallback(tq, tk, block_q):
-        if window is None and q.shape[1] == k.shape[1]:
-            from .parallel.ring_attention import full_attention
-            return full_attention(q, k, v, causal=causal, scale=scale)
         return _dense_attention_lse(q, k, v, causal, scale, window)[0]
     if interpret is None:
         interpret = default_interpret(q, k, v)

@@ -20,7 +20,9 @@ doesn't care about concatenation), so the two modes agree bitwise.
 Env knobs (docs/PERF.md round 11):
   MXNET_TPU_INTERLEAVE_REDUCE=0  force the end-of-backward baseline
       (an optimization_barrier makes every wgrad complete before any
-      reduce issues — the A/B arm BENCH_OVERLAP measures against)
+      reduce issues — the arm
+      tests/test_overlap_fusion.py::test_interleaved_vs_end_parity_mesh
+      holds the interleaved schedule to)
   MXNET_TPU_REDUCE_BUCKETS=N     exact bucket count (per dtype group)
   MXNET_TPU_ZERO_BUCKET_MB       bucket fill target otherwise (shared
       with the ZeRO-1 bucketing, parallel/zero.py)

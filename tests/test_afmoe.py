@@ -1,5 +1,5 @@
 """AFMoE's layer kinds (Trinity-Mini's) on the CPU at a small size: the
-sliding window of the attention core on either path, GatedAttention's
+sliding window of the attention core at whole and ragged T, GatedAttention's
 attributes one at a time, the shares of the expert layer and a whole
 tiny model through Module.bulk_step and fit, against the plain float32
 reference the benchmark compares with (benchmark/reference/afmoe.py,
@@ -25,7 +25,7 @@ from reference import qwen3_next as ref_qwen           # noqa: E402
 
 SEQ = 40
 # a dense layer, then a windowed, a full and a windowed expert layer;
-# the window is under the sequence, the blocks of 512 rows above it
+# the window is under the sequence, a kernel tile above it
 TINY = dict(vocab_size=64, hidden_size=32, num_hidden_layers=4,
             layer_types=['sliding_attention', 'sliding_attention',
                          'full_attention', 'sliding_attention'],
@@ -86,6 +86,13 @@ WINDOW_CASES = {
     'window-past-T': (50, 16, 50, 2, 4),
     'ungrouped-ragged-window-of-two-keys': (37, 8, 2, 3, 1),
     'one-block': (24, 512, 7, 2, 4),
+    # a T no block of 8 rows divides, padded onto the kernels
+    'ragged-33': (33, 16, None, 2, 4),
+    'ungrouped-ragged-33-window': (33, 16, 9, 2, 1),
+    'ungrouped-ragged-36': (36, 16, None, 2, 1),
+    'ragged-36-window': (36, 16, 5, 2, 4),
+    'ragged-50': (50, 16, None, 2, 4),
+    'ungrouped-ragged-50': (50, 16, None, 2, 1),
 }
 
 
@@ -185,10 +192,16 @@ def test_the_counter_of_positions_visited_and_needed(attention_paths, t,
         *(jax.ShapeDtypeStruct(s, jnp.float32) for s in
           ((3, t, kv, group, 16), (3, t, kv, 16), (3, t, kv, 16))))
     (shape,) = attention_paths()['shapes']
-    visited, needed = positions(t, block, window)
-    # a T that blocks of 8 rows divide takes the kernels, whose square
-    # tiles of `block` are the same whole blocks of keys a block of rows
-    assert shape == dict(path='blocked' if t % 8 else 'kernel',
+    # the kernels' square tiles of `block` (halved until they divide T,
+    # ragged T padded to whole sublanes) are the same whole blocks of
+    # keys a block of rows reads; the mask lets through those of T
+    padded = -(-t // 8) * 8
+    tile = block
+    while padded % tile:
+        tile //= 2
+    visited, needed = positions(padded, tile, window)[0], \
+        positions(t, block, window)[1]
+    assert shape == dict(path='kernel',
                          heads=kv * group, group=group,
                          dk=16, dv=16, t=t, window=window, lowerings=1,
                          keys_visited=3 * kv * group * visited,

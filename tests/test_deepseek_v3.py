@@ -111,19 +111,31 @@ def test_latent_attention_gradients(wrt, name):
 
 @pytest.mark.parametrize('t', [SEQ, 24, 33])
 def test_blocked_core_takes_a_value_width_of_its_own(t):
-    """Blocks of 16 query rows, whole and ragged; keys 12 wide, values
-    6: values and all three gradients against the reference's core."""
+    """Tiles of 16 query rows, whole and ragged (33 rows padded to 40
+    onto the kernels); keys 12 wide, values 6: values and all three
+    gradients against the reference's core."""
     net = convnet.Net({})
     q, k, v = rand(1, t, HEADS, 12), rand(2, t, HEADS, 12), \
         rand(3, t, HEADS, 6)
     weight = rand(4, t, HEADS, 6)
 
     def program(q, k, v):
-        return lm.blocked_causal_attention(
+        return lm.causal_attention(
             q[None, :, :, None, :], k[None], v[None], 1.0 / np.sqrt(12),
             block_q=16)[0, :, :, 0]
 
     _core_against_the_reference(net, program, q, k, v, weight, nope=8)
+
+
+def dense_core(q, k, v, scale):
+    """causal_attention's operands and result through the dense float32
+    softmax of pallas_ops (_dense_attention_lse), K and V repeated over
+    a group."""
+    b, t, kv, group, d = q.shape
+    o = pallas_ops._dense_attention_lse(
+        jnp.swapaxes(q.reshape(b, t, kv * group, d), 1, 2),
+        jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), True, scale)[0]
+    return jnp.swapaxes(o, 1, 2).reshape(b, t, kv, group, v.shape[3])
 
 
 def _core_against_the_reference(net, program, q, k, v, weight, nope,
@@ -171,8 +183,8 @@ def test_kernel_core_takes_a_value_width_of_its_own(
         schedule):
     """Ungrouped heads at a T the kernels tile go to the flash kernels,
     keys 12 over values 6 and keys 192 over values 128: values and all
-    three gradients against the reference's core and against the
-    blocked core, on the resident and the streaming schedule of the
+    three gradients against the reference's core and against the dense
+    softmax, on the resident and the streaming schedule of the
     forward, through the one backward kernel."""
     t, block = KERNEL_SHAPES[shape]
     monkeypatch.setattr(pallas_ops, '_BWD_BLOCK', block)
@@ -184,23 +196,25 @@ def test_kernel_core_takes_a_value_width_of_its_own(
     weight = rand(4, t, heads, dv)
     scale = 1.0 / np.sqrt(dk)
 
-    def core(fn):
-        return lambda q, k, v: fn(q[None, :, :, None, :], k[None], v[None],
-                                  scale, block_q=block)[0, :, :, 0]
+    def kernel(q, k, v):
+        return lm.causal_attention(q[None, :, :, None, :], k[None],
+                                   v[None], scale, block_q=block)[0, :, :, 0]
 
-    kernel, blocked = core(lm.causal_attention), \
-        core(lm.blocked_causal_attention)
+    def dense(q, k, v):
+        return dense_core(q[None, :, :, None, :], k[None], v[None],
+                          scale)[0, :, :, 0]
+
     _core_against_the_reference(convnet.Net({}), kernel, q, k, v, weight,
                                 nope)
     stats = attention_paths()
     assert stats['blocked'] == 0 and stats['kernel'] > 0
     assert {(s['heads'], s['group'], s['dk'], s['dv'], s['t'])
             for s in stats['shapes']} == {(heads, 1, dk, dv, t)}
-    close(kernel(q, k, v), blocked(q, k, v), 1e-5)
+    close(kernel(q, k, v), dense(q, k, v), 1e-5)
     for wrt in range(3):
         got, want = (jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
                               argnums=wrt)(q, k, v)
-                     for fn in (kernel, blocked))
+                     for fn in (kernel, dense))
         close(got, want, 1e-4)
 
 
@@ -209,24 +223,41 @@ def test_kernel_core_takes_a_value_width_of_its_own(
     (33, 8, 'a ragged T, grouped heads')])
 def test_what_the_kernel_refuses_takes_the_blocked_core(attention_paths, t,
                                                         group, why):
-    """The path is chosen from the operands' shapes: a T that no block
-    of 8 rows divides is all the kernels refuse, whatever the heads,
-    and the counter says which shape decided."""
+    """What the kernels refuse, a T that no block of 8 rows divides
+    whatever the heads, is padded with zero rows to the next multiple
+    of 8 and run on them: bit for bit the kernels on the padded
+    operands, sliced back; values and all three gradients those of the
+    dense softmax; the counter says which shape decided and counts the
+    positions the padded kernels' tiles score."""
     q, k, v = rand(1, 1, t, 2, group, 12), rand(2, 1, t, 2, 12), \
         rand(3, 1, t, 2, 6)
     got = lm.causal_attention(q, k, v, 0.3, block_q=16)
-    close(got, lm.blocked_causal_attention(q, k, v, 0.3, block_q=16), 0)
     stats = attention_paths()
-    assert (stats['kernel'], stats['blocked']) == (0, 1)
+    assert (stats['kernel'], stats['blocked']) == (1, 0)
     (shape,) = stats['shapes']
     assert {k: shape[k] for k in ('path', 'heads', 'group', 'dk', 'dv', 't',
                                   'window', 'lowerings')} == dict(
-        path='blocked', heads=2 * group, group=group, dk=12, dv=6, t=t,
+        path='kernel', heads=2 * group, group=group, dk=12, dv=6, t=t,
         window=None, lowerings=1)
-    # blocks of 16 rows against the keys up to their last row
+    # tiles of 8 (16 does not divide the padded T) on and under the
+    # diagonal; the keys up to each row
+    tiles = -(-t // 8)
     assert shape['keys_needed'] == 2 * group * t * (t + 1) // 2
-    assert shape['keys_visited'] == 2 * group * sum(
-        min(16, t - r0) * min(r0 + 16, t) for r0 in range(0, t, 16))
+    assert shape['keys_visited'] == \
+        2 * group * 64 * tiles * (tiles + 1) // 2
+    pad = [(0, 0), (0, tiles * 8 - t)]
+    close(got, lm.causal_attention(
+        *(jnp.pad(x, pad + [(0, 0)] * (x.ndim - 2)) for x in (q, k, v)),
+        0.3, block_q=16)[:, :t], 0)
+    weight = rand(4, 1, t, 2, group, 6)
+    close(got, dense_core(q, k, v, 0.3))
+    for wrt in range(3):
+        grad, want = (jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
+                               argnums=wrt)(q, k, v)
+                      for fn in (lambda *a: lm.causal_attention(
+                          *a, 0.3, block_q=16),
+                          lambda *a: dense_core(*a, 0.3)))
+        close(grad, want, 1e-4)
 
 
 def test_adjacent_pair_rotary_gives_the_published_scores():

@@ -568,6 +568,29 @@ def test_async_save_overlaps_training(tmp_path, monkeypatch):
     mgr.close()
 
 
+@pytest.mark.parametrize('async_', [True, False])
+def test_cadence_saves_leave_training_bitwise_unchanged(tmp_path, async_):
+    """Checkpoints at a cadence, the snapshot taken on the train thread
+    and written behind it or in line, do not perturb training: the
+    weights are those of the same steps run without a manager, bit for
+    bit."""
+    batches = _batches(8)
+    plain = _make_module()
+    _train(plain, batches)
+    mod = _make_module()
+    mgr = elastic.CheckpointManager(str(tmp_path), every_n_steps=2,
+                                    keep=2, async_=async_)
+    mgr.attach(mod)
+    for b in batches:
+        mod.forward_backward(b)
+        mod.update()
+        mgr.step_end()
+    assert mgr.wait(10)
+    mgr.close()
+    assert elastic.list_checkpoints(str(tmp_path))
+    _assert_params_equal(plain, mod)
+
+
 def test_write_failure_keeps_training_alive(tmp_path, monkeypatch):
     profiler.clear()
     mod = _make_module()

@@ -14,9 +14,8 @@ profiler family.
 The precise fault shapes (connection refused vs connection dropped
 after delivery) run against in-process raw-socket stubs and in-process
 ReplicaServers — these are the fast tier-1 behavior-keepers for the
-end-to-end subprocess drill (test_supervisor_sigkill_respawn_e2e here,
-plus the BENCH_FLEET --supervisor arm), which spawns real replica
-processes and SIGKILLs one mid-load.
+end-to-end subprocess drill (test_supervisor_sigkill_respawn_e2e here),
+which spawns real replica processes and SIGKILLs one mid-load.
 """
 import json
 import signal
@@ -572,6 +571,46 @@ def test_scale_policy_hysteresis():
     deep = {'p99_over_deadline': False, 'backlog_rows': 100,
             'requests_delta': 1}
     assert [p.decide(deep) for _ in range(3)] == [0, 0, 1]
+
+
+def test_autoscale_follows_a_diurnal_load_without_flapping(monkeypatch,
+                                                         tmp_path):
+    """A night, a peak and a night through the scale loop, without
+    processes: the peak's hot windows add a replica every `up_after`
+    up to three, the idle night retires them one every `down_after`
+    down to the floor, and nothing else moves.  The router's latency
+    window still holds the peak's p99 through both nights: with no
+    request in a window it reads idle, not hot (read as hot it kept
+    the fleet at its peak size all night)."""
+    sup = FleetSupervisor(
+        models=[{'name': 'm', 'prefix': str(tmp_path / 'nope'),
+                 'input_shapes': {'data': [1, DIM]}, 'deadline_ms': 50}],
+        replicas=1, max_replicas=3,
+        scale_policy=ScalePolicy(up_after=2, down_after=3))
+    try:
+        live, requests = [1], [0]
+        monkeypatch.setattr(sup.router, 'requests_delta',
+                            lambda: requests[0])
+        monkeypatch.setattr(sup.router, 'latency_p99_ms',
+                            lambda name: 500.0)
+        monkeypatch.setattr(sup, 'replicas', lambda: [])
+        monkeypatch.setattr(sup, 'live_replicas', lambda: live[0])
+        monkeypatch.setattr(sup, 'spawn_replica',
+                            lambda: live.__setitem__(0, live[0] + 1))
+        monkeypatch.setattr(sup, 'retire_replica',
+                            lambda: live.__setitem__(0, live[0] - 1))
+        timeline = []
+        for n, windows in ((0, 2), (9, 5), (0, 8)):
+            requests[0] = n
+            for _ in range(windows):
+                sup._scale_once()
+                timeline.append(live[0])
+        assert timeline == [1, 1, 1, 2, 2, 3, 3, 3, 3, 2, 2, 2, 1, 1, 1]
+        assert sup._scale_obs() == {'p99_over_deadline': False,
+                                    'backlog_rows': 0,
+                                    'requests_delta': 0}
+    finally:
+        sup.router.close()
 
 
 # ---------------------------------------------------------------------------
